@@ -29,7 +29,9 @@ def time_once(fn):
 
 def main(argv=None):
     p = argparse.ArgumentParser()
-    p.add_argument("--device", default="auto")
+    p.add_argument("--device", default="tpu",
+                   help="strict: that platform or an error (a rate "
+                        "from a host is not a chip number)")
     p.add_argument("--n-new", type=int, default=96)
     args = p.parse_args(argv)
 

@@ -1,9 +1,9 @@
-"""Per-device kernel block DB (ops/autotune.py) — measure → persist →
-reuse, proven on CPU with a fake device_kind and an injected measure
-function (the reference proved its GEMM equivalent against real GPUs
-and shipped the result, veles/backends.py:623-731 +
-devices/device_infos.json; the capability under test is the same:
-first use sweeps, every later use is a lookup)."""
+"""Per-device kernel block DB (ops/autotune.py) — measure → commit →
+reuse, proven on CPU with a fake device_kind and a DB file in tmp (the
+reference proved its GEMM equivalent against real GPUs and shipped the
+result, veles/backends.py:623-731 + devices/device_infos.json; the
+capability under test is the same: an explicit measurement records,
+every model-path use is a read-only lookup)."""
 import json
 import os
 
@@ -15,12 +15,10 @@ from veles_tpu.ops import autotune
 
 @pytest.fixture()
 def tuned_env(tmp_path, monkeypatch):
-    """Redirect the user DB into tmp, neutralize the shipped DB, clear
-    the memo, and pin a fake device_kind."""
-    monkeypatch.setattr(root.common.dirs, "cache", str(tmp_path),
-                        raising=False)
+    """Redirect the DB into tmp, clear the memo, and pin a fake
+    device_kind."""
     monkeypatch.setattr(autotune, "SHIPPED",
-                        str(tmp_path / "shipped.json"))
+                        str(tmp_path / "kernel_tuning.json"))
     monkeypatch.setattr(autotune, "current_device_kind",
                         lambda: "faketpu-v0")
     autotune.clear_memo()
@@ -28,83 +26,50 @@ def tuned_env(tmp_path, monkeypatch):
     autotune.clear_memo()
 
 
-def test_sweep_persists_and_reuses(tuned_env):
-    calls = []
-
-    def fake_measure(t, d, causal, blocks):
-        calls.append(blocks)
-        # (256, 128) is the planted winner
-        return 1.0 if blocks != (256, 128) else 0.25
-
-    best = autotune.sweep_flash(2048, 64, True, measure=fake_measure,
-                                check_bwd=lambda *a: True)
-    assert best == (256, 128)
-    assert len(calls) == len(autotune.candidates_for(2048, 64))
-
-    db_path = os.path.join(str(tuned_env), "kernel_tuning.json")
-    db = json.load(open(db_path))
+def test_record_persists_and_reuses(tuned_env):
+    autotune.record(autotune.flash_key(2048, 64, True),
+                    {"block_q": 256, "block_k": 128, "ms": 0.25})
+    db = json.load(open(autotune.SHIPPED))
     entry = db["faketpu-v0"]["flash_t2048_d64_causal"]
     assert (entry["block_q"], entry["block_k"]) == (256, 128)
-    assert "ts" in entry and "sweep_ms" in entry
-
-    # reuse: the lookup path returns the persisted winner without any
-    # measuring (flash_blocks never calls a measure fn on a hit)
+    assert "ts" in entry
     assert autotune.flash_blocks(2048, 64, causal=True) == (256, 128)
     # ... even in a "fresh process" (memo cleared → file read)
     autotune.clear_memo()
     assert autotune.flash_blocks(2048, 64, causal=True) == (256, 128)
 
 
-def test_sweep_rejects_backward_incompatible_winner(tuned_env):
-    """The fastest forward whose backward does NOT lower must yield to
-    the next candidate (the bwd working set is larger than the fwd's)."""
-    def fake_measure(t, d, causal, blocks):
-        return 0.25 if blocks == (512, 512) else \
-            (0.5 if blocks == (256, 128) else 1.0)
-
-    best = autotune.sweep_flash(
-        2048, 64, True, measure=fake_measure,
-        check_bwd=lambda t, d, c, blocks: blocks != (512, 512))
-    assert best == (256, 128)
-    entry = autotune.lookup(autotune.flash_key(2048, 64, True))
-    assert entry["sweep_ms"]["512x512"] == "bwd_compile_failed"
-
-
-def test_default_blocks_skip_bwd_check(tuned_env):
-    """(128, 128) is the known-safe production default — the sweep
-    must not spend a backward compile validating it."""
-    def fake_measure(t, d, causal, blocks):
-        return 0.1 if blocks == autotune.DEFAULT_BLOCKS else 1.0
-
-    def boom(*a):
-        raise AssertionError("bwd check ran for the default blocks")
-
-    assert autotune.sweep_flash(2048, 64, True, measure=fake_measure,
-                                check_bwd=boom) == (128, 128)
-
-
-def test_multihost_reads_shipped_only(tuned_env, monkeypatch):
-    """Multi-host processes must trace identical blocks: only the
-    committed shipped layer is consulted, never the per-host user DB,
-    and no sweep fires."""
+def test_miss_returns_defaults_and_never_measures(tuned_env, monkeypatch):
+    """A trace never sweeps: a miss on a (pretended) TPU backend
+    resolves to the defaults without compiling or timing anything, and
+    writes nothing — so one commit compiles the same kernels on every
+    machine."""
     import jax
-    monkeypatch.setattr(jax, "process_count", lambda: 2)
-    # user layer has a winner — must be IGNORED under multihost
-    autotune.record(autotune.flash_key(2048, 64, True),
-                    {"block_q": 512, "block_k": 512, "ms": 0.1})
-    autotune.clear_memo()
-    assert autotune.flash_blocks(2048, 64) == autotune.DEFAULT_BLOCKS
-    autotune.clear_memo()
-    shipped = {"faketpu-v0": {"flash_t2048_d64_causal":
-                              {"block_q": 256, "block_k": 128}}}
-    with open(autotune.SHIPPED, "w") as f:
-        json.dump(shipped, f)
-    assert autotune.flash_blocks(2048, 64) == (256, 128)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
+    def boom(*a, **k):
+        raise AssertionError("a model-path lookup compiled a kernel")
 
-def test_miss_off_tpu_returns_defaults(tuned_env):
-    # CPU backend, "auto" mode: no entry → defaults, no sweep attempt
+    monkeypatch.setattr(autotune, "_bwd_compiles", boom)
     assert autotune.flash_blocks(4096, 64) == autotune.DEFAULT_BLOCKS
+    assert not os.path.exists(autotune.SHIPPED)
+
+
+def test_reads_nothing_outside_the_checkout(monkeypatch):
+    """The model path resolves blocks from the committed DB only: no
+    user layer under root.common.dirs.cache / $HOME."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert autotune.SHIPPED == os.path.join(
+        repo, "veles_tpu", "devices", "kernel_tuning.json")
+    opened = []
+    real_read = autotune._read
+    monkeypatch.setattr(autotune, "_read",
+                        lambda path: opened.append(path) or real_read(path))
+    autotune.clear_memo()
+    autotune.flash_blocks(2048, 64, device_kind="TPU v5 lite")
+    autotune.flash_min_t(64, device_kind="TPU v5 lite")
+    autotune.clear_memo()
+    assert opened and set(opened) == {autotune.SHIPPED}
 
 
 def test_nearest_length_fallback(tuned_env):
@@ -133,50 +98,11 @@ def test_nearest_length_fallback_respects_divisibility(tuned_env):
     assert autotune.flash_blocks(1280, 64) == autotune.DEFAULT_BLOCKS
 
 
-def test_nearest_length_fallback_multihost_shipped_only(tuned_env,
-                                                        monkeypatch):
-    """Multi-host nearest-length fallback reads ONLY the shipped layer
-    (host-identical), never the per-host user DB."""
-    import jax
-    monkeypatch.setattr(jax, "process_count", lambda: 2)
-    # user layer nearest entry must be IGNORED under multihost
-    autotune.record(autotune.flash_key(2048, 64, True),
-                    {"block_q": 512, "block_k": 512, "ms": 0.1})
-    autotune.clear_memo()
-    assert autotune.flash_blocks(4096, 64) == autotune.DEFAULT_BLOCKS
-    autotune.clear_memo()
-    shipped = {"faketpu-v0": {"flash_t8192_d64_causal":
-                              {"block_q": 256, "block_k": 256}}}
-    with open(autotune.SHIPPED, "w") as f:
-        json.dump(shipped, f)
-    assert autotune.flash_blocks(4096, 64) == (256, 256)
-
-
 def test_windowed_reuses_causal_entry(tuned_env):
     autotune.record(autotune.flash_key(2048, 64, True),
                     {"block_q": 512, "block_k": 128, "ms": 0.5})
     assert autotune.flash_blocks(2048, 64, causal=True,
                                  window=256) == (512, 128)
-
-
-def test_user_layer_overrides_shipped(tuned_env):
-    shipped = {"faketpu-v0": {"flash_t1024_d64_causal":
-                              {"block_q": 128, "block_k": 128}}}
-    with open(autotune.SHIPPED, "w") as f:
-        json.dump(shipped, f)
-    assert autotune.flash_blocks(1024, 64) == (128, 128)
-    autotune.clear_memo()
-    autotune.record(autotune.flash_key(1024, 64, True),
-                    {"block_q": 256, "block_k": 256, "ms": 0.1})
-    assert autotune.flash_blocks(1024, 64) == (256, 256)
-
-
-def test_disabled_mode(tuned_env, monkeypatch):
-    monkeypatch.setattr(root.common.engine, "kernel_autotune", False,
-                        raising=False)
-    autotune.record(autotune.flash_key(2048, 64, True),
-                    {"block_q": 512, "block_k": 512, "ms": 0.1})
-    assert autotune.flash_blocks(2048, 64) == autotune.DEFAULT_BLOCKS
 
 
 def test_flash_attention_resolves_db_blocks(tuned_env, monkeypatch):
@@ -203,7 +129,7 @@ def test_flash_attention_resolves_db_blocks(tuned_env, monkeypatch):
     rng = numpy.random.RandomState(0)
     q, k, v = (jnp.asarray(rng.randn(1, 256, 2, 64), jnp.float32)
                for _ in range(3))
-    o = flash_attention(q, k, v, causal=True)
+    o = flash_attention(q, k, v, causal=True, interpret=True)
     assert seen["blocks"] == (256, 128)
     ref = attention_reference(q, k, v, causal=True)
     assert float(jnp.max(jnp.abs(o - ref))) < 2e-3
@@ -245,10 +171,6 @@ def _load_chip_experiments():
     return ce
 
 
-class _TpuDev:
-    platform = "tpu"
-
-
 def test_attn_seed_derives_blocks_and_min_t(tuned_env):
     """The chip attn sweep's seeding: block winners per T (train mode
     preferred) AND the measured flash-vs-fused crossover land in the
@@ -264,28 +186,12 @@ def test_attn_seed_derives_blocks_and_min_t(tuned_env):
             "fused_xla": {"ms": 10.0}, "flash_512x512": {"ms": 7.0}}},
     ]
 
-    ce._attn_seed(results, _TpuDev())
+    ce._attn_seed(results)
     assert autotune.flash_blocks(2048, 64) == (256, 128)
     assert autotune.flash_blocks(8192, 64) == (512, 512)
     assert autotune.flash_min_t(64) == 8192
     entry = autotune.lookup(autotune.min_t_key(64))
     assert entry["swept"] == {"2048": False, "8192": True}
-
-
-def test_flash_min_t_multihost_reads_shipped_only(tuned_env,
-                                                  monkeypatch):
-    """Same invariant as block lookup: under multi-host every process
-    must resolve the same gate, so per-host user caches are ignored."""
-    import jax
-    autotune.record(autotune.min_t_key(64), {"min_t": 1024})  # user
-    monkeypatch.setattr(jax, "process_count", lambda: 2)
-    autotune.clear_memo()
-    assert autotune.flash_min_t(64) == 4096      # shipped empty
-    shipped = {"faketpu-v0": {"flash_min_t_d64": {"min_t": 2048}}}
-    with open(autotune.SHIPPED, "w") as f:
-        json.dump(shipped, f)
-    autotune.clear_memo()
-    assert autotune.flash_min_t(64) == 2048
 
 
 def test_attn_seed_min_t_respects_losses_above_wins(tuned_env):
@@ -300,7 +206,7 @@ def test_attn_seed_min_t_respects_losses_above_wins(tuned_env):
             "fused_xla": {"ms": 5.0}, "flash_128x128": {"ms": 9.0}}},
     ]
 
-    ce._attn_seed(results, _TpuDev())
+    ce._attn_seed(results)
     assert autotune.flash_min_t(64) == autotune.NEVER
 
 
@@ -313,10 +219,10 @@ def test_attn_seed_split_sections_merge_crossover(tuned_env):
         "fused_xla": {"ms": 1.0}, "flash_128x128": {"ms": 2.0}}}]
     r8192_win = [{"t": 8192, "b": 1, "train": True, "variants": {
         "fused_xla": {"ms": 10.0}, "flash_512x512": {"ms": 7.0}}}]
-    ce._attn_seed(r2048_loss, _TpuDev())
+    ce._attn_seed(r2048_loss)
     assert autotune.flash_min_t(64) == autotune.NEVER
     autotune.clear_memo()
-    ce._attn_seed(r8192_win, _TpuDev())
+    ce._attn_seed(r8192_win)
     # merged view: loss@2048 + win@8192 -> gate opens at 8192
     assert autotune.flash_min_t(64) == 8192
     entry = autotune.lookup(autotune.min_t_key(64))
@@ -349,8 +255,7 @@ def test_stale_entry_counts_every_lookup_warns_once(tuned_env, caplog):
     re-sweep is due, without a log storm per trace."""
     import logging
     from veles_tpu.telemetry.counters import counters
-    db_path = os.path.join(str(tuned_env), "kernel_tuning.json")
-    with open(db_path, "w") as fout:
+    with open(autotune.SHIPPED, "w") as fout:
         json.dump({"faketpu-v0": {
             "flash_t2048_d64_causal":            # pre-stamp format
                 {"block_q": 512, "block_k": 128},

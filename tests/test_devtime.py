@@ -286,7 +286,7 @@ def test_compare_sections_legacy_wallclock_fallback():
                                     cur_rate=50.0) == []
     delta = counters.delta(before)
     assert delta.get("veles_bench_legacy_sections_total") == 1
-    # total collapse beyond even relay weather still fails
+    # total collapse beyond any measured wall-clock swing still fails
     bad = devtime.compare_sections("mnist", None, _sec(),
                                    base_rate=100.0, cur_rate=1.0)
     assert any("collapsed" in f for f in bad)
@@ -410,7 +410,7 @@ def test_epilogue_composes_with_tensormon_no_silent_fallback():
 def test_fused_fc_reject_message_mentions_epilogue_path():
     """Satellite lock: the fused_fc_scan tensormon rejection names the
     epilogue path as what the general scan keeps."""
-    root.common.engine.fused_fc_scan = True
+    root.common.engine.fused_fc_scan = "force"
     root.common.telemetry.tensormon.enabled = True
     msgs = []
     prng.seed_all(99)

@@ -183,22 +183,6 @@ def _run_sp(mesh_axes, epochs=4):
     return res
 
 
-# jax 0.4.37 limitation: ring attention's custom_vjp + scan inside
-# shard_map, nested in the jitted train step, lowers to a PartitionId
-# instruction XLA's SPMD partitioner rejects ("PartitionId instruction
-# is not supported for SPMD partitioning"). check_rep True/False makes
-# no difference and minimal shard_map+axis_index repros work, so it is
-# the composition itself — unfixable without a jax upgrade. Failed at
-# seed too (then as a shard_map ImportError); xfail keeps tier-1
-# output clean of a known-unfixable failure while strict=False lets a
-# future jax bump surface the fix as an XPASS.
-_SP_XFAIL = pytest.mark.xfail(
-    strict=False,
-    reason="jax 0.4.37: custom_vjp+scan in shard_map nested in jit "
-           "lowers to PartitionId, unsupported by SPMD partitioning")
-
-
-@_SP_XFAIL
 def test_sp_4dev_matches_1dev_trajectory():
     """Sequence-parallel equivalence — the SP analogue of the DP proof:
     ring attention over a {'sequence': 4} mesh is EXACT (K/V rotate via
@@ -215,7 +199,6 @@ def test_sp_4dev_matches_1dev_trajectory():
                                   atol=5e-4)
 
 
-@_SP_XFAIL
 def test_sp_composes_with_dp():
     """dp x sp: batch over 'data' AND sequence over 'sequence' in one
     mesh — the composed run still matches the single-device

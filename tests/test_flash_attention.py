@@ -1,7 +1,11 @@
 """Pallas flash-attention kernel vs the exact reference attention
 (forward + custom-VJP backward), and its wiring into MultiHeadAttention.
-Runs in pallas interpret mode on the CPU test harness; the same kernel
-compiles via Mosaic on TPU (verified in bench/verify drives)."""
+Runs in pallas interpret mode on the CPU test harness (asked for
+explicitly: the kernel's own default is to compile); the same kernel
+compiles via Mosaic on TPU (chip_smoke.py, scripts/chip_experiments.py
+--sections pallas_compile)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy
@@ -10,8 +14,11 @@ import pytest
 import veles_tpu as vt
 from veles_tpu import nn
 from veles_tpu.memory import Array
-from veles_tpu.ops.flash_attention import flash_attention, supported
+from veles_tpu.ops import flash_attention as fa
+from veles_tpu.ops.flash_attention import supported
 from veles_tpu.parallel.ring_attention import attention_reference
+
+flash_attention = functools.partial(fa.flash_attention, interpret=True)
 
 
 def qkv(b=2, t=256, h=2, d=64, seed=0):
@@ -270,3 +277,53 @@ def test_mismatched_kv_heads_refused():
         flash_attention(q2, jnp.zeros((1, 256, 2, 64), jnp.float32),
                         jnp.zeros((1, 256, 2, 64), jnp.float32),
                         causal=True)
+
+
+def test_flash_runs_in_a_shard_map_on_a_multi_device_mesh(monkeypatch):
+    """GSPMD cannot partition a Mosaic custom call, so under a mesh the
+    model path wraps the kernel in a shard_map — batch over 'data',
+    heads over 'tensor' — and still matches the reference, forward and
+    backward. (The refusal itself only shows on real chips: interpret
+    mode lowers to plain HLO.)"""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from veles_tpu.nn.attention import attention_core
+    mesh = Mesh(numpy.asarray(jax.devices()[:4]).reshape(2, 2),
+                ("data", "tensor"))
+    q, k, v = (jax.device_put(x, NamedSharding(mesh, P("data")))
+               for x in qkv(b=2, t=128, h=4, d=64))
+    specs = []
+    real = jax.shard_map
+
+    def spy(fn, **kw):
+        specs.append(kw["in_specs"][0])
+        return real(fn, **kw)
+
+    monkeypatch.setattr(jax, "shard_map", spy)
+    monkeypatch.setattr(vt.root.common.engine, "flash_attention", "force",
+                        raising=False)
+
+    def loss(core):
+        return lambda q, k, v: (core(q, k, v) ** 2).sum()
+
+    def sharded(q, k, v):
+        return attention_core(q, k, v, causal=True, mesh=mesh, n_heads=4)
+
+    def ref(q, k, v):
+        return attention_reference(q, k, v, causal=True)
+
+    o = jax.jit(sharded)(q, k, v)
+    assert specs == [P("data", None, "tensor", None)]
+    numpy.testing.assert_allclose(numpy.asarray(o),
+                                  numpy.asarray(ref(q, k, v)),
+                                  rtol=1e-4, atol=1e-5)
+    g = jax.jit(jax.grad(loss(sharded), argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g, g_ref):
+        numpy.testing.assert_allclose(numpy.asarray(a), numpy.asarray(b),
+                                      rtol=2e-3, atol=2e-4)
+    # a pipeline stage already runs inside the schedule's shard_map
+    pp = Mesh(numpy.asarray(jax.devices()[:2]), ("pipeline",))
+    del specs[:]
+    attention_core(*qkv(b=2, t=128, h=4, d=64), causal=True, mesh=pp,
+                   n_heads=4)
+    assert specs == []

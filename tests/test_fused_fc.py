@@ -44,7 +44,7 @@ def test_kernel_matches_oracle():
     for dims, kw in cases:
         ws, bs, zw, zb = _rand_net(rng, dims)
         out_k = fused_fc_sgd_epoch(ws, bs, zw, zb, ds, lb, plan, 0.05,
-                                   **kw)
+                                   interpret=True, **kw)
         out_o = fused_fc_oracle(ws, bs, zw, zb, ds, lb, plan, 0.05,
                                 **kw)
         for name, kk, oo in zip(("w", "b", "vw", "vb"), out_k[:4],
@@ -61,7 +61,8 @@ def test_kernel_matches_oracle():
         # a SECOND epoch continues from the returned state (the delta
         # recurrence survives the kernel boundary)
         k2 = fused_fc_sgd_epoch(out_k[0], out_k[1], out_k[2], out_k[3],
-                                ds, lb, plan, 0.05, **kw)
+                                ds, lb, plan, 0.05, interpret=True,
+                                **kw)
         o2 = fused_fc_oracle(out_o[0], out_o[1], out_o[2], out_o[3],
                              ds, lb, plan, 0.05, **kw)
         numpy.testing.assert_allclose(
@@ -87,7 +88,7 @@ class Blobs(FullBatchLoader):
 
 def _run(fused, epochs=4, solver="sgd", mb=20, **layer_extra):
     prev = root.common.engine.get("fused_fc_scan", False)
-    root.common.engine.fused_fc_scan = fused
+    root.common.engine.fused_fc_scan = "force" if fused else False
     try:
         prng.seed_all(777)
         wf = nn.StandardWorkflow(
@@ -161,7 +162,7 @@ def test_workflow_trajectory_parity_momentum_decay():
 def test_workflow_three_layer_chain():
     """Depth generality: tanh→tanh→softmax engages and learns."""
     prev = root.common.engine.get("fused_fc_scan", False)
-    root.common.engine.fused_fc_scan = True
+    root.common.engine.fused_fc_scan = "force"
     try:
         prng.seed_all(5)
         wf = nn.StandardWorkflow(
@@ -205,7 +206,7 @@ def test_eligibility_rejects_partial_batches():
 def test_eligibility_rejects_freeze_base():
     """Frozen layers must not be updated by the unconditional kernel."""
     prev = root.common.engine.get("fused_fc_scan", False)
-    root.common.engine.fused_fc_scan = True
+    root.common.engine.fused_fc_scan = "force"
     try:
         prng.seed_all(3)
         wf = nn.StandardWorkflow(
@@ -228,7 +229,7 @@ def test_eligibility_rejects_vmem_oversized_chain():
     """A chain whose VMEM-resident state would blow the kernel budget
     must fall back to the general path instead of dying in Mosaic."""
     prev = root.common.engine.get("fused_fc_scan", False)
-    root.common.engine.fused_fc_scan = True
+    root.common.engine.fused_fc_scan = "force"
     try:
         prng.seed_all(2)
         wf = nn.StandardWorkflow(
@@ -256,7 +257,7 @@ def test_eligibility_rejects_per_layer_act_scales():
     the kernel bakes ONE scaling for the whole chain (ADVICE r4)."""
     from veles_tpu.nn.all2all import All2AllTanh
     prev = root.common.engine.get("fused_fc_scan", False)
-    root.common.engine.fused_fc_scan = True
+    root.common.engine.fused_fc_scan = "force"
     try:
         prng.seed_all(7)
         wf = nn.StandardWorkflow(

@@ -329,22 +329,29 @@ def test_gate_linalg_doc_arithmetic(monkeypatch):
 # -- dtype-correct peak table ------------------------------------------------
 
 def test_peak_flops_f32_is_half_bf16():
-    from veles_tpu.telemetry.cost import (DEFAULT_PEAK,
-                                          DEFAULT_PEAK_F32, PEAK_BF16,
-                                          PEAK_F32, peak_flops_entry)
-    assert DEFAULT_PEAK_F32 == DEFAULT_PEAK / 2
+    from veles_tpu.telemetry.cost import (PEAK_BF16, PEAK_F32,
+                                          UnknownDevice,
+                                          peak_flops_entry)
     bf16 = dict(PEAK_BF16)
     for kind, peak in PEAK_F32:
         assert peak == bf16[kind] / 2, kind
-    src32, p32 = peak_flops_entry("float32")
-    srcbf, pbf = peak_flops_entry("bfloat16")
+    v5e = "TPU v5 lite"
+    src32, p32 = peak_flops_entry("float32", device_kind=v5e)
+    srcbf, pbf = peak_flops_entry("bfloat16", device_kind=v5e)
     assert "PEAK_F32" in src32 and "F32" not in srcbf
-    assert p32 == pbf / 2
+    assert pbf == 197e12 and p32 == pbf / 2
     # device-kind substring match routes to the named entry
     src, p = peak_flops_entry(numpy.float32, device_kind="TPU v4")
     assert src == "telemetry.cost.PEAK_F32[v4]" and p == 137.5e12
     # f64 has no separate table: graded against the f32 ceiling
-    assert peak_flops_entry("float64")[1] == p32
+    assert peak_flops_entry("float64", device_kind=v5e)[1] == p32
+    # a device with no row is an error, not some other chip's peak —
+    # including the CPU these tests run on
+    for dtype in ("float32", "bfloat16"):
+        with pytest.raises(UnknownDevice, match="mystery"):
+            peak_flops_entry(dtype, device_kind="mystery")
+        with pytest.raises(UnknownDevice):
+            peak_flops_entry(dtype)
 
 
 def test_predict_summa_time_states_every_input():

@@ -277,11 +277,21 @@ def test_ring_window_requires_causal():
         ring_attention(q, q, q, seq_mesh(4), causal=False, window=4)
 
 
-def test_ring_attention_flash_engine_matches_reference():
+@pytest.fixture()
+def flash_forced():
+    """engine.flash_attention="force": the kernel interprets off-TPU
+    (its own default is to compile, which the CPU backend refuses)."""
+    prev = vt.root.common.engine.flash_attention
+    vt.root.common.engine.flash_attention = "force"
+    yield
+    vt.root.common.engine.flash_attention = prev
+
+
+def test_ring_attention_flash_engine_matches_reference(flash_forced):
     """Flash-in-ring (Pallas inner engine, peeled diagonal + lse
     merge): forward must match the exact reference for causal AND full
-    attention. CPU runs the kernel in interpret mode (use_flash=True
-    overrides the TPU gate)."""
+    attention. CPU runs the kernel in interpret mode (flash_forced;
+    use_flash=True overrides the length gate)."""
     import jax.numpy as jnp
     rng = numpy.random.RandomState(11)
     b, t, h, d = 1, 256, 2, 8
@@ -298,7 +308,7 @@ def test_ring_attention_flash_engine_matches_reference():
             atol=2e-5)
 
 
-def test_ring_attention_flash_engine_gradients():
+def test_ring_attention_flash_engine_gradients(flash_forced):
     """The blockwise ring backward (global-lse recompute) under the
     flash forward: grads of a scalar loss wrt q, k, v match the
     autodiff of the exact reference."""

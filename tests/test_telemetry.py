@@ -1,7 +1,7 @@
 """Telemetry subsystem (veles_tpu/telemetry/): deterministic
 accounting — counters, spans, cost model, Chrome-trace export, and the
 counter-based perf gate. The regression locks here are the ones
-wall-clock gates cannot hold through relay weather: cached decode is
+wall-clock gates cannot hold through host noise: cached decode is
 ONE dispatch per lax.scan (the round-5 speculative finding was a
 dispatch-count story), and an injected extra dispatch fails the gate
 deterministically."""
@@ -168,7 +168,9 @@ def test_cost_arithmetic_and_peak_lookup():
     assert a.arithmetic_intensity == 2.0
     assert peak_bf16_flops("TPU v5 lite") == 197e12
     assert peak_bf16_flops("TPU v5p") == 459e12
-    assert peak_bf16_flops("weird") == 275e12
+    from veles_tpu.telemetry.cost import UnknownDevice
+    with pytest.raises(UnknownDevice):     # never another chip's peak
+        peak_bf16_flops("weird")
 
 
 def test_pallas_analytic_fallbacks():

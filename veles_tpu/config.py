@@ -185,19 +185,6 @@ def _default_root() -> Config:
             # "ring" (K/V rotation, memory-flat in T) or "ulysses"
             # (all-to-all head re-sharding; needs heads % n_seq == 0)
             "sequence_parallel": "ring",
-            # persistent XLA compilation cache (replaces the reference's
-            # kernel-binary tarball cache, veles/accelerated_units.py:
-            # 605-673): compiled programs survive process restarts, so
-            # resume/relaunch skips the 20-40 s first-compile. "" = off.
-            "compilation_cache": os.path.expanduser(
-                "~/.veles_tpu/cache/xla"),
-            # per-device Pallas block-shape DB (ops/autotune.py — the
-            # build's port of the reference's measured-per-device GEMM
-            # block sizes, veles/backends.py:623-731). "auto" = reuse
-            # persisted winners, sweep-and-persist on first use of an
-            # unseen (device_kind, shape) on a real TPU; "reuse" =
-            # lookup only; False = hard-coded defaults
-            "kernel_autotune": "auto",
         },
         "mesh": {
             # logical mesh axes reserved up front (SURVEY.md §5.7/§5.8):

@@ -64,13 +64,6 @@ class Launcher(Logger):
         """Distributed init + device/mesh resolution; shared by the normal
         path and the meta-learning modes (--optimize/--ensemble-*)."""
         from .error import VelesError
-        from .backends import guard_unresponsive_backend
-        # a dead accelerator transport (e.g. a collapsed TPU tunnel
-        # relay) makes in-process device enumeration HANG, not raise —
-        # probe in a killable subprocess before the first backend init
-        # so a training launch degrades to CPU with a warning instead
-        # of freezing (failure-detection story, SURVEY.md §5.3)
-        guard_unresponsive_backend()
         coordinator, nproc, pid = self._dist
         distributed.initialize_multihost(coordinator, nproc, pid)
         if self._mesh:

@@ -7,9 +7,7 @@ trainer). The kernels follow the TPU linear-algebra literature
 Processing Units", "JAXMg"): dense matrices are tiled into blocks, the
 block grid is laid out block-cyclically over a 2D ("rows", "cols")
 device mesh, and every distributed operation decomposes into *local
-block dots plus psums* expressed with ``shard_map`` (through
-``parallel/compat.py``, the one shim every shard_map call site in this
-tree uses).
+block dots plus psums* expressed with ``jax.shard_map``.
 
 Three layers, each falsifiable against the layer below:
 
@@ -267,8 +265,6 @@ def _matmul_summa(a, b, mesh, cyclic: bool):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.compat import shard_map_compat
-
     jnp = _jnp()
     if len(mesh.devices.shape) != 2:
         raise LinalgError("linalg needs a 2D mesh, got shape %r"
@@ -293,9 +289,10 @@ def _matmul_summa(a, b, mesh, cyclic: bool):
         b_p = b_p[pk][:, pn]
     a_p = _dispatch_block(a_p, op="summa", grid=(int(pr), int(pc)))
     spec = P(ax_r, ax_c)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         _summa_local(ax_r, ax_c, int(pr), int(pc), G, kp // G),
-        mesh=mesh, in_specs=(spec, spec), out_specs=spec)
+        mesh=mesh, in_specs=(spec, spec), out_specs=spec,
+        check_vma=False)
     with mesh:
         c_p = jax.jit(fn)(a_p, b_p)
     if cyclic:

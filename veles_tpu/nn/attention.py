@@ -5,11 +5,14 @@ only, SURVEY.md §5.7); required for long-context parity goals. The unit is
 a standard ForwardBase: pure ``apply``, numpy oracle, matched GD unit.
 When the attached mesh has a 'sequence' axis larger than 1, the attention
 core routes through parallel.ring_attention (exact, sequence-sharded,
-K/V rotating over ICI); otherwise a single fused softmax(QK^T)V.
+K/V rotating over ICI); otherwise a single fused softmax(QK^T)V — the
+Pallas flash kernel past the measured crossover, inside a shard_map
+whenever the step is partitioned over more than one device.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional
 
 import numpy
@@ -41,6 +44,36 @@ def expand_kv(np_mod, x, n_heads: int):
         b, t, n_heads, hd)
 
 
+def device_mesh(device):
+    """What an attention unit keeps of its device: the mesh when it
+    spans more than one device, else None."""
+    mesh = getattr(device, "mesh", None)
+    return mesh if mesh is not None and mesh.devices.size > 1 else None
+
+
+def _flash_sharded(flash, q, k, v, mesh):
+    """The flash kernel under a multi-device jit. GSPMD cannot partition
+    a Mosaic custom call ("Mosaic kernels cannot be automatically
+    partitioned" — first seen on the four-chip host, never on the CPU,
+    where interpret mode lowers to plain HLO), so the kernel runs in a
+    shard_map: batch split over 'data' and heads over 'tensor' where
+    those axes exist and divide — the split GSPMD already gives the
+    surrounding matmuls — and replicated over any other axis. Attention
+    is independent per (batch, head): no collective is needed."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    sizes = dict(mesh.shape)
+
+    def axis(name, *dims):
+        n = sizes.get(name, 1)
+        return name if n > 1 and all(d % n == 0 for d in dims) else None
+
+    spec = P(axis("data", q.shape[0]), None,
+             axis("tensor", q.shape[2], k.shape[2]), None)
+    return jax.shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def attention_core(q, k, v, *, causal=False, mesh=None, n_heads=1,
                    window=None):
     """The per-shape attention chooser, shared by MultiHeadAttention and
@@ -54,16 +87,19 @@ def attention_core(q, k, v, *, causal=False, mesh=None, n_heads=1,
     operands or residuals); the other paths expand via broadcast. The
     ring path additionally SHORTENS the rotation scan to the blocks
     the window can reach; Ulysses passes the window to its inner
-    attention."""
+    attention. ``mesh``: the unit's multi-device mesh (``device_mesh``),
+    None on one device or when the caller already runs inside a
+    shard_map (the serving engine)."""
     from ..ops import flash_attention as fa
     from ..parallel.ring_attention import (ring_attention,
                                            attention_reference)
     t, hd = q.shape[1], q.shape[-1]
     h = q.shape[2]
-    if mesh is not None:
+    sizes = dict(mesh.shape) if mesh is not None else {}
+    if sizes.get("sequence", 1) > 1:
         k, v = expand_kv(None, k, h), expand_kv(None, v, h)
         scheme = root.common.engine.sequence_parallel
-        n_seq = mesh.shape["sequence"]
+        n_seq = sizes["sequence"]
         if scheme == "ulysses" and n_heads % n_seq == 0:
             from ..parallel.ulysses import ulysses_attention
             return ulysses_attention(q, k, v, mesh, causal=causal,
@@ -71,8 +107,13 @@ def attention_core(q, k, v, *, causal=False, mesh=None, n_heads=1,
         return ring_attention(q, k, v, mesh, causal=causal,
                               window=window)
     if fa.choose_flash(t, hd):
-        return fa.flash_attention(q, k, v, causal=causal,
+        flash = functools.partial(fa.flash_attention, causal=causal,
                                   window=window)
+        if mesh is None or sizes.get("pipeline", 1) > 1:
+            # one device — or a pipeline stage, which already runs
+            # inside the schedule's own shard_map (parallel/pipeline.py)
+            return flash(q, k, v)
+        return _flash_sharded(flash, q, k, v, mesh)
     return attention_reference(q, expand_kv(None, k, h),
                                expand_kv(None, v, h), causal=causal,
                                window=window)
@@ -124,10 +165,7 @@ class MultiHeadAttention(ForwardBase):
         res = super().initialize(device=device, **kwargs)
         if res:
             return res
-        mesh = getattr(device, "mesh", None)
-        if mesh is not None and "sequence" in mesh.axis_names \
-                and mesh.shape["sequence"] > 1:
-            self.mesh = mesh
+        self.mesh = device_mesh(device)
         return None
 
     def apply(self, params, x, *, train=False, rng=None):

@@ -287,12 +287,16 @@ class TrainStep(AcceleratedUnit):
         # means one bf16 MXU pass (Precision.DEFAULT), so the two paths
         # would not be trajectory-exact there. On CPU DEFAULT is full
         # f32 and parity holds. "force" opts out of the parity claim
-        # (bench A/Bs carry their own method tag instead)
+        # (bench A/Bs carry their own method tag instead) and of the
+        # backend gate: off-TPU it runs the kernel interpreted
         import jax
-        if flag != "force" and jax.default_backend() == "tpu" \
-                and str(root.common.engine.get(
-                    "compute_dtype", "bfloat16")) in ("bfloat16",
-                                                      "bf16"):
+        on_tpu = jax.default_backend() == "tpu"
+        if flag != "force" and not on_tpu:
+            return reject("not a TPU backend — the Pallas kernel would "
+                          "only run in interpret mode (fused_fc_scan="
+                          "'force' does that; test harness only)")
+        if flag != "force" and str(root.common.engine.get(
+                "compute_dtype", "bfloat16")) in ("bfloat16", "bf16"):
             return reject("TPU compute_dtype policy is bfloat16 — the "
                           "f32 kernel would not be trajectory-exact "
                           "vs the bf16-pass scan path (set "
@@ -388,6 +392,7 @@ class TrainStep(AcceleratedUnit):
             "wd": wd, "wd_bias": wd_bias, "momentum": momentum,
             "act_a": float(fs[0].A), "act_b": float(fs[0].B),
             "names": tuple(f.name for f in fs),
+            "interpret": not on_tpu,
         }
         self.info("fused_fc_scan engaged: whole-epoch Pallas SGD "
                   "kernel (%s)", " → ".join(f.name for f in fs))
@@ -1079,7 +1084,7 @@ class TrainStep(AcceleratedUnit):
                     act_a=ff["act_a"], act_b=ff["act_b"],
                     lr_bias_ratio=ff["lr_bias_ratio"],
                     wd=ff["wd"], wd_bias=ff["wd_bias"],
-                    momentum=ff["momentum"])
+                    momentum=ff["momentum"], interpret=ff["interpret"])
                 p, o = dict(p), dict(o)
                 for i2, n2 in enumerate(names):
                     p[n2] = {"weights": ws[i2], "bias": bs[i2]}

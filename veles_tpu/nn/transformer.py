@@ -25,7 +25,7 @@ from ..config import root
 from ..memory import Array
 from .. import prng
 from .nn_units import ForwardBase, GradientDescentBase, matches
-from .attention import attention_core
+from .attention import attention_core, device_mesh
 
 
 def _layernorm(np_mod, x, g, b, eps=1e-5):
@@ -217,10 +217,7 @@ class TransformerBlock(ForwardBase):
         res = super().initialize(device=device, **kwargs)
         if res:
             return res
-        mesh = getattr(device, "mesh", None)
-        if mesh is not None and "sequence" in mesh.axis_names \
-                and mesh.shape["sequence"] > 1:
-            self.mesh = mesh
+        self.mesh = device_mesh(device)
         return None
 
     def apply(self, params, x, *, train=False, rng=None):

@@ -3,13 +3,3 @@
 from .precision import (matmul_precision, quantize_int8,  # noqa: F401
                         dequantize_int8, quantize_rows_int8,
                         dequantize_rows_int8)
-
-
-def compiler_params(pltpu):
-    """Mosaic compiler-params dataclass across jax versions:
-    ``pltpu.CompilerParams`` (new) was ``pltpu.TPUCompilerParams`` on
-    jax 0.4.x — same fields, renamed class. ONE copy for every Pallas
-    kernel in this package (the shard_map analogue lives in
-    parallel/compat.py)."""
-    return (getattr(pltpu, "CompilerParams", None)
-            or pltpu.TPUCompilerParams)

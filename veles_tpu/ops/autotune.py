@@ -1,34 +1,23 @@
-"""Per-device kernel block-shape database — measure → persist → reuse.
+"""Per-device kernel block-shape database — measure → commit → reuse.
 
 Reference parity: the reference benchmarks GEMM block sizes per device
-on first use and persists them keyed by device name
-(`veles/backends.py:623-731` ``_find_optimal_bs_vo`` →
-``devices/device_infos.json``), so every later run starts tuned. Here
-XLA owns GEMM tuning, but the build's OWN Pallas kernel —
-``ops/flash_attention.py`` — has ``block_q``/``block_k`` knobs the
-compiler does not pick. This module ports the measure-and-persist
-capability to it:
+and persists them keyed by device name (`veles/backends.py:623-731`
+``_find_optimal_bs_vo`` → ``devices/device_infos.json``), so every run
+starts tuned. Here XLA owns GEMM tuning, but the build's OWN Pallas
+kernel — ``ops/flash_attention.py`` — has ``block_q``/``block_k`` knobs
+the compiler does not pick.
 
-- first use of a (device_kind, shape-class) with no recorded entry runs
-  a BOUNDED forward-timing sweep over divisor-compatible block pairs,
-  persists the winner, and returns it;
-- every later use (any process, any day) is a dict lookup.
-
-Two DB layers, user overriding shipped (mirroring the reference's
-in-repo ``device_infos.json`` + user cache):
-
-- shipped: ``veles_tpu/devices/kernel_tuning.json`` (committed; the
-  chip measurement batch seeds it — ``scripts/chip_experiments.py``),
-- user:    ``root.common.dirs.cache / kernel_tuning.json`` (atomic
-  writes; where first-use sweeps land).
+One DB: ``veles_tpu/devices/kernel_tuning.json``, committed. The model
+path only ever READS it (a memoized dict lookup, safe at trace time),
+so one commit compiles the same kernels on every machine and in every
+process of an SPMD job. Measuring is an explicit command on a chip —
+``scripts/chip_experiments.py --sections attn_2048,attn_8192`` — whose
+``record()`` rewrites that file for the next commit; a trace never
+sweeps.
 
 ``fused_fc`` deliberately has no entry here: its only tunable is
 epochs-per-dispatch ``h`` (whole minibatches ARE its blocks), measured
 by the chip batch's h-sweep, not a per-call shape knob.
-
-Config: ``root.common.engine.kernel_autotune`` —
-``"auto"`` (default: lookup, sweep on miss when a real TPU backend is
-up), ``"reuse"`` (lookup only), ``False`` (hard-coded defaults).
 """
 
 from __future__ import annotations
@@ -36,21 +25,19 @@ from __future__ import annotations
 import json
 import os
 import time
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 DEFAULT_BLOCKS = (128, 128)
-#: bounded candidate census (the reference swept a fixed census too,
-#: veles/backends.py:692); filtered per call to divisors of T. The
-#: 1024-wide pairs exist because 512×512 won every r5 sweep length —
-#: the knee hadn't been reached; ``sweep_flash``'s backward-compile
-#: check rejects them wherever the bwd working set overflows VMEM
+#: bounded candidate census the chip attn sweep tries (the reference
+#: swept a fixed census too, veles/backends.py:692), filtered per length
+#: to divisors of T. The 1024-wide pairs exist because 512×512 won every
+#: r5 sweep length — the knee hadn't been reached
 CANDIDATES = ((128, 128), (256, 128), (512, 128), (256, 256),
               (512, 512), (1024, 512), (1024, 1024))
 SHIPPED = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "devices", "kernel_tuning.json")
 
-#: per-process memo: key → blocks (or None after a failed sweep so a
-#: bad environment costs one attempt, not one per trace)
+#: per-process memo: key → blocks / crossover / compile verdict
 _memo: dict = {}
 
 #: (device_kind, key) pairs whose staleness was already warned about —
@@ -91,11 +78,6 @@ def _check_stale(key: str, kind: str, entry: dict) -> None:
         current)
 
 
-def _user_path() -> str:
-    from ..config import root
-    return os.path.join(root.common.dirs.cache, "kernel_tuning.json")
-
-
 def _read(path: str) -> dict:
     try:
         with open(path) as f:
@@ -105,10 +87,7 @@ def _read(path: str) -> dict:
 
 
 def _device_db(device_kind: str) -> dict:
-    """Merged view for one device_kind, user layer winning."""
-    merged = dict(_read(SHIPPED).get(device_kind, {}))
-    merged.update(_read(_user_path()).get(device_kind, {}))
-    return merged
+    return _read(SHIPPED).get(device_kind, {})
 
 
 def current_device_kind() -> str:
@@ -134,14 +113,13 @@ def lookup(key: str, device_kind: Optional[str] = None) -> Optional[dict]:
     return hit
 
 
-def record(key: str, entry: dict, device_kind: Optional[str] = None,
-           shipped: bool = False) -> None:
-    """Persist ``entry`` under (device_kind, key). ``shipped=True``
-    additionally updates the committed in-repo DB — chip measurement
-    batches only, so the repo ships what was actually measured.
-    The read→merge→write is serialized through an flock'd sidecar so
-    concurrent sweeps in processes sharing one cache dir cannot drop
-    each other's entries."""
+def record(key: str, entry: dict,
+           device_kind: Optional[str] = None) -> None:
+    """Write ``entry`` under (device_kind, key) into the committed DB —
+    explicit chip measurement commands only, so the repo ships what
+    was actually measured. The read→merge→write is serialized through
+    an flock'd sidecar so concurrent sweeps cannot drop each other's
+    entries."""
     import fcntl
     kind = device_kind or current_device_kind()
     # provenance stamp: which toolchain + chip measured this entry —
@@ -149,17 +127,15 @@ def record(key: str, entry: dict, device_kind: Optional[str] = None,
     # differs from the running one
     entry = dict(entry, ts=time.strftime("%Y-%m-%d %H:%M:%S"),
                  jax=_jax_version(), device_kind=kind)
-    for path in ([_user_path(), SHIPPED] if shipped else [_user_path()]):
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        with open(path + ".lock", "w") as lock:
-            fcntl.flock(lock, fcntl.LOCK_EX)
-            db = _read(path)
-            db.setdefault(kind, {})[key] = entry
-            tmp = path + ".tmp"
-            with open(tmp, "w") as f:
-                json.dump(db, f, indent=1, sort_keys=True)
-                f.write("\n")       # POSIX text file: end with newline
-            os.replace(tmp, path)
+    with open(SHIPPED + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        db = _read(SHIPPED)
+        db.setdefault(kind, {})[key] = entry
+        tmp = SHIPPED + ".tmp"
+        with open(tmp, "w") as f:
+            json.dump(db, f, indent=1, sort_keys=True)
+            f.write("\n")       # POSIX text file: end with newline
+        os.replace(tmp, SHIPPED)
     if "block_q" in entry:
         _memo[(kind, key)] = (entry["block_q"], entry["block_k"])
     elif "min_t" in entry:          # refresh the crossover memo too
@@ -181,20 +157,14 @@ def flash_min_t(d: int, device_kind: Optional[str] = None,
     device_kind (seeded by the chip attn sweep — the reference
     persisted measured per-device decisions the same way,
     `veles/backends.py:623-731`); ``default`` (the v5e-measured 4096,
-    docs/perf.md) until a sweep has run here. Memoized (this runs per
-    attention layer per trace), and under multi-host it reads ONLY the
-    shipped layer — same invariant as ``flash_blocks``: every SPMD
-    process must resolve the same gate or traced programs diverge."""
+    docs/perf.md) for a device_kind no sweep has covered. Memoized:
+    this runs per attention layer per trace."""
     kind = device_kind or current_device_kind()
     key = min_t_key(d)
     memo_key = (kind, key, "min_t")
     if memo_key in _memo:
         return _memo[memo_key]
-    import jax
-    if jax.process_count() > 1:
-        hit = _read(SHIPPED).get(kind, {}).get(key)
-    else:
-        hit = lookup(key, kind)
+    hit = lookup(key, kind)
     val = default if hit is None else int(hit["min_t"])
     _memo[memo_key] = val
     return val
@@ -212,43 +182,12 @@ def resolved_min_t(d: int, device_kind: Optional[str] = None) -> int:
     return int(cfg or 0)
 
 
-def candidates_for(t: int, d: int) -> Tuple[Tuple[int, int], ...]:
-    from .flash_attention import supported
-    out = tuple((bq, bk) for bq, bk in CANDIDATES
-                if supported(t, d, bq, bk))
-    return out or ((min(t, 128), min(t, 128)),)
-
-
-def _time_flash(t: int, d: int, causal: bool,
-                blocks: Tuple[int, int]) -> float:
-    """Forward-mode timing probe on synthetic bf16 operands (b=1, h=1 —
-    the grid repeats per head/batch, so the per-block ranking
-    transfers); returns seconds per call."""
-    import jax
-    import jax.numpy as jnp
-    import numpy
-    from .flash_attention import flash_attention
-    rng = numpy.random.RandomState(0)
-    q, k, v = (jnp.asarray(rng.randn(1, t, 1, d), jnp.bfloat16)
-               for _ in range(3))
-    fn = jax.jit(lambda q, k, v: flash_attention(
-        q, k, v, causal=causal, block_q=blocks[0], block_k=blocks[1],
-        interpret=False))
-    jax.block_until_ready(fn(q, k, v))          # compile
-    t0 = time.time()
-    for _ in range(4):
-        out = fn(q, k, v)
-    jax.block_until_ready(out)
-    return (time.time() - t0) / 4
-
-
 def _bwd_compiles(t: int, d: int, causal: bool,
                   blocks: Tuple[int, int]) -> bool:
     """Whether the custom-VJP backward pair LOWERS at these blocks.
-    The sweep times only the forward, but _prepare feeds its winner to
-    the backward kernels too — whose VMEM working set is larger (q/do/
-    k/v blocks + dk/dv accumulators resident), so a forward-fine
-    (512, 512) can be a backward Mosaic OOM. Compile-only: no timing."""
+    Its VMEM working set is larger than the forward's (q/do/k/v blocks
+    + dk/dv accumulators resident), so a forward-fine (512, 512) can
+    be a backward Mosaic OOM. Compile-only: no timing."""
     import jax
     import jax.numpy as jnp
     import numpy
@@ -268,67 +207,23 @@ def _bwd_compiles(t: int, d: int, causal: bool,
         return False
 
 
-def sweep_flash(t: int, d: int, causal: bool = True,
-                device_kind: Optional[str] = None,
-                measure: Optional[Callable] = None,
-                cands: Optional[Sequence[Tuple[int, int]]] = None,
-                persist: bool = True,
-                check_bwd: Optional[Callable] = None) -> Tuple[int, int]:
-    """Bounded block sweep for one shape class; persists and returns the
-    winner — the fastest forward whose BACKWARD also compiles
-    (``_bwd_compiles``). ``measure(t, d, causal, blocks) -> seconds``
-    and ``check_bwd(t, d, causal, blocks) -> bool`` are injectable
-    (tests use a fake device_kind + fakes to prove persist/reuse
-    without a chip)."""
-    measure = measure or _time_flash
-    check_bwd = check_bwd or _bwd_compiles
-    rows = {}
-    timed = []
-    for blocks in (cands or candidates_for(t, d)):
-        try:
-            dt = measure(t, d, causal, blocks)
-        except Exception:        # noqa: BLE001 — candidate didn't lower
-            continue
-        rows["%dx%d" % blocks] = round(dt * 1e3, 3)
-        timed.append((dt, blocks))
-    best = best_dt = None
-    for dt, blocks in sorted(timed):
-        if blocks == DEFAULT_BLOCKS or check_bwd(t, d, causal, blocks):
-            best, best_dt = blocks, dt
-            break
-        rows["%dx%d" % blocks] = "bwd_compile_failed"
-    if best is None:
-        raise RuntimeError("flash autotune: no candidate ran for "
-                           "t=%d d=%d" % (t, d))
-    if persist:
-        record(flash_key(t, d, causal),
-               {"block_q": best[0], "block_k": best[1],
-                "ms": round(best_dt * 1e3, 3), "sweep_ms": rows,
-                "mode": "fwd_inline_sweep"},
-               device_kind=device_kind)
-    return best
-
-
-def _nearest_blocks(t: int, d: int, causal: bool, kind: str,
-                    shipped_only: bool) -> Optional[Tuple[int, int]]:
+def _nearest_blocks(t: int, d: int, causal: bool,
+                    kind: str) -> Optional[Tuple[int, int]]:
     """Measured winner from the nearest tuned length of the same
     (d, mode) class whose blocks divide this ``t``. Rationale
     (measured, docs/perf.md attn sweep): the per-device block
     preference is set by MXU-pipeline fill, which transfers across
-    lengths — the committed v5e winners are 1024×1024 at BOTH 2048
-    and 8192 (devices/kernel_tuning.json, round-5 extended census;
-    512×512 is the runner-up throughout), while the 128×128
-    DEFAULT_BLOCKS lost to fused XLA at 2048. Without this, an
-    untuned T between swept lengths would pair the measured
-    ``flash_min_t`` gate with the unmeasured default blocks — the
-    exact combination the sweep showed regressing."""
-    db = (_read(SHIPPED).get(kind, {}) if shipped_only
-          else _device_db(kind))
+    lengths — the committed v5e winners agree at 2048 and 8192
+    (devices/kernel_tuning.json), while the 128×128 DEFAULT_BLOCKS
+    lost to fused XLA at 2048. Without this, an untuned T between
+    swept lengths would pair the measured ``flash_min_t`` gate with
+    the unmeasured default blocks — the exact combination the sweep
+    showed regressing."""
     pref = "flash_t"
     suf = "_d%d_%s" % (d, "causal" if causal else "full")
     from .flash_attention import supported
     best = None
-    for key, entry in db.items():
+    for key, entry in _device_db(kind).items():
         if not (key.startswith(pref) and key.endswith(suf)):
             continue
         try:
@@ -351,13 +246,13 @@ def _check_inherited(t: int, d: int, causal: bool,
                      blocks: Tuple[int, int], kind: str
                      ) -> Tuple[int, int]:
     """First use of a length-INHERITED winner at this ``t``: confirm
-    the custom-VJP pair actually LOWERS (mirroring ``sweep_flash``'s
-    ``_bwd_compiles`` gate, which only ran at the swept lengths) and
-    fall back to DEFAULT_BLOCKS instead of erroring inside the model's
-    jitted step. TPU-only: off-TPU the kernel runs in interpret mode
-    where there is no Mosaic lowering to fail (and tests drive
-    inheritance with fake device kinds). The verdict is memoized per
-    (kind, t, blocks) so the compile probe costs once, not per trace."""
+    the custom-VJP pair actually LOWERS (the sweep only compiled it at
+    the swept lengths) and use DEFAULT_BLOCKS otherwise. TPU-only: off-TPU there is no Mosaic
+    lowering to fail (and tests drive inheritance with fake device
+    kinds). The verdict is memoized per (kind, t, blocks) so the
+    compile probe costs once, not per trace. A committed entry for
+    ``t`` itself is NOT probed here: the chip batch
+    (``--sections pallas_compile``) compiles every committed pair."""
     if blocks == DEFAULT_BLOCKS:
         return blocks
     import jax
@@ -373,84 +268,28 @@ def _check_inherited(t: int, d: int, causal: bool,
 def flash_blocks(t: int, d: int, causal: bool = True, window: int = 0,
                  device_kind: Optional[str] = None) -> Tuple[int, int]:
     """THE policy lookup ``flash_attention`` resolves its default
-    blocks through. Lookup is a memoized dict read (safe at trace
-    time); a first-use sweep only fires in ``"auto"`` mode on a real
-    TPU backend — its timing probes are independent eager programs, so
-    running them while an outer jit traces is legal."""
-    from ..config import root
-    mode = root.common.engine.get("kernel_autotune", "auto")
-    if not mode:
-        return DEFAULT_BLOCKS
+    blocks through: the committed entry for this shape class, else (for
+    a windowed shape) the causal entry's ranking, else the nearest tuned
+    length's winner, else DEFAULT_BLOCKS. Read-only — the same commit
+    resolves the same blocks on every machine. Hits are memoized;
+    misses are not, so a ``record()`` later in the process (a sweep
+    command) changes the answer."""
     kind = device_kind or current_device_kind()
     key = flash_key(t, d, causal, window)
     memo_key = (kind, key)
     if memo_key in _memo:
-        return _memo[memo_key] or DEFAULT_BLOCKS
-    import jax
-    multihost = jax.process_count() > 1
-    if multihost:
-        # every process of an SPMD program must trace IDENTICAL block
-        # shapes or the jobs' executables diverge and hang at the first
-        # collective — so multi-host reads ONLY the shipped (committed,
-        # host-identical) DB layer and never sweeps: per-host sweeps
-        # could pick different near-tied winners, and per-host user DBs
-        # can differ
-        hit = _read(SHIPPED).get(kind, {}).get(key)
-        if hit is not None:
-            blocks = (int(hit["block_q"]), int(hit["block_k"]))
-        else:
-            # shipped-layer nearest-length fallback: deterministic and
-            # host-identical, so SPMD processes still trace the same
-            # shapes (the compile probe is host-identical too — same
-            # kernel code on the same device kind)
-            inherited = _nearest_blocks(t, d, causal, kind,
-                                        shipped_only=True)
-            blocks = (_check_inherited(t, d, causal, inherited, kind)
-                      if inherited else DEFAULT_BLOCKS)
-        _memo[memo_key] = blocks
-        return blocks
+        return _memo[memo_key]
     hit = lookup(key, kind)
+    if hit is None and window:
+        hit = lookup(flash_key(t, d, causal), kind)
     if hit is not None:
-        blocks = (int(hit["block_q"]), int(hit["block_k"]))
-        _memo[memo_key] = blocks
+        blocks = _memo[memo_key] = (int(hit["block_q"]),
+                                    int(hit["block_k"]))
         return blocks
-    if mode != "auto" or jax.default_backend() != "tpu" or window:
-        # windowed shapes reuse the causal entry's ranking if present,
-        # else defaults — no dedicated sweep for every window size.
-        # Misses are deliberately NOT memoized here: a later record()
-        # or a mode switch back to "auto" must be able to change the
-        # answer within the process.
-        if window:
-            base = lookup(flash_key(t, d, causal), kind)
-            if base is not None:
-                blocks = (int(base["block_q"]), int(base["block_k"]))
-                _memo[memo_key] = blocks
-                return blocks
-        # NOT memoized, same as the DEFAULT_BLOCKS miss below: a later
-        # record() of a nearer length or a switch back to "auto" must
-        # be able to change the answer within the process
-        inherited = _nearest_blocks(t, d, causal, kind,
-                                    shipped_only=False)
-        if inherited is None:
-            return DEFAULT_BLOCKS
-        return _check_inherited(t, d, causal, inherited, kind)
-    try:
-        blocks = sweep_flash(t, d, causal, device_kind=kind)
-    except Exception:            # noqa: BLE001 — never fail the model;
-        # a failed sweep IS memoized (retrying it every trace would
-        # re-pay the compile storm each time) — but as the nearest
-        # tuned length's measured winner when one exists (compile-
-        # checked at THIS t), not the unmeasured defaults
-        fallback = _nearest_blocks(t, d, causal, kind,
-                                   shipped_only=False)
-        if fallback is not None:
-            fallback = _check_inherited(t, d, causal, fallback, kind)
-            if fallback == DEFAULT_BLOCKS:
-                fallback = None  # store the miss, not a fake winner
-        _memo[memo_key] = fallback   # None → DEFAULT_BLOCKS on re-read
-        return fallback or DEFAULT_BLOCKS
-    _memo[memo_key] = blocks
-    return blocks
+    inherited = _nearest_blocks(t, d, causal, kind)
+    if inherited is None:
+        return DEFAULT_BLOCKS
+    return _check_inherited(t, d, causal, inherited, kind)
 
 
 def clear_memo() -> None:

@@ -34,9 +34,6 @@ LANE = 128
 NEG_INF = -1e30
 
 
-from . import compiler_params as _compiler_params
-
-
 def _mask_scores(s, q_start, k_start, block_q: int, block_k: int,
                  causal: bool, window: int):
     """The one copy of the score mask all three kernels share:
@@ -186,7 +183,7 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float, block_q: int,
         # carry no state between steps, so Mosaic may parallelize /
         # pipeline them; only the K/V dim accumulates in scratch and
         # must stay sequential ("arbitrary")
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -422,7 +419,7 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, do, k, v, lse8, pad8)
@@ -451,7 +448,7 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
         ],
         out_shape=[jax.ShapeDtypeStruct((g, t, d), out_dtype or q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, do, k, v, lse8, pad8)
@@ -598,7 +595,13 @@ def _prepare(q, k, v, scale, block_q, block_k, interpret, caller,
         raise ValueError("%s: T=%d D=%d not supported with blocks "
                          "(%d, %d)" % (caller, t, d, block_q, block_k))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        # compiled, or an error: a kernel reached off-TPU must not run
+        # interpreted without a word. The one exception is the test
+        # harness's engine.flash_attention="force" (tests may also
+        # pass interpret=True themselves)
+        from ..config import root
+        interpret = (root.common.engine.flash_attention == "force"
+                     and jax.default_backend() != "tpu")
     d_pad = ((d + LANE - 1) // LANE) * LANE
 
     def fold(x):
@@ -680,8 +683,10 @@ def flash_attention(q, k, v, causal: bool = False,
     """(B, T, H, D) × 3 → (B, T, H, D), differentiable.
 
     Falls back is the caller's job — check ``supported(T, D)`` first.
-    ``interpret`` defaults to True off-TPU so tests exercise the same
-    kernel on the CPU backend. ``window=W`` restricts each query to
+    ``interpret=None`` compiles the kernel (Mosaic; an error off-TPU)
+    unless ``engine.flash_attention == "force"`` runs it off-TPU, where
+    it interprets; tests exercising the kernel on the CPU backend pass
+    ``interpret=True``. ``window=W`` restricts each query to
     itself plus W-1 predecessors (sliding-window / Mistral convention;
     requires ``causal``): compute AND the blockwise backward drop the
     dead blocks, so long-T cost scales O(T·W) instead of O(T²).
@@ -705,6 +710,8 @@ def flash_attention(q, k, v, causal: bool = False,
     from ..telemetry.counters import inc
     from ..telemetry.cost import note_kernel_cost
     inc("veles_flash_attention_traces_total")
+    if interpret:
+        inc("veles_flash_attention_interpret_traces_total")
     note_kernel_cost(analytic_cost(b, t, h, d, causal, window))
     o = _flash(q3, k3, v3, causal, scale,
                block_q, block_k, interpret, window, h, kv, d)

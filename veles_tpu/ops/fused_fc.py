@@ -39,9 +39,6 @@ SUB = 8
 NEG = -1e30
 
 
-from . import compiler_params as _compiler_params
-
-
 def _pad_to(x, axis, mult):
     size = x.shape[axis]
     want = ((size + mult - 1) // mult) * mult
@@ -223,7 +220,7 @@ def fused_fc_sgd_epoch(weights: Sequence, biases: Sequence,
                        lr_bias_ratio: float = 1.0,
                        wd: float = 0.0, wd_bias: float = 0.0,
                        momentum: float = 0.0,
-                       interpret: Optional[bool] = None,
+                       interpret: bool = False,
                        precision: Optional[str] = None):
     """One SGD epoch of an L-layer tanh chain + softmax-CE head as a
     single Pallas program with VMEM-resident weights AND momentum
@@ -260,8 +257,6 @@ def fused_fc_sgd_epoch(weights: Sequence, biases: Sequence,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     L = len(weights)
     assert len(biases) == len(vel_w) == len(vel_b) == L and L >= 1
     k_steps, mb = plan.shape
@@ -330,7 +325,7 @@ def fused_fc_sgd_epoch(weights: Sequence, biases: Sequence,
         scratch_shapes=scratch,
         # one sequential dimension: every step reads+writes the same
         # VMEM-resident weights
-        compiler_params=_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lr2, xg, yg, *wp, *bp, *vwp, *vbp)

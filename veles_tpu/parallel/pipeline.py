@@ -85,8 +85,6 @@ def gpipe(fn: Callable[[Any, Any], Any], stage_params: Any, xs: Any,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from .compat import shard_map_compat
-
     if batch_spec is None:
         batch_spec = P()
     n = mesh.shape[axis]
@@ -112,9 +110,9 @@ def gpipe(fn: Callable[[Any, Any], Any], stage_params: Any, xs: Any,
 
     params_spec = jax.tree_util.tree_map(
         lambda _: P(axis), stage_params)
-    fn_sharded = shard_map_compat(
-        local, mesh=mesh,
-        in_specs=(params_spec, batch_spec), out_specs=batch_spec)
+    fn_sharded = jax.shard_map(
+        local, mesh=mesh, in_specs=(params_spec, batch_spec),
+        out_specs=batch_spec, check_vma=False)
     return fn_sharded(stage_params, xs)
 
 
@@ -153,8 +151,6 @@ def gpipe_hetero(stage_fns: List[Callable[[Any, Any], Any]],
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-
-    from .compat import shard_map_compat
 
     if batch_spec is None:
         batch_spec = P()
@@ -199,9 +195,9 @@ def gpipe_hetero(stage_fns: List[Callable[[Any, Any], Any]],
             lambda y: y[:sizes[n]].reshape(shapes[n]), shapes[n])
 
     params_spec = jax.tree_util.tree_map(lambda _: P(), stage_params)
-    fn_sharded = shard_map_compat(
-        local, mesh=mesh,
-        in_specs=(params_spec, batch_spec), out_specs=batch_spec)
+    fn_sharded = jax.shard_map(
+        local, mesh=mesh, in_specs=(params_spec, batch_spec),
+        out_specs=batch_spec, check_vma=False)
     return fn_sharded(stage_params, xs)
 
 

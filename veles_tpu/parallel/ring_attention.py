@@ -57,13 +57,11 @@ def ring_attention(q, k, v, mesh, axis: str = "sequence",
 
     ``use_flash``: None = auto (``ops.flash_attention.choose_flash`` on
     the LOCAL block length, windowless, equal q/kv heads); True forces
-    the Pallas engine (tests: pallas interpret off-TPU), False forces
-    the einsum engine."""
+    the Pallas engine past the length gate, False forces the einsum
+    engine."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-
-    from .compat import shard_map_compat
 
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -103,8 +101,8 @@ def ring_attention(q, k, v, mesh, axis: str = "sequence",
                     scale=float(scale), window=window,
                     use_flash=bool(use_flash))
     spec = P(batch_axis, axis, None, None)
-    fn = shard_map_compat(local, mesh=mesh,
-                          in_specs=(spec, spec, spec), out_specs=spec)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 

@@ -30,7 +30,6 @@ def ulysses_attention(q, k, v, mesh, axis: str = "sequence",
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from .compat import shard_map_compat
     from .ring_attention import attention_reference
 
     n = mesh.shape[axis]
@@ -65,6 +64,6 @@ def ulysses_attention(q, k, v, mesh, axis: str = "sequence",
                                   tiled=True)
 
     spec = P(batch_axis, axis, None, None)
-    fn = shard_map_compat(local, mesh=mesh,
-                          in_specs=(spec, spec, spec), out_specs=spec)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)

@@ -1253,9 +1253,9 @@ class ContinuousEngine(Logger):
         import jax
         if self.tp <= 1:
             return jax.jit(fn, donate_argnums=donate)
-        from ..parallel.compat import shard_map_compat
         return jax.jit(
-            shard_map_compat(fn, self._tp_mesh(), in_specs, out_specs),
+            jax.shard_map(fn, mesh=self._tp_mesh(), in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False),
             donate_argnums=donate)
 
     # -- admission ------------------------------------------------------------

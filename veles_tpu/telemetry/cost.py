@@ -23,12 +23,15 @@ from typing import Any, Callable, Dict, Optional
 
 #: nominal dense bf16 peak FLOP/s per chip by device kind (public
 #: numbers; substring-matched against jax device_kind, first hit wins).
-#: THE one copy — bench.py imports it from here.
+#: THE one copy — bench.py imports it from here. A device_kind with no
+#: row is an error (``UnknownDevice``), never a default: a utilization
+#: graded against another chip's peak is not a measurement.
+#: ``"TPU v5 lite"`` (the v5e, as jax names it) matches the ``v5`` row:
+#: 197 TFLOP/s bf16 — Google Cloud documentation, "TPU v5e".
 PEAK_BF16 = [
     ("v6", 918e12), ("v5p", 459e12), ("v5", 197e12),
     ("v4", 275e12), ("v3", 123e12), ("v2", 45e12),
 ]
-DEFAULT_PEAK = 275e12
 
 #: nominal dense f32 peak FLOP/s per chip. The MXU computes bf16
 #: products with f32 accumulation; full-f32 matmul throughput is the
@@ -40,7 +43,12 @@ PEAK_F32 = [
     ("v6", 459e12), ("v5p", 229.5e12), ("v5", 98.5e12),
     ("v4", 137.5e12), ("v3", 61.5e12), ("v2", 22.5e12),
 ]
-DEFAULT_PEAK_F32 = 137.5e12
+
+
+class UnknownDevice(LookupError):
+    """No peak FLOP/s on file for this device_kind: a utilization
+    cannot be graded here (callers that also run on the CPU drop the
+    field)."""
 
 
 #: assumed aggregate ICI bandwidth per chip, bytes/s (public nominal
@@ -58,20 +66,22 @@ ICI_BW_BYTES = [
 DEFAULT_ICI_BW = 1.0e11
 
 
+def _device_kind(device_kind: Optional[str]) -> str:
+    """``device_kind`` lower-cased, defaulting to the first visible jax
+    device's."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    return str(device_kind).lower()
+
+
 def ici_bandwidth_entry(device_kind: Optional[str] = None):
     """(source label, assumed per-chip ICI bytes/s) for
     ``device_kind`` — the label names the EXACT assumption used
     (``ICI_BW_BYTES[<key>]`` on a table hit, ``DEFAULT_ICI_BW``
     otherwise), so the scaling model's falsifiability record can never
     misattribute its own input."""
-    if device_kind is None:
-        import jax
-        try:
-            device_kind = str(getattr(jax.devices()[0], "device_kind",
-                                      "unknown"))
-        except Exception:            # noqa: BLE001 — backend init failure
-            device_kind = "unknown"
-    kind = str(device_kind).lower()
+    kind = _device_kind(device_kind)
     for key, bw in ICI_BW_BYTES:
         if key in kind:
             return "telemetry.cost.ICI_BW_BYTES[%s]" % key, bw
@@ -87,16 +97,8 @@ def ici_bandwidth(device_kind: Optional[str] = None) -> float:
 
 def peak_bf16_flops(device_kind: Optional[str] = None) -> float:
     """Nominal dense bf16 peak FLOP/s for ``device_kind`` (default: the
-    first visible jax device)."""
-    if device_kind is None:
-        import jax
-        try:
-            device_kind = str(getattr(jax.devices()[0], "device_kind",
-                                      "unknown"))
-        except Exception:            # noqa: BLE001 — backend init failure
-            device_kind = "unknown"
-    kind = str(device_kind).lower()
-    return next((p for key, p in PEAK_BF16 if key in kind), DEFAULT_PEAK)
+    first visible jax device); ``UnknownDevice`` when there is no row."""
+    return peak_flops_entry("bfloat16", device_kind)[1]
 
 
 def peak_flops_entry(dtype=None, device_kind: Optional[str] = None):
@@ -105,7 +107,8 @@ def peak_flops_entry(dtype=None, device_kind: Optional[str] = None):
     priced at the f32 table as the optimistic bound) resolves through
     PEAK_F32, everything else (bf16/f16/int8-ish mixed precision)
     through PEAK_BF16. The label names the exact table entry used so
-    bench sections can stamp the peak they were graded against."""
+    bench sections can stamp the peak they were graded against. Raises
+    ``UnknownDevice`` for a device_kind neither table lists."""
     if dtype is None:
         name = "bfloat16"
     else:
@@ -114,31 +117,17 @@ def peak_flops_entry(dtype=None, device_kind: Optional[str] = None):
             name = numpy.dtype(dtype).name
         except TypeError:       # e.g. "bf16" shorthand, jax weak types
             name = str(getattr(dtype, "name", dtype))
-    name = name.lower()
-    f32_class = name in ("float32", "f32", "float64", "f64")
-    table, default, tname = (
-        (PEAK_F32, DEFAULT_PEAK_F32, "PEAK_F32") if f32_class
-        else (PEAK_BF16, DEFAULT_PEAK, "PEAK_BF16"))
-    if device_kind is None:
-        import jax
-        try:
-            device_kind = str(getattr(jax.devices()[0], "device_kind",
-                                      "unknown"))
-        except Exception:            # noqa: BLE001 — backend init failure
-            device_kind = "unknown"
-    kind = str(device_kind).lower()
+    f32_class = name.lower() in ("float32", "f32", "float64", "f64")
+    table, tname = ((PEAK_F32, "PEAK_F32") if f32_class
+                    else (PEAK_BF16, "PEAK_BF16"))
+    kind = _device_kind(device_kind)
     for key, p in table:
         if key in kind:
             return "telemetry.cost.%s[%s]" % (tname, key), p
-    return "telemetry.cost.DEFAULT_%s" % ("PEAK_F32" if f32_class
-                                          else "PEAK"), default
-
-
-def peak_flops(dtype=None, device_kind: Optional[str] = None) -> float:
-    """Nominal dense peak FLOP/s for ``dtype`` on ``device_kind``
-    (default: the first visible jax device) — the dtype-aware MFU
-    denominator. ``peak_flops("float32") == peak_bf16_flops()/2``."""
-    return peak_flops_entry(dtype, device_kind)[1]
+    raise UnknownDevice(
+        "no peak FLOP/s on file for device_kind %r (telemetry/cost.py "
+        "%s): utilization cannot be graded on this device"
+        % (kind, tname))
 
 
 class Cost:
