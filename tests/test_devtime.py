@@ -6,8 +6,9 @@ The load-bearing locks:
   ("XLA Modules") and host processes excluded, nested/overlapping
   events interval-unioned (never double counted), torn traces
   salvaged like ``spans.read_jsonl``;
-- span attribution: device intervals clip onto telemetry span windows
-  under an explicit or estimated clock offset;
+- span attribution: device intervals clip onto telemetry span windows,
+  the spans being the capture's own annotations or records under an
+  explicit clock offset;
 - the host-sync fallback path: counted, wall ≥ device, stamped
   ``source="host_sync"``;
 - gate arithmetic: device-time medians compare at the stated
@@ -130,12 +131,39 @@ def test_attribute_spans_clips_and_aggregates():
     assert blk["device_time_s"] == pytest.approx(125e-6)
     assert blk["spans"] == 2
     assert per["unit.loader"]["device_time_s"] == 0.0
-    # default offset aligns earliest device event to earliest span:
-    # shifting every span by a constant changes nothing
+    # records from another clock come with the explicit offset of one
+    # common instant; nothing is estimated
     shifted = [dict(s, ts=s["ts"] + 1000.0) for s in spans]
-    per2 = devtime.attribute_spans(evs, shifted)
+    per2 = devtime.attribute_spans(evs, shifted, offset_us=-1000.0e6)
     assert per2["train_step.epoch_block"]["device_time_s"] == \
         pytest.approx(125e-6)
+
+
+def test_attribute_spans_takes_the_captures_own_annotations():
+    """A span is also a profiler annotation (telemetry/spans.py), so a
+    capture holds it on the device operations' own timeline: with no
+    records given, the host process's events that carry a span's name
+    are the spans. Other host events (the python tracer's) are not."""
+    evs = _fake_trace(extra=[
+        {"ph": "X", "pid": 2, "tid": 1, "ts": 0.0, "dur": 75.0,
+         "name": "train_step.epoch_block"},
+        {"ph": "X", "pid": 2, "tid": 1, "ts": 100.0, "dur": 50.0,
+         "name": "train_step.epoch_block"},
+        {"ph": "X", "pid": 2, "tid": 7, "ts": 120.0, "dur": 10.0,
+         "name": "serving.stream.write"},
+        {"ph": "X", "pid": 2, "tid": 1, "ts": 0.0, "dur": 150.0,
+         "name": "json.dumps"}])
+    assert [r["name"] for r in devtime.annotation_spans(evs)] == [
+        "train_step.epoch_block", "train_step.epoch_block",
+        "serving.stream.write"]
+    per = devtime.attribute_spans(evs)
+    assert per["train_step.epoch_block"]["device_time_s"] == \
+        pytest.approx(125e-6)
+    assert per["train_step.epoch_block"]["spans"] == 2
+    assert per["serving.stream.write"]["device_time_s"] == \
+        pytest.approx(10e-6)
+    assert "json.dumps" not in per and "python" not in per
+    assert devtime.attribute_spans(_fake_trace()) == {}
 
 
 # -- trace loading + salvage --------------------------------------------------
@@ -174,17 +202,19 @@ def test_torn_trace_salvaged_with_warning(tmp_path, caplog):
 def test_self_time_cli(tmp_path, capsys):
     from veles_tpu.__main__ import main
     trace = tmp_path / "trace.json"
-    trace.write_text(json.dumps({"traceEvents": _fake_trace()}))
-    spans = tmp_path / "run.jsonl"
-    spans.write_text(json.dumps(
-        {"name": "train_step.epoch_block", "ts": 0.0, "dur": 150e-6,
-         "sid": 1, "tid": 1}) + "\n")
-    rc = main(["trace", "self-time", str(trace),
-               "--spans", str(spans)])
+    trace.write_text(json.dumps({"traceEvents": _fake_trace(extra=[
+        {"ph": "X", "pid": 2, "tid": 1, "ts": 0.0, "dur": 150.0,
+         "name": "train_step.epoch_block"}])}))
+    rc = main(["trace", "self-time", str(trace)])
     out = capsys.readouterr().out
     assert rc == 0
     assert "/device:TPU:0/XLA Ops" in out
     assert "train_step.epoch_block" in out
+    # the span file of old is no flag any more: the capture holds the
+    # spans itself
+    with pytest.raises(SystemExit):
+        main(["trace", "self-time", str(trace), "--spans", "run.jsonl"])
+    capsys.readouterr()
     # a missing file is a clean rc=1, not a traceback
     assert main(["trace", "self-time",
                  str(tmp_path / "nope.json")]) == 1

@@ -123,6 +123,10 @@ def main(argv=None) -> int:
             args.serve_drain_handoff == "on"
     if args.serve_qos is not None:
         _root.common.serving.qos = args.serve_qos == "on"
+    if args.profile_dir and args.serve_generate is not None:
+        # a server never returns from a run() to bracket: the flag
+        # arms POST /generate/profile instead
+        _root.common.serving.profile_dir = args.profile_dir
     if args.router_qos is not None:
         _root.common.router.qos = args.router_qos == "on"
     if args.router_slo_ttft_ms is not None:
@@ -249,12 +253,14 @@ def _trace_cli(argv) -> int:
     telemetry.spans.recorder.to_jsonl dump) into Chrome trace_event
     JSON viewable in Perfetto / chrome://tracing.
 
-    ``veles-tpu trace self-time TRACE.json[.gz]`` — summarize a
-    captured profiler trace (or a ``jax.profiler`` log DIRECTORY)
-    into per-stream device self-time, and — with ``--spans
-    RUN.jsonl`` — per-telemetry-span device self-time: the
-    operator-facing view of the numbers ``bench.py gate``'s
-    device-time sections consume (telemetry/devtime.py)."""
+    ``veles-tpu trace self-time DIR`` — read the capture in a
+    ``jax.profiler`` log directory (``--profile-dir``, ``POST
+    /generate/profile``) and print device time by program, by named
+    scope within each program, and the device's idle gaps by the
+    program's own span that covers each (telemetry/devtime.py). A
+    Chrome trace file ``TRACE.json[.gz]`` gives per-stream and
+    per-span device self-time, the numbers ``bench.py gate``'s
+    device-time sections consume."""
     import argparse
     parser = argparse.ArgumentParser(
         prog="veles_tpu trace",
@@ -297,11 +303,10 @@ def _trace_cli(argv) -> int:
         help="device self-time summary of a captured trace "
              "(docs/perf.md 'Device-time measurement plane')")
     st.add_argument("trace",
-                    help="Chrome trace-event JSON[.gz], or a "
-                         "jax.profiler log directory")
-    st.add_argument("--spans", default=None, metavar="RUN.jsonl",
-                    help="telemetry span JSONL to attribute device "
-                         "time onto (per-span-name table)")
+                    help="a jax.profiler log directory, or a Chrome "
+                         "trace-event JSON[.gz]")
+    st.add_argument("--depth", type=int, default=2, metavar="N",
+                    help="scope levels kept in the by-scope table")
     st.add_argument("--top", type=int, default=12, metavar="N",
                     help="print at most N rows per table")
     args = parser.parse_args(argv)
@@ -381,12 +386,23 @@ def _trace_fleet(args) -> int:
 
 
 def _trace_self_time(args) -> int:
-    """Parse the trace-event stream (torn/truncated files are
-    salvaged with a counted warning, like ``spans.read_jsonl``) and
-    print per-stream — and optionally per-span — device self-time."""
+    """A profiler log directory: the three tables of its ``.xplane.pb``
+    (device time by program, by scope, idle gaps by host span). A
+    Chrome trace-event file (torn/truncated files are salvaged with a
+    counted warning, like ``spans.read_jsonl``): per-stream device
+    self-time, and per span for the spans the trace itself holds."""
     import os as _os
     from .telemetry import devtime
+    top = max(0, args.top)
     try:
+        capture = (devtime.find_capture(args.trace)
+                   if _os.path.isdir(args.trace) else None)
+        if capture is not None:
+            summary = devtime.summarize_capture(
+                devtime.load_capture(capture), depth=args.depth)
+            print("capture %s" % capture)
+            print("\n".join(devtime.format_capture(summary, top)))
+            return 0
         if _os.path.isdir(args.trace):
             events = devtime.load_profile_dir(args.trace)
         else:
@@ -401,21 +417,15 @@ def _trace_self_time(args) -> int:
         print("  (no device streams — a host-only capture; bench "
               "falls back to host-sync timing here)")
     rows = sorted(st["by_stream"].items(), key=lambda kv: -kv[1])
-    for label, secs in rows[:max(0, args.top)]:
+    for label, secs in rows[:top]:
         print("  %-40s %.6f s" % (label, secs))
-    if args.spans:
-        from .telemetry.spans import read_jsonl
-        try:
-            span_records = read_jsonl(args.spans)
-        except OSError as e:
-            print("trace self-time failed: %s" % e, file=sys.stderr)
-            return 1
-        per = devtime.attribute_spans(events, span_records)
+    per = devtime.attribute_spans(events)
+    if per:
         print("per-span device self-time (%d span name(s)):"
               % len(per))
         rows = sorted(per.items(),
                       key=lambda kv: -kv[1]["device_time_s"])
-        for name, row in rows[:max(0, args.top)]:
+        for name, row in rows[:top]:
             print("  %-40s %.6f s over %d span(s)"
                   % (name, row["device_time_s"], row["spans"]))
     return 0

@@ -293,8 +293,12 @@ def make_parser() -> argparse.ArgumentParser:
                         "compose)")
     p.add_argument("--profile-dir", default=None,
                    help="capture a jax/XPlane profiler trace of the run "
-                        "into this directory (view with tensorboard or "
-                        "xprof; the TPU-era --timings deep dive)")
+                        "into this directory (read it with `veles_tpu "
+                        "trace self-time DIR`, tensorboard or xprof); "
+                        "under --serve-generate it arms POST "
+                        "/generate/profile {\"seconds\": N} instead, "
+                        "which captures a running server into a fresh "
+                        "subdirectory")
     p.add_argument("--job-timeout", type=float, default=0.0,
                    help="floor (seconds) for the per-dispatch hang "
                         "watchdog; 0 keeps only the mean+3σ adaptive "

@@ -112,6 +112,7 @@ def _block_prefill(block, p, x, cache_k, cache_v, tp=1, tp_axis=None):
     derives from the FULL head count — the residual ``d`` never
     shards."""
     import jax.numpy as jnp
+    from jax import named_scope
     from .attention import attention_core
     from ..ops import matmul_precision
     prec = matmul_precision()
@@ -120,28 +121,41 @@ def _block_prefill(block, p, x, cache_k, cache_v, tp=1, tp_axis=None):
     kv = getattr(block, "n_kv_heads", block.n_heads) // tp
     hd = d // block.n_heads
 
-    a_in = block_norm(jnp, block, p, x, "ln1")
-    q = jnp.dot(a_in, p["wq"], precision=prec).reshape(b, t, h, hd)
-    k = jnp.dot(a_in, p["wk"], precision=prec).reshape(b, t, kv, hd)
-    v = jnp.dot(a_in, p["wv"], precision=prec).reshape(b, t, kv, hd)
-    if block.rope:
-        base = getattr(block, 'rope_base', 10000.0)
-        q, k = _rope(jnp, q, base), _rope(jnp, k, base)
-    # the cache stores the UNREPEATED kv heads — with GQA it is
-    # n_heads/n_kv_heads times smaller than an MHA cache
-    cache_k = cache_k.at[:, :t].set(k)
-    cache_v = cache_v.at[:, :t].set(v)
-    o = attention_core(q, k, v, causal=True, mesh=None, n_heads=h,
-                       window=getattr(block, "window", None)
-                       ).reshape(b, t, h * hd)
-    proj = jnp.dot(o, p["wo"], precision=prec)
-    if tp_axis is not None:
-        import jax
-        proj = jax.lax.psum(proj, tp_axis)
-    x = x + proj
-    f_in = block_norm(jnp, block, p, x, "ln2")
-    return x + block_ffn(jnp, block, p, f_in, prec, tp_axis=tp_axis), \
-        cache_k, cache_v
+    # the same scopes as TransformerBlock.apply, under the unit's name
+    with named_scope(block.name):
+        with named_scope("norm1"):
+            a_in = block_norm(jnp, block, p, x, "ln1")
+        with named_scope("attn_qkv"):
+            q = jnp.dot(a_in, p["wq"],
+                        precision=prec).reshape(b, t, h, hd)
+            k = jnp.dot(a_in, p["wk"],
+                        precision=prec).reshape(b, t, kv, hd)
+            v = jnp.dot(a_in, p["wv"],
+                        precision=prec).reshape(b, t, kv, hd)
+        if block.rope:
+            base = getattr(block, 'rope_base', 10000.0)
+            with named_scope("rope"):
+                q, k = _rope(jnp, q, base), _rope(jnp, k, base)
+        with named_scope("attn"):
+            # the cache stores the UNREPEATED kv heads — with GQA it is
+            # n_heads/n_kv_heads times smaller than an MHA cache
+            cache_k = cache_k.at[:, :t].set(k)
+            cache_v = cache_v.at[:, :t].set(v)
+            o = attention_core(q, k, v, causal=True, mesh=None,
+                               n_heads=h,
+                               window=getattr(block, "window", None)
+                               ).reshape(b, t, h * hd)
+        with named_scope("attn_out"):
+            proj = jnp.dot(o, p["wo"], precision=prec)
+            if tp_axis is not None:
+                import jax
+                proj = jax.lax.psum(proj, tp_axis)
+            x = x + proj
+        with named_scope("norm2"):
+            f_in = block_norm(jnp, block, p, x, "ln2")
+        with named_scope("ffn"):
+            return x + block_ffn(jnp, block, p, f_in, prec,
+                                 tp_axis=tp_axis), cache_k, cache_v
 
 
 def _block_step(block, p, x_t, cache_k, cache_v, pos, tp=1,
@@ -151,6 +165,7 @@ def _block_step(block, p, x_t, cache_k, cache_v, pos, tp=1,
     ``tp``/``tp_axis``: head-sharded weights + ``kv/tp``-head caches
     inside a shard_map, exactly as :func:`_block_prefill`."""
     import jax.numpy as jnp
+    from jax import named_scope
     from ..ops import matmul_precision
     prec = matmul_precision()
     b, _, d = x_t.shape
@@ -159,44 +174,59 @@ def _block_step(block, p, x_t, cache_k, cache_v, pos, tp=1,
     g = h // kv
     hd = d // block.n_heads
 
-    a_in = block_norm(jnp, block, p, x_t, "ln1")
-    q = jnp.dot(a_in, p["wq"], precision=prec).reshape(b, 1, h, hd)
-    k = jnp.dot(a_in, p["wk"], precision=prec).reshape(b, 1, kv, hd)
-    v = jnp.dot(a_in, p["wv"], precision=prec).reshape(b, 1, kv, hd)
-    if block.rope:
-        base = getattr(block, 'rope_base', 10000.0)
-        q, k = _rope_at(jnp, q, pos, base), _rope_at(jnp, k, pos, base)
-    cache_k = jnp.asarray(cache_k).at[:, pos].set(k[:, 0])
-    cache_v = jnp.asarray(cache_v).at[:, pos].set(v[:, 0])
-    t_max = cache_k.shape[1]
-    # single-row attention over the cache; scores/softmax in f32 like
-    # attention_reference so the step matches the full-window forward.
-    # GQA reads the unrepeated cache through a (kv, group) view of the
-    # query heads — no (B, T, H, Dh) materialization.
-    q5 = q.reshape(b, 1, kv, g, hd).astype(jnp.float32)
-    s = jnp.einsum("bqkgd,btkd->bkgqt", q5,
-                   cache_k.astype(jnp.float32)) / numpy.sqrt(hd)
-    valid = jnp.arange(t_max) <= pos
-    win = getattr(block, "window", None)
-    if win:
-        # sliding window: only the last `win` cached rows are visible
-        valid = valid & (jnp.arange(t_max) > pos - win)
-    valid = valid[None, None, None, None, :]
-    s = jnp.where(valid, s, -1e30)
-    w = jnp.exp(s - s.max(axis=-1, keepdims=True))
-    w = w / w.sum(axis=-1, keepdims=True)
-    o = jnp.einsum("bkgqt,btkd->bqkgd", w,
-                   cache_v.astype(jnp.float32)).astype(x_t.dtype)
-    o = o.reshape(b, 1, h * hd)
-    proj = jnp.dot(o, p["wo"], precision=prec)
-    if tp_axis is not None:
-        import jax
-        proj = jax.lax.psum(proj, tp_axis)
-    x_t = x_t + proj
-    f_in = block_norm(jnp, block, p, x_t, "ln2")
-    return x_t + block_ffn(jnp, block, p, f_in, prec,
-                           tp_axis=tp_axis), \
-        cache_k, cache_v
+    with named_scope(block.name):
+        with named_scope("norm1"):
+            a_in = block_norm(jnp, block, p, x_t, "ln1")
+        with named_scope("attn_qkv"):
+            q = jnp.dot(a_in, p["wq"],
+                        precision=prec).reshape(b, 1, h, hd)
+            k = jnp.dot(a_in, p["wk"],
+                        precision=prec).reshape(b, 1, kv, hd)
+            v = jnp.dot(a_in, p["wv"],
+                        precision=prec).reshape(b, 1, kv, hd)
+        if block.rope:
+            base = getattr(block, 'rope_base', 10000.0)
+            with named_scope("rope"):
+                q = _rope_at(jnp, q, pos, base)
+                k = _rope_at(jnp, k, pos, base)
+        with named_scope("attn"):
+            cache_k = jnp.asarray(cache_k).at[:, pos].set(k[:, 0])
+            cache_v = jnp.asarray(cache_v).at[:, pos].set(v[:, 0])
+            t_max = cache_k.shape[1]
+            # single-row attention over the cache; scores/softmax in
+            # f32 like attention_reference so the step matches the
+            # full-window forward. GQA reads the unrepeated cache
+            # through a (kv, group) view of the query heads — no
+            # (B, T, H, Dh) materialization.
+            q5 = q.reshape(b, 1, kv, g, hd).astype(jnp.float32)
+            s = jnp.einsum("bqkgd,btkd->bkgqt", q5,
+                           cache_k.astype(jnp.float32)) / numpy.sqrt(hd)
+            valid = jnp.arange(t_max) <= pos
+            win = getattr(block, "window", None)
+            if win:
+                # sliding window: only the last `win` cached rows are
+                # visible
+                valid = valid & (jnp.arange(t_max) > pos - win)
+            valid = valid[None, None, None, None, :]
+            s = jnp.where(valid, s, -1e30)
+            w = jnp.exp(s - s.max(axis=-1, keepdims=True))
+            w = w / w.sum(axis=-1, keepdims=True)
+            o = jnp.einsum("bkgqt,btkd->bqkgd", w,
+                           cache_v.astype(jnp.float32)
+                           ).astype(x_t.dtype)
+            o = o.reshape(b, 1, h * hd)
+        with named_scope("attn_out"):
+            proj = jnp.dot(o, p["wo"], precision=prec)
+            if tp_axis is not None:
+                import jax
+                proj = jax.lax.psum(proj, tp_axis)
+            x_t = x_t + proj
+        with named_scope("norm2"):
+            f_in = block_norm(jnp, block, p, x_t, "ln2")
+        with named_scope("ffn"):
+            return x_t + block_ffn(jnp, block, p, f_in, prec,
+                                   tp_axis=tp_axis), \
+                cache_k, cache_v
 
 
 def _embed_ids(stem, params, ids, tp=1, tp_axis=None):
@@ -206,20 +236,21 @@ def _embed_ids(stem, params, ids, tp=1, tp_axis=None):
     this shard owns gather locally, foreign rows contribute EXACT
     zeros, and the psum rebuilds the full embedding bit-exactly (a
     sum of one real row and N-1 exact zeros is the row)."""
+    import jax
     import jax.numpy as jnp
     table = params[stem.name]["table"]
     ids = ids.astype(jnp.int32)
-    if tp_axis is None:
-        return jnp.take(table, ids, axis=0, mode="clip")
-    import jax
-    vloc = table.shape[0]
-    gids = jnp.clip(ids, 0, vloc * tp - 1)
-    local = gids - jax.lax.axis_index(tp_axis) * vloc
-    own = (local >= 0) & (local < vloc)
-    x = jnp.where(own[..., None],
-                  jnp.take(table, jnp.clip(local, 0, vloc - 1),
-                           axis=0), 0)
-    return jax.lax.psum(x, tp_axis)
+    with jax.named_scope(stem.name), jax.named_scope("embed"):
+        if tp_axis is None:
+            return jnp.take(table, ids, axis=0, mode="clip")
+        vloc = table.shape[0]
+        gids = jnp.clip(ids, 0, vloc * tp - 1)
+        local = gids - jax.lax.axis_index(tp_axis) * vloc
+        own = (local >= 0) & (local < vloc)
+        x = jnp.where(own[..., None],
+                      jnp.take(table, jnp.clip(local, 0, vloc - 1),
+                               axis=0), 0)
+        return jax.lax.psum(x, tp_axis)
 
 
 def _embed_prompt(stem, pos_emb, params, ids, pos0=0, tp=1,
@@ -271,14 +302,15 @@ def _head_logits(head, params, x_last, prec, tp_axis=None):
     (bit-exact — every column is one full-depth dot), and a tiled
     all_gather rebuilds the full replicated (…, V) row so sampling
     runs identically on every shard."""
+    import jax
     import jax.numpy as jnp
-    out = (jnp.dot(x_last, params[head.name]["weights"],
-                   precision=prec) + params[head.name]["bias"])
-    if tp_axis is not None:
-        import jax
-        out = jax.lax.all_gather(out, tp_axis, axis=out.ndim - 1,
-                                 tiled=True)
-    return out
+    with jax.named_scope(head.name), jax.named_scope("head"):
+        out = (jnp.dot(x_last, params[head.name]["weights"],
+                       precision=prec) + params[head.name]["bias"])
+        if tp_axis is not None:
+            out = jax.lax.all_gather(out, tp_axis, axis=out.ndim - 1,
+                                     tiled=True)
+        return out
 
 
 def _build_sampler(wf, t_p, n_new, temperature):

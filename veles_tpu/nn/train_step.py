@@ -775,16 +775,22 @@ class TrainStep(AcceleratedUnit):
         if self.mixed_precision:
             batch = self._amp_cast(batch)
 
+        # the scopes name the step's device work in a profiler capture
+        # (`veles_tpu trace self-time`; trace-time only). jax marks the
+        # operations of the backward pass itself, as
+        # transpose(jvp(forward)), which the reader calls "backward"
         def loss_fn(p):
-            if self.mixed_precision:
-                p = self._amp_cast(p)
-            if self.remat:
-                out = jax.checkpoint(
-                    lambda pp, bb: self._forward_pure(pp, bb, True,
-                                                      rng))(p, batch)
-            else:
-                out = self._forward_pure(p, batch, True, rng)
-            return self.evaluator.loss(out, tgt, mask), out
+            with jax.named_scope("forward"):
+                if self.mixed_precision:
+                    p = self._amp_cast(p)
+                if self.remat:
+                    out = jax.checkpoint(
+                        lambda pp, bb: self._forward_pure(
+                            pp, bb, True, rng))(p, batch)
+                else:
+                    out = self._forward_pure(p, batch, True, rng)
+            with jax.named_scope("loss"):
+                return self.evaluator.loss(out, tgt, mask), out
 
         (loss, out), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params)
@@ -792,9 +798,10 @@ class TrainStep(AcceleratedUnit):
         new_params, new_opt = self._apply_updates(params, grads,
                                                   opt_state, lr_scale,
                                                   valid)
-        metrics = self.evaluator.metrics_fn(out, tgt, mask)
-        metrics["sum_loss"] = loss * self.evaluator.sum_loss_weight(
-            out, mask)
+        with jax.named_scope("accumulate"):
+            metrics = self.evaluator.metrics_fn(out, tgt, mask)
+            metrics["sum_loss"] = loss * self.evaluator.sum_loss_weight(
+                out, mask)
         if self._tensormon is not None:
             # auxiliary tensor taps (telemetry/tensormon.py): pure
             # scalars over values this step already computed — extra
@@ -803,9 +810,10 @@ class TrainStep(AcceleratedUnit):
             metrics.update(tensormon.step_stats(
                 params, new_params, grads, loss, out,
                 self._tensormon["sat_threshold"]))
-        accum = jax.tree_util.tree_map(
-            lambda a, m: a + m, accum,
-            {k: metrics[k] for k in accum})
+        with jax.named_scope("accumulate"):
+            accum = jax.tree_util.tree_map(
+                lambda a, m: a + m, accum,
+                {k: metrics[k] for k in accum})
         return new_params, new_opt, accum, loss
 
     def _apply_updates(self, params, grads, opt_state, lr_scale, valid):
@@ -817,13 +825,14 @@ class TrainStep(AcceleratedUnit):
         new_params, new_opt = {}, {}
         for name, p in params.items():
             gd = self._gd_for[name]
-            up_p, up_s = gd.update(p, grads[name], opt_state[name],
-                                   lr_scale)
-            new_params[name] = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(valid, new, old), up_p, p)
-            new_opt[name] = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(valid, new, old), up_s,
-                opt_state[name])
+            with jax.named_scope("optimizer"), jax.named_scope(name):
+                up_p, up_s = gd.update(p, grads[name], opt_state[name],
+                                       lr_scale)
+                new_params[name] = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(valid, new, old), up_p, p)
+                new_opt[name] = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(valid, new, old), up_s,
+                    opt_state[name])
         for name, masks in self.param_masks.items():
             if name in new_params:
                 for k, m in masks.items():

@@ -186,6 +186,10 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float, block_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        # the name is the device operation's in a profiler capture
+        # (veles_flash_fwd.N); chipbench/metrics/flash_step_ms.py and
+        # `veles_tpu trace self-time` find the kernels by it
+        name="veles_flash_fwd",
     )(q, k, v)
 
 
@@ -422,6 +426,7 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="veles_flash_bwd_dkv",
     )(q, do, k, v, lse8, pad8)
     dq, = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
@@ -451,6 +456,7 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="veles_flash_bwd_dq",
     )(q, do, k, v, lse8, pad8)
     return dq, dk, dv
 

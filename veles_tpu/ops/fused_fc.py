@@ -328,6 +328,7 @@ def fused_fc_sgd_epoch(weights: Sequence, biases: Sequence,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="veles_fused_fc",
     )(lr2, xg, yg, *wp, *bp, *vwp, *vbp)
 
     w_o = outs[:L]

@@ -15,6 +15,8 @@ output and sets the event.
 
 from __future__ import annotations
 
+import itertools
+import os
 import threading
 import time
 from http.server import BaseHTTPRequestHandler
@@ -31,7 +33,13 @@ from .resilience import health
 from .resilience.faults import FaultInjected, fire as fire_fault
 from .serving.scheduler import (Ticket as _Ticket, shed_expired,
                                 split_expired)
+from .telemetry.spans import span
 from .units import Unit
+
+
+#: numbers the profile captures of this process, so that two in one
+#: second get directories of their own
+_profile_ids = itertools.count(1)
 
 
 class RESTfulAPI(Unit):
@@ -319,7 +327,8 @@ class GenerationAPI(Unit):
                  artifact: str = None,
                  prefix_cache: bool = None,
                  prefill_chunk: int = None,
-                 state_cache: bool = None, **kwargs) -> None:
+                 state_cache: bool = None,
+                 profile_dir: str = None, **kwargs) -> None:
         super().__init__(workflow, **kwargs)
         self.view_group = "SERVICE"
         #: the TARGET model workflow is the unit's own workflow; an
@@ -376,6 +385,13 @@ class GenerationAPI(Unit):
         # serving"): None defers to root.common.serving.state_cache
         # inside the RecurrentEngine
         self.state_cache = state_cache
+        #: where ``POST <path>/profile`` writes its captures
+        #: (``--profile-dir`` under ``--serve-generate``); None: the
+        #: endpoint answers 403
+        self.profile_dir = (profile_dir if profile_dir is not None
+                            else serving_cfg.get("profile_dir", None))
+        self._profile_lock = threading.Lock()
+        self._profile_stop = threading.Event()
         self._engine = None
         self._service: Optional[HTTPService] = None
         #: serializes initialize()/stop(): a supervisor respawning a
@@ -785,6 +801,7 @@ class GenerationAPI(Unit):
             return res
         if self._service is not None:
             return None
+        self._profile_stop.clear()
         if self.engine_kind == "recurrent" and self._engine is None:
             # operator pinned the O(1)-state lane: a non-recurrent
             # stack degrades to the window worker (same answers, no
@@ -905,6 +922,15 @@ class GenerationAPI(Unit):
                         "already_draining": not started,
                         "in_flight": api._inflight,
                         "queue_depth": len(api._queue)})
+                    return
+                if self.path == api.path + "/profile":
+                    try:
+                        body = read_json_object(self)
+                    except ValueError as e:
+                        json_reply(self, 400, {"error":
+                                               "bad request: %s" % e})
+                        return
+                    json_reply(self, *api.profile(body.get("seconds", 5)))
                     return
                 if self.path != api.path:
                     self.send_error(404)
@@ -1138,7 +1164,11 @@ class GenerationAPI(Unit):
                 sse_headers(self)
 
                 def event(payload):
-                    sse_event(self, payload)
+                    # handler threads: the time the request plane is
+                    # busy serialising and writing (a histogram too,
+                    # telemetry/spans.py SPAN_HISTOGRAMS)
+                    with span("serving.stream.write"):
+                        sse_event(self, payload)
 
                 sent = 0
                 deadline = time.time() + wait_budget + 1.0
@@ -1228,6 +1258,53 @@ class GenerationAPI(Unit):
         """Standalone service: nothing to do per graph pass."""
 
     # -- graceful drain ------------------------------------------------------
+    #: the longest capture ``POST <path>/profile`` takes, seconds
+    PROFILE_MAX_SECONDS = 60.0
+
+    def profile(self, seconds) -> tuple:
+        """``POST <path>/profile {"seconds": N}``: a ``jax.profiler``
+        capture of this running server, N seconds (at most
+        :data:`PROFILE_MAX_SECONDS`) into a fresh subdirectory of
+        ``profile_dir``; the answer comes when the capture is written
+        and names the directory (read it with ``veles_tpu trace
+        self-time DIR``). The engine's tick spans are in it as host
+        annotations, beside the device's operations. 403 unless the
+        server was started with a profile directory, 409 while another
+        capture runs, 400 for a bad ``seconds``. Returns (status,
+        payload)."""
+        if not self.profile_dir:
+            return 403, {"error": "profiling is off: start the server "
+                                  "with --profile-dir"}
+        if isinstance(seconds, bool) \
+                or not isinstance(seconds, (int, float)) \
+                or not seconds > 0:
+            return 400, {"error": "'seconds' must be a positive number"}
+        seconds = min(float(seconds), self.PROFILE_MAX_SECONDS)
+        if not self._profile_lock.acquire(blocking=False):
+            return 409, {"error": "a profile capture is running"}
+        try:
+            import jax
+            directory = os.path.join(
+                self.profile_dir, "%s-%d" % (
+                    time.strftime("%Y%m%d-%H%M%S"), next(_profile_ids)))
+            os.makedirs(directory, exist_ok=True)
+            try:
+                jax.profiler.start_trace(directory)
+            except RuntimeError as e:
+                # the profiler is one to a process: someone else's
+                # session (a benchmark's, devtime.measure's) holds it
+                return 409, {"error": "profiler busy: %s" % e}
+            try:
+                # stop() ends a capture early rather than wait it out
+                self._profile_stop.wait(seconds)
+            finally:
+                jax.profiler.stop_trace()
+            self.info("%s: %.1f s profile capture -> %s", self.name,
+                      seconds, directory)
+            return 200, {"dir": directory, "seconds": seconds}
+        finally:
+            self._profile_lock.release()
+
     def begin_drain(self) -> bool:
         """Stop admission and flip ``/readyz`` to draining (the load
         balancer's cue to spill elsewhere) while in-flight tickets
@@ -1295,6 +1372,7 @@ class GenerationAPI(Unit):
         return drained
 
     def stop(self) -> None:
+        self._profile_stop.set()
         with self._lifecycle:
             from .telemetry import timeseries
             timeseries.remove_gauge_provider("serve.%s" % self.name)

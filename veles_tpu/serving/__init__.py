@@ -90,7 +90,6 @@ SERVING_COUNTERS = (
     "veles_serving_prefill_dispatches_total",
     "veles_serving_decode_dispatches_total",
     "veles_serving_tokens_total",
-    "veles_serving_queue_wait_seconds_total",
     "veles_serving_expired_total",
     "veles_serving_compile_seconds_total",
     "veles_serving_pages_alloc_total",

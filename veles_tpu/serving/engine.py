@@ -806,7 +806,8 @@ class ContinuousEngine(Logger):
                            and self.scheduler.busy_count() == 0
                            and self._handoff is None
                            and not self._closing):
-                        self.scheduler.cv.wait(timeout=5.0)
+                        with span("serving.loop.wait"):
+                            self.scheduler.cv.wait(timeout=5.0)
                         if not self._closing:
                             health.heartbeats.beat(hb)
                     if self._closing:
@@ -875,6 +876,60 @@ class ContinuousEngine(Logger):
                 if death is not None:
                     death()
                 return
+        with span("serving.tick", active=self.scheduler.busy_count()):
+            self._tick_phases()
+
+    def _tick_phases(self) -> None:
+        """The tick proper, one span a phase under ``serving.tick``
+        (``serving.tick.{admit,prefill,prepare,dispatch,device,emit}``,
+        each also a histogram: telemetry/spans.py SPAN_HISTOGRAMS), so
+        that a profiler capture and ``/metrics`` both say where the
+        host's time between two dispatches goes."""
+        with span("serving.tick.prepare"):
+            params = self._tick_params()
+        from .scheduler import shed_expired
+        # co-tenants in flight BEFORE this tick's admissions: only
+        # their decode latency can be stalled by prefill work, so the
+        # chunked-prefill stall gauge measures exactly that window
+        had_inflight = self.scheduler.busy_count() > 0
+        t_prefill = time.time()
+        with span("serving.tick.admit"):
+            if self.qos:
+                # QoS preemption happens HERE, at the step boundary
+                # before admission, so freed slots/pages are handed to
+                # the waiting interactive requests in this same tick
+                self._preempt_for_interactive()
+            admissions, expired = self.scheduler.take_admissions()
+            shed_expired(expired)
+        with span("serving.tick.prefill", admitted=len(admissions)):
+            if not self._admit_all(params, admissions):
+                return
+            self.peak_slots = max(self.peak_slots,
+                                  self.scheduler.busy_count())
+            # _prefill_tick handles its own serve.prefill_chunk fault
+            # internally (sheds ONLY the faulted row, co-tenants keep
+            # decoding) — no blanket abort may wrap it, or one injected
+            # chunk fault would shed the whole pool
+            prefill_work = bool(admissions) | self._prefill_tick(params)
+        if prefill_work and had_inflight:
+            self.prefill_stall_last = time.time() - t_prefill
+            self.prefill_stall_max = max(self.prefill_stall_max,
+                                         self.prefill_stall_last)
+        try:
+            if self._decodable():
+                self._decode(params)
+            if self._active(("speculative",)):
+                self._spec_tick(params)
+            if self.scheduler.active_beams():
+                self._beam_tick(params)
+        except FaultInjected as e:
+            # an injected decode fault DEGRADES: in-flight rows are
+            # shed with Retry-After, the pool stays consistent (the
+            # fault fires before the dispatch)
+            self._abort_active(str(e), code=503, retry_after=1.0)
+
+    def _tick_params(self):
+        """The parameter snapshot this tick decodes on, and the pool."""
         # the param device-view walk (per-array locks) is too heavy to
         # repeat per decode chunk, but a snapshot held forever would
         # serve stale weights after a host-side update. Middle ground:
@@ -889,19 +944,11 @@ class ContinuousEngine(Logger):
             if self.draft is not None:
                 self._draft_params = self._prepare_draft_params()
         self._ensure_pool(params)
-        from .scheduler import shed_expired
-        # co-tenants in flight BEFORE this tick's admissions: only
-        # their decode latency can be stalled by prefill work, so the
-        # chunked-prefill stall gauge measures exactly that window
-        had_inflight = self.scheduler.busy_count() > 0
-        t_prefill = time.time()
-        if self.qos:
-            # QoS preemption happens HERE, at the step boundary
-            # before admission, so freed slots/pages are handed to
-            # the waiting interactive requests in this same tick
-            self._preempt_for_interactive()
-        admissions, expired = self.scheduler.take_admissions()
-        shed_expired(expired)
+        return params
+
+    def _admit_all(self, params, admissions) -> bool:
+        """Admit the taken slots in order; False when an admission
+        failed and the pool was reset (the tick ends there)."""
         for slot in admissions:
             if self.scheduler.slots[slot.idx] is not slot:
                 # already retired within this very loop — an n_new=1
@@ -934,30 +981,8 @@ class ContinuousEngine(Logger):
                                    "admission", code=503,
                                    retry_after=1.0)
                 self._reset_pool()
-                return
-        self.peak_slots = max(self.peak_slots,
-                              self.scheduler.busy_count())
-        # _prefill_tick handles its own serve.prefill_chunk fault
-        # internally (sheds ONLY the faulted row, co-tenants keep
-        # decoding) — no blanket abort may wrap it, or one injected
-        # chunk fault would shed the whole pool
-        prefill_work = bool(admissions) | self._prefill_tick(params)
-        if prefill_work and had_inflight:
-            self.prefill_stall_last = time.time() - t_prefill
-            self.prefill_stall_max = max(self.prefill_stall_max,
-                                         self.prefill_stall_last)
-        try:
-            if self._decodable():
-                self._decode(params)
-            if self._active(("speculative",)):
-                self._spec_tick(params)
-            if self.scheduler.active_beams():
-                self._beam_tick(params)
-        except FaultInjected as e:
-            # an injected decode fault DEGRADES: in-flight rows are
-            # shed with Retry-After, the pool stays consistent (the
-            # fault fires before the dispatch)
-            self._abort_active(str(e), code=503, retry_after=1.0)
+                return False
+        return True
 
     # -- QoS preemption --------------------------------------------------------
     @staticmethod
@@ -1313,8 +1338,6 @@ class ContinuousEngine(Logger):
                                                              0))))
         if resume_k and group is None:
             inc("veles_resume_tokens_total", resume_k)
-        wait = max(0.0, (slot.ticket.admitted or time.time())
-                   - slot.ticket.enqueued)
         with span("serving.prefill", bucket=bucket, slot=slot.idx,
                   t_p=t_p, mode=slot.mode,
                   request_id=slot.ticket.request_id,
@@ -1334,11 +1357,11 @@ class ContinuousEngine(Logger):
             inc("veles_serving_prefill_dispatches_total")
         if group is None:
             if not slot.req.get("_requeued"):
-                # a preempted-and-requeued request was admitted (and
-                # its queue wait counted) once already — exactly-once
-                # accounting holds across preempt → requeue → finish
+                # a preempted-and-requeued request was admitted once
+                # already — exactly-once accounting holds across
+                # preempt → requeue → finish (its queue wait is the
+                # ticket's, veles_serving_queue_wait_seconds)
                 inc("veles_serving_admitted_total")
-                inc("veles_serving_queue_wait_seconds_total", wait)
                 self.admitted += 1
             first = int(first)
             # the int() above synced the prefill dispatch: this step
@@ -1359,7 +1382,6 @@ class ContinuousEngine(Logger):
         # top_k arithmetic nn/beam.py's first expansion runs)
         if slot is group.slots[0]:
             inc("veles_serving_admitted_total")
-            inc("veles_serving_queue_wait_seconds_total", wait)
             self.admitted += 1
             logp0 = jax.nn.log_softmax(
                 jnp.asarray(logits).astype(jnp.float32))
@@ -1469,13 +1491,10 @@ class ContinuousEngine(Logger):
         resume_k = int(slot.req.get("resume_k", 0) or 0)
         if resume_k:
             inc("veles_resume_tokens_total", resume_k)
-        wait = max(0.0, (slot.ticket.admitted or time.time())
-                   - slot.ticket.enqueued)
         if not slot.req.get("_requeued"):
             # preempted-and-requeued rows were counted at their first
             # admission (see _admit) — never twice
             inc("veles_serving_admitted_total")
-            inc("veles_serving_queue_wait_seconds_total", wait)
             self.admitted += 1
         slot.prefilled = start
         self._pos[slot.idx] = start
@@ -1628,45 +1647,55 @@ class ContinuousEngine(Logger):
     # -- the decode chunk ------------------------------------------------------
     def _decode(self, params) -> None:
         import jax.numpy as jnp
-        active = self._grow_or_shed(
-            self._decodable(),
-            lambda s: min(s.t_p + s.n_new,
-                          int(self._pos[s.idx]) + self.decode_block))
-        if not active:
-            return
-        mask = numpy.zeros(self.max_slots, numpy.int32)
-        for slot in active:
-            mask[slot.idx] = 1
-        base_len = {id(s): len(s.tokens) for s in active}
-        fire_fault("serve.decode_step")
+        with span("serving.tick.prepare"):
+            active = self._grow_or_shed(
+                self._decodable(),
+                lambda s: min(s.t_p + s.n_new,
+                              int(self._pos[s.idx]) + self.decode_block))
+            if not active:
+                return
+            mask = numpy.zeros(self.max_slots, numpy.int32)
+            for slot in active:
+                mask[slot.idx] = 1
+            base_len = {id(s): len(s.tokens) for s in active}
+            fire_fault("serve.decode_step")
+            step = self._program("step")
+            host = (jnp.asarray(self._tok), jnp.asarray(self._pos),
+                    jnp.asarray(self._temp), jnp.asarray(mask),
+                    jnp.asarray(self._page_table),
+                    jnp.asarray(self._shared))
         with span("serving.decode_step", active=len(active),
                   chunk=self.decode_block):
-            toks, self._keys, self._caches = self._program("step")(
-                params, jnp.asarray(self._tok), jnp.asarray(self._pos),
-                jnp.asarray(self._temp), jnp.asarray(mask),
-                jnp.asarray(self._page_table),
-                jnp.asarray(self._shared), self._keys,
-                self._caches)
-            toks = numpy.asarray(toks)          # (decode_block, S)
+            with span("serving.tick.dispatch"):
+                toks, self._keys, self._caches = step(
+                    params, *host, self._keys, self._caches)
+                # dropped here, not at the function's end: freeing a
+                # device array yields the interpreter lock, and after
+                # the emit phase every handler thread is waiting for it
+                del host
+            with span("serving.tick.device"):
+                toks = numpy.asarray(toks)      # (decode_block, S)
         inc("veles_serving_decode_dispatches_total")
-        finished: List = []
-        for h in range(toks.shape[0]):
-            still = [s for s in active if s not in finished]
-            if not still:
-                break
-            for slot in still:
-                token = int(toks[h, slot.idx])
-                self._tok[slot.idx] = token
-                self._pos[slot.idx] += 1
-                if slot.record(token):
-                    finished.append(slot)
-        for slot in active:
-            # streaming rows hand this chunk's tokens to their drain
-            # loop at the step boundary — before _finish's terminal
-            # sentinel, so the wire order is tokens-then-done
-            slot.ticket.push_tokens(slot.tokens[base_len[id(slot)]:])
-        for slot in finished:
-            self._finish(slot)
+        with span("serving.tick.emit"):
+            finished: List = []
+            for h in range(toks.shape[0]):
+                still = [s for s in active if s not in finished]
+                if not still:
+                    break
+                for slot in still:
+                    token = int(toks[h, slot.idx])
+                    self._tok[slot.idx] = token
+                    self._pos[slot.idx] += 1
+                    if slot.record(token):
+                        finished.append(slot)
+            for slot in active:
+                # streaming rows hand this chunk's tokens to their
+                # drain loop at the step boundary — before _finish's
+                # terminal sentinel, so the wire order is
+                # tokens-then-done
+                slot.ticket.push_tokens(slot.tokens[base_len[id(slot)]:])
+            for slot in finished:
+                self._finish(slot)
 
     # -- the speculative round -------------------------------------------------
     def _spec_tick(self, params) -> None:
@@ -1678,46 +1707,53 @@ class ContinuousEngine(Logger):
         own accepted lengths inside one fixed-shape dispatch."""
         import jax.numpy as jnp
         gamma = self.spec_gamma
-        active = self._grow_or_shed(
-            self._active(("speculative",)),
-            lambda s: min(s.t_p + s.n_new + gamma + 1,
-                          int(self._pos[s.idx]) + gamma))
-        if not active:
-            return
-        smask = numpy.zeros(self.max_slots, numpy.int32)
-        for slot in active:
-            smask[slot.idx] = 1
-        fire_fault("serve.decode_step")
+        with span("serving.tick.prepare"):
+            active = self._grow_or_shed(
+                self._active(("speculative",)),
+                lambda s: min(s.t_p + s.n_new + gamma + 1,
+                              int(self._pos[s.idx]) + gamma))
+            if not active:
+                return
+            smask = numpy.zeros(self.max_slots, numpy.int32)
+            for slot in active:
+                smask[slot.idx] = 1
+            fire_fault("serve.decode_step")
+            spec = self._program("spec")
+            host = (jnp.asarray(self._tok), jnp.asarray(self._pos),
+                    jnp.asarray(self._temp), jnp.asarray(smask),
+                    jnp.asarray(self._page_table))
         with span("serving.spec_round", active=len(active),
                   gamma=gamma):
-            (out_vec, n_emit, acc, new_tok, self._keys, self._caches,
-             self._draft_caches) = self._program("spec")(
-                params, self._draft_params, jnp.asarray(self._tok),
-                jnp.asarray(self._pos), jnp.asarray(self._temp),
-                jnp.asarray(smask), jnp.asarray(self._page_table),
-                self._keys, self._caches, self._draft_caches)
-            out_vec = numpy.asarray(out_vec)     # (S, gamma)
-            n_emit = numpy.asarray(n_emit)
-            acc = numpy.asarray(acc)
-            new_tok = numpy.asarray(new_tok)
+            with span("serving.tick.dispatch"):
+                (out_vec, n_emit, acc, new_tok, self._keys,
+                 self._caches, self._draft_caches) = spec(
+                    params, self._draft_params, *host, self._keys,
+                    self._caches, self._draft_caches)
+                del host            # as in _decode
+            with span("serving.tick.device"):
+                out_vec = numpy.asarray(out_vec)     # (S, gamma)
+                n_emit = numpy.asarray(n_emit)
+                acc = numpy.asarray(acc)
+                new_tok = numpy.asarray(new_tok)
         inc("veles_serving_decode_dispatches_total")
         inc("veles_serving_spec_rounds_total", len(active))
-        for slot in active:
-            i = slot.idx
-            emitted = int(n_emit[i])
-            slot.rounds += 1
-            slot.acc += int(acc[i])
-            self._pos[i] += emitted
-            self._tok[i] = int(new_tok[i])
-            done = False
-            base = len(slot.tokens)
-            for t in out_vec[i, :emitted]:
-                if slot.record(int(t)):
-                    done = True
-                    break
-            slot.ticket.push_tokens(slot.tokens[base:])
-            if done:
-                self._finish(slot)
+        with span("serving.tick.emit"):
+            for slot in active:
+                i = slot.idx
+                emitted = int(n_emit[i])
+                slot.rounds += 1
+                slot.acc += int(acc[i])
+                self._pos[i] += emitted
+                self._tok[i] = int(new_tok[i])
+                done = False
+                base = len(slot.tokens)
+                for t in out_vec[i, :emitted]:
+                    if slot.record(int(t)):
+                        done = True
+                        break
+                slot.ticket.push_tokens(slot.tokens[base:])
+                if done:
+                    self._finish(slot)
 
     # -- the beam step ---------------------------------------------------------
     def _beam_tick(self, params) -> None:
@@ -1730,60 +1766,66 @@ class ContinuousEngine(Logger):
         frozen-eos lanes, flat top_k), so a pooled beam request's
         tokens equal its solo ``beam_generate`` exactly."""
         import jax.numpy as jnp
-        groups = self.scheduler.active_beams()
-        hyps = [s for g in groups for s in g.slots]
-        alive_slots = self._grow_or_shed(
-            hyps, lambda s: min(s.t_p + max(s.n_new - 1, 1),
-                                int(self._pos[s.idx]) + 1))
-        groups = [g for g in groups
-                  if all(s in alive_slots for s in g.slots)]
-        if not groups:
-            return
-        G, W, P = self._beam_G, self.beam_width, self.pages_per_slot
-        cur = numpy.zeros((G, W), numpy.int32)
-        pos = numpy.zeros(G, numpy.int32)
-        scores = numpy.full((G, W), -numpy.inf, numpy.float32)
-        finished = numpy.zeros((G, W), bool)
-        eosv = numpy.full(G, -1, numpy.int32)
-        gmask = numpy.zeros(G, numpy.int32)
-        tables_g = numpy.zeros((G, W, P), numpy.int32)
-        for gi, group in enumerate(groups):
-            cur[gi] = group.cur
-            pos[gi] = group.t_p + group.step
-            scores[gi] = group.scores
-            finished[gi] = group.finished
-            eosv[gi] = (-1 if group.slots[0].eos_id is None
-                        else int(group.slots[0].eos_id))
-            gmask[gi] = 1
-            for wi, slot in enumerate(group.slots):
-                tables_g[gi, wi] = self._page_table[slot.idx]
-        fire_fault("serve.decode_step")
-        with span("serving.beam_step", groups=len(groups),
-                  width=W):
-            tok, parent, new_scores, new_fin, self._caches = \
-                self._program("beam")(
-                    params, jnp.asarray(cur), jnp.asarray(pos),
+        with span("serving.tick.prepare"):
+            groups = self.scheduler.active_beams()
+            hyps = [s for g in groups for s in g.slots]
+            alive_slots = self._grow_or_shed(
+                hyps, lambda s: min(s.t_p + max(s.n_new - 1, 1),
+                                    int(self._pos[s.idx]) + 1))
+            groups = [g for g in groups
+                      if all(s in alive_slots for s in g.slots)]
+            if not groups:
+                return
+            G, W, P = self._beam_G, self.beam_width, self.pages_per_slot
+            cur = numpy.zeros((G, W), numpy.int32)
+            pos = numpy.zeros(G, numpy.int32)
+            scores = numpy.full((G, W), -numpy.inf, numpy.float32)
+            finished = numpy.zeros((G, W), bool)
+            eosv = numpy.full(G, -1, numpy.int32)
+            gmask = numpy.zeros(G, numpy.int32)
+            tables_g = numpy.zeros((G, W, P), numpy.int32)
+            for gi, group in enumerate(groups):
+                cur[gi] = group.cur
+                pos[gi] = group.t_p + group.step
+                scores[gi] = group.scores
+                finished[gi] = group.finished
+                eosv[gi] = (-1 if group.slots[0].eos_id is None
+                            else int(group.slots[0].eos_id))
+                gmask[gi] = 1
+                for wi, slot in enumerate(group.slots):
+                    tables_g[gi, wi] = self._page_table[slot.idx]
+            fire_fault("serve.decode_step")
+            beam = self._program("beam")
+            host = (jnp.asarray(cur), jnp.asarray(pos),
                     jnp.asarray(scores), jnp.asarray(finished),
                     jnp.asarray(eosv), jnp.asarray(gmask),
-                    jnp.asarray(tables_g), self._caches)
-            tok = numpy.asarray(tok)
-            parent = numpy.asarray(parent)
-            new_scores = numpy.asarray(new_scores)
-            new_fin = numpy.asarray(new_fin)
+                    jnp.asarray(tables_g))
+        with span("serving.beam_step", groups=len(groups),
+                  width=W):
+            with span("serving.tick.dispatch"):
+                tok, parent, new_scores, new_fin, self._caches = \
+                    beam(params, *host, self._caches)
+                del host            # as in _decode
+            with span("serving.tick.device"):
+                tok = numpy.asarray(tok)
+                parent = numpy.asarray(parent)
+                new_scores = numpy.asarray(new_scores)
+                new_fin = numpy.asarray(new_fin)
         inc("veles_serving_decode_dispatches_total")
         inc("veles_serving_beam_steps_total", len(groups))
-        for gi, group in enumerate(groups):
-            i = group.step + 1
-            group.toks = group.toks[parent[gi]].copy()
-            group.toks[:, i] = tok[gi]
-            group.cur = tok[gi].copy()
-            group.scores = new_scores[gi].copy()
-            group.finished = new_fin[gi].copy()
-            group.step = i
-            for slot in group.slots:
-                self._pos[slot.idx] += 1
-            if i >= group.slots[0].n_new - 1:
-                self._finish_beam(group)
+        with span("serving.tick.emit"):
+            for gi, group in enumerate(groups):
+                i = group.step + 1
+                group.toks = group.toks[parent[gi]].copy()
+                group.toks[:, i] = tok[gi]
+                group.cur = tok[gi].copy()
+                group.scores = new_scores[gi].copy()
+                group.finished = new_fin[gi].copy()
+                group.step = i
+                for slot in group.slots:
+                    self._pos[slot.idx] += 1
+                if i >= group.slots[0].n_new - 1:
+                    self._finish_beam(group)
 
     # -- retirement -------------------------------------------------------------
     def _retire_slot(self, slot) -> None:
@@ -2119,9 +2161,11 @@ class ContinuousEngine(Logger):
         row: (pages, page_size, kv, hd) + (P,) -> (P*page_size, kv,
         hd). Unallocated entries point at the sink page; its garbage
         rows sit beyond the causal mask until a write claims them."""
+        import jax
         import jax.numpy as jnp
-        pages = jnp.take(payload, table_row, axis=0, mode="clip")
-        return pages.reshape((-1,) + payload.shape[2:])
+        with jax.named_scope("page_gather"):
+            pages = jnp.take(payload, table_row, axis=0, mode="clip")
+            return pages.reshape((-1,) + payload.shape[2:])
 
     def _row_targets(self, tables, pos, mask):
         """Per-slot (page id, in-page offset) for writing position
@@ -2163,18 +2207,22 @@ class ContinuousEngine(Logger):
         at this slot's page ids (a static-length index slice — the
         program stays fixed-shape). ``scales`` rides along for the
         int8 pool's per-page sidecar."""
+        import jax
         import jax.numpy as jnp
         n_pages = -(-bucket // self.page_size)
         pad = n_pages * self.page_size - bucket
-        if pad:
-            rows = jnp.pad(rows, ((0, pad),) + ((0, 0),) * (rows.ndim - 1))
-        rows = rows.reshape((n_pages, self.page_size) + rows.shape[1:])
-        pool = pool.at[table_row[:n_pages]].set(rows)
-        if scales is None:
-            return pool
-        if pad:
-            scales = jnp.pad(scales, ((0, pad),))
-        return pool, scales.reshape(n_pages, self.page_size)
+        with jax.named_scope("page_writeback"):
+            if pad:
+                rows = jnp.pad(rows,
+                               ((0, pad),) + ((0, 0),) * (rows.ndim - 1))
+            rows = rows.reshape((n_pages, self.page_size)
+                                + rows.shape[1:])
+            pool = pool.at[table_row[:n_pages]].set(rows)
+            if scales is None:
+                return pool
+            if pad:
+                scales = jnp.pad(scales, ((0, pad),))
+            return pool, scales.reshape(n_pages, self.page_size)
 
     # -- program builders ------------------------------------------------------
     def _build_prefill(self, bucket: int):
@@ -2244,14 +2292,15 @@ class ContinuousEngine(Logger):
             x_last = jnp.take(x[0], t_p - 1, axis=0, mode="clip")
             logits = _head_logits(head, params, x_last, prec,
                                   tp_axis=tp_axis)
-            k2 = jax.random.split(seed_key)
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            samp = jax.random.categorical(
-                k2[1], logits / jnp.maximum(temp, _TEMP_EPS)
-            ).astype(jnp.int32)
-            first = jnp.where(temp > 0, samp, greedy)
-            keys = jax.lax.dynamic_update_slice(keys, k2[0][None],
-                                                (slot, 0))
+            with jax.named_scope("sample"):
+                k2 = jax.random.split(seed_key)
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                samp = jax.random.categorical(
+                    k2[1], logits / jnp.maximum(temp, _TEMP_EPS)
+                ).astype(jnp.int32)
+                first = jnp.where(temp > 0, samp, greedy)
+                keys = jax.lax.dynamic_update_slice(
+                    keys, k2[0][None], (slot, 0))
             return first, logits, keys, tuple(new_caches)
 
         if tp <= 1:
@@ -2347,16 +2396,18 @@ class ContinuousEngine(Logger):
                 # carry/subkey convention solo and batched generate
                 # use — advanced only for rows this step owns, so
                 # co-tenant spec rows keep their own stream positions
-                keys2, subs = _split_rows(keys)
-                keys = jnp.where(mask[:, None] > 0, keys2, keys)
-                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                samp = jax.vmap(jax.random.categorical)(
-                    subs,
-                    logits / jnp.maximum(temp, _TEMP_EPS)[:, None]
-                ).astype(jnp.int32)
-                nxt = jnp.where(temp > 0, samp, greedy)
-                nxt = jnp.where(mask > 0, nxt, tok)
-                return nxt, pos + (mask > 0), keys
+                with jax.named_scope("sample"):
+                    keys2, subs = _split_rows(keys)
+                    keys = jnp.where(mask[:, None] > 0, keys2, keys)
+                    greedy = jnp.argmax(logits,
+                                        axis=-1).astype(jnp.int32)
+                    samp = jax.vmap(jax.random.categorical)(
+                        subs,
+                        logits / jnp.maximum(temp, _TEMP_EPS)[:, None]
+                    ).astype(jnp.int32)
+                    nxt = jnp.where(temp > 0, samp, greedy)
+                    nxt = jnp.where(mask > 0, nxt, tok)
+                    return nxt, pos + (mask > 0), keys
 
             if not quant_kv:
                 # CHUNK-VIEW formulation: gather each row's logical
@@ -2402,17 +2453,19 @@ class ContinuousEngine(Logger):
                 # leading SHARED (prefix-adopted) pages go to the sink
                 # — a shared page is structurally read-only here, so a
                 # retired (or live) writer can never mutate one
-                keep = (mask[:, None] > 0) & (
-                    jnp.arange(tables.shape[1])[None, :]
-                    >= shared[:, None])
-                wtab = jnp.where(keep, tables, 0).reshape(-1)  # (S*P,)
-                new_caches = []
-                for (kp, vp), (ck, cv) in zip(caches, views):
-                    shape = (wtab.shape[0],
-                             self.page_size) + kp.shape[2:]
-                    kp = kp.at[wtab].set(ck.reshape(shape))
-                    vp = vp.at[wtab].set(cv.reshape(shape))
-                    new_caches.append((kp, vp))
+                with jax.named_scope("page_writeback"):
+                    keep = (mask[:, None] > 0) & (
+                        jnp.arange(tables.shape[1])[None, :]
+                        >= shared[:, None])
+                    wtab = jnp.where(keep, tables,
+                                     0).reshape(-1)        # (S*P,)
+                    new_caches = []
+                    for (kp, vp), (ck, cv) in zip(caches, views):
+                        shape = (wtab.shape[0],
+                                 self.page_size) + kp.shape[2:]
+                        kp = kp.at[wtab].set(ck.reshape(shape))
+                        vp = vp.at[wtab].set(cv.reshape(shape))
+                        new_caches.append((kp, vp))
                 return toks, keys, tuple(new_caches)
 
             # int8 pool: per-step gather/scatter — the read has to
@@ -2454,11 +2507,12 @@ class ContinuousEngine(Logger):
 
                     x, kn, ksn, vn, vsn = jax.vmap(rowq)(
                         x, tables, pos)
-                    pg, off = self._row_targets(tables, pos, mask)
-                    kq = kq.at[pg, off].set(kn)
-                    vq = vq.at[pg, off].set(vn)
-                    ks = ks.at[pg, off].set(ksn)
-                    vs = vs.at[pg, off].set(vsn)
+                    with jax.named_scope("page_writeback"):
+                        pg, off = self._row_targets(tables, pos, mask)
+                        kq = kq.at[pg, off].set(kn)
+                        vq = vq.at[pg, off].set(vn)
+                        ks = ks.at[pg, off].set(ksn)
+                        vs = vs.at[pg, off].set(vsn)
                     new_caches.append((kq, vq, ks, vs))
                 nxt, pos, keys = sample_next(tok, pos, keys, x)
                 return (nxt, pos, keys, tuple(new_caches)), nxt

@@ -604,8 +604,6 @@ class RecurrentEngine(Logger):
         resume_k = int(slot.req.get("resume_k", 0) or 0)
         if resume_k:
             inc("veles_resume_tokens_total", resume_k)
-        wait = max(0.0, (slot.ticket.admitted or time.time())
-                   - slot.ticket.enqueued)
         seed = int(slot.req.get("seed", 0))
         # -- chunked scan over the (unmatched) prompt ----------------
         snaps: Dict[int, Tuple] = {}
@@ -653,7 +651,6 @@ class RecurrentEngine(Logger):
             # a preempt-requeue is the SAME admitted request coming
             # back — count it once, at its first admission
             inc("veles_serving_admitted_total")
-            inc("veles_serving_queue_wait_seconds_total", wait)
             self.admitted += 1
         first = int(first)
         slot.ticket.mark_prefill_done()

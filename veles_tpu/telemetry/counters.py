@@ -108,8 +108,6 @@ DESCRIPTIONS = {
         "engine",
     "veles_serving_tokens_total":
         "Tokens emitted by the continuous-batching engine",
-    "veles_serving_queue_wait_seconds_total":
-        "Seconds requests waited in the serving queue before a slot",
     "veles_serving_expired_total":
         "Queued generation requests answered 503 past their deadline",
     "veles_serving_pages_alloc_total":
@@ -383,6 +381,13 @@ DESCRIPTIONS = {
 }
 
 
+#: buckets of the span-fed histograms below (telemetry/spans.py
+#: SPAN_HISTOGRAMS): 50 us to 1 s, since a phase of a serving tick is
+#: tens of microseconds when it has nothing to do and the device wait
+#: tens of milliseconds
+SPAN_BUCKETS = (0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
+                0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0)
+
 #: canonical histogram names: HELP string + FIXED bucket upper bounds
 #: (seconds). Same registration discipline as DESCRIPTIONS — every
 #: ``observe("veles_*")`` call site must appear here with HELP and
@@ -418,6 +423,56 @@ HISTOGRAMS = {
                 "answered ticket, per retired request",
         "buckets": (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                     5.0, 10.0, 30.0, 60.0, 120.0),
+    },
+    # the serving tick from inside (serving/engine.py _tick, one span
+    # a phase; observed on span close, telemetry/spans.py
+    # SPAN_HISTOGRAMS). Unlabelled, one sample a tick and phase, and
+    # no second is in two of them: their _sum series add up to the
+    # tick thread's time
+    "veles_serving_tick_admit_seconds": {
+        "help": "Serving tick, admission phase: QoS preemption, "
+                "taking admissions off the queue, shedding expired "
+                "requests",
+        "buckets": SPAN_BUCKETS,
+    },
+    "veles_serving_tick_prefill_seconds": {
+        "help": "Serving tick, prefill phase: admitting the taken "
+                "requests (each one's prefill dispatch and first "
+                "token) and one chunk of every chunk-prefilling row",
+        "buckets": SPAN_BUCKETS,
+    },
+    "veles_serving_tick_prepare_seconds": {
+        "help": "Serving tick, host preparation: the parameter "
+                "snapshot and pool check at the tick's start, then "
+                "page growth, the slot mask and the uploads of the "
+                "step's host arrays before each dispatch",
+        "buckets": SPAN_BUCKETS,
+    },
+    "veles_serving_tick_dispatch_seconds": {
+        "help": "Serving tick, dispatch: the call of the decode "
+                "(speculative, beam) program until it returns",
+        "buckets": SPAN_BUCKETS,
+    },
+    "veles_serving_tick_device_seconds": {
+        "help": "Serving tick, device wait: the host blocked "
+                "fetching the step's tokens from the device",
+        "buckets": SPAN_BUCKETS,
+    },
+    "veles_serving_tick_emit_seconds": {
+        "help": "Serving tick, emission: recording the step's tokens "
+                "per slot, pushing them to the streams, retiring "
+                "finished requests",
+        "buckets": SPAN_BUCKETS,
+    },
+    "veles_serving_loop_wait_seconds": {
+        "help": "Serving loop idle: the tick thread waiting for work "
+                "between ticks (zero when saturated)",
+        "buckets": SPAN_BUCKETS,
+    },
+    "veles_serving_stream_write_seconds": {
+        "help": "Serialising and writing one SSE event of a streamed "
+                "reply, per event, all handler threads together",
+        "buckets": SPAN_BUCKETS,
     },
 }
 
@@ -599,6 +654,15 @@ class CounterRegistry:
     def get(self, name: str) -> float:
         with self._lock:
             return self._values.get(name, 0)
+
+    def read(self, names: Tuple[str, ...]) -> List[float]:
+        """The values of ``names`` and nothing else of the registry (a
+        span reads its four counters on begin and on end:
+        telemetry/spans.py). Each value is read whole; the lock that
+        would make the four one instant's is not taken, since a span's
+        deltas are of what ran inside it on its own thread."""
+        get = self._values.get
+        return [get(k, 0) for k in names]
 
     def snapshot(self) -> Dict[str, float]:
         """Point-in-time copy of every counter."""

@@ -248,34 +248,53 @@ def device_self_time(events: Iterable[Dict[str, Any]]
             "n_events": n}
 
 
+#: the names the program's own spans carry (telemetry/spans.py enters a
+#: ``jax.profiler.TraceAnnotation`` of the span's name, so a capture's
+#: host plane holds them on the device operations' own timeline)
+SPAN_PREFIXES = ("serving.", "train_step.", "unit.", "workflow.")
+
+
+def annotation_spans(events: Iterable[Dict[str, Any]]
+                     ) -> List[Dict[str, Any]]:
+    """The program's spans as a Chrome trace holds them: complete
+    events off the device processes whose name carries one of
+    :data:`SPAN_PREFIXES`, as span records on the trace's own clock
+    (``ts`` and ``dur`` in seconds)."""
+    events = list(events)
+    procs, _ = _metadata(events)
+    dev_pids = {pid for pid, name in procs.items()
+                if _is_device_process(name)}
+    return [{"name": ev["name"], "ts": float(ev.get("ts", 0.0)) / 1e6,
+             "dur": float(ev.get("dur", 0.0)) / 1e6}
+            for ev in events
+            if ev.get("ph") == "X" and ev.get("pid") not in dev_pids
+            and str(ev.get("name", "")).startswith(SPAN_PREFIXES)]
+
+
 def attribute_spans(events: Iterable[Dict[str, Any]],
-                    span_records: Iterable[Dict[str, Any]],
-                    offset_us: Optional[float] = None
+                    span_records: Optional[Iterable[Dict[str, Any]]]
+                    = None, offset_us: float = 0.0
                     ) -> Dict[str, Dict[str, float]]:
     """Device self-time per telemetry span NAME: for every span record
-    (``{"name", "ts" (epoch s), "dur" (s)}`` — the
-    :mod:`~veles_tpu.telemetry.spans` schema), the interval union of
+    (``{"name", "ts" (s), "dur" (s)}``), the interval union of
     device-stream events overlapping the span's window, clipped to it.
 
-    The two clocks differ: spans carry host epoch seconds, profiler
-    events carry trace-clock microseconds. ``offset_us`` is
-    ``device_ts − host_ts·1e6`` for one common instant; when None it
-    is estimated by aligning the earliest device event to the
-    earliest span start — exact enough when the capture brackets the
-    spans (how :func:`measure` uses it), stated here because it IS an
-    approximation. Same-name spans aggregate; a parent span's window
-    includes its children's (self-time here is *device* self-time per
-    span window, not host-tree-exclusive time)."""
+    ``span_records`` None: the spans are the capture's own
+    (:func:`annotation_spans`), put on the profiler's clock by the
+    profiler itself, so nothing is estimated. Records from another
+    clock (the span ring's epoch seconds) need the explicit
+    ``offset_us`` = ``device_ts − host_ts·1e6`` of one common instant.
+    Same-name spans aggregate; a parent span's window includes its
+    children's (self-time here is *device* self-time per span window,
+    not host-tree-exclusive time)."""
+    events = list(events)
+    if span_records is None:
+        span_records = annotation_spans(events)
     span_records = [r for r in span_records
                     if "name" in r and "ts" in r]
     devs = [(float(e.get("ts", 0.0)),
              float(e.get("ts", 0.0)) + float(e.get("dur", 0.0)))
             for e in device_events(events)]
-    if offset_us is None:
-        if not devs or not span_records:
-            return {}
-        offset_us = (min(s for s, _ in devs)
-                     - min(float(r["ts"]) for r in span_records) * 1e6)
     out: Dict[str, Dict[str, float]] = {}
     for rec in span_records:
         s0 = float(rec["ts"]) * 1e6 + offset_us
@@ -288,6 +307,345 @@ def attribute_spans(events: Iterable[Dict[str, Any]],
         row["device_time_s"] += _interval_union_us(clipped) / 1e6
         row["spans"] += 1
         row["events"] += len(clipped)
+    return out
+
+
+# -- reading a profiler capture (.xplane.pb) ---------------------------------
+#
+# A capture is handled in a plain form, a list of planes ``{"name",
+# "lines": [{"name", "events": [(name, start_ns, duration_ns, tag)]}]}``:
+# for a device plane the "XLA Ops" and "XLA Modules" lines, ``tag`` an
+# operation's HLO metadata ``op_name`` (the jax name stack, scopes
+# included); for the host plane one line a thread holding only the
+# program's own spans (:data:`SPAN_PREFIXES`), ``tag`` unused.
+
+DEVICE_PLANE = "/device:"
+HOST_PLANE = "/host:"
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+#: the event stats that carry an operation's ``op_name`` metadata, in
+#: the order tried (``tf_op`` in the planes the TPU profiler writes)
+OP_NAME_STATS = ("tf_op", "op_name", "long_name")
+NO_SCOPE = "(no scope)"
+NO_SPAN = "(no span)"
+#: name-stack segments that are control flow, not scopes
+_STRUCTURAL = frozenset(("while", "body", "cond", "closed_call",
+                         "checkpoint", "rematted_computation",
+                         "custom_jvp_call", "custom_vjp_call",
+                         "custom_vjp_call_jaxpr", "core_call",
+                         "remat", "pjit", "shard_map"))
+KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+
+
+def find_capture(logdir: str) -> Optional[str]:
+    """The newest ``.xplane.pb`` under a ``jax.profiler`` log directory
+    (or one of its run directories), None when there is none."""
+    import glob as _glob
+    paths = [p for pat in (
+        os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb"),
+        os.path.join(logdir, "*.xplane.pb"))
+        for p in _glob.glob(pat)]
+    return max(paths, key=os.path.getmtime) if paths else None
+
+
+def _varint(buf, i: int) -> Tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        if byte < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf, start: int, end: int):
+    """(field number, value) of one protobuf message in ``buf[start:
+    end]``: a varint's value, or the (start, end) of a length-delimited
+    field; fixed-width fields are skipped."""
+    i = start
+    while i < end:
+        tag, i = _varint(buf, i)
+        kind = tag & 7
+        if kind == 0:
+            value, i = _varint(buf, i)
+        elif kind == 2:
+            size, i = _varint(buf, i)
+            value = (i, i + size)
+            i += size
+        elif kind in (1, 5):
+            i += 8 if kind == 1 else 4
+            continue
+        else:
+            raise ValueError("not an xplane: wire type %d" % kind)
+        yield tag >> 3, value
+
+
+def _text(buf, span: Tuple[int, int]) -> str:
+    return bytes(buf[span[0]:span[1]]).decode("utf-8", "replace")
+
+
+def _map_entries(buf, plane: Tuple[int, int], field: int):
+    """The value messages of a ``map<int64, Message>`` field of an
+    XPlane: [(start, end)]."""
+    for number, entry in _fields(buf, *plane):
+        if number == field:
+            for key, value in _fields(buf, *entry):
+                if key == 2:
+                    yield value
+
+
+def load_capture(path: str) -> List[Dict[str, Any]]:
+    """An ``.xplane.pb`` in the plain form. ``jax.profiler.ProfileData``
+    shows an event's own stats only, and an operation's ``op_name`` is a
+    stat (``tf_op``) of its event *metadata*, so the file's few message
+    kinds (XSpace, XPlane, XLine, XEvent, XEventMetadata, XStat,
+    XStatMetadata of tsl's ``xplane.proto``) are read here directly."""
+    with open(path, "rb") as f:
+        buf = memoryview(f.read())
+    planes = []
+    for number, plane in _fields(buf, 0, len(buf)):
+        if number != 1:
+            continue
+        name = next((_text(buf, v) for n, v in _fields(buf, *plane)
+                     if n == 2), "")
+        device = name.startswith(DEVICE_PLANE)
+        if not device and not name.startswith(HOST_PLANE):
+            continue
+        stat_names = {}
+        for meta in _map_entries(buf, plane, 5):
+            row = dict(_fields(buf, *meta))
+            if 1 in row and 2 in row:
+                stat_names[row[1]] = _text(buf, row[2])
+        wanted = {i for i, n in stat_names.items() if n in OP_NAME_STATS}
+        # event metadata: id -> (name, op_name); on the host only the
+        # program's own spans are kept
+        events_meta = {}
+        for meta in _map_entries(buf, plane, 4):
+            ident, label, tag = None, "", ""
+            for n, v in _fields(buf, *meta):
+                if n == 1:
+                    ident = v
+                elif n == 2:
+                    label = _text(buf, v)
+                elif n == 5 and device:
+                    stat = dict(_fields(buf, *v))
+                    if stat.get(1) in wanted and 5 in stat:
+                        tag = _text(buf, stat[5])
+            if device or label.startswith(SPAN_PREFIXES):
+                events_meta[ident] = (label, tag)
+        lines = []
+        for number, line in _fields(buf, *plane):
+            if number != 3:
+                continue
+            head, raw = {}, []
+            for n, v in _fields(buf, *line):
+                if n == 4:
+                    raw.append(v)
+                else:
+                    head[n] = v
+            line_name = _text(buf, head[2]) if 2 in head else ""
+            if device and line_name not in (OPS_LINE, MODULES_LINE):
+                continue
+            base_ps = head.get(3, 0) * 1000
+            events = []
+            for v in raw:
+                ev = dict(_fields(buf, *v))
+                meta = events_meta.get(ev.get(1))
+                if meta is not None:
+                    events.append((meta[0],
+                                   (base_ps + ev.get(2, 0)) // 1000,
+                                   ev.get(3, 0) // 1000, meta[1]))
+            if events:
+                lines.append({"name": line_name, "events": events})
+        if lines:
+            planes.append({"name": name, "lines": lines})
+    return planes
+
+
+def _segments(op_name: str) -> List[str]:
+    """An ``op_name`` split at the slashes outside parentheses."""
+    out, depth, cur = [], 0, []
+    for ch in op_name:
+        if ch == "/" and depth == 0:
+            out.append("".join(cur))
+            cur = []
+            continue
+        depth += (ch == "(") - (ch == ")")
+        cur.append(ch)
+    out.append("".join(cur))
+    return out
+
+
+def scope_of(op_name: str, depth: int = 2) -> str:
+    """The named scopes of an operation's ``op_name`` metadata, cut to
+    ``depth`` levels: ``jit(step)/while/body/closed_call/vmap(blk3)/
+    ffn/dot_general`` is ``blk3/ffn``. Function names (``jit(..)``),
+    control flow and the primitive at the end are no scopes; a
+    transform around a scope (``vmap(blk3)``, ``jvp(forward)``) is
+    peeled off, and the backward pass, which jax marks
+    ``transpose(jvp(forward))``, reads ``backward``. Where XLA merged
+    two operations' names (``a;b``) the first stands."""
+    scopes, backward = [], False
+    for seg in _segments(op_name.split(";")[0])[:-1]:
+        transforms = []
+        while seg.endswith(")") and "(" in seg:
+            head, _, seg = seg.partition("(")
+            seg = seg[:-1]
+            transforms.append(head)
+        if not seg or seg in _STRUCTURAL \
+                or any(t in ("jit", "pjit") for t in transforms):
+            continue
+        backward = backward or "transpose" in transforms
+        scopes.append(seg)
+    if backward:
+        scopes = ["backward"] + scopes[scopes[:1] == ["forward"]:]
+    return "/".join(scopes[:depth]) or NO_SCOPE
+
+
+def _program(module_event: str) -> str:
+    """``jit_step(9886478132021696463)`` is ``jit_step``."""
+    return module_event.split("(")[0].strip()
+
+
+def _op_label(event_name: str) -> str:
+    """An operation's event name is its whole HLO line on the TPU:
+    keep the result's name (``%fusion.17 = ...`` is ``fusion.17``)."""
+    return event_name.partition(" = ")[0].lstrip("%")[:80]
+
+
+def _innermost(spans):
+    """[(time_ns, name or None)] in time order: from each instant on,
+    the innermost of one thread's (properly nested) spans."""
+    marks = []
+    for name, start, dur, _ in spans:
+        marks.append((start, 1, name))
+        marks.append((start + dur, 0, name))
+    # at one instant ends go before starts; a parent starts before its
+    # child and ends after it
+    marks.sort(key=lambda m: (m[0], m[1]))
+    out, stack = [], []
+    for t, is_start, name in marks:
+        if is_start:
+            stack.append(name)
+        elif stack:
+            stack.pop()
+        out.append((t, stack[-1] if stack else None))
+    return out
+
+
+def summarize_capture(planes: List[Dict[str, Any]], depth: int = 2
+                      ) -> Dict[str, Any]:
+    """Three tables of a capture in the plain form: device time by
+    program (``programs``: name -> [calls, seconds]); by scope within
+    each program (``scopes``: (program, scope) -> seconds; a Pallas
+    call counts under its kernel's name; ``unnamed``: operation ->
+    seconds of what no scope names); and the device's idle gaps by the
+    host span that covers each gap's middle (``gaps``: span name ->
+    [gaps, seconds]), the spans being those of the thread that recorded
+    the most, which is the dispatching one (a server's handler threads
+    each write one event a step). Seconds are summed over the device
+    planes; ``busy_s`` and ``window_s`` likewise."""
+    import bisect
+    host_lines = [ln["events"] for p in planes
+                  if p["name"].startswith(HOST_PLANE)
+                  for ln in p["lines"]]
+    marks = _innermost(max(host_lines, key=len)) if host_lines else []
+    mark_times = [t for t, _ in marks]
+    programs: Dict[str, List[float]] = {}
+    scopes: Dict[Tuple[str, str], float] = {}
+    unnamed: Dict[str, float] = {}
+    gaps: Dict[str, List[float]] = {}
+    busy = window = 0.0
+    for plane in planes:
+        if not plane["name"].startswith(DEVICE_PLANE):
+            continue
+        lines = {ln["name"]: ln["events"] for ln in plane["lines"]}
+        mods = sorted(lines.get(MODULES_LINE, ()), key=lambda e: e[1])
+        mod_starts = [e[1] for e in mods]
+        for name, _, dur, _ in mods:
+            row = programs.setdefault(_program(name), [0, 0.0])
+            row[0] += 1
+            row[1] += dur / 1e9
+        ops = sorted(lines.get(OPS_LINE, ()), key=lambda e: e[1])
+        cur_end = None
+        for name, start, dur, op_name in ops:
+            j = bisect.bisect_right(mod_starts, start) - 1
+            program = (_program(mods[j][0])
+                       if j >= 0 and start < mods[j][1] + mods[j][2]
+                       else "(no program)")
+            if KERNEL_TARGET in name:
+                # a Pallas call: its kernel's name is the result's
+                scope = _op_label(name).rsplit(".", 1)[0]
+            else:
+                scope = scope_of(op_name, depth)
+            if scope == NO_SCOPE:
+                label = _op_label(name)
+                unnamed[label] = unnamed.get(label, 0.0) + dur / 1e9
+            key = (program, scope)
+            scopes[key] = scopes.get(key, 0.0) + dur / 1e9
+            if cur_end is not None and start > cur_end:
+                mid = (cur_end + start) // 2
+                i = bisect.bisect_right(mark_times, mid) - 1
+                span = (marks[i][1] if i >= 0 else None) or NO_SPAN
+                row = gaps.setdefault(span, [0, 0.0])
+                row[0] += 1
+                row[1] += (start - cur_end) / 1e9
+            if cur_end is None or start + dur > cur_end:
+                covered = start if cur_end is None else max(start, cur_end)
+                busy += (start + dur - covered) / 1e9
+                cur_end = start + dur
+        if ops:
+            window += (cur_end - ops[0][1]) / 1e9
+    return {"programs": programs, "scopes": scopes, "unnamed": unnamed,
+            "gaps": gaps, "busy_s": busy, "window_s": window}
+
+
+def format_capture(summary: Dict[str, Any], top: int = 12) -> List[str]:
+    """The three tables of :func:`summarize_capture` as text lines."""
+    busy, window = summary["busy_s"], summary["window_s"]
+    out = ["device busy %.6f s of %.6f s between its first and last "
+           "operation (idle %.2f %%)"
+           % (busy, window, 100.0 * (1.0 - busy / window) if window
+              else 0.0)]
+    programs = summary["programs"]
+    out.append("device time by program:")
+    for name, (calls, secs) in sorted(programs.items(),
+                                      key=lambda kv: -kv[1][1])[:top]:
+        out.append("  %-44s %6d call(s) %10.6f s %9.3f ms/call"
+                   % (name, calls, secs, 1000.0 * secs / calls))
+    out.append("device time by scope (ms a call of its program; "
+               "Pallas calls by kernel name):")
+    by_program: Dict[str, List[Tuple[str, float]]] = {}
+    for (program, scope), secs in summary["scopes"].items():
+        by_program.setdefault(program, []).append((scope, secs))
+    named = total = 0.0
+    for program, rows in sorted(by_program.items(),
+                                key=lambda kv: -sum(r[1] for r in kv[1])):
+        calls = programs.get(program, [0, 0.0])[0] or 1
+        whole = sum(secs for _, secs in rows)
+        out.append("  %s (%.6f s in operations):" % (program, whole))
+        for scope, secs in sorted(rows, key=lambda r: -r[1])[:top]:
+            out.append("    %-42s %10.6f s %9.3f ms/call %5.1f %%"
+                       % (scope, secs, 1000.0 * secs / calls,
+                          100.0 * secs / whole if whole else 0.0))
+        total += whole
+        named += sum(secs for scope, secs in rows if scope != NO_SCOPE)
+    if total:
+        out.append("  %.1f %% of device time is under a named scope or "
+                   "kernel; the rest by operation:"
+                   % (100.0 * named / total))
+        for label, secs in sorted(summary["unnamed"].items(),
+                                  key=lambda kv: -kv[1])[:top]:
+            out.append("    %-42s %10.6f s" % (label, secs))
+    out.append("idle gaps by host span (the innermost span of the "
+               "dispatching thread over each gap's middle):")
+    idle = sum(secs for _, secs in summary["gaps"].values())
+    for span, (count, secs) in sorted(summary["gaps"].items(),
+                                      key=lambda kv: -kv[1][1])[:top]:
+        out.append("  %-44s %6d gap(s) %10.6f s %5.1f %%"
+                   % (span, count, secs,
+                      100.0 * secs / idle if idle else 0.0))
     return out
 
 
@@ -327,9 +685,7 @@ def profiler_usable() -> bool:
 
 
 def measure(fn: Callable[[], Any], sync: Callable[[], Any],
-            calls: int = 1,
-            span_records: Optional[List[Dict[str, Any]]] = None
-            ) -> Dict[str, Any]:
+            calls: int = 1) -> Dict[str, Any]:
     """ONE device-time measurement: run ``fn`` ``calls`` times between
     scalar-fetch syncs. Returns::
 
@@ -340,9 +696,8 @@ def measure(fn: Callable[[], Any], sync: Callable[[], Any],
     Profiler path (when usable): the run is captured with
     ``jax.profiler``, the trace-event stream parsed for device-stream
     self-time (``veles_devtime_captures_total``) and attributed onto
-    the telemetry spans that closed inside the window
-    (``span_records``; default: the global span recorder's records
-    from the capture window) under ``out["spans"]``. A capture with no
+    the telemetry spans the capture itself holds
+    (:func:`annotation_spans`) under ``out["spans"]``. A capture with no
     device streams disables the profiler for the process and falls
     back. Fallback: the synced wall time IS the device-time estimate
     (upper bound by one host round trip per call —
@@ -350,7 +705,6 @@ def measure(fn: Callable[[], Any], sync: Callable[[], Any],
     fused program per call), counted
     ``veles_devtime_fallbacks_total``."""
     sync()
-    t0_epoch = time.time()
     started = False
     tmpdir = None
     if profiler_usable():
@@ -390,15 +744,11 @@ def measure(fn: Callable[[], Any], sync: Callable[[], Any],
                "device_time_per_call": parsed["device_time_s"] / calls,
                "source": "profiler",
                "by_stream": parsed["by_stream"]}
-        if span_records is None:
-            # attribute onto the telemetry spans that closed inside
-            # THIS window — the existing span names are the section
-            # vocabulary the gate and `trace self-time` share
-            from .spans import recorder as _span_recorder
-            span_records = [r for r in _span_recorder.records()
-                            if r.get("ts", 0) >= t0_epoch]
-        if span_records:
-            out["spans"] = attribute_spans(events, span_records)
+        # the telemetry span names are the section vocabulary the gate
+        # and `trace self-time` share
+        spans = attribute_spans(events)
+        if spans:
+            out["spans"] = spans
         return out
     if started:
         _disable_profiler("capture carried no device-stream events "

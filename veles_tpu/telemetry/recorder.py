@@ -52,6 +52,7 @@ from ..config import root
 # the package is unreliable during (and after) package init
 from .counters import add_inc_hook as _add_inc_hook
 from .counters import inc as _counter_inc
+from .spans import SPAN_HISTOGRAMS as _SPAN_HISTOGRAMS
 from .spans import add_close_hook as _add_close_hook
 # the ONE request-correlation predicate (spans.py owns it), re-
 # exported here because `blackbox inspect --request` is its flight-
@@ -321,6 +322,10 @@ def _on_counter(name: str, value: float, total: float) -> None:
 
 
 def _on_span_close(rec: Dict[str, Any]) -> None:
+    if rec.get("name") in _SPAN_HISTOGRAMS:
+        # the phases of a serving tick close nine times a tick; their
+        # histograms keep them, and the black box keeps its horizon
+        return
     ev = {"name": rec.get("name"), "dur": rec.get("dur"),
           "tid": rec.get("tid")}
     for key in ("unit", "workflow", "error", "steps", "counters"):

@@ -32,11 +32,12 @@ import functools
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Deque, Dict, Iterable, List, Optional, Tuple
 
-from .counters import counters
+from .counters import counters, observe
 
 #: counters whose per-span deltas ride in every span record; the rest
 #: of the registry is process-global only (a span that moved no bytes
@@ -44,7 +45,52 @@ from .counters import counters
 SPAN_COUNTERS = ("veles_dispatches_total", "veles_compiles_total",
                  "veles_h2d_bytes_total", "veles_d2h_bytes_total")
 
+#: span names whose duration is also observed, on close, into an
+#: UNLABELLED histogram of counters.HISTOGRAMS: the phases of one
+#: serving tick (serving/engine.py ``_tick``), the loop's idle wait and
+#: a handler thread's SSE write. The parent ``serving.tick`` and the
+#: inner ``serving.prefill*`` spans are deliberately absent, so the
+#: phase sums never count a second twice. Readers: chipbench/metrics/
+#: tick_ms.py, stream_write_ms.py (the ``_sum`` series on /metrics).
+SPAN_HISTOGRAMS = {
+    "serving.tick.admit": "veles_serving_tick_admit_seconds",
+    "serving.tick.prefill": "veles_serving_tick_prefill_seconds",
+    "serving.tick.prepare": "veles_serving_tick_prepare_seconds",
+    "serving.tick.dispatch": "veles_serving_tick_dispatch_seconds",
+    "serving.tick.device": "veles_serving_tick_device_seconds",
+    "serving.tick.emit": "veles_serving_tick_emit_seconds",
+    "serving.loop.wait": "veles_serving_loop_wait_seconds",
+    "serving.stream.write": "veles_serving_stream_write_seconds",
+}
+
+#: of those, the spans that leave no record: only the histogram and the
+#: profiler annotation carry them. A handler thread closes one for
+#: every SSE event, hundreds a second under load, which would turn the
+#: span ring and the flight recorder over in seconds; and every
+#: microsecond on that path is paid 32 times a tick on the interpreter
+#: lock the tick thread waits for (with a record the decode cell lost
+#: 2 % of its rate on the chip: PERF.md, PR 26)
+UNRECORDED = frozenset(("serving.stream.write",))
+
 _ids = itertools.count(1)
+
+#: ``jax.profiler.TraceAnnotation`` once jax is imported (looked up
+#: once; this module never imports jax itself): every live span also
+#: enters one of the same name, so a profiler session puts the span in
+#: the capture's host plane, on the device operations' own timeline.
+#: With no session on, entering one is a flag test in native code.
+_annotation = None
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:      # jax half-imported: ask again later
+            return None
+        _annotation = TraceAnnotation
+    return _annotation
 
 #: span-close observers installed by the flight recorder
 #: (telemetry/recorder.py): called with the completed record AFTER the
@@ -98,12 +144,15 @@ def _enabled() -> bool:
 
 
 class _Frame:
-    __slots__ = ("name", "sid", "t0", "before", "attrs", "disabled")
+    __slots__ = ("name", "sid", "t0", "p0", "before", "attrs",
+                 "disabled", "annotation")
 
     def __init__(self, name, sid, t0, before, attrs, disabled=False):
         self.name, self.sid, self.t0 = name, sid, t0
         self.before, self.attrs = before, attrs
         self.disabled = disabled
+        self.annotation = None
+        self.p0 = 0.0
 
 
 class SpanRecorder:
@@ -171,15 +220,31 @@ class SpanRecorder:
     def begin(self, name: str, **attrs: Any) -> _Frame:
         if not _enabled():
             # disabled: hand back an inert frame (attrs writes land in
-            # a discarded dict) — no stack push, no counter snapshot
-            return _Frame(name, 0, 0.0, {}, attrs, disabled=True)
-        frame = _Frame(name, next(_ids), time.time(),
-                       counters.snapshot(), attrs)
-        self._stack().append(frame)
+            # a discarded dict) — no stack push, no clock, no counters
+            return _Frame(name, 0, 0.0, (), attrs, disabled=True)
+        if name in UNRECORDED:
+            frame = _Frame(name, 0, 0.0, None, attrs)
+        else:
+            # ``ts`` is epoch seconds (the fleet merge aligns hosts on
+            # it); ``dur`` comes from the monotonic clock
+            frame = _Frame(name, next(_ids), time.time(),
+                           counters.read(SPAN_COUNTERS), attrs)
+            self._stack().append(frame)
+        annotation = _trace_annotation()
+        if annotation is not None:
+            frame.annotation = annotation(name)
+            frame.annotation.__enter__()
+        frame.p0 = time.perf_counter()
         return frame
 
     def end(self, frame: _Frame) -> Dict[str, Any]:
         if frame.disabled:
+            return {}
+        dur = time.perf_counter() - frame.p0
+        if frame.annotation is not None:
+            frame.annotation.__exit__(None, None, None)
+        if frame.before is None:                # UNRECORDED
+            observe(SPAN_HISTOGRAMS[frame.name], dur)
             return {}
         stack = self._stack()
         # pop through to our frame: a leaked child (generator never
@@ -191,16 +256,21 @@ class SpanRecorder:
         rec: Dict[str, Any] = {
             "name": frame.name,
             "ts": frame.t0,
-            "dur": time.time() - frame.t0,
+            "dur": dur,
             "depth": len(stack),
             "parent": stack[-1].sid if stack else None,
             "sid": frame.sid,
             "tid": threading.get_ident(),
         }
-        delta = counters.delta(frame.before, SPAN_COUNTERS)
+        delta = {k: now - was for k, now, was in zip(
+            SPAN_COUNTERS, counters.read(SPAN_COUNTERS), frame.before)
+            if now != was}
         if delta:
             rec["counters"] = delta
         rec.update(frame.attrs)
+        histogram = SPAN_HISTOGRAMS.get(frame.name)
+        if histogram is not None:
+            observe(histogram, dur)
         self._append(rec)
         return rec
 
