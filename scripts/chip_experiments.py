@@ -12,7 +12,9 @@ items 1/2/3):
   ae_fp32  — same net, f32 everything: the AMP delta, measured
   lm       — transformer-LM tokens/s (mixed precision, 4-epoch blocks)
   attn     — flash vs fused-XLA at T=2048/8192, fwd and train mode,
-             sweeping Pallas block shapes (the T=2048 0.62x regression)
+             sweeping Pallas block shapes (the T=2048 0.62x regression);
+             attn_d128: the 4k training cell's call (head size 128,
+             GQA, float32 operands), each kernel timed on the device
   profile  — XPlane trace of AE steps for the HBM-residual analysis
 
 Run:  python scripts/chip_experiments.py [--sections mnist,ae_amp,...]
@@ -237,22 +239,28 @@ def sec_pallas_compile(bench, dev, n):
     record("flash_gqa_fwd", flash_gqa, tol=0.02)
     record("fused_fc_scan", fused_fc, tol=1e-3)
 
-    def db_entry(t_db, bq, bk):
+    def db_entry(t_db, d_db, entry):
         # b=1, h=2: the grid repeats per head/batch, so the per-block
         # compile verdict transfers; small enough that the f32
-        # reference's (T, T) scores fit at T=8192
+        # reference's (T, T) scores fit at T=8192. Operand dtype and
+        # grouping as the row says it was measured; blocks resolved as
+        # the model path resolves them (None), so a row's backward
+        # tiles are the ones compiled
         r = numpy.random.RandomState(4)
-        q2, k2, v2 = (jnp.asarray(r.randn(1, t_db, 2, ATTN_SWEEP_D),
-                                  jnp.bfloat16) for _ in range(3))
-        qf2, kf2, vf2 = (x.astype(jnp.float32) for x in (q2, k2, v2))
+        dtype = entry.get("dtype", "bfloat16")
+        kv_db = 1 if entry.get("kv", 0) < entry.get("h", 0) else 2
+        q2, k2, v2 = (jnp.asarray(r.randn(1, t_db, heads, d_db), dtype)
+                      for heads in (2, kv_db, kv_db))
+        qf2, kf2, vf2 = (jnp.repeat(x, 2 // x.shape[2], axis=2).astype(
+            jnp.float32) for x in (q2, k2, v2))
 
         def loss(attn):
             return lambda q, k, v: attn(q, k, v).astype(
                 jnp.float32).sum()
 
         def flash(q, k, v):
-            return fa.flash_attention(q, k, v, causal=True, block_q=bq,
-                                      block_k=bk, interpret=interp)
+            return fa.flash_attention(q, k, v, causal=True,
+                                      interpret=interp)
 
         def ref(q, k, v):
             return attention_reference(q, k, v, causal=True)
@@ -261,22 +269,25 @@ def sec_pallas_compile(bench, dev, n):
         grads, binfo = compile_run(
             jax.grad(loss(flash), argnums=(0, 1, 2)), q2, k2, v2)
         info["bwd"] = binfo
-        info["rel_diff"] = max(
-            rel_diff(o, ref(qf2, kf2, vf2)),
-            rel_diff(grads, jax.grad(loss(ref), argnums=(0, 1, 2))(
-                qf2, kf2, vf2)))
+        gq, gk, gv = jax.grad(loss(ref), argnums=(0, 1, 2))(
+            qf2, kf2, vf2)
+        if kv_db == 1:          # the group's two query heads share a row
+            gk, gv = (g.sum(axis=2, keepdims=True) for g in (gk, gv))
+        info["rel_diff"] = max(rel_diff(o, ref(qf2, kf2, vf2)),
+                               rel_diff(grads, (gq, gk, gv)))
         return info
 
     import re
     from veles_tpu.ops import autotune
     kind = autotune.current_device_kind()
     for key, entry in sorted(autotune._device_db(kind).items()):
-        m = re.fullmatch(r"flash_t(\d+)_d%d_causal" % ATTN_SWEEP_D, key)
+        m = re.fullmatch(r"flash_t(\d+)_d(\d+)_causal", key)
         if m and "block_q" in entry:
-            bq, bk = int(entry["block_q"]), int(entry["block_k"])
-            name = "db_%s_%dx%d" % (key, bq, bk)
-            record(name, functools.partial(db_entry, int(m.group(1)),
-                                           bq, bk), tol=0.05)
+            name = "db_%s_%dx%d" % (key, entry["block_q"],
+                                    entry["block_k"])
+            record(name, functools.partial(
+                db_entry, int(m.group(1)), int(m.group(2)), entry),
+                tol=0.05)
             out[name]["jax_stamp"] = entry.get("jax")
     out["all_ok"] = all(v.get("ok") for k, v in out.items()
                         if isinstance(v, dict))
@@ -408,13 +419,15 @@ def sec_lm_big(bench, dev, n):
                           epochs_per_dispatch=2)
 
 
-def sec_attn(bench, dev, n, pairs=None):
+def sec_attn(bench, dev, n, pairs=None, **shape):
     """The explicit block sweep: measure, then rewrite the committed
     tuning DB with the winners (stamped with this jax), and copy it
-    beside the results so it survives the chip tool's machine."""
+    beside the results so it survives the chip tool's machine.
+    ``shape``: ``_attn_measure``'s h, kv, d, dtype, candidates, modes,
+    extras."""
     import shutil
     from veles_tpu.ops import autotune
-    results = _attn_measure(bench, dev, n, pairs=pairs)
+    results = _attn_measure(bench, dev, n, pairs=pairs, **shape)
     _attn_seed(results)
     shutil.copy(autotune.SHIPPED, os.path.dirname(OUT))
     return results
@@ -434,26 +447,92 @@ def sec_attn_8192(bench, dev, n):
     return sec_attn(bench, dev, n, pairs=((8192, 1),))
 
 
-ATTN_SWEEP_H, ATTN_SWEEP_D = 8, 64   # shared by measure AND DB seeding
+def sec_attn_d128(bench, dev, n):
+    """The call of the 4k training cell (chipbench internlm2_train4k:
+    one sequence, 16 query heads on 8 KV heads of 128, float32 operands
+    as nn/transformer.py hands them over), forward plus the custom-VJP
+    backward, at the cell's length and at half of it for the crossover.
+    Train mode alone: what the row records is the train-mode winner."""
+    from veles_tpu.ops.autotune import CANDIDATES_WIDE
+    return sec_attn(bench, dev, n, pairs=((2048, 1), (4096, 1)),
+                    h=16, kv=8, d=128, dtype="float32",
+                    candidates=CANDIDATES_WIDE, modes=(True,),
+                    extras=False)
 
 
-def _attn_measure(bench, dev, n, pairs=None):
+ATTN_SWEEP_H, ATTN_SWEEP_D = 8, 64   # the sweep's shape unless told
+
+#: the three Pallas calls of one flash forward + backward, by the name
+#: a profiler capture gives the device operation
+FLASH_KERNELS = ("veles_flash_fwd", "veles_flash_bwd_dkv",
+                 "veles_flash_bwd_dq")
+
+
+def _kernel_ms(fn, args, iters=4):
+    """Device milliseconds a call of (compiled, warm) ``fn`` spends in
+    each of FLASH_KERNELS, from a profiler capture of ``iters`` calls:
+    {"fwd_ms", "bwd_dkv_ms", "bwd_dq_ms"}, a kernel the call does not
+    run left out; {} where the capture shows none."""
+    import shutil
+    import tempfile
+    import jax
+    import bench_attention as ba
+    from veles_tpu.telemetry import devtime
+    logdir = tempfile.mkdtemp(prefix="veles_attn_sweep_")
+    try:
+        with jax.profiler.trace(logdir):
+            out = None
+            for _ in range(iters):
+                out = fn(*args)
+            ba.sync(out)
+        capture = devtime.find_capture(logdir)
+        scopes = (devtime.summarize_capture(
+            devtime.load_capture(capture))["scopes"] if capture else {})
+    finally:
+        shutil.rmtree(logdir, ignore_errors=True)
+    out = {}
+    for (_, scope), seconds in scopes.items():
+        for kernel in FLASH_KERNELS:
+            if kernel in scope:     # jax may wrap it: jvp_veles_flash_fwd_
+                key = kernel[len("veles_flash_"):] + "_ms"
+                out[key] = out.get(key, 0.0) + 1e3 * seconds / iters
+    return {k: round(ms, 3) for k, ms in out.items()}
+
+
+def _attn_measure(bench, dev, n, pairs=None, h=ATTN_SWEEP_H, kv=None,
+                  d=ATTN_SWEEP_D, dtype="bfloat16", candidates=None,
+                  modes=(False, True), extras=True):
+    """Fused XLA against the flash kernels at each candidate tile pair,
+    at (T, B) ``pairs`` of ``h`` query heads on ``kv`` KV heads (None:
+    MHA) of size ``d``, operands of ``dtype``. A variant's ``ms`` is
+    the host's clock over the whole call; ``fwd_ms``, ``bwd_dkv_ms``,
+    ``bwd_dq_ms`` are each kernel's device time in a capture. Every row
+    carries the shape it was measured at, for ``_attn_seed``.
+    ``extras``: the A/Bs beside the sweep (grouped against expanded
+    K/V, windows, the jnp backward), which are of the standard shape."""
     import jax.numpy as jnp
     import bench_attention as ba
     from veles_tpu.config import root as vt_root
+    from veles_tpu.ops.autotune import CANDIDATES
     from veles_tpu.ops.flash_attention import flash_attention
+    from veles_tpu.nn.attention import expand_kv
     from veles_tpu.parallel.ring_attention import attention_reference
     import jax
+    kv = kv or h
     results = []
+
+    def fused(q, k, v, causal=True):
+        return attention_reference(q, expand_kv(None, k, h),
+                                   expand_kv(None, v, h), causal=causal)
+
     # (T, B) pairs from docs/perf.md so old and new numbers compare
     for t, b in (pairs or ((2048, 16), (8192, 1))):
-        h, d = ATTN_SWEEP_H, ATTN_SWEEP_D
         import numpy
         rng = numpy.random.RandomState(0)
-        q, k, v = (jnp.asarray(rng.randn(b, t, h, d), jnp.bfloat16)
-                   for _ in range(3))
+        q, k, v = (jnp.asarray(rng.randn(b, t, heads, d), dtype)
+                   for heads in (h, kv, kv))
         flops_fwd = 4.0 * b * h * t * t * d / 2     # causal half
-        for train in (False, True):
+        for train in modes:
             flops = flops_fwd * (3.5 if train else 1.0)
 
             def wrap(core):
@@ -466,13 +545,19 @@ def _attn_measure(bench, dev, n, pairs=None):
                         causal=True).astype(jnp.float32).sum(),
                     argnums=(0, 1, 2)))
 
-            row = {"t": t, "b": b, "train": train, "variants": {}}
-            dt = ba.time_fn(wrap(attention_reference), q, k, v)
-            row["variants"]["fused_xla"] = {
-                "ms": round(dt * 1e3, 2),
-                "tflops": round(flops / dt / 1e12, 2)}
-            from veles_tpu.ops.autotune import CANDIDATES
-            for bq, bk in CANDIDATES:
+            row = {"t": t, "b": b, "train": train, "h": h, "kv": kv,
+                   "d": d, "dtype": str(dtype), "variants": {}}
+            try:
+                dt = ba.time_fn(wrap(fused), q, k, v)
+                row["variants"]["fused_xla"] = {
+                    "ms": round(dt * 1e3, 2),
+                    "tflops": round(flops / dt / 1e12, 2)}
+            except Exception as e:            # noqa: BLE001 — the (T, T)
+                # scores may not fit beside the process's other arrays
+                row["variants"]["fused_xla"] = {"error": str(e)[-300:]}
+            print("  attn t=%d train=%s fused_xla: %s"
+                  % (t, train, row["variants"]["fused_xla"]), flush=True)
+            for bq, bk in (candidates or CANDIDATES):
                 if t % bq or t % bk:
                     continue
                 name = "flash_%dx%d" % (bq, bk)
@@ -481,27 +566,28 @@ def _attn_measure(bench, dev, n, pairs=None):
                     return flash_attention(q, k, v, causal=causal,
                                            block_q=bq, block_k=bk)
                 try:
-                    dt = ba.time_fn(wrap(core), q, k, v)
-                    row["variants"][name] = {
-                        "ms": round(dt * 1e3, 2),
-                        "tflops": round(flops / dt / 1e12, 2)}
+                    fn = wrap(core)
+                    dt = ba.time_fn(fn, q, k, v)
+                    row["variants"][name] = dict(
+                        _kernel_ms(fn, (q, k, v)),
+                        ms=round(dt * 1e3, 2),
+                        tflops=round(flops / dt / 1e12, 2))
                 except Exception as e:        # noqa: BLE001
                     row["variants"][name] = {"error": str(e)[-300:]}
                 print("  attn t=%d train=%s %s: %s"
                       % (t, train, name, row["variants"][name]),
                       flush=True)
-            if not train:
+            if not train and extras:
                 # GQA A/B: grouped k/v (index-map remapping) vs the
                 # same attention on pre-expanded K/V — the grouped
                 # kernel reads each kv block once per group instead of
                 # re-reading an expanded copy
-                kv = 2
                 kg = jnp.asarray(numpy.random.RandomState(1).randn(
-                    b, t, kv, d), jnp.bfloat16)
+                    b, t, 2, d), dtype)
                 vg = jnp.asarray(numpy.random.RandomState(2).randn(
-                    b, t, kv, d), jnp.bfloat16)
-                kx = jnp.repeat(kg, h // kv, axis=2)
-                vx = jnp.repeat(vg, h // kv, axis=2)
+                    b, t, 2, d), dtype)
+                kx = jnp.repeat(kg, h // 2, axis=2)
+                vx = jnp.repeat(vg, h // 2, axis=2)
                 for name, args in (("flash_gqa_kv2", (q, kg, vg)),
                                    ("flash_gqa_expanded", (q, kx, vx))):
                     try:
@@ -534,7 +620,7 @@ def _attn_measure(bench, dev, n, pairs=None):
                     print("  attn t=%d %s: %s"
                           % (t, name, row["variants"][name]),
                           flush=True)
-            if train:
+            if train and extras:
                 # pallas-bwd (default) vs jnp blockwise bwd, same
                 # 128x128 forward — the new backward's own A/B
                 from veles_tpu.config import root as vt_root
@@ -570,6 +656,11 @@ def _attn_measure(bench, dev, n, pairs=None):
     return results
 
 
+#: the backward pair gets tiles of its own in the row only if they beat
+#: the forward's winner at the backward by more than this share
+BWD_SPLIT_GAIN = 0.03
+
+
 def _attn_seed(results):
     # Seed the per-device block DB (ops/autotune.py — the build's port
     # of the reference's measured-per-device GEMM block sizes,
@@ -577,43 +668,73 @@ def _attn_seed(results):
     # flash calls stop using the hard-coded 128x128 default on this
     # device_kind. Train-mode winners take precedence (training is the
     # dominant consumer). record() rewrites the committed in-repo DB.
+    # Where the capture gave each kernel's device time, the forward's
+    # tiles are the forward kernel's winner and the row's ms the three
+    # kernels' sum; else both come from the host's clock over the call.
     import re
     from veles_tpu.ops import autotune
-    d_swept = ATTN_SWEEP_D
+    if not results:
+        return
+    shape = {k: results[0][k] for k in ("h", "kv", "dtype")
+             if k in results[0]}
+    d_swept = results[0].get("d", ATTN_SWEEP_D)
     crossover = {}          # t -> flash beat fused (train-preferred)
     for t in sorted({r["t"] for r in results}):
-        best = {}              # train_mode -> (ms, bq, bk)
+        cands = {}             # train_mode -> {(bq, bk): variant}
         for r in results:
             if r["t"] != t:
                 continue
             for name, res in r["variants"].items():
                 m = re.fullmatch(r"flash_(\d+)x(\d+)", name)
-                if not m or "ms" not in res:
-                    continue
-                cur = best.get(r["train"])
-                cand = (res["ms"], int(m.group(1)), int(m.group(2)))
-                if cur is None or cand[0] < cur[0]:
-                    best[r["train"]] = cand
-        pick = best.get(True) or best.get(False)
-        if pick is None:
+                if m and "ms" in res:
+                    cands.setdefault(r["train"], {})[
+                        (int(m.group(1)), int(m.group(2)))] = res
+        train = True in cands
+        pool = cands.get(True) or cands.get(False)
+        if not pool:
             continue
-        ms, bq, bk = pick
+        entry = dict(shape, mode="train_sweep" if train else "fwd_sweep")
+        kernels = ("fwd_ms", "bwd_dkv_ms", "bwd_dq_ms")
+        if train and all(all(k in res for k in kernels)
+                         for res in pool.values()):
+            def bwd_ms(blocks):
+                return pool[blocks]["bwd_dkv_ms"] + pool[blocks]["bwd_dq_ms"]
+            fwd = min(pool, key=lambda blocks: pool[blocks]["fwd_ms"])
+            bwd = min(pool, key=bwd_ms)
+            if bwd_ms(bwd) > (1.0 - BWD_SPLIT_GAIN) * bwd_ms(fwd):
+                bwd = fwd
+            else:
+                entry.update(bwd_block_q=bwd[0], bwd_block_k=bwd[1])
+            entry.update(fwd_ms=pool[fwd]["fwd_ms"],
+                         bwd_dkv_ms=pool[bwd]["bwd_dkv_ms"],
+                         bwd_dq_ms=pool[bwd]["bwd_dq_ms"])
+            # the host's clock over the whole call, where one pair ran
+            # it all: what the fused reference is compared by
+            ms = (pool[fwd]["ms"] if bwd == fwd else
+                  round(pool[fwd]["fwd_ms"] + bwd_ms(bwd), 2))
+        else:
+            fwd = min(pool, key=lambda blocks: pool[blocks]["ms"])
+            ms = pool[fwd]["ms"]
+        bq, bk = fwd
         # flash-vs-fused verdict at this T, same mode as the pick
         mode_rows = [r for r in results if r["t"] == t
-                     and r["train"] == (True in best)]
-        fused = min((r["variants"].get("fused_xla", {}).get("ms")
-                     for r in mode_rows
-                     if r["variants"].get("fused_xla", {}).get("ms")
-                     is not None), default=None)
+                     and r["train"] == train]
+        fused_rows = [r["variants"].get("fused_xla", {})
+                      for r in mode_rows]
+        fused = min((f["ms"] for f in fused_rows if "ms" in f),
+                    default=None)
         if fused is not None:
             crossover[t] = ms < fused
+        elif any("error" in f for f in fused_rows):
+            crossover[t] = True     # the fused reference did not run
         autotune.record(
             autotune.flash_key(t, d_swept, True),
-            {"block_q": bq, "block_k": bk, "ms": ms,
-             "mode": ("train_sweep" if True in best
-                      else "fwd_sweep")})
-        print("  autotune seeded t=%d d=%d -> %dx%d (%.2f ms)"
-              % (t, d_swept, bq, bk, ms), flush=True)
+            dict(entry, block_q=bq, block_k=bk, ms=ms))
+        print("  autotune seeded t=%d d=%d -> %dx%d (%.2f ms)%s"
+              % (t, d_swept, bq, bk, ms,
+                 " backward %dx%d" % (entry["bwd_block_q"],
+                                      entry["bwd_block_k"])
+                 if "bwd_block_q" in entry else ""), flush=True)
     # persist the MEASURED flash-vs-fused crossover: the smallest
     # swept T where tuned flash beat the fused-XLA reference AND no
     # larger swept T measured a loss — 't >= min_t' routes every
@@ -784,6 +905,7 @@ SECTIONS = [("pallas_compile", sec_pallas_compile),
             ("ae_mb256", sec_ae_mb256),
             ("lm", sec_lm), ("lm_big", sec_lm_big),
             ("attn_2048", sec_attn_2048), ("attn_8192", sec_attn_8192),
+            ("attn_d128", sec_attn_d128),
             ("generation", sec_generation), ("profile", sec_profile)]
 
 

@@ -281,3 +281,168 @@ def test_stale_entry_counts_every_lookup_warns_once(tuned_env, caplog):
         autotune.lookup("flash_t2048_d64_causal")
     assert len([r for r in caplog.records
                 if "stale" in r.getMessage()]) == 3
+
+
+# -- head size 128 (PR 27): the committed row, the miss that is counted,
+# -- the probe's dtype, the sweep's per-kernel winners --
+
+V5E = "TPU v5 lite"
+
+
+def test_shipped_d128_row_resolves_without_a_probe(monkeypatch):
+    """The 4k training cell's call has a committed row on the v5e: its
+    tiles divide 4,096 and are returned as they stand, no compile probe
+    (a trace on a committed row pays nothing at set-up)."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def boom(*a, **k):
+        raise AssertionError("a committed row was probed")
+
+    monkeypatch.setattr(autotune, "_bwd_compiles", boom)
+    autotune.clear_memo()
+    row = json.load(open(autotune.SHIPPED))[V5E]["flash_t4096_d128_causal"]
+    fwd = (row["block_q"], row["block_k"])
+    bwd = (row.get("bwd_block_q", fwd[0]), row.get("bwd_block_k", fwd[1]))
+    assert all(4096 % b == 0 and b > 128 for b in fwd + bwd)
+    assert row["dtype"] == "float32" and (row["h"], row["kv"]) == (16, 8)
+    assert autotune.flash_blocks(4096, 128, True, device_kind=V5E) == fwd
+    assert autotune.flash_blocks_fwd_bwd(
+        4096, 128, True, device_kind=V5E) == (fwd, bwd)
+    assert "min_t" in json.load(open(autotune.SHIPPED))[V5E][
+        "flash_min_t_d128"]
+    assert autotune.flash_min_t(128, device_kind=V5E) <= 4096
+    autotune.clear_memo()
+
+
+@pytest.mark.parametrize("t", [2048, 8192])
+def test_shipped_d64_rows_resolve_as_before(t):
+    autotune.clear_memo()
+    assert autotune.flash_blocks(t, 64, True,
+                                 device_kind=V5E) == (1024, 1024)
+    assert autotune.flash_min_t(64, device_kind=V5E) == 2048
+    autotune.clear_memo()
+
+
+def test_default_blocks_on_a_tpu_are_counted_and_logged_once(
+        tuned_env, caplog):
+    """Resolving to DEFAULT_BLOCKS for a TPU-named device kind moves
+    veles_flash_default_blocks_traces_total at every resolution and
+    names the missing row in the log once per (kind, key); any other
+    kind (the tests' fake, the CPU) does neither."""
+    import logging
+    from veles_tpu.telemetry.counters import counters
+    name = "veles_flash_default_blocks_traces_total"
+    c0 = counters.get(name)
+    with caplog.at_level(logging.WARNING,
+                         logger="veles_tpu.ops.autotune"):
+        assert autotune.flash_blocks(4096, 128) == autotune.DEFAULT_BLOCKS
+        assert counters.get(name) == c0          # "faketpu-v0"
+        assert autotune.flash_blocks(
+            4096, 128, device_kind="TPU v0 fake") == autotune.DEFAULT_BLOCKS
+        assert counters.get(name) == c0 + 1
+        autotune.flash_blocks(4096, 128, device_kind="TPU v0 fake")
+        autotune.flash_blocks(8192, 128, device_kind="TPU v0 fake")
+    assert counters.get(name) == c0 + 3
+    said = [r.getMessage() for r in caplog.records
+            if "fallback tiles" in r.getMessage()]
+    assert len(said) == 2                        # once per key
+    assert "flash_t4096_d128_causal" in said[0]
+    assert "chip_experiments.py" in said[0]
+    # a row of the class to inherit from is no miss
+    autotune.record(autotune.flash_key(2048, 128, True),
+                    {"block_q": 512, "block_k": 512},
+                    device_kind="TPU v0 fake")
+    assert autotune.flash_blocks(4096, 128,
+                                 device_kind="TPU v0 fake") == (512, 512)
+    assert counters.get(name) == c0 + 3
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", None])
+def test_bwd_compiles_probes_in_the_dtype_it_is_given(monkeypatch, dtype):
+    """The compile probe is built from the operand dtype of the call it
+    decides for (bfloat16 only when the caller has none), and
+    ``flash_blocks`` hands an inherited pair's probe that dtype."""
+    import jax.numpy as jnp
+    from veles_tpu.ops import flash_attention as fa
+    seen = []
+
+    def spy(q, k, v, **kw):
+        seen.append((q.dtype, k.dtype, v.dtype, q.shape,
+                     kw["block_q"], kw["block_k"], kw["interpret"]))
+        return q
+
+    monkeypatch.setattr(fa, "flash_attention", spy)
+    assert autotune._bwd_compiles(512, 128, True, (256, 128), dtype)
+    want = jnp.dtype(dtype or "bfloat16")
+    assert seen == [(want, want, want, (1, 512, 1, 128), 256, 128, False)]
+
+
+def test_inherited_pair_is_probed_at_the_calls_dtype(tuned_env,
+                                                     monkeypatch):
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    autotune.record(autotune.flash_key(2048, 128, True),
+                    {"block_q": 1024, "block_k": 512})
+    probes = []
+    monkeypatch.setattr(
+        autotune, "_bwd_compiles",
+        lambda t, d, causal, blocks, dtype=None:
+        probes.append((t, blocks, str(dtype))) or str(dtype) != "float32")
+    # lowers at bfloat16, not at float32: one verdict per dtype, memoized
+    assert autotune.flash_blocks(4096, 128, dtype="bfloat16") == (1024, 512)
+    assert autotune.flash_blocks(4096, 128,
+                                 dtype="float32") == autotune.DEFAULT_BLOCKS
+    autotune.flash_blocks(4096, 128, dtype="float32")
+    assert probes == [(4096, (1024, 512), "bfloat16"),
+                      (4096, (1024, 512), "float32")]
+
+
+def _kernel_row(t, variants, **shape):
+    return dict({"t": t, "b": 1, "train": True, "variants": variants},
+                **shape)
+
+
+def test_attn_seed_picks_each_kernels_winner_and_records_the_shape(
+        tuned_env):
+    """Where the capture timed each kernel, the row's tiles are the
+    forward kernel's winner, the backward pair gets tiles of its own
+    only past BWD_SPLIT_GAIN, and the row says at which heads and dtype
+    it was measured; the crossover is recorded for that head size."""
+    ce = _load_chip_experiments()
+    shape = {"h": 16, "kv": 8, "d": 128, "dtype": "float32"}
+
+    def v(ms, fwd, dkv, dq):
+        return {"ms": ms, "fwd_ms": fwd, "bwd_dkv_ms": dkv,
+                "bwd_dq_ms": dq}
+
+    ce._attn_seed([
+        # one pair wins all three kernels: no backward tiles in the row
+        _kernel_row(2048, {"fused_xla": {"ms": 5.0},
+                           "flash_512x512": v(4.0, 1.0, 1.6, 1.4),
+                           "flash_1024x1024": v(3.0, 0.8, 1.2, 1.0),
+                           "flash_2048x2048": {"error": "vmem"}}, **shape),
+        # the backward is 10 % faster on other tiles than the forward's
+        _kernel_row(4096, {"fused_xla": {"error": "out of memory"},
+                           "flash_1024x1024": v(9.0, 2.0, 4.0, 3.0),
+                           "flash_512x1024": v(9.5, 3.2, 3.3, 3.0),
+                           # 2 % faster at the backward: under the gain
+                           "flash_1024x512": v(9.1, 2.2, 3.9, 2.96)},
+                    **shape)])
+    db = json.load(open(autotune.SHIPPED))["faketpu-v0"]
+    r2048 = db["flash_t2048_d128_causal"]
+    assert (r2048["block_q"], r2048["block_k"]) == (1024, 1024)
+    assert "bwd_block_q" not in r2048 and r2048["ms"] == 3.0
+    assert (r2048["h"], r2048["kv"], r2048["dtype"]) == (16, 8, "float32")
+    assert (r2048["fwd_ms"], r2048["bwd_dkv_ms"]) == (0.8, 1.2)
+    r4096 = db["flash_t4096_d128_causal"]
+    assert (r4096["block_q"], r4096["block_k"]) == (1024, 1024)
+    assert (r4096["bwd_block_q"], r4096["bwd_block_k"]) == (512, 1024)
+    assert r4096["ms"] == 2.0 + 3.3 + 3.0
+    assert autotune.flash_blocks_fwd_bwd(4096, 128) == ((1024, 1024),
+                                                        (512, 1024))
+    assert autotune.flash_blocks(2048, 128) == (1024, 1024)
+    # flash won at 2048; the fused reference did not run at 4096
+    assert db["flash_min_t_d128"]["swept"] == {"2048": True, "4096": True}
+    assert autotune.flash_min_t(128) == 2048
+    assert "flash_min_t_d64" not in db

@@ -327,3 +327,92 @@ def test_flash_runs_in_a_shard_map_on_a_multi_device_mesh(monkeypatch):
     attention_core(*qkv(b=2, t=128, h=4, d=64), causal=True, mesh=pp,
                    n_heads=4)
     assert specs == []
+
+
+# -- the tiles committed for head size 128 (PR 27) --
+
+def _v5e_row(key):
+    import json
+    from veles_tpu.ops import autotune
+    with open(autotune.SHIPPED) as f:
+        return json.load(f)["TPU v5 lite"][key]
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("key", ["flash_t4096_d128_causal",
+                                 "flash_t2048_d128_causal"])
+def test_committed_d128_tiles_match_reference(key, dtype, tol, tmp_path,
+                                              monkeypatch):
+    """Forward and gradients at the tiles a v5e row commits, in the
+    cell's shape class (GQA 2 on 1, head size 128, float32 and bfloat16
+    operands), T two of the largest tile a side: the causal skip, the
+    diagonal tile and the last tile's ``_finish`` are all walked. The
+    row is planted at that T in a DB of its own and resolved as the
+    model path resolves it (None blocks), so a row's backward tiles run
+    the backward."""
+    from veles_tpu.ops import autotune
+    row = _v5e_row(key)
+    tiles = {k: row[k] for k in ("block_q", "block_k", "bwd_block_q",
+                                 "bwd_block_k") if k in row}
+    t = 2 * max(tiles.values())
+    monkeypatch.setattr(autotune, "SHIPPED", str(tmp_path / "db.json"))
+    monkeypatch.setattr(autotune, "current_device_kind", lambda: "fake")
+    autotune.clear_memo()
+    autotune.record(autotune.flash_key(t, 128, True), tiles)
+    walked = {}
+    fwd, bwd = fa._fwd_pallas, fa._bwd_pallas_core
+    monkeypatch.setattr(fa, "_fwd_pallas", lambda *a, **kw: (
+        walked.setdefault("fwd", a[5:7]), fwd(*a, **kw))[1])
+    monkeypatch.setattr(fa, "_bwd_pallas_core", lambda *a, **kw: (
+        walked.setdefault("bwd", a[8:10]), bwd(*a, **kw))[1])
+    rng = numpy.random.RandomState(7)
+    q, k, v = (jnp.asarray(rng.randn(1, t, heads, 128), dtype)
+               for heads in (2, 1, 1))
+
+    def loss(attn):
+        return lambda q, k, v: (attn(q, k, v).astype(jnp.float32)
+                                ** 2).sum()
+
+    def ref(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        return attention_reference(q, jnp.repeat(k, 2, axis=2),
+                                   jnp.repeat(v, 2, axis=2), causal=True)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    try:
+        got = (flash(q, k, v),) + jax.grad(
+            loss(flash), argnums=(0, 1, 2))(q, k, v)
+    finally:
+        autotune.clear_memo()
+    want = (ref(q, k, v),) + jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    assert walked == {
+        "fwd": (tiles["block_q"], tiles["block_k"]),
+        "bwd": (tiles.get("bwd_block_q", tiles["block_q"]),
+                tiles.get("bwd_block_k", tiles["block_k"]))}
+    for a, b in zip(got, want):
+        scale = float(jnp.abs(b).max())
+        numpy.testing.assert_allclose(
+            numpy.asarray(a, numpy.float32) / scale,
+            numpy.asarray(b, numpy.float32) / scale, rtol=0, atol=tol)
+
+
+def test_vmem_limit_is_counted_from_the_tiles():
+    """Small tiles ask for Mosaic's default, so they lower as they
+    always have; the limit grows with the tile and the itemsize, and
+    stops at the cap."""
+    small = fa._vmem_limit(128, 128, 128, 4, q_tiles=2, k_tiles=4,
+                           f32_elems=2 * 128 * 128, scores=fa.BWD_SCORES)
+    assert small == fa.VMEM_DEFAULT
+    f32 = fa._vmem_limit(1024, 1024, 128, 4, q_tiles=2, k_tiles=4,
+                         f32_elems=2 * 1024 * 128, scores=fa.BWD_SCORES)
+    bf16 = fa._vmem_limit(1024, 1024, 128, 2, q_tiles=2, k_tiles=4,
+                          f32_elems=2 * 1024 * 128, scores=fa.BWD_SCORES)
+    # four float32 score tiles of 4 MiB and six double-buffered operand
+    # tiles: more than the default, and more at four bytes than at two
+    assert fa.VMEM_DEFAULT < bf16 < f32 < fa.VMEM_CAP
+    assert f32 >= 4 * 4 * 1024 * 1024 + 2 * 6 * 1024 * 128 * 4
+    huge = fa._vmem_limit(4096, 4096, 512, 4, q_tiles=2, k_tiles=4,
+                          f32_elems=2 * 4096 * 512, scores=fa.BWD_SCORES)
+    assert huge == fa.VMEM_CAP

@@ -174,12 +174,10 @@ def _default_root() -> Config:
             # below this sequence length the fused-XLA reference wins on
             # the MXU. "auto" (default) = the per-device MEASURED
             # crossover from the chip attn sweep (ops/autotune.py
-            # flash_min_t; falls back to the v5e-measured 4096 — naive
-            # 4.7 vs flash 2.9 TFLOP/s at T=2048, flash 12.6x at
-            # T=8192 where naive's (T,T) scores saturate HBM,
-            # docs/perf.md — until a sweep has run on this
-            # device_kind); an int pins it; "force" engine mode
-            # ignores the threshold entirely
+            # flash_min_t, per head size: 2048 on the v5e at 64 and
+            # at 128, devices/kernel_tuning.json; 4096 until a sweep
+            # has run on this device_kind and head size); an int pins
+            # it; "force" engine mode ignores the threshold entirely
             "flash_attention_min_t": "auto",
             # long-context scheme over the 'sequence' mesh axis:
             # "ring" (K/V rotation, memory-flat in T) or "ulysses"

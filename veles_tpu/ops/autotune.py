@@ -11,9 +11,9 @@ One DB: ``veles_tpu/devices/kernel_tuning.json``, committed. The model
 path only ever READS it (a memoized dict lookup, safe at trace time),
 so one commit compiles the same kernels on every machine and in every
 process of an SPMD job. Measuring is an explicit command on a chip —
-``scripts/chip_experiments.py --sections attn_2048,attn_8192`` — whose
-``record()`` rewrites that file for the next commit; a trace never
-sweeps.
+``scripts/chip_experiments.py --sections attn_2048,attn_8192,attn_d128``
+— whose ``record()`` rewrites that file for the next commit; a trace
+never sweeps.
 
 ``fused_fc`` deliberately has no entry here: its only tunable is
 epochs-per-dispatch ``h`` (whole minibatches ARE its blocks), measured
@@ -34,6 +34,11 @@ DEFAULT_BLOCKS = (128, 128)
 #: r5 sweep length — the knee hadn't been reached
 CANDIDATES = ((128, 128), (256, 128), (512, 128), (256, 256),
               (512, 512), (1024, 512), (1024, 1024))
+#: the census of the head-size-128 sweep (PR 27): every pair of 256 ...
+#: 2048, block_q != block_k included, and the fallback to measure it by
+CANDIDATES_WIDE = (DEFAULT_BLOCKS,) + tuple(
+    (bq, bk) for bq in (256, 512, 1024, 2048)
+    for bk in (256, 512, 1024, 2048))
 SHIPPED = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "devices", "kernel_tuning.json")
 
@@ -43,6 +48,9 @@ _memo: dict = {}
 #: (device_kind, key) pairs whose staleness was already warned about —
 #: one log line per entry per process, however many traces look it up
 _stale_warned: set = set()
+
+#: (device_kind, key) pairs already named as running on DEFAULT_BLOCKS
+_default_warned: set = set()
 
 
 def _jax_version() -> str:
@@ -136,10 +144,9 @@ def record(key: str, entry: dict,
             json.dump(db, f, indent=1, sort_keys=True)
             f.write("\n")       # POSIX text file: end with newline
         os.replace(tmp, SHIPPED)
-    if "block_q" in entry:
-        _memo[(kind, key)] = (entry["block_q"], entry["block_k"])
-    elif "min_t" in entry:          # refresh the crossover memo too
-        _memo[(kind, key, "min_t")] = int(entry["min_t"])
+    # the next lookup reads the new row
+    _memo.pop((kind, key), None)
+    _memo.pop((kind, key, "min_t"), None)
 
 
 #: sentinel "flash never won a swept length on this device" — keeps
@@ -154,11 +161,14 @@ def min_t_key(d: int) -> str:
 def flash_min_t(d: int, device_kind: Optional[str] = None,
                 default: int = 4096) -> int:
     """The measured flash-vs-fused crossover length for this
-    device_kind (seeded by the chip attn sweep — the reference
-    persisted measured per-device decisions the same way,
-    `veles/backends.py:623-731`); ``default`` (the v5e-measured 4096,
-    docs/perf.md) for a device_kind no sweep has covered. Memoized:
-    this runs per attention layer per trace."""
+    device_kind and head size (seeded by the chip attn sweep — the
+    reference persisted measured per-device decisions the same way,
+    `veles/backends.py:623-731`); ``default`` for a device_kind or
+    head size no sweep has covered: long enough that the fused
+    reference's (T, T) scores still fit, a guess and no measurement
+    (both of the v5e's swept head sizes cross over at 2048 with tuned
+    tiles, devices/kernel_tuning.json). Memoized: this runs per
+    attention layer per trace."""
     kind = device_kind or current_device_kind()
     key = min_t_key(d)
     memo_key = (kind, key, "min_t")
@@ -183,25 +193,24 @@ def resolved_min_t(d: int, device_kind: Optional[str] = None) -> int:
 
 
 def _bwd_compiles(t: int, d: int, causal: bool,
-                  blocks: Tuple[int, int]) -> bool:
-    """Whether the custom-VJP backward pair LOWERS at these blocks.
-    Its VMEM working set is larger than the forward's (q/do/k/v blocks
-    + dk/dv accumulators resident), so a forward-fine (512, 512) can
-    be a backward Mosaic OOM. Compile-only: no timing."""
+                  blocks: Tuple[int, int], dtype=None) -> bool:
+    """Whether the custom-VJP backward pair LOWERS at these blocks with
+    operands of ``dtype`` (the dtype of the call being decided for;
+    bfloat16 when the caller has none): a tile pair that lowers at two
+    bytes an element can be a Mosaic VMEM failure at four. One head —
+    the tiles, not the head count, set a kernel's working set.
+    Compile-only: no timing."""
     import jax
     import jax.numpy as jnp
-    import numpy
     from .flash_attention import flash_attention
-    rng = numpy.random.RandomState(0)
-    q, k, v = (jnp.asarray(rng.randn(1, t, 1, d), jnp.bfloat16)
-               for _ in range(3))
+    x = jax.ShapeDtypeStruct((1, t, 1, d), jnp.dtype(dtype or jnp.bfloat16))
     try:
         jax.jit(jax.grad(
             lambda q, k, v: flash_attention(
                 q, k, v, causal=causal, block_q=blocks[0],
                 block_k=blocks[1],
                 interpret=False).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))).lower(q, k, v).compile()
+            argnums=(0, 1, 2))).lower(x, x, x).compile()
         return True
     except Exception:            # noqa: BLE001 — lowering/VMEM failure
         return False
@@ -211,14 +220,25 @@ def _nearest_blocks(t: int, d: int, causal: bool,
                     kind: str) -> Optional[Tuple[int, int]]:
     """Measured winner from the nearest tuned length of the same
     (d, mode) class whose blocks divide this ``t``. Rationale
-    (measured, docs/perf.md attn sweep): the per-device block
-    preference is set by MXU-pipeline fill, which transfers across
-    lengths — the committed v5e winners agree at 2048 and 8192
-    (devices/kernel_tuning.json), while the 128×128 DEFAULT_BLOCKS
-    lost to fused XLA at 2048. Without this, an untuned T between
-    swept lengths would pair the measured ``flash_min_t`` gate with
-    the unmeasured default blocks — the exact combination the sweep
-    showed regressing."""
+    (measured on the v5e, devices/kernel_tuning.json): the per-device
+    block preference is set by how many grid steps a call makes and
+    how well each fills the MXU pipeline, which transfers across
+    lengths — the committed d64 winners are 1024x1024 at both 2048 and
+    8192, while the 128x128 DEFAULT_BLOCKS lost to fused XLA at 2048
+    and ran the d128 training cell at a twenty-fourth of its roofline
+    (PERF.md, PR 27). Without this, an untuned T between swept lengths
+    would pair the measured ``flash_min_t`` gate with the unmeasured
+    default blocks — the exact combination the sweep showed
+    regressing.
+
+    By head size, on purpose: a d128 call inherits nothing from the
+    d64 rows, although both pad to one 128-lane width. The v5e's
+    winners do not agree well enough to share: d64 (bfloat16, MHA) is
+    1024x1024 at 2048 and 8192; d128 (float32, GQA 16 on 8) is
+    1024x1024 at 4096 but 2048x2048 forward, 1024x1024 backward at
+    2048 (PR 27's sweep), and the two were measured at different
+    operand dtypes. A head size no sweep has covered resolves to
+    DEFAULT_BLOCKS, which ``_note_default_blocks`` counts and logs."""
     pref = "flash_t"
     suf = "_d%d_%s" % (d, "causal" if causal else "full")
     from .flash_attention import supported
@@ -243,14 +263,15 @@ def _nearest_blocks(t: int, d: int, causal: bool,
 
 
 def _check_inherited(t: int, d: int, causal: bool,
-                     blocks: Tuple[int, int], kind: str
-                     ) -> Tuple[int, int]:
+                     blocks: Tuple[int, int], kind: str,
+                     dtype=None) -> Tuple[int, int]:
     """First use of a length-INHERITED winner at this ``t``: confirm
-    the custom-VJP pair actually LOWERS (the sweep only compiled it at
-    the swept lengths) and use DEFAULT_BLOCKS otherwise. TPU-only: off-TPU there is no Mosaic
+    the custom-VJP pair actually LOWERS at the call's operand dtype
+    (the sweep only compiled it at the swept lengths and dtype) and
+    use DEFAULT_BLOCKS otherwise. TPU-only: off-TPU there is no Mosaic
     lowering to fail (and tests drive inheritance with fake device
-    kinds). The verdict is memoized per (kind, t, blocks) so the
-    compile probe costs once, not per trace. A committed entry for
+    kinds). The verdict is memoized per (kind, t, blocks, dtype) so
+    the compile probe costs once, not per trace. A committed entry for
     ``t`` itself is NOT probed here: the chip batch
     (``--sections pallas_compile``) compiles every committed pair."""
     if blocks == DEFAULT_BLOCKS:
@@ -258,19 +279,52 @@ def _check_inherited(t: int, d: int, causal: bool,
     import jax
     if jax.default_backend() != "tpu":
         return blocks
-    memo_key = (kind, "inherit_ok", t, d, causal, blocks)
+    memo_key = (kind, "inherit_ok", t, d, causal, blocks, str(dtype))
     ok = _memo.get(memo_key)
     if ok is None:
-        ok = _memo[memo_key] = _bwd_compiles(t, d, causal, blocks)
+        ok = _memo[memo_key] = _bwd_compiles(t, d, causal, blocks, dtype)
     return blocks if ok else DEFAULT_BLOCKS
 
 
-def flash_blocks(t: int, d: int, causal: bool = True, window: int = 0,
-                 device_kind: Optional[str] = None) -> Tuple[int, int]:
+def _note_default_blocks(kind: str, key: str) -> None:
+    """A call that was sent to the kernel resolved to DEFAULT_BLOCKS on
+    a TPU: counted at every trace
+    (``veles_flash_default_blocks_traces_total``), named in the log
+    once per (kind, key). The 128x128 fallback is a grid of
+    (T/128)^2 steps a head, each about half a microsecond before it
+    does any work on a v5e; the d128 training cell ran on it unseen for
+    five rounds."""
+    if not kind.startswith("TPU"):
+        return
+    from ..telemetry.counters import inc
+    inc("veles_flash_default_blocks_traces_total")
+    if (kind, key) in _default_warned:
+        return
+    _default_warned.add((kind, key))
+    import logging
+    logging.getLogger("veles_tpu.ops.autotune").warning(
+        "kernel_tuning.json has no row %s for %s and no tuned length "
+        "of its class to inherit from: the flash kernels run on the "
+        "%dx%d fallback tiles. Measure it on the chip: give "
+        "scripts/chip_experiments.py an attn section for this shape "
+        "(sec_attn_d128 is the pattern), run `python "
+        "scripts/chip_experiments.py --sections <section>` and commit "
+        "veles_tpu/devices/kernel_tuning.json",
+        key, kind, *DEFAULT_BLOCKS)
+
+
+def flash_blocks_fwd_bwd(t: int, d: int, causal: bool = True,
+                         window: int = 0,
+                         device_kind: Optional[str] = None, dtype=None
+                         ) -> Tuple[Tuple[int, int], Tuple[int, int]]:
     """THE policy lookup ``flash_attention`` resolves its default
-    blocks through: the committed entry for this shape class, else (for
-    a windowed shape) the causal entry's ranking, else the nearest tuned
-    length's winner, else DEFAULT_BLOCKS. Read-only — the same commit
+    blocks through, (forward's, backward pair's): the committed entry
+    for this shape class, else (for a windowed shape) the causal
+    entry's ranking, else the nearest tuned length's winner (probed
+    once at the call's operand ``dtype``), else DEFAULT_BLOCKS —
+    counted and logged on a TPU. The backward pair's differ from the
+    forward's only where the committed row carries
+    ``bwd_block_q``/``bwd_block_k``. Read-only — the same commit
     resolves the same blocks on every machine. Hits are memoized;
     misses are not, so a ``record()`` later in the process (a sweep
     command) changes the answer."""
@@ -283,15 +337,28 @@ def flash_blocks(t: int, d: int, causal: bool = True, window: int = 0,
     if hit is None and window:
         hit = lookup(flash_key(t, d, causal), kind)
     if hit is not None:
-        blocks = _memo[memo_key] = (int(hit["block_q"]),
-                                    int(hit["block_k"]))
-        return blocks
+        fwd = (int(hit["block_q"]), int(hit["block_k"]))
+        bwd = (int(hit.get("bwd_block_q", fwd[0])),
+               int(hit.get("bwd_block_k", fwd[1])))
+        _memo[memo_key] = (fwd, bwd)
+        return fwd, bwd
     inherited = _nearest_blocks(t, d, causal, kind)
-    if inherited is None:
-        return DEFAULT_BLOCKS
-    return _check_inherited(t, d, causal, inherited, kind)
+    blocks = (DEFAULT_BLOCKS if inherited is None else
+              _check_inherited(t, d, causal, inherited, kind, dtype))
+    if blocks == DEFAULT_BLOCKS:
+        _note_default_blocks(kind, key)
+    return blocks, blocks
+
+
+def flash_blocks(t: int, d: int, causal: bool = True, window: int = 0,
+                 device_kind: Optional[str] = None,
+                 dtype=None) -> Tuple[int, int]:
+    """The forward's blocks of ``flash_blocks_fwd_bwd``."""
+    return flash_blocks_fwd_bwd(t, d, causal, window, device_kind,
+                                dtype)[0]
 
 
 def clear_memo() -> None:
     _memo.clear()
     _stale_warned.clear()
+    _default_warned.clear()

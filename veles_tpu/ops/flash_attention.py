@@ -33,6 +33,44 @@ import jax.numpy as jnp
 LANE = 128
 NEG_INF = -1e30
 
+#: Mosaic's own scoped-VMEM budget: what a ``pallas_call`` gets when it
+#: asks for nothing, so small tiles compile exactly as they always have
+VMEM_DEFAULT = 16 << 20
+#: the most a kernel here asks for: three quarters of the 128 MiB a
+#: v5e core has (the smallest VMEM of the TPUs this build runs on)
+VMEM_CAP = 96 << 20
+#: (block_q, block_k) float32 temporaries a kernel's step holds: s and
+#: p in the forward; s, p, dp and ds in the backward pair
+FWD_SCORES, BWD_SCORES = 2, 4
+
+
+def _vmem_limit(block_q: int, block_k: int, d: int, itemsize: int,
+                q_tiles: int, k_tiles: int, f32_elems: int,
+                scores: int) -> int:
+    """``vmem_limit_bytes`` of one kernel, counted from its tiles: the
+    pipelined (block, D) operand and result tiles, two buffers each
+    (``q_tiles`` of block_q rows, ``k_tiles`` of block_k rows, at the
+    widest operand's ``itemsize``); ``f32_elems`` of float32 scratch
+    (accumulators, and the forward's two lane-wide running rows); four
+    sublane-padded (8, block_q) float32 rows, two buffers each; and
+    ``scores`` float32 temporaries of (block_q, block_k). An upper
+    bound, not a fit: this Mosaic (libtpu 0.0.34) chains the
+    elementwise steps through registers and lowered the float32
+    backward at 1024x1024, D 128, in 9 MiB where this counts 23, and
+    at 2048x2048 in 35 where this counts 78 (compiled for a described
+    v5e, PR 27). Never under Mosaic's default, so tiles that lowered
+    without a limit still do; never over ``VMEM_CAP`` (a tile pair
+    that needs more fails to lower, and the sweep records that)."""
+    tiles = 2 * itemsize * d * (q_tiles * block_q + k_tiles * block_k)
+    rows = 2 * 4 * 8 * block_q * 4
+    need = tiles + rows + 4 * f32_elems + 4 * scores * block_q * block_k
+    return max(VMEM_DEFAULT, min(VMEM_CAP, need))
+
+
+def _itemsize(*arrays_or_dtypes) -> int:
+    return max(jnp.dtype(getattr(x, "dtype", x)).itemsize
+               for x in arrays_or_dtypes)
+
 
 def _mask_scores(s, q_start, k_start, block_q: int, block_k: int,
                  causal: bool, window: int):
@@ -184,7 +222,11 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float, block_q: int,
         # pipeline them; only the K/V dim accumulates in scratch and
         # must stay sequential ("arbitrary")
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(
+                block_q, block_k, d, _itemsize(q, k, v), q_tiles=2,
+                k_tiles=2, f32_elems=block_q * (d + 2 * LANE),
+                scores=FWD_SCORES)),
         interpret=interpret,
         # the name is the device operation's in a profiler capture
         # (veles_flash_fwd.N); chipbench/metrics/flash_step_ms.py and
@@ -393,6 +435,7 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
         # dkv grid: b indexes grouped K/V rows; j = qh * nq + qi
         return (b // kv) * h + (b % kv) * group + j // nq
 
+    itemsize = _itemsize(q, k, v, do, *([out_dtype] if out_dtype else []))
     pad8 = jnp.broadcast_to(delta[:, None, :], (g, 8, t))
     lse8 = jnp.broadcast_to(lse[:, None, :], (g, 8, t))
     common = dict(scale=scale, causal=causal, block_q=block_q,
@@ -424,7 +467,10 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(
+                block_q, block_k, d, itemsize, q_tiles=2, k_tiles=4,
+                f32_elems=2 * block_k * d, scores=BWD_SCORES)),
         interpret=interpret,
         name="veles_flash_bwd_dkv",
     )(q, do, k, v, lse8, pad8)
@@ -454,7 +500,10 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
         out_shape=[jax.ShapeDtypeStruct((g, t, d), out_dtype or q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(
+                block_q, block_k, d, itemsize, q_tiles=3, k_tiles=2,
+                f32_elems=block_q * d, scores=BWD_SCORES)),
         interpret=interpret,
         name="veles_flash_bwd_dq",
     )(q, do, k, v, lse8, pad8)
@@ -469,24 +518,25 @@ def _use_pallas_bwd() -> bool:
 
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret,
+def _flash(q, k, v, causal, scale, blocks, bwd_blocks, interpret,
            window, h, kv, d_logical):
-    o, _ = _fwd_pallas(q, k, v, causal, scale, block_q, block_k,
+    o, _ = _fwd_pallas(q, k, v, causal, scale, *blocks,
                        interpret, window, h, kv)
     return o
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+def _flash_fwd(q, k, v, causal, scale, blocks, bwd_blocks, interpret,
                window, h, kv, d_logical):
-    o, lse = _fwd_pallas(q, k, v, causal, scale, block_q, block_k,
+    o, lse = _fwd_pallas(q, k, v, causal, scale, *blocks,
                          interpret, window, h, kv)
     # residuals keep the GROUPED k/v — the GQA memory saving holds
     # through the backward
     return o, (q, k, v, o, lse[:, 0, :])
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, window,
+def _flash_bwd(causal, scale, blocks, bwd_blocks, interpret, window,
                h, kv, d_logical, res, do):
+    block_q, block_k = bwd_blocks
     q = res[0]
     # trace-time analytic note for the backward pair (standard 2.5×
     # the forward: blockwise recompute + 4 gradient matmuls), billed
@@ -507,8 +557,11 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-#: VMEM budget bound: the kernel keeps ~5 (block, D_padded) f32 tiles
-#: resident; 512 lanes ≈ 1.3 MiB — comfortably inside the ~16 MiB VMEM
+#: VMEM budget bound. A kernel's working set grows with its tiles, not
+#: with D alone, and ``_vmem_limit`` counts it per call: the backward
+#: at the largest committed tiles (1024x1024), float32, D 512 counts
+#: 44 MiB of ``VMEM_CAP``'s 96; at the 128-row default tiles it is
+#: under 4 MiB. Past 512 lanes nothing has been compiled or measured
 MAX_D = 512
 
 
@@ -541,8 +594,8 @@ def choose_flash(t: int, d: int) -> bool:
     if jax.default_backend() != "tpu":
         return False          # before any DB read — off-TPU never flash
     # per-device measured crossover (seeded by the chip attn sweep;
-    # v5e-measured 4096 until then); one resolver shared with the
-    # bench gate
+    # 4096 for a device or head size not swept yet); one resolver
+    # shared with the bench gate
     from .autotune import resolved_min_t
     return t >= resolved_min_t(d)
 
@@ -583,23 +636,30 @@ def _prepare(q, k, v, scale, block_q, block_k, interpret, caller,
     build's port of the reference's measured-per-device block sizes,
     `veles/backends.py:623-731`), and the head-fold + lane-pad of the
     operands. Returns (q3, k3, v3, scale, interpret, b, t, h, kv, d,
-    block_q, block_k)."""
+    blocks, bwd_blocks): the backward pair's blocks are the forward's
+    unless the DB's row carries its own."""
     b, t, h, d = q.shape
     kv = k.shape[2]
+    bwd_q, bwd_k = block_q, block_k
     if block_q is None or block_k is None:
-        from .autotune import flash_blocks
-        abq, abk = flash_blocks(t, d, causal=causal, window=window)
-        block_q = abq if block_q is None else block_q
-        block_k = abk if block_k is None else block_k
+        from .autotune import flash_blocks_fwd_bwd
+        fwd, bwd = flash_blocks_fwd_bwd(
+            t, d, causal=causal, window=window,
+            dtype=jnp.result_type(q, k, v))
+        if block_q is None:
+            block_q, bwd_q = fwd[0], bwd[0]
+        if block_k is None:
+            block_k, bwd_k = fwd[1], bwd[1]
     if v.shape[2] != kv or h % kv:
         raise ValueError(
             "k/v head counts must match and divide q heads: q has %d, "
             "k %d, v %d" % (h, kv, v.shape[2]))
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if not supported(t, d, block_q, block_k):
-        raise ValueError("%s: T=%d D=%d not supported with blocks "
-                         "(%d, %d)" % (caller, t, d, block_q, block_k))
+    for bq, bk in {(block_q, block_k), (bwd_q, bwd_k)}:
+        if not supported(t, d, bq, bk):
+            raise ValueError("%s: T=%d D=%d not supported with blocks "
+                             "(%d, %d)" % (caller, t, d, bq, bk))
     if interpret is None:
         # compiled, or an error: a kernel reached off-TPU must not run
         # interpreted without a word. The one exception is the test
@@ -618,7 +678,7 @@ def _prepare(q, k, v, scale, block_q, block_k, interpret, caller,
         return xt
 
     return (fold(q), fold(k), fold(v), float(scale), interpret,
-            b, t, h, kv, d, block_q, block_k)
+            b, t, h, kv, d, (block_q, block_k), (bwd_q, bwd_k))
 
 
 def flash_attention_fwd_lse(q, k, v, causal: bool = False,
@@ -632,10 +692,10 @@ def flash_attention_fwd_lse(q, k, v, causal: bool = False,
     partials by lse and defines the blockwise ring backward itself
     (parallel/ring_attention.py). Same folding/padding/support rules
     as :func:`flash_attention`."""
-    q3, k3, v3, scale, interpret, b, t, h, kv, d, block_q, block_k = \
+    q3, k3, v3, scale, interpret, b, t, h, kv, d, blocks, _ = \
         _prepare(q, k, v, scale, block_q, block_k, interpret,
                  "flash_attention_fwd_lse", causal=causal)
-    o, lse = _fwd_pallas(q3, k3, v3, causal, scale, block_q, block_k,
+    o, lse = _fwd_pallas(q3, k3, v3, causal, scale, *blocks,
                          interpret, 0, h, kv)
     o = jnp.moveaxis(o[..., :d].reshape(b, h, t, d), 1, 2)
     lse = jnp.moveaxis(lse[:, 0, :].reshape(b, h, t), 1, 2)  # (B,T,H)
@@ -655,7 +715,7 @@ def flash_attention_bwd_lse(q, k, v, lse, delta, do,
     attention's per-step backward engine (the global lse makes each
     block's probabilities exact regardless of which blocks this call
     sees). VMEM-resident kernels; no (T, T) materialization."""
-    q3, k3, v3, scale, interpret, b, t, h, kv, d, block_q, block_k = \
+    q3, k3, v3, scale, interpret, b, t, h, kv, d, _, blocks = \
         _prepare(q, k, v, scale, block_q, block_k, interpret,
                  "flash_attention_bwd_lse", causal=causal)
 
@@ -672,7 +732,7 @@ def flash_attention_bwd_lse(q, k, v, lse, delta, do,
     dq, dk, dv = _bwd_pallas_core(
         q3, k3, v3, fold_g(lse).astype(jnp.float32),
         fold_g(delta).astype(jnp.float32), do3, causal, scale,
-        block_q, block_k, interpret, 0, h, kv, out_dtype=jnp.float32)
+        *blocks, interpret, 0, h, kv, out_dtype=jnp.float32)
 
     def unfold(x, heads):
         return jnp.moveaxis(x[..., :d].reshape(b, heads, t, d), 1, 2)
@@ -704,7 +764,7 @@ def flash_attention(q, k, v, causal: bool = False,
         raise ValueError("sliding-window attention requires causal=True")
     if window >= q.shape[1]:
         window = 0          # a window covering everything is no window
-    q3, k3, v3, scale, interpret, b, t, h, kv, d, block_q, block_k = \
+    q3, k3, v3, scale, interpret, b, t, h, kv, d, blocks, bwd_blocks = \
         _prepare(q, k, v, scale, block_q, block_k, interpret,
                  "flash_attention", causal=causal, window=window)
     # trace-time events (run once per trace, not per execution): the
@@ -720,6 +780,6 @@ def flash_attention(q, k, v, causal: bool = False,
         inc("veles_flash_attention_interpret_traces_total")
     note_kernel_cost(analytic_cost(b, t, h, d, causal, window))
     o = _flash(q3, k3, v3, causal, scale,
-               block_q, block_k, interpret, window, h, kv, d)
+               blocks, bwd_blocks, interpret, window, h, kv, d)
     o = o[..., :d].reshape(b, h, t, d)
     return jnp.moveaxis(o, 1, 2)

@@ -46,6 +46,11 @@ DESCRIPTIONS = {
     "veles_flash_attention_interpret_traces_total":
         "Of those, programs whose kernel runs in Pallas interpret mode "
         "(test harness only; 0 on any chip run)",
+    "veles_flash_default_blocks_traces_total":
+        "Flash-attention block lookups on a TPU that found no measured "
+        "row in kernel_tuning.json and fell back to the 128x128 default "
+        "tiles (each about a microsecond of grid overhead a tile; "
+        "0 once the shape is swept)",
     "veles_spans_total":
         "Telemetry spans recorded",
     # resilience subsystem (veles_tpu/resilience/): these exist so
