@@ -124,6 +124,20 @@ def place_like(new, old):
     return jax.tree_util.tree_map(put, new, old)
 
 
+def metrics_series():
+    """{series: value} of what the program's ``/metrics`` would print now:
+    every counter, and a histogram as its ``_sum`` and ``_count``. A serving
+    cell's parent scrapes the page; a training cell has no server, so this
+    process reads the two registries the page is rendered from. Two
+    dictionary copies on the host, no device sync."""
+    from veles_tpu.telemetry.counters import counters, histograms
+    out = {k: float(v) for k, v in counters.snapshot().items()}
+    for name, h in histograms.snapshot().items():
+        out[name + "_sum"] = float(h["sum"])
+        out[name + "_count"] = float(h["count"])
+    return out
+
+
 class TrainWindow:
     """Drives nothing itself: it sits around ``TrainStep.xla_run`` while
     the workflow's own loop (loader, step, decision) runs. The first
@@ -131,7 +145,9 @@ class TrainWindow:
     compared; then the window opens, and closes at the first step boundary
     past ``seconds``. The compiled step and its state are one object from
     the first dispatch to the last. With ``--trace 1`` the profiler is on
-    for a slice of ``trace_seconds`` in the window's middle."""
+    for a slice of ``trace_seconds`` in the window's middle. The window's
+    and the slice's ``counters`` are the rise of the program's ``/metrics``
+    series between their two ends, as a serving slice's are."""
 
     def __init__(self, spec, wf, probe):
         self.spec, self.wf, self.probe = spec, wf, probe
@@ -230,6 +246,7 @@ class TrainWindow:
         self.slice_s = min(seconds, self.wl.get("trace_seconds", seconds))
         self.probe.window_open = self.opened = True
         self.result["setup_s"] = time.time() - self.spec["t_start"]
+        self.series0 = metrics_series()
         self.t0 = time.perf_counter()
         self.deadline = self.t0 + seconds
         self.slice_at = (self.t0 + (seconds - self.slice_s) / 2.0
@@ -240,14 +257,18 @@ class TrainWindow:
         jax.block_until_ready(self.wf.train_step.params)
         jax.profiler.start_trace(self.spec["trace_dir"])
         self.slice_at = None
-        self.slice = {"t0": time.perf_counter(), "steps": -self.steps,
+        self.slice = {"series0": metrics_series(),
+                      "t0": time.perf_counter(), "steps": -self.steps,
                       "from_s": time.perf_counter() - self.t0}
 
     def slice_close(self):
         import jax
         jax.block_until_ready(self.wf.train_step.params)
         t1 = time.perf_counter()
+        series = metrics_series()
         jax.profiler.stop_trace()
+        self.slice["counters"] = reduce.counters_rise(
+            self.slice.pop("series0"), series)
         self.slice["window_s"] = t1 - self.slice["t0"]
         self.slice["steps"] += self.steps
         self.slice["tokens"] = (self.slice["steps"] * self.wl["minibatch"]
@@ -258,11 +279,13 @@ class TrainWindow:
         step = self.wf.train_step
         jax.block_until_ready(step.params)
         t1 = time.perf_counter()
+        series = metrics_series()
         self.probe.window_open = False
         if self.slice and "window_s" not in self.slice:
             self.slice_close()
         self.closed = True
         self.result.update(
+            counters=reduce.counters_rise(self.series0, series),
             window_s=t1 - self.t0, steps=self.steps,
             tokens=self.steps * self.wl["minibatch"] * self.wl["seq_len"],
             memory=self.probe.memory())
@@ -287,7 +310,7 @@ class TrainWindow:
             planes = reduce.load_xplane(self.spec["trace_dir"])
             out["trace"] = reduce.reduce_trace(planes, self.slice["window_s"])
             out["slice"] = {k: self.slice[k] for k in (
-                "from_s", "window_s", "steps", "tokens")}
+                "from_s", "window_s", "steps", "tokens", "counters")}
             keep_sample(self.spec, planes)
         rows = reference.make_tokens(
             self.spec["seed"], self.wl["rows_per_epoch"], self.wl["seq_len"],
@@ -380,12 +403,13 @@ def run(load, main):
     if device["count"] < spec["workload"]["chips"]:
         raise SystemExit("chipbench: %d device(s), the cell needs %d"
                          % (device["count"], spec["workload"]["chips"]))
+    kind = spec["workload"]["kind"]
     try:
-        modules.reference_of(spec["config"],
-                             training=spec["workload"]["kind"] == "train")
+        modules.reference_of(spec["config"], training=kind == "train",
+                             serving=kind == "serve")
     except modules.ContractError as e:
         raise SystemExit("chipbench: %s" % e)
-    if spec["workload"]["kind"] == "train":
+    if kind == "train":
         report = train_cell(spec, load, main, probe)
     else:
         from chipbench import serve_side

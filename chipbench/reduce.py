@@ -64,6 +64,15 @@ def tail_ms(samples, q=95.0):
     return p * 1000.0
 
 
+def counters_rise(before, after):
+    """{series: rise} between two readings of the program's ``/metrics``
+    series ({name: value}): every counter, and a histogram as its ``_sum``
+    and ``_count``. A slice's and a window's ``counters`` are this, in a
+    cell that serves and in one that trains."""
+    return {k: after[k] - before.get(k, 0.0) for k in after
+            if k.endswith(("_total", "_sum", "_count"))}
+
+
 def interval_union(intervals):
     """Total length covered by [start, end) intervals, overlaps once."""
     total, cur_s, cur_e = 0, None, None

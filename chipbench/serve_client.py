@@ -16,7 +16,7 @@ import statistics
 import threading
 import time
 
-from chipbench import traffic as traffic_mod
+from chipbench import reduce, traffic as traffic_mod
 
 WARM_TIMEOUT = 1100.0
 REQUEST_TIMEOUT = 150.0
@@ -160,8 +160,7 @@ def drive(child, spec):
         os.kill(child.proc.pid, signal.SIGUSR1)
         child.wait_for("chipbench: trace off", 120)
         piece = {"from_s": a - t0, "to_s": b - t0, "window_s": b - a,
-                 "counters": {k: after[k] - before.get(k, 0.0) for k in after
-                              if k.endswith(("_total", "_sum", "_count"))}}
+                 "counters": reduce.counters_rise(before, after)}
     time.sleep(max(0.0, t0 + seconds - clock()))
     t1 = clock()
     os.kill(child.proc.pid, signal.SIGUSR1)

@@ -42,7 +42,7 @@ def build_workflow(cfg, wl):
 
     std = nn.StandardWorkflow(
         name="chipbench-serve",
-        layers=modules.reference_of(cfg).layer_list(cfg),
+        layers=modules.reference_of(cfg, serving=True).layer_list(cfg),
         loader_unit=NoData(None, minibatch_size=1, name="nodata"),
         loss_function="softmax_seq")
     wf = std.extract_forward_workflow()
@@ -56,7 +56,7 @@ class ServeSide:
     def __init__(self, spec, wf, probe):
         self.spec, self.wf, self.probe = spec, wf, probe
         self.cfg, self.wl = spec["config"], spec["workload"]
-        self.reference = modules.reference_of(self.cfg)
+        self.reference = modules.reference_of(self.cfg, serving=True)
         self.todo = (["window open"]
                      + (["trace on", "trace off"] if spec["trace"] else [])
                      + ["window closed"])

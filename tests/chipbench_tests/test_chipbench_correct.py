@@ -8,7 +8,9 @@
   returns its state unchanged; half of the batch left out, the mean taken
   over the rest; a served token altered where it is produced): ``correct``
   comes out false, and true with nothing broken;
-- a rehearsal run's last line: the keys the driver reads, and no metric.
+- a rehearsal run's last line: the keys the driver reads, and no metric;
+  the child's report behind it: a training cell's carries the window's
+  counters, a serving cell's is key for key what it was.
 """
 import json
 import os
@@ -267,6 +269,9 @@ def test_the_profiler_is_on_for_a_slice_in_the_middle(monkeypatch):
     assert 0 < piece["steps"] < report["steps"]
     assert piece["from_s"] + piece["window_s"] <= report["window_s"]
     assert report["trace"]["window_s"] == piece["window_s"]
+    # the slice's counters are of its own steps, the window's of all
+    assert piece["counters"]["veles_dispatches_total"] == piece["steps"]
+    assert report["counters"]["veles_dispatches_total"] == report["steps"]
 
 
 # -- a rehearsal run's last line ---------------------------------------------
@@ -280,22 +285,93 @@ def rehearse(workload, *extra):
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
 
 
+def rehearse_kept(workload, keep):
+    """A rehearsal with ``--keep``: the run, and as its ``report`` the
+    child's whole report (with what the parent added) where it ended well."""
+    run = rehearse(workload, "--trace", "0", "--rehearse", "--keep",
+                   str(keep))
+    run.report = None
+    if run.returncode == 0:
+        (name,) = os.listdir(keep)
+        with open(os.path.join(str(keep), name)) as f:
+            run.report = json.load(f)
+    return run
+
+
 @pytest.fixture(scope="module", params=sorted(SERVING))
-def served_run(request):
-    return rehearse(request.param, "--trace", "0", "--rehearse")
+def served_run(request, tmp_path_factory):
+    return rehearse_kept(request.param, tmp_path_factory.mktemp("keep"))
 
 
-def test_rehearsal_line(served_run):
-    assert served_run.returncode == 0, served_run.stderr[-2000:]
-    line = json.loads(served_run.stdout.strip().splitlines()[-1])
+@pytest.fixture(scope="module")
+def trained_run(tmp_path_factory):
+    return rehearse_kept("tiny_train", tmp_path_factory.mktemp("keep"))
+
+
+def rehearsal_line(run):
+    """The last line of a rehearsal that ended well: the keys the driver
+    reads, ``checks`` last, the CPU named and no metric."""
+    assert run.returncode == 0, run.stderr[-2000:]
+    line = json.loads(run.stdout.strip().splitlines()[-1])
     assert list(line)[-1] == "checks"
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
     assert line["device"]["platform"] == "cpu" and line["rehearsal"] is True
     assert line["metrics"] == {}, "a CPU run carries no metric"
     assert line["correct"] is True and line["failed"] == 0
-    assert line["attempted"] >= 4
+    assert "compilations inside the window: 0" in run.stdout
+    return line
+
+
+def test_rehearsal_line(served_run):
+    assert rehearsal_line(served_run)["attempted"] >= 4
     assert "served_logit_gap" in served_run.stderr.strip().splitlines()[-1]
-    assert "compilations inside the window: 0" in served_run.stdout
+
+
+#: a serving report's keys, and a request's, as they were before the training
+#: report gained its counters: the serving side gained and lost none
+SERVING_REPORT = [
+    "attempted", "checks", "compile_s", "compiles", "compiles_in_window",
+    "correct", "device", "failed", "generator_late_ms", "memory", "program",
+    "reference_s", "requests", "setup_s", "slice", "window_s"]
+SERVED_REQUEST = [
+    "due", "error", "first", "i", "last", "n_new", "ok", "prompt_len",
+    "sampled", "sent", "stamps", "tokens", "tokens_in_window"]
+
+
+def test_a_serving_report_is_key_for_key_what_it_was(served_run):
+    report = served_run.report
+    assert sorted(report) == SERVING_REPORT
+    assert report["slice"] is None, "the slice is the profiler's"
+    assert all(sorted(r) == SERVED_REQUEST for r in report["requests"])
+
+
+def test_training_rehearsal_line(trained_run):
+    assert sorted(rehearsal_line(trained_run)["checks"]) == [
+        "delta_norm_gap", "grad_norm_gap", "loss_gap"]
+
+
+def test_a_training_report_carries_the_window_s_counters(trained_run):
+    """Without the profiler there is no slice; the window's counters are
+    the rise of the program's ``/metrics`` series over it, under the names
+    and in the shape that a serving slice's have."""
+    report = trained_run.report
+    assert "slice" not in report
+    counters = report["counters"]
+    assert counters["veles_dispatches_total"] >= report["steps"] >= 1
+    assert counters["veles_compiles_total"] == 0
+    assert all(k.endswith(("_total", "_sum", "_count"))
+               and isinstance(v, float) for k, v in counters.items())
+
+
+def test_counters_rise_is_one_shape_for_both_kinds_of_cell():
+    from chipbench import reduce
+    before = {"a_total": 2.0, "h_seconds_sum": 0.5, "h_seconds_count": 4.0,
+              "h_seconds_p50": 0.1, "a_gauge": 7.0}
+    after = dict(before, a_total=5.0, h_seconds_sum=0.75, h_seconds_count=6.0,
+                 b_total=3.0, h_seconds_p50=0.2)
+    assert reduce.counters_rise(before, after) == {
+        "a_total": 3.0, "h_seconds_sum": 0.25, "h_seconds_count": 2.0,
+        "b_total": 3.0}
 
 
 def test_no_accelerator_no_result():

@@ -3,6 +3,12 @@
 Every name, unit, layer, file and cross-reference is one parametrised
 case, so a manifest that would be sent back as ``manifest_invalid`` fails
 here first, on the CPU.
+
+The rules on a configuration (``config_faults``) go further than the
+driver's refusal list: what ``reduced`` may name and how far it may cut is
+the ``model-configs`` guide's section 4, where a configuration is one
+chip's share of a stated deployment (its experts, its heads, its slice of
+the vocabulary), held to that guide's floors.
 """
 import json
 import os
@@ -154,29 +160,217 @@ def test_four_chip_cells_are_few():
     assert four <= max(1, len(M["workloads"]) // 4)
 
 
+#: keys of ``reduced`` by what they count; a width is none of them
+EXPERTS = ("num_experts", "n_routed_experts", "num_local_experts")
+HEADS = re.compile(r"(^|_)heads?$")
+DEPTH, VOCAB = "num_hidden_layers", "vocab_size"
+LEAST_DEPTH, LEAST_EXPERTS, LEAST_HEADS, VOCAB_SHARE = 4, 8, 1, 8
+
+
+def whole(value, least):
+    return (isinstance(value, int) and not isinstance(value, bool)
+            and value >= least)
+
+
+def floor_of(key, published):
+    """(the least a reduced ``key`` may be, that in words), or (None, None)
+    for a key whose count the guide gives no floor."""
+    if key == DEPTH:
+        return LEAST_DEPTH, "%d layers" % LEAST_DEPTH
+    if key == VOCAB and whole(published, 1):
+        return -(-published // VOCAB_SHARE), "an eighth of the vocabulary"
+    if key in EXPERTS:
+        return LEAST_EXPERTS, "%d experts" % LEAST_EXPERTS
+    if HEADS.search(key):
+        return LEAST_HEADS, "%d head" % LEAST_HEADS
+    return None, None
+
+
+def config_faults(entry, sizes):
+    """What is wrong with one configuration: ``entry`` as ``BENCHMARK.json``
+    has it and ``sizes``, its file. No fault is the empty list.
+
+    No width is ever reduced. Depth may be, and what one chip of a stated
+    deployment holds of a layer: its experts, its heads, its rows of the
+    vocabulary (the guide's section 4), each no further than its floor, and
+    only so far that ``chips_per_layer`` such shares (for the vocabulary
+    ``vocab_shards``, where the file gives it) make up the published count."""
+    faults = []
+    if set(entry) != {"name", "source", "file", "reduced", "why"}:
+        faults.append("the entry's keys are %s" % sorted(entry))
+    for key in ("source", "why"):
+        if not line(entry.get(key)):
+            faults.append("%s is no line of 1 to 200 characters" % key)
+    if not PATH.match(entry.get("file", "")):
+        faults.append("file is no path: %r" % entry.get("file"))
+    reduced = entry.get("reduced", [])
+    if len(reduced) > 16:
+        faults.append("reduced has %d keys, over 16" % len(reduced))
+    if sizes.get("reduced") != reduced:
+        faults.append("the file's reduced is not the entry's")
+    if sizes.get("source") != entry.get("source"):
+        faults.append("the file's source is not the entry's")
+    published = sizes.get("published", {})
+    for key, value in published.items():
+        if key in sizes and key not in reduced and sizes[key] != value:
+            faults.append("%s differs from its published value and is not "
+                          "in reduced" % key)
+    chips = sizes.get("chips_per_layer")
+    shards = {VOCAB: sizes.get("vocab_shards", chips)}
+    for key in reduced:
+        if key not in sizes or key not in published:
+            faults.append("%s is reduced from no published value" % key)
+            continue
+        here, there = sizes[key], published[key]
+        if here == there:
+            faults.append("%s is in reduced and equals its published value"
+                          % key)
+        if (key.endswith(("_dim", "_rank", "_size")) and key != VOCAB) \
+                or key.endswith("_per_tok"):
+            faults.append("a width is never reduced: %s" % key)
+            continue
+        least, what = floor_of(key, there)
+        if least is not None and not whole(here, least):
+            faults.append("%s is %r, under the floor of %s"
+                          % (key, here, what))
+        if key == DEPTH:
+            continue
+        # anything but the depth is this chip's share of a layer
+        over = shards.get(key, chips)
+        if whole(chips, 2) and whole(over, 1) and whole(here, 1) \
+                and here * over < there:
+            faults.append("%s: %d shares of %r are less than the published "
+                          "%r: what is held here is a share, not a smaller "
+                          "model" % (key, over, here, there))
+    if set(reduced) - {DEPTH}:
+        if not (isinstance(sizes.get("deployment"), str)
+                and sizes["deployment"].strip()):
+            faults.append("a share of a layer states its deployment")
+        if not whole(chips, 2):
+            faults.append("a share of a layer states chips_per_layer, a "
+                          "whole number of 2 or more: %r" % chips)
+        if "vocab_shards" in sizes and not whole(sizes["vocab_shards"], 1):
+            faults.append("vocab_shards is no whole number: %r"
+                          % sizes["vocab_shards"])
+    return faults
+
+
 @pytest.mark.parametrize("config", M["configs"], ids=lambda c: c["name"])
 def test_config(config):
-    assert set(config) == {"name", "source", "file", "reduced", "why"}
-    assert line(config["source"]) and line(config["why"])
-    assert PATH.match(config["file"])
     assert any(config["file"].startswith(p + "/") for p in M["paths"])
-    assert len(config["reduced"]) <= 16
     assert any(w["config"] == config["name"] for w in M["workloads"])
     with open(os.path.join(ROOT, config["file"])) as f:
         sizes = json.load(f)
-    assert sizes["reduced"] == config["reduced"]
-    assert sizes["source"] == config["source"]
-    for key in config["reduced"]:
-        assert not key.endswith(("_dim", "_rank", "_size")), \
-            "a width (the vocabulary is the head's) is never reduced: %s" % key
-    published = sizes["published"]
-    for key, value in published.items():
-        if key in sizes and key not in config["reduced"]:
-            assert sizes[key] == value, key
-    for key in config["reduced"]:
-        assert sizes[key] != published[key]
+    assert config_faults(config, sizes) == []
     files = [c["file"] for c in M["configs"]]
     assert files.count(config["file"]) == 1
+
+
+#: the published sizes of two sparse models, as far as the rules read them
+SPARSE_512 = {"hidden_size": 2048, "moe_intermediate_size": 512,
+              "head_dim": 256, "num_attention_heads": 16,
+              "num_key_value_heads": 2, "num_experts": 512,
+              "num_experts_per_tok": 10, "num_hidden_layers": 48,
+              "vocab_size": 151936}
+SPARSE_384 = {"hidden_size": 7168, "moe_intermediate_size": 2048,
+              "kv_lora_rank": 512, "num_attention_heads": 64,
+              "n_routed_experts": 384, "num_experts_per_tok": 8,
+              "num_hidden_layers": 61, "vocab_size": 163840}
+
+
+def made_up(published, cut, **stated):
+    """(entry, file) of a configuration that no manifest names: every
+    published size but those of ``cut``, which are reduced to its values;
+    ``stated`` is what else the file says (``chips_per_layer``, ...)."""
+    entry = {"name": "made-up", "source": "https://example.org/config.json",
+             "file": "chipbench/configs/made-up.json",
+             "reduced": list(cut), "why": "a case of the rules"}
+    sizes = dict(published, **cut)
+    sizes.update(published=dict(published), reduced=list(cut),
+                 source=entry["source"],
+                 deployment="each layer divided over the chips stated")
+    sizes.update(stated)
+    return entry, {k: v for k, v in sizes.items() if v is not None}
+
+
+SHARE_512 = {"num_hidden_layers": 4, "num_experts": 32, "vocab_size": 18992}
+SHARE_384 = {"num_hidden_layers": 4, "n_routed_experts": 12,
+             "vocab_size": 20480}
+
+
+@pytest.mark.parametrize("published,cut,stated", [
+    (SPARSE_512, SHARE_512, {"chips_per_layer": 16, "vocab_shards": 8}),
+    (SPARSE_384, SHARE_384, {"chips_per_layer": 32, "vocab_shards": 8}),
+    (SPARSE_512, {"num_hidden_layers": 4}, {"deployment": None}),
+    (SPARSE_512, {"num_experts": 64, "num_attention_heads": 2,
+                  "num_key_value_heads": 1, "vocab_size": 18992},
+     {"chips_per_layer": 8}),
+], ids=["one_of_16_chips_32_of_512_experts_an_eighth_of_the_vocabulary",
+        "one_of_32_chips_12_of_384_experts_an_eighth_of_the_vocabulary",
+        "depth_alone_states_no_deployment",
+        "heads_and_experts_over_8_chips_the_vocabulary_with_them"])
+def test_a_share_the_rules_admit(published, cut, stated):
+    assert config_faults(*made_up(published, cut, **stated)) == []
+
+
+SIXTEEN = {"chips_per_layer": 16, "vocab_shards": 8}
+
+
+@pytest.mark.parametrize("published,cut,stated,fault", [
+    (SPARSE_512, {"hidden_size": 1024}, SIXTEEN,
+     "a width is never reduced: hidden_size"),
+    (SPARSE_512, {"moe_intermediate_size": 256}, SIXTEEN,
+     "a width is never reduced: moe_intermediate_size"),
+    (SPARSE_512, {"head_dim": 128}, SIXTEEN,
+     "a width is never reduced: head_dim"),
+    (SPARSE_384, {"kv_lora_rank": 256}, SIXTEEN,
+     "a width is never reduced: kv_lora_rank"),
+    (SPARSE_512, {"num_experts_per_tok": 2}, SIXTEEN,
+     "a width is never reduced: num_experts_per_tok"),
+    (SPARSE_512, dict(SHARE_512, vocab_size=18991), {"chips_per_layer": 16},
+     "vocab_size is 18991, under the floor of an eighth of the vocabulary"),
+    (SPARSE_512, dict(SHARE_512, num_experts=7),
+     {"chips_per_layer": 128, "vocab_shards": 8},
+     "num_experts is 7, under the floor of 8 experts"),
+    (SPARSE_512, dict(SHARE_512, num_hidden_layers=3), SIXTEEN,
+     "num_hidden_layers is 3, under the floor of 4 layers"),
+    (SPARSE_512, {"num_key_value_heads": 0}, SIXTEEN,
+     "num_key_value_heads is 0, under the floor of 1 head"),
+    (SPARSE_512, SHARE_512, {"vocab_shards": 8},
+     "a share of a layer states chips_per_layer"),
+    (SPARSE_512, SHARE_512, {"chips_per_layer": 1, "vocab_shards": 8},
+     "a share of a layer states chips_per_layer"),
+    (SPARSE_512, SHARE_512, dict(SIXTEEN, deployment=""),
+     "a share of a layer states its deployment"),
+    (SPARSE_512, SHARE_512, {"chips_per_layer": 8, "vocab_shards": 8},
+     "num_experts: 8 shares of 32 are less than the published 512"),
+    (SPARSE_512, SHARE_512, {"chips_per_layer": 16, "vocab_shards": 4},
+     "vocab_size: 4 shares of 18992 are less than the published 151936"),
+    (SPARSE_512, dict(SHARE_512, num_experts=512), SIXTEEN,
+     "num_experts is in reduced and equals its published value"),
+    (SPARSE_512, dict(SHARE_512, rope_theta=1e6), SIXTEEN,
+     "rope_theta is reduced from no published value"),
+], ids=["hidden_size", "moe_intermediate_size", "head_dim", "kv_lora_rank",
+        "num_experts_per_tok", "vocabulary_under_an_eighth", "7_experts",
+        "depth_3", "no_head", "no_chips_per_layer", "one_chip_a_layer",
+        "no_deployment", "experts_are_no_share", "vocabulary_is_no_share",
+        "reduced_equals_published", "reduced_from_nothing"])
+def test_a_configuration_the_rules_refuse(published, cut, stated, fault):
+    """Each refusal has its own message, and a case that breaks one rule
+    meets that rule and no other."""
+    faults = config_faults(*made_up(published, cut, **stated))
+    assert len(faults) == 1 and faults[0].startswith(fault), faults
+
+
+def test_an_unreduced_key_equals_its_published_value():
+    entry, sizes = made_up(SPARSE_512, SHARE_512, **SIXTEEN)
+    sizes["num_attention_heads"] = 8
+    assert config_faults(entry, sizes) == [
+        "num_attention_heads differs from its published value and is not in "
+        "reduced"]
+    entry["source"] = "another"
+    assert "the file's source is not the entry's" in config_faults(
+        entry, sizes)
 
 
 @pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])

@@ -1,7 +1,9 @@
 """The seam between the harness and an architecture: every configuration
 names a ``reference`` and a ``counts`` module that load and export the
-contract of ``chipbench/modules.py``, the counts without jax; and the
-harness's own files name no key or leaf of any one architecture.
+contract of ``chipbench/modules.py``, the counts without jax; a reference
+module brings the serving half, the training half or both, and is asked
+for the one its cell needs; and the harness's own files name no key or
+leaf of any one architecture.
 """
 import glob
 import json
@@ -70,24 +72,88 @@ def test_counts_load_without_jax(path):
     assert out.stdout.strip()
 
 
-@pytest.mark.parametrize("path", CONFIGS, ids=stem)
-def test_reference_module_exports_the_contract(path):
-    cfg = config(path)
+HALVES = {"training": (modules.TRAINING, "the training half"),
+          "serving": (modules.SERVING, "the serving half")}
+
+
+def held_to_what_it_exports(cfg):
+    """What the module exports decides which of ``reference_of(cfg,
+    training=True)`` and ``reference_of(cfg, serving=True)`` raise, and the
+    line names the half that is missing. Returns the halves it has."""
     mod = modules.reference_of(cfg)
     for name in modules.REFERENCE:
         assert callable(getattr(mod, name)), name
-    layers = mod.layer_list(cfg)
+    has = []
+    for flag, (names, words) in HALVES.items():
+        if all(callable(getattr(mod, n, None)) for n in names):
+            assert modules.reference_of(cfg, **{flag: True}) is mod
+            has.append(flag)
+        else:
+            with pytest.raises(modules.ContractError,
+                               match="exports no .*: " + words):
+                modules.reference_of(cfg, **{flag: True})
+    assert has, "a module may leave either half out, not both"
+    return has
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=stem)
+def test_reference_module_exports_the_contract(path):
+    cfg = config(path)
+    held_to_what_it_exports(cfg)
+    layers = modules.reference_of(cfg).layer_list(cfg)
     assert len([ly for ly in layers if ly["name"].startswith("blk")]) \
         == cfg["num_hidden_layers"]
     assert "solver" not in layers[0]
     assert all(ly["solver"] == "adam" and ly["learning_rate"] == 0.5
-               for ly in mod.layer_list(cfg, lr=0.5))
-    trains = all(callable(getattr(mod, n, None)) for n in modules.TRAINING)
-    if trains:
-        assert modules.reference_of(cfg, training=True) is mod
-    else:
-        with pytest.raises(modules.ContractError, match="exports no"):
-            modules.reference_of(cfg, training=True)
+               for ly in modules.reference_of(cfg).layer_list(cfg, lr=0.5))
+
+
+def tiny(name):
+    return config(os.path.join(ROOT, "chipbench", "configs", name + ".json"))
+
+
+def trains_only():
+    """``tiny`` on a module made for this test: it has the three training
+    names and no ``served_gaps``."""
+    return dict(tiny("tiny"), name="trains-only", reference=os.path.join(
+        "tests", "chipbench_tests", "reference_trains_only.py"))
+
+
+@pytest.mark.parametrize("cfg,halves", [
+    (tiny("tiny-gpt"), ["serving"]), (tiny("tiny"), ["training", "serving"]),
+    (trains_only(), ["training"])],
+    ids=["serves_only", "both", "trains_only"])
+def test_the_contract_s_three_shapes(cfg, halves):
+    assert held_to_what_it_exports(cfg) == halves
+
+
+def test_a_module_with_neither_half_is_refused(monkeypatch):
+    cfg = trains_only()
+    monkeypatch.delattr(modules.reference_of(cfg), "train_reference")
+    with pytest.raises(modules.ContractError,
+                       match="neither half .* lacks served_gaps, .* lacks "
+                             "train_reference$"):
+        modules.reference_of(cfg)
+
+
+@pytest.mark.parametrize("cfg,kind,lacks", [
+    (tiny("tiny-gpt"), "train", "the training half (TRAINING)"),
+    (trains_only(), "serve", "exports no served_gaps: the serving half "
+                             "(SERVING)")],
+    ids=["train_cell_on_serves_only", "serve_cell_on_trains_only"])
+def test_a_cell_on_a_module_that_lacks_its_half(monkeypatch, cfg, kind,
+                                                lacks):
+    """``model_file.run`` asks by the cell's kind, before it builds
+    anything, and stops with one plain line."""
+    from chipbench import model_file
+    spec = {"workload": {"name": "x", "kind": kind, "chips": 1},
+            "config": cfg, "platform": "cpu", "t_start": 0.0, "seed": 1}
+    monkeypatch.setenv(model_file.SPEC_ENV, json.dumps(spec))
+    with pytest.raises(SystemExit) as stop:
+        model_file.run(None, None)
+    text = str(stop.value)
+    assert text.startswith("chipbench: ") and "\n" not in text
+    assert lacks in text
 
 
 def test_the_parent_imports_no_jax():
