@@ -8,6 +8,7 @@ riding out long co-tenants, the jit cache is bounded by
 ``len(buckets) + 1`` programs, and tickets older than their deadline
 are answered 503 + Retry-After instead of rotting in the queue."""
 import json
+import re
 import time
 import urllib.error
 import urllib.request
@@ -656,3 +657,188 @@ def test_program_count_bounded_with_spec_and_beam(pooled):
     base = [k for k in engine._progs
             if k[0] in ("prefill", "step")]
     assert len(base) <= len(engine.buckets) + 1
+
+
+# -- the float decode step's pool writes ----------------------------------------
+
+#: slot layout for the two tests below: (mask, shared, pages, pos).
+#: Page 5 is a prefix page three slots adopted; slot 2 starts INSIDE
+#: its shared pages (the engine copies such a page first, the step
+#: must not rely on it); slot 0 runs off the view's end at
+#: decode_block 4; slots 3-5 are masked out (co-tenant spec/beam rows)
+_STEP_SLOTS = [(1, 0, [1, 2, 3, 4], 26),
+               (1, 1, [5, 6, 7, 0], 9),
+               (1, 2, [5, 8, 9, 0], 14),
+               (0, 0, [10, 11, 0, 0], 3),
+               (0, 1, [5, 12, 0, 0], 9),
+               (0, 0, [13, 14, 15, 16], 30)]
+
+
+def _step_fixture(wf, decode_block):
+    """An unstarted float engine's decode-step program, its parameters
+    and a pool of noise (so an unwanted write shows wherever it lands)
+    under the ``_STEP_SLOTS`` layout."""
+    import jax.numpy as jnp
+    engine = ContinuousEngine(wf, max_slots=len(_STEP_SLOTS),
+                              buckets=(8,), max_context=32, page_size=8,
+                              pages=24, decode_block=decode_block,
+                              name="eng_rows%d" % decode_block)
+    params = engine._prepare_params()
+    engine._ensure_pool(params)
+    rng = numpy.random.RandomState(31)
+    caches = tuple(tuple(jnp.asarray(rng.standard_normal(a.shape)
+                                     .astype(a.dtype)) for a in pool)
+                   for pool in engine._caches)
+    mask, shared, pages, pos = zip(*_STEP_SLOTS)
+    state = dict(
+        tok=numpy.arange(3, 3 + len(pos), dtype=numpy.int32),
+        pos=numpy.array(pos, numpy.int32),
+        temp=numpy.array([0.0, 0.8] * (len(pos) // 2), numpy.float32),
+        mask=numpy.array(mask, numpy.int32),
+        tables=numpy.array(pages, numpy.int32),
+        shared=numpy.array(shared, numpy.int32),
+        keys=jnp.asarray(rng.randint(0, 2 ** 31, (len(pos), 2))
+                         .astype(numpy.uint32)))
+    return engine, params, caches, state
+
+
+def _whole_view_step(engine):
+    """The oracle: gather every slot's whole view, ``_block_step``,
+    write every view back whole — the formulation the row write-back
+    replaced, kept here as the plain statement of what the pool must
+    hold after a step."""
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu.nn.sampling import (_block_step, _embed_ids,
+                                       _head_logits, _split_rows)
+    from veles_tpu.ops import matmul_precision
+    from veles_tpu.serving.engine import _TEMP_EPS
+    stem, head = engine.stack["stem"], engine.stack["head"]
+    blocks, pos_emb = engine.stack["blocks"], engine.stack["pos_emb"]
+    prec = matmul_precision()
+
+    def step(params, tok, pos, temp, mask, tables, shared, keys, caches):
+        def view(pool):
+            return jnp.take(pool, tables, axis=0).reshape(
+                (tables.shape[0], -1) + pool.shape[2:])
+        views = [(view(kp), view(vp)) for kp, vp in caches]
+        toks = []
+        for _ in range(engine.decode_block):
+            x = _embed_ids(stem, params, tok)
+            if pos_emb is not None:
+                x = x + jnp.take(params[pos_emb.name]["table"], pos,
+                                 axis=0, mode="clip")
+            for i, blk in enumerate(blocks):
+                def row(x_row, ck, cv, pos_row, blk=blk):
+                    y, ck, cv = _block_step(
+                        blk, params[blk.name], x_row[None, None, :],
+                        ck[None], cv[None], pos_row)
+                    return y[0, 0], ck[0], cv[0]
+                x, ck, cv = jax.vmap(row)(x, *views[i], pos)
+                views[i] = (ck, cv)
+            logits = _head_logits(head, params, x, prec)
+            keys2, subs = _split_rows(keys)
+            keys = jnp.where(mask[:, None] > 0, keys2, keys)
+            samp = jax.vmap(jax.random.categorical)(
+                subs, logits / jnp.maximum(temp, _TEMP_EPS)[:, None])
+            nxt = jnp.where(temp > 0, samp.astype(jnp.int32),
+                            jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            tok = jnp.where(mask > 0, nxt, tok)
+            pos = pos + (mask > 0)
+            toks.append(tok)
+        keep = (mask[:, None] > 0) & (
+            jnp.arange(tables.shape[1])[None, :] >= shared[:, None])
+        wtab = jnp.where(keep, tables, 0).reshape(-1)
+        out = tuple(
+            tuple(pool.at[wtab].set(v.reshape((-1,) + pool.shape[1:]))
+                  for pool, v in zip(pools, vws))
+            for pools, vws in zip(caches, views))
+        return jnp.stack(toks), keys, out
+    return jax.jit(step)
+
+
+@pytest.mark.parametrize("decode_block,ticks", [(1, 5), (4, 2), (2, 4)])
+def test_float_step_pool_equals_whole_view_oracle(served, decode_block,
+                                                  ticks):
+    """The float decode step writes ONE row a slot and iteration; the
+    pool it leaves must be, page for page and bit for bit, the pool the
+    whole-view write-back left — over several ticks (each tick gathers
+    what the last one stored), with half the batch masked out, slots
+    holding shared pages, a row landing inside a shared page and a row
+    past the view's end. The sink page (0) is the one page whose
+    content is nobody's."""
+    _, wf, _ = served
+    engine, params, caches, st = _step_fixture(wf, decode_block)
+    before = [[numpy.asarray(a) for a in pool] for pool in caches]
+    new, old = engine._build_decode(), _whole_view_step(engine)
+    got = want = caches
+    tok_g, tok_w = st["tok"], st["tok"]
+    keys_g = keys_w = st["keys"]
+    pos = st["pos"].copy()
+    rest = (st["temp"], st["mask"], st["tables"], st["shared"])
+    for _tick in range(ticks):
+        toks_w, keys_w, want = old(params, tok_w, pos, *rest, keys_w,
+                                   want)
+        # THE step donates its keys and pool: hand it copies
+        toks_g, keys_g, got = new(
+            params, tok_g, pos, *rest, keys_g + 0,
+            tuple(tuple(a + 0 for a in pool) for pool in got))
+        numpy.testing.assert_array_equal(numpy.asarray(toks_g),
+                                         numpy.asarray(toks_w))
+        numpy.testing.assert_array_equal(numpy.asarray(keys_g),
+                                         numpy.asarray(keys_w))
+        tok_g = tok_w = numpy.asarray(toks_w)[-1]
+        pos = pos + decode_block * (st["mask"] > 0)
+    frozen = sorted({p for m, _, pages, _ in _STEP_SLOTS if not m
+                     for p in pages if p} | {5} | set(range(17, 25)))
+    wrote = 0
+    for pool_g, pool_w, pool_b in zip(got, want, before):
+        for a_g, a_w, a_b in zip(pool_g, pool_w, pool_b):
+            a_g = numpy.asarray(a_g)
+            numpy.testing.assert_array_equal(a_g[1:],
+                                             numpy.asarray(a_w)[1:])
+            numpy.testing.assert_array_equal(a_g[frozen], a_b[frozen])
+            wrote += int((a_g[1:] != a_b[1:]).any(axis=(2, 3)).sum())
+    # and the rows did land: every in-view position a masked-in slot
+    # wrote outside its shared pages, per block and tensor
+    live = sum(1 for m, sh, _, p0 in _STEP_SLOTS if m
+               for p in range(p0, p0 + decode_block * ticks)
+               if sh * 8 <= p < 32)
+    assert wrote == live * len(before) * 2
+
+
+@pytest.mark.parametrize("decode_block", [1, 4])
+def test_float_step_scatters_rows_not_views(served, decode_block):
+    """No scatter of the lowered decode step updates more than
+    ``decode_block x S x kv x hd`` elements (the rows one chunk made,
+    per block and tensor) — the whole-view write-back was 32 x that on
+    this engine and 2,048 x on the decode cell — and the row scatter
+    sits under the ``page_writeback`` scope, which
+    ``scope_ms.page_writeback`` reads."""
+    import jax
+    _, wf, _ = served
+    engine, params, caches, st = _step_fixture(wf, decode_block)
+    step = engine._build_decode()
+    args = (params, st["tok"], st["pos"], st["temp"], st["mask"],
+            st["tables"], st["shared"], st["keys"], caches)
+    kp = caches[0][0]
+    limit = decode_block * len(_STEP_SLOTS) * kp.shape[2] * kp.shape[3]
+
+    def scatters(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name.startswith("scatter"):
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scatters(sub)
+
+    found = list(scatters(jax.make_jaxpr(step)(*args).jaxpr))
+    sizes = [int(numpy.prod(e.invars[2].aval.shape)) for e in found]
+    assert sizes and max(sizes) <= limit, (sizes, limit)
+    # one row scatter per block and tensor into the pool itself
+    into_pool = [e for e in found
+                 if e.invars[0].aval.shape == kp.shape]
+    assert len(into_pool) == 2 * len(caches)
+    lowered = step.lower(*args)
+    assert "page_writeback/scatter" in lowered.as_text(debug_info=True)
+    assert re.search(r'op_name="[^"]*page_writeback[^"]*"',
+                     lowered.compile().as_text())

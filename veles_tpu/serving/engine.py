@@ -77,9 +77,10 @@ float-pool only:
   its pages and its prefill FLOPs once across the whole pool. The
   first write that must land inside a shared page (a full-prompt
   match re-computing its last position) copies that page first
-  (copy-on-write, counted); the decode step's write-back masks every
-  shared page to the sink, so a writer can never mutate one. LRU
-  leaves evict under allocator pressure;
+  (copy-on-write, counted); the decode step writes back only the rows
+  it made, and sends a row whose page lies among its slot's shared
+  pages to the sink, so a writer can never mutate one. LRU leaves
+  evict under allocator pressure;
 - **chunked prefill** (``prefill_chunk``): long admissions prefill in
   fixed-size chunks co-scheduled with the decode tick — one chunk per
   tick per admitting row — instead of one monolithic bucketed pass,
@@ -2353,9 +2354,13 @@ class ContinuousEngine(Logger):
         page view — one fixed shape, compiled exactly once; page
         tables arrive as DATA. The float pool gathers each row's view
         ONCE per chunk, carries it through the scan (the inner step
-        runs at dense-pool cost), and scatters the pages back in one
-        batched write per block at chunk end (masked rows target the
-        sink page). Per-row sampling draws from each slot's private
+        runs at dense-pool cost) and writes into the pool only the
+        rows the chunk made: ``decode_block`` rows a slot, in one
+        batched row scatter per block and tensor at chunk end,
+        through the same ``_row_targets`` the int8 pool and the
+        speculative round write through (a masked row, a row inside a
+        shared page and a row past the view's end target the sink
+        page). Per-row sampling draws from each slot's private
         key stream, advanced ONLY for masked-in rows, so a row's
         noise is a pure function of its request's seed whatever other
         modes share the pool. Under ``quant_kv`` each scan iteration
@@ -2411,13 +2416,13 @@ class ContinuousEngine(Logger):
 
             if not quant_kv:
                 # CHUNK-VIEW formulation: gather each row's logical
-                # view ONCE per chunk, carry it through the scan (the
-                # per-iteration math is then exactly the dense pool's
-                # — no gathers on the inner step), and scatter every
-                # page back in one batched write per block at chunk
-                # end. Masked rows' write-back targets the sink, so
-                # co-tenant spec/beam pages are untouchable from here
-                # exactly as with per-step scatters.
+                # view ONCE per chunk and carry it through the scan
+                # (the per-iteration math is then exactly the dense
+                # pool's — no gathers on the inner step). The views
+                # are scratch: each iteration also yields the ONE row
+                # it wrote per slot, block and tensor, and only those
+                # rows are scattered into the pool at chunk end — the
+                # pages a step did not write are never rewritten.
                 views = []
                 for kp, vp in caches:
                     views.append((
@@ -2429,7 +2434,7 @@ class ContinuousEngine(Logger):
                 def body(carry, _):
                     tok, pos, keys, vws = carry
                     x = embed_rows(params, tok, pos)
-                    new_vws = []
+                    new_vws, rows = [], []
                     for blk, (ck, cv) in zip(blocks, vws):
                         p = params[blk.name]
 
@@ -2439,33 +2444,43 @@ class ContinuousEngine(Logger):
                                 blk, p, x_row[None, None, :],
                                 ck_row[None], cv_row[None], pos_row,
                                 tp=tp, tp_axis=tp_axis)
-                            return y[0, 0], ck2[0], cv2[0]
+                            return (y[0, 0], ck2[0], cv2[0],
+                                    jnp.take(ck2[0], pos_row, axis=0,
+                                             mode="clip"),
+                                    jnp.take(cv2[0], pos_row, axis=0,
+                                             mode="clip"))
 
-                        x, ck, cv = jax.vmap(row)(x, ck, cv, pos)
+                        x, ck, cv, kn, vn = jax.vmap(row)(
+                            x, ck, cv, pos)
                         new_vws.append((ck, cv))
-                    nxt, pos, keys = sample_next(tok, pos, keys, x)
-                    return (nxt, pos, keys, tuple(new_vws)), nxt
+                        rows.append((kn, vn))       # each (S, kv, hd)
+                    nxt, pos2, keys = sample_next(tok, pos, keys, x)
+                    return ((nxt, pos2, keys, tuple(new_vws)),
+                            (nxt, pos, tuple(rows)))
 
-                (tok, pos, keys, views), toks = jax.lax.scan(
+                (_, _, keys, _), (toks, wpos, rows) = jax.lax.scan(
                     body, (tok, pos, keys, tuple(views)), None,
                     length=self.decode_block)
-                # write-back targets: masked rows AND each row's
-                # leading SHARED (prefix-adopted) pages go to the sink
-                # — a shared page is structurally read-only here, so a
-                # retired (or live) writer can never mutate one
+                # row write-back, (decode_block, S) targets at once.
+                # Sent to the sink page instead of the pool: a masked
+                # row; a row inside one of its slot's leading SHARED
+                # (prefix-adopted) pages — a shared page is
+                # structurally read-only here, so a retired (or live)
+                # writer can never mutate one; and a row at or past
+                # the view's end (a finished row's overshoot inside
+                # its last chunk), which _row_targets would clip onto
+                # the slot's last real row.
                 with jax.named_scope("page_writeback"):
-                    keep = (mask[:, None] > 0) & (
-                        jnp.arange(tables.shape[1])[None, :]
-                        >= shared[:, None])
-                    wtab = jnp.where(keep, tables,
-                                     0).reshape(-1)        # (S*P,)
+                    ok = ((mask[None, :] > 0)
+                          & (wpos // self.page_size >= shared[None, :])
+                          & (wpos < tables.shape[1] * self.page_size))
+                    pg, off = jax.vmap(
+                        self._row_targets, in_axes=(None, 0, 0))(
+                            tables, wpos, ok)
                     new_caches = []
-                    for (kp, vp), (ck, cv) in zip(caches, views):
-                        shape = (wtab.shape[0],
-                                 self.page_size) + kp.shape[2:]
-                        kp = kp.at[wtab].set(ck.reshape(shape))
-                        vp = vp.at[wtab].set(cv.reshape(shape))
-                        new_caches.append((kp, vp))
+                    for (kp, vp), (kn, vn) in zip(caches, rows):
+                        new_caches.append((kp.at[pg, off].set(kn),
+                                           vp.at[pg, off].set(vn)))
                 return toks, keys, tuple(new_caches)
 
             # int8 pool: per-step gather/scatter — the read has to
