@@ -47,7 +47,7 @@ import urllib.request
 HERE = os.path.dirname(os.path.abspath(__file__))
 LOG_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 BACKEND = "tpu"
-#: lm_big (scripts/chip_experiments.py sec_lm_big); nothing is cut
+#: the widest shipped LM; nothing is cut
 MODEL = dict(seq_len=2048, dim=1024, n_blocks=8, ffn_hidden=4096,
              n_heads=16, vocab=256)
 TRAIN_STEPS, TRAIN_EPOCHS = 4, 2

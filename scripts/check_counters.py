@@ -6,7 +6,7 @@ Every ``veles_*`` counter the tree increments (``inc("veles_...")`` /
 must be registered with a HELP string in
 ``veles_tpu/telemetry/counters.py::DESCRIPTIONS`` — an unregistered
 name still counts, but renders on ``/metrics`` with the generic HELP
-and silently escapes the bench gate's zero-leakage sections. This
+and belongs to no family that a test holds at zero. This
 script fails (exit 1) on any used-but-unregistered name, so the drift
 is caught at CI time instead of on a dashboard.
 
@@ -32,7 +32,7 @@ COUNTERS_PY = os.path.join(REPO, "veles_tpu", "telemetry",
 #: literal counter-name usages: inc("veles_x") — the module helper,
 #: the registry method (matches after the dot) AND import aliases
 #: ending in `inc` like recorder.py's `_counter_inc(` — plus
-#: counters.get("veles_x") (bench gate sections). Dynamically-built
+#: counters.get("veles_x"). Dynamically-built
 #: names cannot be checked statically and are out of scope.
 USE_RE = re.compile(
     r"""\b[A-Za-z_]*inc\(\s*["'](veles_[a-z0-9_]+)["']"""
@@ -41,11 +41,11 @@ USE_RE = re.compile(
 #: literal histogram-name usages: observe("veles_x") — the module
 #: helper and the registry method — plus the quantile/count/sum reads
 #: through any registry-looking receiver (``histograms.quantile``,
-#: bench.py's ``_hists.count`` alias: a name containing ``hist``).
+#: a ``_hists.count`` alias: a name containing ``hist``).
 #: Every such name must be registered in counters.py HISTOGRAMS with
 #: a HELP string AND bucket bounds — same fail-closed rule as
 #: counters: an unregistered histogram still records (on DEFAULT
-#: buckets) but escapes the gate's zero-leakage section.
+#: buckets) but escapes the zero-leakage test.
 HIST_USE_RE = re.compile(
     r"""\b[A-Za-z_]*observe\(\s*["'](veles_[a-z0-9_]+)["']"""
     r"""|\b[A-Za-z_]*[Hh]ist[A-Za-z_]*\.(?:quantile|count|sum)"""
@@ -53,7 +53,7 @@ HIST_USE_RE = re.compile(
 
 #: directories scanned for usages (tests may inc ad-hoc names on
 #: purpose and are excluded)
-SCAN = ("veles_tpu", "scripts", "bench.py")
+SCAN = ("veles_tpu", "scripts")
 
 #: the operator-facing registry mirror: every REGISTERED veles_*
 #: counter/histogram must have a row here (the --docs pass)

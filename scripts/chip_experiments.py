@@ -4,20 +4,16 @@ incremental saves, so a failure mid-batch still leaves the sections
 that finished. The device is the strict ``Device_for("tpu")``: without
 a chip nothing runs, and a failed section makes the exit code non-zero.
 
-Sections (most important first, per VERDICT r3 items 1/2/5 and r4
-items 1/2/3):
-  pallas_compile — per-kernel Mosaic compile/execute/numerics artifact
-  mnist    — MNIST-784 h=8 block dispatch (the driver headline config)
-  ae_amp   — conv-AE 128px mb=64 under bf16 activations + bf16 dataset
-  ae_fp32  — same net, f32 everything: the AMP delta, measured
-  lm       — transformer-LM tokens/s (mixed precision, 4-epoch blocks)
-  attn     — flash vs fused-XLA at T=2048/8192, fwd and train mode,
-             sweeping Pallas block shapes (the T=2048 0.62x regression);
+Sections:
+  pallas_compile — per-kernel Mosaic compile/execute/numerics artifact,
+             then every row of the committed tuning DB at its own shape
+  attn_2048, attn_8192 (``attn``: both) — flash vs fused-XLA, fwd and
+             train mode, sweeping Pallas block shapes;
              attn_d128: the 4k training cell's call (head size 128,
              GQA, float32 operands), each kernel timed on the device
-  profile  — XPlane trace of AE steps for the HBM-residual analysis
+  generation — KV-cached, speculative, beam and batched decode tokens/s
 
-Run:  python scripts/chip_experiments.py [--sections mnist,ae_amp,...]
+Run:  python scripts/chip_experiments.py [--sections attn_d128,...]
 Results: chiprun_out/chip_experiments.json (atomic incremental writes
 per section; git-ignored — what the chip tool brings back). The attn
 sections also rewrite the committed veles_tpu/devices/kernel_tuning.json
@@ -52,7 +48,7 @@ def save(section, value):
     print("== saved %s" % section, flush=True)
 
 
-def sec_pallas_compile(bench, dev, n):
+def sec_pallas_compile(dev, n):
     """VERDICT r4 item 2, its OWN artifact before any sweep rests on
     the kernels: first Mosaic compile + execution + numerics status of
     the build's Pallas kernels on the real chip — flash forward, the
@@ -294,132 +290,7 @@ def sec_pallas_compile(bench, dev, n):
     return out
 
 
-def sec_mnist(bench, dev, n):
-    return bench.bench_mnist(dev, n)        # h=8 blocks
-
-
-def sec_mnist_fused(bench, dev, n):
-    """Round-4 lever: the whole-epoch Pallas SGD kernel
-    (ops/fused_fc.py, engine.fused_fc_scan) vs the h=8 scan headline.
-    Same config, same whole-epoch semantics (eval segments + train);
-    distinct method tag — never comparable to the scan-mode anchors."""
-    import jax
-    from veles_tpu.config import root as vt_root
-    prev = vt_root.common.engine.get("fused_fc_scan", False)
-    # "force": the bench A/B carries its own method tag, so the
-    # TPU bf16-policy parity gate must not silently fall back
-    vt_root.common.engine.fused_fc_scan = "force"
-    try:
-        jax.clear_caches()
-        out = bench.bench_mnist(dev, n)
-        if not out.get("fused_fc_active"):
-            # scan-path numbers must never wear the fused tag
-            raise RuntimeError(
-                "fused_fc_scan did not engage (eligibility fallback) — "
-                "refusing to record a scan measurement under the "
-                "fused method tag")
-        out["method"] = "median_of_3x10s_h8_fusedkernel"
-        return out
-    finally:
-        vt_root.common.engine.fused_fc_scan = prev
-        jax.clear_caches()
-
-
-def sec_mnist_h_sweep(bench, dev, n):
-    """Dispatch-amortization knee: h=1 (plan mode — comparable to the
-    stored 1.52M 'median_of_3x10s' anchor) and h=32 (4x the headline's
-    block) bracket the h=8 headline. If h=32 keeps scaling, the
-    headline config should move."""
-    out = {}
-    for h in (1, 32):
-        out["h%d" % h] = bench.bench_mnist(dev, n, h=h)
-        print("  mnist h=%d: %.0f samples/s/chip" % (
-            h, out["h%d" % h]["samples_per_sec_per_chip"]), flush=True)
-    return out
-
-
-def sec_mnist_mb1000(bench, dev, n):
-    """Framework-ceiling EXTRA (not the headline; its own key): the
-    headline's mb=100 is sequential-SGD-bound at ~36 us/step
-    (docs/perf.md). mb=1000 makes every matmul 10x larger at the same
-    step count per epoch /10 — same net, same data budget, different
-    config — showing what the stack does when the config lets the MXU
-    work. Never compared against the mb=100 method tag."""
-    from mnist import build_workflow
-    wf = build_workflow(epochs=10 ** 9, minibatch_size=1000,
-                        epochs_per_dispatch=8)
-    wf.initialize(device=dev)
-    run_epoch = bench.epoch_runner(wf)
-    run_epoch()
-    bench.host_sync(wf.train_step)
-    rates, _, _, _ = bench.measure_windows(
-        run_epoch, lambda: bench.host_sync(wf.train_step))
-    import statistics
-    return {"samples_per_sec_per_chip": statistics.median(rates) / n,
-            "max_window": max(rates) / n, "minibatch_size": 1000}
-
-
-def sec_ae_amp(bench, dev, n):
-    return bench.bench_conv_ae(dev, n)      # AMP + bf16 dataset (bench cfg)
-
-
-def sec_ae_fp32(bench, dev, n):
-    return bench._bench_conv_ae_inner(dev, n)   # no AMP, f32 dataset
-
-
-def sec_ae_amp_remat(bench, dev, n):
-    """AMP + activation rematerialization + bf16 activation storage
-    END-TO-END (the section default since ISSUE 9): for an HBM-bound
-    net, recomputing activations in the backward trades cheap MXU
-    FLOPs for the expensive stored-activation traffic — the roofline
-    says that direction is free up to ~3x FLOPs — and
-    engine.bf16_activations keeps every interlayer activation that a
-    unit would upcast stored bfloat16 (masters/accumulation stay f32),
-    halving what traffic remains."""
-    import imagenet_ae
-    from veles_tpu.config import root as vt_root
-    orig = imagenet_ae.build_bench_workflow
-    imagenet_ae.build_bench_workflow = \
-        lambda **kw: orig(remat=True, **kw)
-    prev_bf16 = vt_root.common.engine.get("bf16_activations", False)
-    vt_root.common.engine.bf16_activations = True
-    try:
-        out = bench.bench_conv_ae(dev, n)
-    finally:
-        imagenet_ae.build_bench_workflow = orig
-        vt_root.common.engine.bf16_activations = prev_bf16
-    out["remat"] = True
-    out["bf16_activations"] = True
-    return out
-
-
-def sec_ae_mb256(bench, dev, n):
-    """Framework-ceiling EXTRA for the conv-AE (its own key, like
-    mnist_mb1000): the method-tagged mb=64 row measured 11.9 % MFU
-    under AMP — HBM-bound with per-step buffers too small to hide
-    latencies. mb=256 quadruples every conv's spatial batch at the
-    same model: what the stack reaches when the config lets the MXU
-    work. Never compared against the mb=64 method tag."""
-    return bench.bench_conv_ae(dev, n, minibatch_size=256)
-
-
-def sec_lm(bench, dev, n):
-    return bench.bench_lm(dev, n)
-
-
-def sec_lm_big(bench, dev, n):
-    """Framework-ceiling EXTRA for the LM (its own key): dim=1024 /
-    8 blocks / T=2048 / mb=4 — 4x the matmul width and a sequence
-    long enough (>= the measured min_t crossover) that attention runs
-    the autotuned flash kernel inside a full training step, on-chip.
-    The default lm row (dim=512, T=512) stays the comparable anchor."""
-    cfg = dict(seq_len=2048, dim=1024, n_blocks=8, ffn_hidden=4096,
-               n_heads=16, minibatch_size=4, n_train=256, n_valid=32)
-    return bench.bench_lm(dev, n, cfg_overrides=cfg,
-                          epochs_per_dispatch=2)
-
-
-def sec_attn(bench, dev, n, pairs=None, **shape):
+def sec_attn(dev, n, pairs=None, **shape):
     """The explicit block sweep: measure, then rewrite the committed
     tuning DB with the winners (stamped with this jax), and copy it
     beside the results so it survives the chip tool's machine.
@@ -427,34 +298,34 @@ def sec_attn(bench, dev, n, pairs=None, **shape):
     extras."""
     import shutil
     from veles_tpu.ops import autotune
-    results = _attn_measure(bench, dev, n, pairs=pairs, **shape)
+    results = _attn_measure(dev, n, pairs=pairs, **shape)
     _attn_seed(results)
     shutil.copy(autotune.SHIPPED, os.path.dirname(OUT))
     return results
 
 
-def sec_attn_2048(bench, dev, n):
+def sec_attn_2048(dev, n):
     """Half the attn sweep per section (~20 compiles each, not ~40):
     a mid-section failure costs one length's measurements,
     not both — and the T=2048 crossover regime (the r3 0.62x result)
     lands first. Each half seeds its own DB entries, and
     _attn_seed's per-T crossover floor only ever OPENS the gate above
     a measured loss, so half-seeded state is safe."""
-    return sec_attn(bench, dev, n, pairs=((2048, 16),))
+    return sec_attn(dev, n, pairs=((2048, 16),))
 
 
-def sec_attn_8192(bench, dev, n):
-    return sec_attn(bench, dev, n, pairs=((8192, 1),))
+def sec_attn_8192(dev, n):
+    return sec_attn(dev, n, pairs=((8192, 1),))
 
 
-def sec_attn_d128(bench, dev, n):
+def sec_attn_d128(dev, n):
     """The call of the 4k training cell (chipbench internlm2_train4k:
     one sequence, 16 query heads on 8 KV heads of 128, float32 operands
     as nn/transformer.py hands them over), forward plus the custom-VJP
     backward, at the cell's length and at half of it for the crossover.
     Train mode alone: what the row records is the train-mode winner."""
     from veles_tpu.ops.autotune import CANDIDATES_WIDE
-    return sec_attn(bench, dev, n, pairs=((2048, 1), (4096, 1)),
+    return sec_attn(dev, n, pairs=((2048, 1), (4096, 1)),
                     h=16, kv=8, d=128, dtype="float32",
                     candidates=CANDIDATES_WIDE, modes=(True,),
                     extras=False)
@@ -499,7 +370,7 @@ def _kernel_ms(fn, args, iters=4):
     return {k: round(ms, 3) for k, ms in out.items()}
 
 
-def _attn_measure(bench, dev, n, pairs=None, h=ATTN_SWEEP_H, kv=None,
+def _attn_measure(dev, n, pairs=None, h=ATTN_SWEEP_H, kv=None,
                   d=ATTN_SWEEP_D, dtype="bfloat16", candidates=None,
                   modes=(False, True), extras=True):
     """Fused XLA against the flash kernels at each candidate tile pair,
@@ -767,7 +638,7 @@ def _attn_seed(results):
               flush=True)
 
 
-def sec_generation(bench, dev, n):
+def sec_generation(dev, n):
     """KV-cached decode throughput on chip (tokens/s). The re-forward
     oracle is SKIPPED here: it recompiles per context length; its
     parity is CPU-gated in CI."""
@@ -877,36 +748,10 @@ def sec_generation(bench, dev, n):
     return rows
 
 
-def sec_profile(bench, dev, n):
-    import jax
-    from imagenet_ae import build_bench_workflow
-    rel_dir = os.path.join("docs", "profiles", "r03_ae")
-    prof_dir = os.path.join(REPO, rel_dir)
-    os.makedirs(prof_dir, exist_ok=True)
-    with bench.mixed_precision_on():
-        wf = build_bench_workflow(image_size=128, minibatch_size=64,
-                                  n_train=256, n_valid=64)
-        wf.initialize(device=dev)
-        run_epoch = bench.epoch_runner(wf)
-        run_epoch()                           # compile outside the trace
-        bench.host_sync(wf.train_step)
-        with jax.profiler.trace(prof_dir):
-            run_epoch()
-            bench.host_sync(wf.train_step)
-    return {"trace_dir": rel_dir}
-
-
 SECTIONS = [("pallas_compile", sec_pallas_compile),
-            ("mnist", sec_mnist), ("mnist_fused", sec_mnist_fused),
-            ("mnist_h_sweep", sec_mnist_h_sweep),
-            ("mnist_mb1000", sec_mnist_mb1000),
-            ("ae_amp", sec_ae_amp),
-            ("ae_fp32", sec_ae_fp32), ("ae_amp_remat", sec_ae_amp_remat),
-            ("ae_mb256", sec_ae_mb256),
-            ("lm", sec_lm), ("lm_big", sec_lm_big),
             ("attn_2048", sec_attn_2048), ("attn_8192", sec_attn_8192),
             ("attn_d128", sec_attn_d128),
-            ("generation", sec_generation), ("profile", sec_profile)]
+            ("generation", sec_generation)]
 
 
 def main():
@@ -923,7 +768,6 @@ def main():
         print("unknown section(s) %s" % unknown, file=sys.stderr)
         return 1
 
-    import bench
     import veles_tpu as vt
     try:
         dev = vt.Device_for("tpu")
@@ -940,7 +784,7 @@ def main():
         print("== section %s" % name, flush=True)
         t0 = time.time()
         try:
-            out = by_name[name](bench, dev, n)
+            out = by_name[name](dev, n)
             save(name, {"result": out,
                         "elapsed_s": round(time.time() - t0, 1)})
             if isinstance(out, dict) and out.get("all_ok") is False:

@@ -171,6 +171,40 @@ def _load_chip_experiments():
     return ce
 
 
+def test_chip_experiments_sections_are_the_kernels_and_generation():
+    """What is left of the chip batch: the sections that write or check
+    ``devices/kernel_tuning.json`` and the decode rates, each taking the
+    device and the chip count and nothing else."""
+    import inspect
+    ce = _load_chip_experiments()
+    assert [name for name, _ in ce.SECTIONS] == [
+        "pallas_compile", "attn_2048", "attn_8192", "attn_d128",
+        "generation"]
+    for _, fn in ce.SECTIONS:
+        assert list(inspect.signature(fn).parameters) == ["dev", "n"]
+
+
+def test_chip_experiments_refuses_an_unknown_section_before_jax():
+    """A section that went (``mnist``) is an error said at once: exit 1
+    before jax is imported or a device asked for."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import runpy, sys\n"
+         "sys.argv = ['chip_experiments.py', '--sections', 'mnist']\n"
+         "ce = runpy.run_path(%r)\n"
+         "rc = ce['main']()\n"
+         "assert 'jax' not in sys.modules, 'jax was imported'\n"
+         "assert 'veles_tpu' not in sys.modules\n"
+         "sys.exit(rc)\n"
+         % os.path.join(repo, "scripts", "chip_experiments.py")],
+        capture_output=True, text=True, timeout=60)
+    assert r.returncode == 1, r.stderr
+    assert "unknown section" in r.stderr and "mnist" in r.stderr
+
+
 def test_attn_seed_derives_blocks_and_min_t(tuned_env):
     """The chip attn sweep's seeding: block winners per T (train mode
     preferred) AND the measured flash-vs-fused crossover land in the

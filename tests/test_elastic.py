@@ -10,7 +10,6 @@ equals the uninterrupted run). The multi-process kill drill and the
 N=4 → N=2/N=8 reshard round-trip spawn real subprocess fleets and ride
 the @slow lane (alongside tests/test_multihost.py's coordinator-kill).
 """
-import json
 import os
 import subprocess
 import sys
@@ -284,53 +283,6 @@ def test_predict_step_time_states_inputs():
     from veles_tpu.telemetry.cost import DEFAULT_ICI_BW
     pred2 = predict_step_time(0.08, 1e6, 8, device_kind="weird")
     assert pred2["inputs"]["ici_bw_bytes_per_s"] == DEFAULT_ICI_BW
-
-
-def test_scaling_json_carries_model_stamp():
-    with open(os.path.join(REPO, "SCALING.json")) as fin:
-        doc = json.load(fin)
-    model = doc["scaling_model"]
-    assert model["per_width"], model
-    for row in model["per_width"]:
-        assert "predicted_step_s" in row and "measured_step_s" in row
-    ins = model["inputs"]
-    # the acceptance criterion: prediction inputs STATED
-    assert ins["grad_bytes"] > 0
-    assert ins["ici_bw_assumed_bytes_per_s"] > 0
-    assert "t1_step_s" in ins
-
-
-# ---------------------------------------------------------------------------
-# bench gate
-# ---------------------------------------------------------------------------
-
-def test_bench_elastic_section_and_gate():
-    sys.path.insert(0, REPO)
-    import bench
-    sec = bench._elastic_section()
-    for key in ("enabled", "generations", "preemptions",
-                "reshard_seconds", "barrier_timeouts"):
-        assert key in sec
-    # clean docs: no failures
-    clean = {"elastic": {"enabled": False, "generations": 0,
-                         "preemptions": 0, "reshard_seconds": 0.0,
-                         "barrier_timeouts": 0}}
-    assert bench.gate_elastic(clean, clean) == []
-    # leakage: elastic machinery in a non-elastic run fails the gate
-    leaky = {"elastic": dict(clean["elastic"], generations=2,
-                             reshard_seconds=1.5)}
-    fails = bench.gate_elastic(clean, leaky)
-    assert any("generations" in f for f in fails)
-    assert any("resharding" in f for f in fails)
-    # elastic run inside the reshard budget passes...
-    on = {"elastic": {"enabled": True, "generations": 3,
-                      "preemptions": 2, "reshard_seconds": 1.0,
-                      "barrier_timeouts": 0}}
-    assert bench.gate_elastic(clean, on) == []
-    # ...and a blown budget fails
-    slow = {"elastic": dict(on["elastic"],
-                            reshard_seconds=10 ** 6)}
-    assert any("budget" in f for f in bench.gate_elastic(clean, slow))
 
 
 def test_supervisor_classifies_loss_vs_restart(tmp_path):

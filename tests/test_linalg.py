@@ -13,17 +13,12 @@ The family's contract, each clause locked here:
   iterations; a finish claiming convergence is re-verified through the
   trusted dense path, so a corrupt block op can NEVER yield a
   silently-wrong answer (chaos-tested via ``linalg.block_op``).
-- **telemetry + gate plumbing**: every veles_linalg_* counter is
-  registered, ``bench.py``'s linalg section reads them absolutely, and
-  ``gate_linalg`` fails leakage, tolerates pre-family legacy documents
-  (counted, never crashing) and exempts ``linalg_bench`` documents.
+- **telemetry**: every veles_linalg_* counter is registered and
+  counts (that they read zero in a run without linalg is
+  ``tests/test_telemetry.py``'s).
 - **dtype-correct peaks**: f32 work is graded against the f32 peak
   table (half the bf16 entry), and the stamped source label says so.
 """
-import json
-import os
-import sys
-
 import numpy
 import pytest
 
@@ -39,18 +34,7 @@ from veles_tpu.linalg import (LINALG_COUNTERS, LinalgError,
 from veles_tpu.resilience.faults import FaultInjected
 from veles_tpu.telemetry.counters import DESCRIPTIONS, counters
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 F32_TOL = default_tolerance(numpy.float32)
-
-
-def _import_bench():
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    return bench
 
 
 def _spd(n, seed=3, dtype=numpy.float32):
@@ -292,40 +276,6 @@ def test_linalg_counters_registered():
     assert delta.get("veles_linalg_block_ops_total")
 
 
-def test_bench_linalg_section_shape():
-    bench = _import_bench()
-    sec = bench._linalg_section()
-    assert sec["linalg_bench"] is False
-    short = [n[len("veles_linalg_"):-len("_total")]
-             for n in LINALG_COUNTERS]
-    for key in short:
-        assert isinstance(sec[key], int)
-
-
-def test_gate_linalg_doc_arithmetic(monkeypatch):
-    """Doc arithmetic in isolation (the live proof is stubbed out):
-    leakage fails, linalg_bench documents are exempt, and legacy
-    documents lacking the section entirely are counted on
-    veles_bench_legacy_sections_total — never a crash (PR 8 rule)."""
-    bench = _import_bench()
-    monkeypatch.setattr(bench, "_linalg_proof", lambda: ([], {}))
-    clean = {"linalg": {"linalg_bench": False, "matmuls": 0,
-                        "solves": 0}}
-    assert bench.gate_linalg(clean, clean) == []
-    leaked = {"linalg": {"linalg_bench": False, "matmuls": 3,
-                         "solves": 0}}
-    failures = bench.gate_linalg(clean, leaked)
-    assert failures and "leaked" in failures[0]
-    marked = {"linalg": {"linalg_bench": True, "matmuls": 3}}
-    assert bench.gate_linalg(clean, marked) == []
-    # pre-family legacy document: tolerated + counted, no crash
-    legacy = {"value": 1.0, "extras": []}
-    before = counters.snapshot()
-    assert bench.gate_linalg(legacy, clean) == []
-    assert counters.delta(before).get(
-        "veles_bench_legacy_sections_total") == 1
-
-
 # -- dtype-correct peak table ------------------------------------------------
 
 def test_peak_flops_f32_is_half_bf16():
@@ -371,13 +321,3 @@ def test_predict_summa_time_states_every_input():
     assert solo["predicted_step_s"] == pytest.approx(1.0)
 
 
-def test_scaling_json_carries_linalg_row():
-    with open(os.path.join(REPO, "SCALING.json")) as fin:
-        doc = json.load(fin)
-    block = doc["linalg"]
-    assert "formula" in block and "per_width" in block
-    assert block["inputs"]["ici_bw_assumed_bytes_per_s"] > 0
-    for row in block["per_width"]:
-        assert row["matches_dense"]
-        assert row["predicted_step_s"] > 0
-        assert row["psum_bytes_per_device"] >= 0

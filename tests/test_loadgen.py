@@ -5,13 +5,14 @@ Everything here is deterministic and fleet-free: the workload is a
 seeded program (same knobs + seed -> same arrivals, same bodies), a
 storm is a ``window=T0:T1`` fault clause armed via ``VELES_FAULTS``
 and ALWAYS restored, and the verdict folds explicit aggregates into
-explicit pass/fail checks. The one live :class:`LoadGen` run targets
+explicit pass/fail checks. One live :class:`LoadGen` run targets
 a dead port — a refused connection is data (the errors lane), and it
 exercises the whole open-loop dispatch/join machinery in
-milliseconds. The full fleet-under-storm drill lives in bench.py's
-``gate_overload``.
+milliseconds. The last test is the fleet under a burst: two QoS
+replicas behind a QoS router, every offered request answered once.
 """
 import os
+import time
 
 import pytest
 
@@ -233,8 +234,7 @@ def test_verdict_interactive_loss_and_goodput_bounds():
 def test_loadgen_records_a_dead_fleet_as_errors():
     """A refused connection is DATA: every offered request answers as
     an error (not a shed), the report stays whole, and the counters
-    move — the machinery the live drill (bench.py gate_overload)
-    builds on."""
+    move — the machinery the live drill below builds on."""
     wl = Workload(n_requests=4, rate=1000.0, min_prompt=4,
                   max_prompt=4, n_new=1, seed=2)
     gen = LoadGen("http://127.0.0.1:9", wl, timeout=5.0)
@@ -249,3 +249,81 @@ def test_loadgen_records_a_dead_fleet_as_errors():
     assert counters.get("veles_loadgen_requests_total") - off0 == 4
     assert counters.get("veles_loadgen_errors_total") - err0 == 4
     assert verdict(report, max_interactive_loss=0.0)["pass"] is False
+
+
+# -- the fleet under a burst of twice what its slots sustain -----------------
+
+def test_fleet_under_burst_answers_every_request_exactly_once():
+    """Two QoS replicas (2 slots each) behind a QoS router take 24
+    mixed interactive/batch requests offered at once. The interactive
+    class comes through without a shed or an error, the QoS plane
+    visibly works, every offered request has one terminal on the
+    client's side and the server's, and both replicas' page and queue
+    ledgers read zero after the drain."""
+    import veles_tpu as vt
+    from conftest import import_model
+    from veles_tpu import prng
+    from veles_tpu.config import root
+    from veles_tpu.serving.router import FleetRouter
+    from veles_tpu.telemetry.counters import histograms
+    lm = import_model("char_lm")
+    prng.seed_all(8282)
+    wf = lm.build_workflow(epochs=1, minibatch_size=32, n_blocks=1,
+                           dim=32, n_train=64, n_valid=32)
+    wf.initialize(device=vt.XLADevice(mesh_axes={"data": 1}))
+    pressure_names = ("veles_qos_throttled_total",
+                      "veles_qos_preemptions_total",
+                      "veles_qos_batch_deferrals_total")
+    root.common.serving.qos = True
+    root.common.router.qos = True
+    apis = [vt.GenerationAPI(wf, port=0, engine="continuous",
+                             max_slots=2, buckets=(8, 16),
+                             max_context=32, name="burst_%d" % i)
+            for i in range(2)]
+    router = None
+    try:
+        for api in apis:
+            api.initialize()
+        router = FleetRouter(
+            ["127.0.0.1:%d" % api.port for api in apis],
+            probe_interval=0.2, failure_threshold=3, retry_budget=2,
+            attempt_timeout=60.0, request_timeout=90.0,
+            name="burst.router").start()
+        workload = Workload(n_requests=24, rate=400.0, shape="burst",
+                            min_prompt=4, max_prompt=8, n_new=4,
+                            vocab=lm.VOCAB, batch_fraction=0.5,
+                            stream_fraction=0.0, sample_fraction=0.0,
+                            shared_fraction=0.25, seed=11)
+        e2e0 = histograms.count("veles_serving_e2e_seconds")
+        pressure0 = sum(counters.get(n) for n in pressure_names)
+        report = LoadGen("http://127.0.0.1:%d" % router.port, workload,
+                         timeout=120.0, name="burst.loadgen").run()
+        agg = report["aggregates"]
+        assert report["answered"] == report["offered"] == 24
+        assert sum(agg[c][k] for c in ("interactive", "batch")
+                   for k in ("ok", "shed", "errors")) == 24
+        assert agg["interactive"]["shed"] == 0
+        assert agg["interactive"]["errors"] == 0
+        # park the TTFT bound: it is a time, and aggregate() folds in
+        # the process-global server histogram
+        assert verdict(report, slo_ttft_ms=1e9,
+                       max_interactive_loss=0.0)["pass"] is True
+        assert sum(counters.get(n) for n in pressure_names) > pressure0
+        ok_total = agg["interactive"]["ok"] + agg["batch"]["ok"]
+        assert histograms.count("veles_serving_e2e_seconds") - e2e0 \
+            == ok_total
+        schedulers = [api._engine.scheduler for api in apis]
+        deadline = time.time() + 15
+        while time.time() < deadline and any(
+                s.busy_count() or s.queue_depth() for s in schedulers):
+            time.sleep(0.05)
+        for api in apis:
+            assert api._engine.page_pool.in_use() == 0
+            assert api._engine.scheduler.queue_depth() == 0
+    finally:
+        root.common.serving.qos = False
+        root.common.router.qos = False
+        if router is not None:
+            router.stop()
+        for api in apis:
+            api.stop()

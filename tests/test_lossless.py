@@ -219,33 +219,6 @@ def test_advanced_prng_key_matches_split_chain():
                              numpy.asarray(advanced_prng_key(11, 5)))
 
 
-# -- gate arithmetic (live proof stubbed; the drills below ARE live) ----------
-
-def _bench():
-    sys.path.insert(0, REPO)
-    sys.path.insert(0, os.path.join(REPO, "models"))
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    return bench
-
-
-def test_gate_lossless_doc_checks(monkeypatch):
-    bench = _bench()
-    monkeypatch.setattr(bench, "_lossless_resume_proof", lambda: [])
-    sec = bench._lossless_section()
-    assert set(sec) == {"journal_appends", "journal_replayed",
-                        "journal_salvaged", "journal_compactions",
-                        "resume_attempts", "resume_tokens",
-                        "handoff_requests"}
-    clean = {"lossless": {k: 0 for k in sec}}
-    leaked = {"lossless": dict(clean["lossless"], resume_attempts=2)}
-    failures = bench.gate_lossless(clean, leaked)
-    assert any("leaked" in f for f in failures)
-    assert not bench.gate_lossless(clean, clean)
-
-
 # -- the identity drills (one tiny LM, module-scoped) -------------------------
 
 @pytest.fixture(scope="module")

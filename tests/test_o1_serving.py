@@ -445,30 +445,3 @@ def test_slots_at_equal_hbm_multiplier(lstm_wf, ssm_wf, paged_wf):
         assert multiplier >= 4.0, \
             "equal-HBM multiplier %.1f < 4 (kv=%d state=%d)" \
             % (multiplier, kv_per_slot, per_slot)
-
-
-# -- bench gate wiring ---------------------------------------------------------
-
-def test_o1state_bench_section_and_gate_registration(monkeypatch):
-    """The bench doc's o1state section stamps the five lane counters
-    and gate_o1state fails a doc that carries leakage (live proof
-    stubbed — it runs inside ``python bench.py gate``, not tier-1)."""
-    import bench
-    section = bench._o1state_section()
-    assert sorted(section) == ["checkpoints", "evictions", "rescans",
-                               "restored_tokens", "restores"]
-    from veles_tpu.telemetry.counters import DESCRIPTIONS
-    for name in O1_COUNTERS:
-        assert name in DESCRIPTIONS
-    monkeypatch.setattr(bench, "_o1state_proof", lambda: ([], {}))
-    leaky = {"o1state": {"checkpoints": 2, "restores": 0,
-                         "restored_tokens": 0, "rescans": 1,
-                         "evictions": 0},
-             "serving": {"serving_bench": False}}
-    failures = [f for f in bench.gate_o1state(leaky, None)
-                if "leaked" in f]
-    assert len(failures) == 2          # checkpoints + rescans
-    # a serving-mode bench document checkpoints on purpose — not a leak
-    serving_doc = dict(leaky, serving={"serving_bench": True})
-    assert not [f for f in bench.gate_o1state(serving_doc, None)
-                if "leaked" in f]

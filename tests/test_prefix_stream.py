@@ -172,12 +172,18 @@ def test_prefix_on_id_exact_greedy_and_sampled(served, prefix_engine):
                               temperature=r["temperature"],
                               seed=r["seed"]) for r in reqs]
     hits0 = counters.get("veles_prefix_hits_total")
+    chunks0 = engine.chunk_dispatches
     # cold wave: misses, full (chunked) prefills — still id-exact
     assert engine.serve([dict(r) for r in reqs]) == solo
+    cold_chunks = engine.chunk_dispatches - chunks0
     # warm wave: every admission adopts the shared blocks
     assert engine.serve([dict(r) for r in reqs]) == solo
     assert counters.get("veles_prefix_hits_total") - hits0 >= 4
     assert counters.get("veles_prefix_shared_pages_total") > 0
+    # what sharing is for: an adopted prefix is not prefilled again,
+    # so the warm wave runs fewer chunk programs than the cold one
+    warm_chunks = engine.chunk_dispatches - chunks0 - cold_chunks
+    assert 0 < warm_chunks < cold_chunks, (warm_chunks, cold_chunks)
 
 
 def test_full_prompt_match_cow_and_post_cow_divergence(served,
@@ -509,26 +515,3 @@ def test_check_counters_passes_with_prefix_counters():
         sys.path.pop(0)
 
 
-def test_prefix_bench_section_and_gate_registration(monkeypatch):
-    """The bench doc's prefix section stamps the five counters and
-    gate_prefix fails a doc that carries leakage (live proof stubbed
-    — it runs inside ``python bench.py gate``, not tier-1)."""
-    import bench
-    section = bench._prefix_section()
-    assert sorted(section) == ["cow_copies", "evictions", "hits",
-                               "misses", "shared_pages"]
-    from veles_tpu.serving import PREFIX_COUNTERS
-    from veles_tpu.telemetry.counters import DESCRIPTIONS
-    for name in PREFIX_COUNTERS:
-        assert name in DESCRIPTIONS
-    monkeypatch.setattr(bench, "_prefix_sharing_proof", lambda: [])
-    leaky = {"prefix": {"hits": 3, "misses": 0, "shared_pages": 2,
-                        "cow_copies": 0, "evictions": 0},
-             "serving": {"serving_bench": False}}
-    failures = [f for f in bench.gate_prefix(leaky, None)
-                if "leaked" in f]
-    assert len(failures) == 2          # hits + shared_pages
-    # a serving-mode bench document shares on purpose — not a leak
-    serving_doc = dict(leaky, serving={"serving_bench": True})
-    assert not [f for f in bench.gate_prefix(serving_doc, None)
-                if "leaked" in f]

@@ -126,6 +126,20 @@ def test_quantize_params_eligibility_and_round_trip(trained):
                 assert (a == b).all()
 
 
+def test_dequantized_weights_move_no_logit_past_a_quarter(trained):
+    """What int8 weights cost in the model's own terms: the prompt's
+    last-position logits through the dequantized twin lie within 0.25
+    of the float tree's (broken scales move them by whole units)."""
+    from veles_tpu.nn.sampling import params_of, prompt_logits
+    lm, wf = trained
+    dq = dequantize_params(quantize_params(params_of(wf))[0])
+    deltas = [float(numpy.abs(
+        numpy.asarray(prompt_logits(wf, r["prompt"]))
+        - numpy.asarray(prompt_logits(wf, r["prompt"], params=dq))
+    ).max()) for r in _requests(lm)[:2]]      # eager: seconds a prompt
+    assert 0.0 < max(deltas) <= 0.25, deltas
+
+
 def test_bad_granularity_rejected(trained):
     from veles_tpu.nn.sampling import params_of
     _lm, wf = trained

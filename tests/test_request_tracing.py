@@ -15,7 +15,6 @@ import http.server
 import io
 import json
 import os
-import sys
 import threading
 import time
 from contextlib import redirect_stdout
@@ -354,71 +353,6 @@ def test_check_counters_verifies_histograms(tmp_path):
     uses = mod.used_histograms(str(tmp_path))
     assert set(uses) == {"veles_bogus_seconds",
                          "veles_bogus2_seconds"}
-
-
-# -- gate arithmetic (bench.py, no live proof) --------------------------------
-
-def _bench():
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    return bench
-
-
-def test_gate_serving_doc_checks(monkeypatch):
-    """The REAL gate_serving, with only its (minutes-long) live proof
-    stubbed out: histogram leakage in a non-serving doc fails, a
-    TTFT-p99 regression beyond tolerance fails, an in-tolerance doc
-    pair contributes no latency/leakage failures."""
-    bench = _bench()
-    monkeypatch.setattr(bench, "_serving_throughput_proof",
-                        lambda: [])
-    histograms.reset()
-    clean = {"serving": {"admitted": 0, "histogram_samples": 0,
-                         "ttft_p99": None, "queue_wait_p99": None}}
-    leaked = {"serving": {"admitted": 0, "histogram_samples": 3}}
-    failures = bench.gate_serving(clean, leaked)
-    assert any("histogram_samples" in f for f in failures)
-    # serving-mode docs (serving_bench: true) skip the leakage checks
-    # — their serving activity IS the measurement — and are gated on
-    # the latency quantiles instead
-    slow_base = {"serving": {"serving_bench": True, "admitted": 40,
-                             "histogram_samples": 160,
-                             "ttft_p99": 0.1, "queue_wait_p99": 0.05}}
-    slow_cur = {"serving": {"serving_bench": True, "admitted": 40,
-                            "histogram_samples": 160,
-                            "ttft_p99": 0.5, "queue_wait_p99": 0.04}}
-    failures = bench.gate_serving(slow_base, slow_cur)
-    assert any("ttft_p99 regressed" in f for f in failures)
-    assert not any("leaked" in f for f in failures)
-    ok_cur = {"serving": {"serving_bench": True, "admitted": 40,
-                          "histogram_samples": 160,
-                          "ttft_p99": 0.2, "queue_wait_p99": 0.05}}
-    failures = bench.gate_serving(slow_base, ok_cur)
-    assert not any("regressed" in f or "leaked" in f
-                   for f in failures)
-
-
-def test_bench_serving_section_stamps_slo_quantiles():
-    bench = _bench()
-    histograms.reset()
-    try:
-        sec = bench._serving_section()
-        assert sec["histogram_samples"] == 0
-        assert sec["ttft_p50"] is None and sec["ttft_p99"] is None
-        assert sec["tpot_p50"] is None
-        assert sec["queue_wait_p99"] is None
-        observe("veles_serving_ttft_seconds", 0.02)
-        observe("veles_serving_tpot_seconds", 0.004)
-        observe("veles_serving_queue_wait_seconds", 0.001)
-        sec = bench._serving_section()
-        assert sec["histogram_samples"] == 3
-        assert 0.0 < sec["ttft_p50"] <= sec["ttft_p99"]
-        assert sec["tpot_p50"] > 0 and sec["queue_wait_p99"] > 0
-    finally:
-        histograms.reset()
 
 
 # -- engine e2e: ids, histograms, spans, dispatch lock ------------------------

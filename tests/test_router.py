@@ -519,37 +519,6 @@ def test_fleet_metrics_and_roster_share_the_roster(fake_fleet,
     assert "veles_router_draining 0" in text
 
 
-# -- bench gate arithmetic (live proof stubbed; the drill below IS the
-# live behavior) --------------------------------------------------------------
-
-def _bench():
-    sys.path.insert(0, REPO)
-    sys.path.insert(0, os.path.join(REPO, "models"))
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    return bench
-
-
-def test_gate_fleet_doc_checks(monkeypatch):
-    bench = _bench()
-    monkeypatch.setattr(bench, "_fleet_failover_proof", lambda: [])
-    sec = bench._fleet_section()
-    assert set(sec) == {"requests", "attempts", "failovers",
-                        "replica_errors", "breaker_opens",
-                        "duplicate_answers", "respawns"}
-    clean = {"fleet": {k: 0 for k in sec}}
-    leaked = {"fleet": dict(clean["fleet"], requests=5, failovers=1)}
-    failures = bench.gate_fleet(clean, leaked)
-    assert any("leaked" in f for f in failures)
-    # registration + clean docs: only the process-zero check remains,
-    # and it keys on the live counters (which these tests DO move) —
-    # so assert no DOC failures rather than none at all
-    failures = bench.gate_fleet(clean, clean)
-    assert not any("doc" in f for f in failures)
-
-
 # -- the route CLI: SIGTERM drains and exits 0 --------------------------------
 
 @pytest.mark.skipif(sys.platform.startswith("win"),

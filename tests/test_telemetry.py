@@ -1,10 +1,9 @@
 """Telemetry subsystem (veles_tpu/telemetry/): deterministic
-accounting — counters, spans, cost model, Chrome-trace export, and the
-counter-based perf gate. The regression locks here are the ones
-wall-clock gates cannot hold through host noise: cached decode is
-ONE dispatch per lax.scan (the round-5 speculative finding was a
-dispatch-count story), and an injected extra dispatch fails the gate
-deterministically."""
+accounting — counters, spans, cost model, Chrome-trace export. The
+regression locks here are the ones no clock can hold through host
+noise: cached decode is ONE dispatch per lax.scan (the round-5
+speculative finding was a dispatch-count story), and a subsystem that
+is off counts nothing."""
 import json
 import threading
 import urllib.request
@@ -13,8 +12,9 @@ import numpy
 import pytest
 
 import veles_tpu as vt
-from veles_tpu.telemetry import (Cost, CostModel, gate_counters,
-                                 peak_bf16_flops)
+from veles_tpu import (linalg, loadgen, overlap, quant, resilience,
+                       serving, telemetry)
+from veles_tpu.telemetry import Cost, CostModel, peak_bf16_flops
 from veles_tpu.telemetry import chrome_trace, spans
 from veles_tpu.telemetry.counters import counters
 from veles_tpu.telemetry.cost import cost_of_fn
@@ -360,8 +360,8 @@ def test_cached_decode_is_one_dispatch_per_scan(tiny_lm):
 
 
 def test_train_step_cost_report(tiny_lm):
-    """The TrainStep's own program cost (the CostModel source bench.py
-    reads): real FLOPs from Compiled.cost_analysis at the recorded arg
+    """The TrainStep's own program cost (the CostModel's source): real
+    FLOPs from Compiled.cost_analysis at the recorded arg
     shapes."""
     _, wf = tiny_lm
     rep = wf.train_step.cost_report()
@@ -374,105 +374,83 @@ def test_train_step_cost_report(tiny_lm):
     assert 0 <= cost.mfu(1.0, peak_flops=197e12) < 1e-3
 
 
-# -- counter gate ------------------------------------------------------------
+# -- off means off -----------------------------------------------------------
 
-def test_gate_passes_on_equal_and_fails_on_extra_dispatch():
-    """The gate reads window-independent rates only (raw totals scale
-    with how many epochs fit a time-boxed window)."""
-    base = {"dispatches": 120, "dispatches_per_epoch": 3.0,
-            "compiles": 0, "flops_per_dispatch": 1e9,
-            "bytes_per_dispatch": 5e6}
-    assert gate_counters(dict(base), dict(base)) == []
-    # an extra dispatch per epoch = a real program regression
-    worse = dict(base, dispatches_per_epoch=4.0)
-    failures = gate_counters(worse, base)
-    assert len(failures) == 1 and "dispatches_per_epoch" in failures[0]
-    # raw total growth alone (longer/faster window) does NOT fail
-    assert gate_counters(dict(base, dispatches=900), base) == []
-    # recompile where the baseline had none
-    assert gate_counters({"compiles": 1}, {"compiles": 0}) != []
-    # tolerated growth under the ratio rules
-    assert gate_counters(dict(base, flops_per_dispatch=1.04e9),
-                         base) == []
+@pytest.fixture(scope="module")
+def feature_off_run():
+    """One plain run from zeroed registries: a tiny LM trains for an
+    epoch, then a ContinuousEngine with every feature option at its
+    default serves four requests. Returns what the counters and the
+    histograms' sample counts read after the training alone and after
+    both."""
+    from veles_tpu import prng
+    from veles_tpu.serving.engine import ContinuousEngine, make_request
+    from veles_tpu.telemetry.counters import histograms
+    lm = import_model("char_lm")
+    prng.seed_all(4321)
+    counters.reset()
+    histograms.reset()
+    wf = lm.build_workflow(epochs=1, minibatch_size=64, n_blocks=1,
+                           dim=16, n_train=256, n_valid=64)
+    wf.initialize(device=vt.XLADevice(mesh_axes={"data": 1}))
+    wf.run()
 
+    def reading():
+        return dict(counters.snapshot(),
+                    **{name: histograms.count(name)
+                       for name in histograms.snapshot()})
 
-def test_gate_decode_dispatches_per_token_ceiling():
-    failures = gate_counters({"dispatches_per_token": 2.0}, {},
-                             max_dispatches_per_token=1.0)
-    assert failures and "dispatches_per_token" in failures[0]
-    assert gate_counters({"dispatches_per_token": 0.04}, {},
-                         max_dispatches_per_token=1.0) == []
-
-
-def test_bench_gate_docs_fails_on_injected_regression():
-    """Acceptance gate: bench.py's counter-gate mode fails on an
-    injected extra-dispatch regression (and passes unchanged docs)."""
-    import os
-    import sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo)
+    trained = reading()
+    engine = ContinuousEngine(wf, max_slots=4, buckets=(16,),
+                              max_context=48, name="off_t").start()
     try:
-        import bench
+        rng = numpy.random.RandomState(5)
+        outs = engine.serve([
+            make_request(list(lm.make_corpus(rng, 8 + i)), 6,
+                         temperature=0.0 if i % 2 else 0.8, seed=i)
+            for i in range(4)])
     finally:
-        sys.path.remove(repo)
-    baseline = {
-        "counters": {"dispatches": 100, "dispatches_per_epoch": 1.0,
-                     "compiles": 0, "flops_per_dispatch": 1e10,
-                     "bytes_per_dispatch": 2e7},
-        "extras": [{"metric": "lm",
-                    "counters": {"dispatches_per_epoch": 1.0,
-                                 "compiles": 0}}],
-    }
-    same = json.loads(json.dumps(baseline))
-    assert bench.gate_docs(baseline, same) == []
-    worse = json.loads(json.dumps(baseline))
-    # injected extra-dispatch regression (per epoch, so it cannot be
-    # explained away by window length)
-    worse["counters"]["dispatches_per_epoch"] = 2.0
-    failures = bench.gate_docs(baseline, worse)
-    assert failures and "headline" in failures[0]
-    worse2 = json.loads(json.dumps(baseline))
-    worse2["extras"][0]["counters"]["compiles"] = 3
-    failures = bench.gate_docs(baseline, worse2)
-    assert failures and failures[0].startswith("lm:")
-    # a decode section above the per-token ceiling fails absolutely
-    worse3 = json.loads(json.dumps(baseline))
-    worse3["counters"]["dispatches_per_token"] = 1.5
-    baseline3 = json.loads(json.dumps(baseline))
-    baseline3["counters"]["dispatches_per_token"] = 0.05
-    assert bench.gate_docs(baseline3, worse3) != []
-    # sections without counters (legacy baselines) are ignored
-    assert bench.gate_docs({}, worse) == []
+        engine.stop()
+    assert [len(o) for o in outs] == [6] * 4
+    return {"trained": trained, "served": reading()}
 
 
-def test_bench_gate_cli(tmp_path):
-    import os
-    import subprocess
-    import sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    base = {"counters": {"dispatches_per_epoch": 1.0, "compiles": 0},
-            "extras": []}
-    cur = {"counters": {"dispatches_per_epoch": 1.2, "compiles": 0},
-           "extras": []}
-    bp, cp = tmp_path / "b.json", tmp_path / "c.json"
-    bp.write_text(json.dumps(base))
-    cp.write_text(json.dumps(cur))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    # the gate runs every live drill (fleet failover, overload burst,
-    # watchtower storm, ...) — budget for the whole acceptance suite,
-    # not just the doc comparison
-    r = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "gate",
-         str(bp), str(cp)], capture_output=True, text=True, env=env,
-        timeout=420)
-    assert r.returncode == 1
-    assert "GATE FAIL" in r.stderr
-    cp.write_text(json.dumps(base))
-    r = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "gate",
-         str(bp), str(cp)], capture_output=True, text=True, env=env,
-        timeout=420)
-    assert r.returncode == 0, r.stderr
+#: every family whose subsystem the run leaves off reads zero after
+#: the whole run; the serving plane's own, after the training alone
+OFF_FAMILIES = {
+    "LOSSLESS_COUNTERS": (serving.LOSSLESS_COUNTERS, "served"),
+    "PREFIX_COUNTERS": (serving.PREFIX_COUNTERS, "served"),
+    "TP_COUNTERS": (serving.TP_COUNTERS, "served"),
+    "QOS_COUNTERS": (serving.QOS_COUNTERS, "served"),
+    "O1_COUNTERS": (serving.O1_COUNTERS, "served"),
+    "ROUTER_COUNTERS": (serving.ROUTER_COUNTERS, "served"),
+    "QUANT_COUNTERS": (quant.QUANT_COUNTERS, "served"),
+    "OVERLAP_COUNTERS": (overlap.OVERLAP_COUNTERS, "served"),
+    "LINALG_COUNTERS": (linalg.LINALG_COUNTERS, "served"),
+    "LOADGEN_COUNTERS": (loadgen.LOADGEN_COUNTERS, "served"),
+    "RESILIENCE_COUNTERS": (resilience.RESILIENCE_COUNTERS, "served"),
+    "ELASTIC_COUNTERS": (resilience.ELASTIC_COUNTERS, "served"),
+    "TENSORMON_COUNTERS": (telemetry.TENSORMON_COUNTERS, "served"),
+    "WATCH_COUNTERS": (telemetry.WATCH_COUNTERS, "served"),
+    "TRACE_COUNTERS": (telemetry.TRACE_COUNTERS, "served"),
+    "SERVING_COUNTERS": (serving.SERVING_COUNTERS, "trained"),
+    "SERVING_HISTOGRAMS": (serving.SERVING_HISTOGRAMS, "trained"),
+}
+
+
+@pytest.mark.parametrize("family", OFF_FAMILIES)
+def test_feature_off_counters_stay_zero(feature_off_run, family):
+    """A subsystem that the run leaves off counts nothing: the one
+    promise every ``*_COUNTERS`` family makes, held on a live run."""
+    names, when = OFF_FAMILIES[family]
+    reading = feature_off_run[when]
+    assert names
+    assert {n: reading[n] for n in names if reading.get(n)} == {}
+    # the run did run: the planes that are on counted
+    served = feature_off_run["served"]
+    assert feature_off_run["trained"]["veles_dispatches_total"] > 0
+    assert served["veles_serving_tokens_total"] == 24
+    assert served["veles_serving_e2e_seconds"] == 4
 
 
 # -- /metrics endpoints ------------------------------------------------------

@@ -575,7 +575,8 @@ def test_watch_and_alerts_clis_over_live_fleet(lm_wf):
     # park the latency SLOs out of range: compile-heavy first
     # requests on a CI host would legitimately burn the shipped
     # 500 ms budget, and this test wants a QUIET fleet (the firing
-    # path is locked by the engine tests above and bench gate_watch)
+    # path is locked by the engine tests above and the storm drill
+    # below)
     node.slo_ttft_ms = 600000.0
     node.slo_e2e_ms = 600000.0
     apis = [vt.GenerationAPI(wf, port=0, engine="continuous",
@@ -674,6 +675,133 @@ def test_watch_and_alerts_clis_over_live_fleet(lm_wf):
         assert rc == 0
         assert "rule(s), 0 firing" in out.getvalue()
         assert main(["alerts", "127.0.0.1:9", "--timeout", "1"]) == 2
+    finally:
+        if router is not None:
+            router.stop()
+        for api in apis:
+            api.stop()
+        timeseries.stop_watch()
+
+
+def test_storm_fires_then_heals_slo_ttft_burn_over_live_fleet(lm_wf):
+    """The watchtower drill. A ``serve.decode_step:delay`` storm rides
+    an open-loop burst, so TTFT blows its SLO: the burn-rate rule
+    fires while load is offered and the harness's abort-on-alert poller
+    stops the burst; the storm heals and clean traffic resolves the
+    alert through the rule's hysteresis. Both transitions show wherever
+    an operator looks: the ``/metrics/history`` pull (after a sample
+    that holds a TTFT over the SLO), the flight recorder, and a
+    ``watch --once`` frame taken while the alert fires."""
+    from veles_tpu.__main__ import main
+    from veles_tpu.loadgen import ChaosStorm, LoadGen, Workload
+    from veles_tpu.serving.router import FleetRouter
+    lm, wf = lm_wf
+    slo_ms = 250.0
+    node = root.common.telemetry.watch
+    node.enabled = True
+    node.period = 0.25
+    node.retention = 120.0
+    node.fast_window = 2.0
+    node.slow_window = 6.0
+    node.burn_factor = 2.0
+    node.objective = 0.95
+    node.slo_ttft_ms = slo_ms
+    # the other rules parked out of range: exactly the TTFT pair
+    node.slo_e2e_ms = 600000.0
+    node.queue_depth_limit = 100000.0
+    node.shed_rate_limit = 100000.0
+
+    def workload(n, rate, seed):
+        return Workload(n_requests=n, rate=rate, shape="steady",
+                        min_prompt=4, max_prompt=8, n_new=4,
+                        vocab=lm.VOCAB, batch_fraction=0.0,
+                        stream_fraction=0.0, sample_fraction=0.0,
+                        shared_fraction=0.0, seed=seed)
+
+    def transitions(state):
+        return [e for e in timeseries.store().records("watch.alert")
+                if e.get("rule") == "slo_ttft_burn"
+                and e.get("state") == state]
+
+    aborts0 = counters.get("veles_loadgen_alert_aborts_total")
+    samples0 = counters.get("veles_watch_samples_total")
+    pulls0 = counters.get("veles_watch_pulls_total")
+    apis = [vt.GenerationAPI(wf, port=0, engine="continuous",
+                             max_slots=2, buckets=(8,),
+                             max_context=24, name="stormtest_%d" % i)
+            for i in range(2)]
+    router = None
+    try:
+        for api in apis:
+            api.initialize()
+        assert timeseries.store() is not None
+        router = FleetRouter(
+            ["127.0.0.1:%d" % api.port for api in apis],
+            probe_interval=0.2, failure_threshold=3, retry_budget=2,
+            attempt_timeout=60.0, request_timeout=120.0,
+            name="stormtest.router").start()
+        base = "http://127.0.0.1:%d" % router.port
+        storm = ChaosStorm("serve.decode_step", "delay",
+                           window=(0, 1000000))
+        report = LoadGen(base, workload(80, 8.0, seed=5),
+                         storms=[storm], timeout=120.0,
+                         abort_on_alert=True, alert_poll=0.2,
+                         name="stormtest.storm").run()
+        assert report.get("aborted_on_alert"), \
+            "no rule fired while load was offered"
+        assert counters.get("veles_loadgen_alert_aborts_total") \
+            - aborts0 == 1
+        deadline = time.time() + 30
+        while not transitions("firing") and time.time() < deadline:
+            time.sleep(0.1)
+        fired = transitions("firing")[0]
+        out = io.StringIO()
+        with redirect_stdout(out):
+            rc = main(["watch", base, "--once", "--no-clear",
+                       "--period", "0.5", "--window", "5"])
+        assert rc == 0
+        assert "slo_ttft_burn" in out.getvalue()
+        assert "FIRING" in out.getvalue()
+        # the storm is gone with the burst (StormPlan restored the
+        # fault plane): clean traffic walks the rule back
+        for round_ in range(8):
+            LoadGen(base, workload(40, 12.0, seed=6 + round_),
+                    timeout=120.0,
+                    name="stormtest.heal_%d" % round_).run()
+            if any(e["ts"] > fired["ts"]
+                   for e in transitions("resolved")):
+                break
+        assert any(e["ts"] > fired["ts"]
+                   for e in transitions("resolved"))
+        header, records = parse_history(
+            _get_text(base + "/metrics/history?since=0"))
+        assert header["enabled"] is True
+        states = [r.get("state") for r in records
+                  if r.get("kind") == "watch.alert"
+                  and r.get("rule") == "slo_ttft_burn"]
+        assert "firing" in states and "resolved" in states
+        # the pull tells the story in order: a sample whose TTFT
+        # histogram grew a count over the SLO lies before the firing
+        bad_before, prev_bad = False, None
+        for rec in records:
+            h = (rec.get("hist") or {}).get(TTFT)
+            if rec.get("kind") != "watch.sample" or not h:
+                continue
+            bad = int(h.get("count", 0)) - sum(
+                c for b, c in zip(h["bounds"], h["counts"])
+                if float(b) * 1000.0 <= slo_ms)
+            if prev_bad is not None and bad > prev_bad \
+                    and rec.get("ts", 0) <= fired["ts"]:
+                bad_before = True
+                break
+            prev_bad = bad
+        assert bad_before
+        seen = {(r.get("rule"), r.get("state"))
+                for r in flight.records() if r.get("kind") == "alert"}
+        assert ("slo_ttft_burn", "firing") in seen
+        assert ("slo_ttft_burn", "resolved") in seen
+        assert counters.get("veles_watch_samples_total") > samples0
+        assert counters.get("veles_watch_pulls_total") > pulls0
     finally:
         if router is not None:
             router.stop()

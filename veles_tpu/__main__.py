@@ -259,8 +259,7 @@ def _trace_cli(argv) -> int:
     scope within each program, and the device's idle gaps by the
     program's own span that covers each (telemetry/devtime.py). A
     Chrome trace file ``TRACE.json[.gz]`` gives per-stream and
-    per-span device self-time, the numbers ``bench.py gate``'s
-    device-time sections consume."""
+    per-span device self-time."""
     import argparse
     parser = argparse.ArgumentParser(
         prog="veles_tpu trace",
@@ -414,8 +413,7 @@ def _trace_self_time(args) -> int:
     print("device self-time: %.6f s over %d device-stream event(s)"
           % (st["device_time_s"], st["n_events"]))
     if not st["n_events"]:
-        print("  (no device streams — a host-only capture; bench "
-              "falls back to host-sync timing here)")
+        print("  (no device streams — a host-only capture)")
     rows = sorted(st["by_stream"].items(), key=lambda kv: -kv[1])
     for label, secs in rows[:top]:
         print("  %-40s %.6f s" % (label, secs))

@@ -105,9 +105,9 @@ class AcceleratedUnit(Unit):
 
         The returned callable is telemetry-instrumented: every call
         counts one ``veles_dispatches_total``; a call that grows the
-        jit's trace cache counts one ``veles_compiles_total`` (the
-        counter the bench gate reads — recompiles are a deterministic
-        regression signal the wall-clock medians cannot see); lookups
+        jit's trace cache counts one ``veles_compiles_total``
+        (recompiles are a deterministic regression signal that no
+        clock can see); lookups
         served from the per-unit cache count
         ``veles_jit_cache_hits_total``."""
         cached = self._jit_cache.get(key)
@@ -151,7 +151,7 @@ class AcceleratedUnit(Unit):
         recorded arg shapes (in-process, so XLA's compilation cache
         absorbs most of the cost). Returns a telemetry ``Cost`` or None
         when nothing has been dispatched under ``key``. On-demand only
-        (bench sections, tests) — never on the hot path."""
+        (``TrainStep.cost_report``, tests) — never on the hot path."""
         entry = self._jit_fns.get(key)
         shapes = self._jit_arg_shapes.get(key)
         if entry is None or shapes is None:

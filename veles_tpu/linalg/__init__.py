@@ -6,9 +6,10 @@ non-NN workloads on this platform, instrumented through the same
 telemetry/cost/fault planes as training. See docs/workloads.md.
 """
 
-# every counter this package increments — bench.py's gate_linalg
-# checks each one is registered in telemetry.counters.DESCRIPTIONS and
-# that non-linalg bench docs show them all at zero (no leakage).
+# every counter this package increments — registered in
+# telemetry.counters.DESCRIPTIONS (scripts/check_counters.py) and read
+# at zero after a non-linalg run by
+# tests/test_telemetry.py test_feature_off_counters_stay_zero
 LINALG_COUNTERS = (
     "veles_linalg_block_ops_total",
     "veles_linalg_matmuls_total",

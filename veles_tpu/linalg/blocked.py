@@ -463,7 +463,7 @@ def verify_residual(operator: Union[Callable, object], x, b,
 
 
 # ---------------------------------------------------------------------------
-# the falsifiable SUMMA step-time model (SCALING.json's linalg row)
+# the falsifiable SUMMA step-time model
 # ---------------------------------------------------------------------------
 
 def predict_summa_time(m: int, k: int, n: int, grid: Tuple[int, int],

@@ -29,7 +29,8 @@ from .harness import (LoadGen, aggregate,               # noqa: F401
 
 #: every counter the load harness increments — registered in
 #: telemetry/counters.py DESCRIPTIONS and asserted zero in
-#: non-loadgen runs by ``python bench.py gate``'s overload section
+#: non-loadgen runs by
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
 LOADGEN_COUNTERS = (
     "veles_loadgen_requests_total",
     "veles_loadgen_shed_total",

@@ -38,8 +38,9 @@ from .executor import (SidePlane, SidePlaneError,       # noqa: F401
 from .prefetch import Prefetcher                        # noqa: F401
 
 #: every counter this subsystem increments — registered with HELP
-#: strings in telemetry.counters.DESCRIPTIONS; ``python bench.py
-#: gate``'s overlap section asserts they read zero in overlap-off runs
+#: strings in telemetry.counters.DESCRIPTIONS;
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
+#: asserts they read zero in overlap-off runs
 OVERLAP_COUNTERS = (
     "veles_sideplane_tasks_total",
     "veles_sideplane_errors_total",

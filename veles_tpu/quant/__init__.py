@@ -35,8 +35,8 @@ from .kv import (block_page_pool, block_pool,                # noqa: F401
 
 #: every counter the quantization/artifact plane increments —
 #: registered with HELP strings in telemetry/counters.py DESCRIPTIONS
-#: and asserted zero in quant-off runs by ``python bench.py gate``'s
-#: quant section
+#: and asserted zero in quant-off runs by
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
 QUANT_COUNTERS = (
     "veles_quant_params_total",
     "veles_quant_bytes_saved_total",
@@ -49,7 +49,7 @@ QUANT_COUNTERS = (
 def policy() -> dict:
     """The active quantization policy
     (``root.common.quant.{weights,kv,granularity}``) as plain values —
-    what the engine, the bench section and the /metrics gauges read."""
+    what the engine and the /metrics gauges read."""
     from ..config import root
     from .weights import granularity_from_config
     return {

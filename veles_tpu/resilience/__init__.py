@@ -22,8 +22,9 @@ is the operator guide):
   shedding for the bounded serving queues.
 
 Everything observable lands in the PR-1 telemetry counters
-(:data:`RESILIENCE_COUNTERS`); ``python bench.py gate`` asserts they
-exist and read zero in clean (no-spec) runs.
+(:data:`RESILIENCE_COUNTERS`); ``tests/test_telemetry.py``
+(``test_feature_off_counters_stay_zero``) asserts they read zero in
+clean (no-spec) runs.
 """
 
 from __future__ import annotations
@@ -44,8 +45,9 @@ from .elastic import (ELASTIC_COUNTERS,                   # noqa: F401
 
 #: every counter this subsystem increments — registered with HELP
 #: strings in telemetry.counters.DESCRIPTIONS and asserted zero in
-#: clean runs by ``python bench.py gate``'s resilience section (the
-#: elastic generation counters have their own tuple + gate section:
+#: clean runs by
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
+#: (the elastic generation counters have their own tuple and case:
 #: resilience.elastic.ELASTIC_COUNTERS)
 RESILIENCE_COUNTERS = (
     "veles_faults_injected_total",

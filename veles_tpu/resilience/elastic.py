@@ -29,8 +29,8 @@ carries an ``{epoch, step, world_size}`` cursor
 loader's shuffle indices re-derive from the restored PRNG streams +
 epoch cursor — so a run interrupted mid-epoch resumes at the last
 epoch boundary and converges to the same state tree as an
-uninterrupted run (the psum-DP equivalence proven 1→64 in
-SCALING.json makes this hold across world-size changes too).
+uninterrupted run (the psum-DP equivalence, tests/test_scaling.py,
+makes this hold across world-size changes too).
 
 Two halves:
 
@@ -48,10 +48,9 @@ The **falsifiable scaling model** (:func:`predict_step_time`) predicts
 data-parallel step time at any world size N from two stated inputs:
 the gradient psum bytes a step moves (ring all-reduce wire cost,
 ``2·(N-1)/N · grad_bytes`` per chip) and the assumed per-chip ICI
-bandwidth (:data:`~veles_tpu.telemetry.cost.ICI_BW_BYTES`).
-``scripts/scaling_sweep.py`` stamps predicted-vs-measured step time
-per workflow into SCALING.json so any future chip allocation confirms
-or refutes the model in one run.
+bandwidth (:data:`~veles_tpu.telemetry.cost.ICI_BW_BYTES`), so one
+measurement across chips confirms or refutes the model; none has been
+made (ROADMAP W2).
 """
 
 from __future__ import annotations
@@ -94,8 +93,9 @@ def base_generation() -> int:
         return 1
 
 #: every counter this module increments — registered with HELP strings
-#: in telemetry.counters.DESCRIPTIONS; ``bench.py gate``'s elastic
-#: section asserts zero leakage in non-elastic runs
+#: in telemetry.counters.DESCRIPTIONS;
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
+#: asserts zero leakage in non-elastic runs
 ELASTIC_COUNTERS = (
     "veles_elastic_generations_total",
     "veles_elastic_preemptions_total",

@@ -32,7 +32,8 @@ this way).
 The spec comes from the ``VELES_FAULTS`` env var (wins) or
 ``root.common.resilience.faults``. With neither set, every
 :func:`fire` is a no-op and the fault counters stay at zero — asserted
-by ``python bench.py gate``'s resilience section. Every fired fault
+by ``tests/test_telemetry.py``'s
+``test_feature_off_counters_stay_zero``. Every fired fault
 increments ``veles_faults_injected_total``.
 """
 

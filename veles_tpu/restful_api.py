@@ -1292,7 +1292,7 @@ class GenerationAPI(Unit):
                 jax.profiler.start_trace(directory)
             except RuntimeError as e:
                 # the profiler is one to a process: someone else's
-                # session (a benchmark's, devtime.measure's) holds it
+                # session (a benchmark's, say) holds it
                 return 409, {"error": "profiler busy: %s" % e}
             try:
                 # stop() ends a capture early rather than wait it out

@@ -56,8 +56,8 @@ from .overload import (AIMDController,                # noqa: F401
 #: every counter the lossless request plane increments (durable
 #: journal + token-level failover resume + drain-by-handoff) —
 #: registered with HELP strings in telemetry/counters.py DESCRIPTIONS
-#: and asserted zero in non-fleet runs by ``python bench.py gate``'s
-#: lossless section
+#: and asserted zero in non-fleet runs by
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
 LOSSLESS_COUNTERS = (
     "veles_journal_appends_total",
     "veles_journal_replayed_total",
@@ -71,8 +71,8 @@ LOSSLESS_COUNTERS = (
 #: every counter the prefix-sharing request plane increments (radix
 #: prefix cache + copy-on-write + LRU eviction over the page pool) —
 #: registered with HELP strings in telemetry/counters.py DESCRIPTIONS
-#: and asserted zero in non-serving runs by ``python bench.py gate``'s
-#: prefix section
+#: and asserted zero with the prefix cache off by
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
 PREFIX_COUNTERS = (
     "veles_prefix_hits_total",
     "veles_prefix_misses_total",
@@ -82,8 +82,9 @@ PREFIX_COUNTERS = (
 )
 
 #: every counter the serving plane increments — registered with HELP
-#: strings in telemetry/counters.py DESCRIPTIONS and asserted zero in
-#: non-serving runs by ``python bench.py gate``'s serving section
+#: strings in telemetry/counters.py DESCRIPTIONS and asserted zero
+#: after a training-only run by
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
 SERVING_COUNTERS = (
     "veles_serving_admitted_total",
     "veles_serving_retired_total",
@@ -102,8 +103,8 @@ SERVING_COUNTERS = (
 #: every counter the O(1)-state serving lane increments (recurrent
 #: slot pool + state-checkpoint prefix cache, serving/recurrent.py) —
 #: registered with HELP strings in telemetry/counters.py DESCRIPTIONS
-#: and asserted zero in non-recurrent runs by ``python bench.py
-#: gate``'s o1state section
+#: and asserted zero in non-recurrent runs by
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
 O1_COUNTERS = (
     "veles_o1_state_checkpoints_total",
     "veles_o1_state_restores_total",
@@ -116,7 +117,7 @@ O1_COUNTERS = (
 #: (shard_mapped decode/prefill/pagecopy over the ("model",) mesh
 #: slice, engine.py ``tp=`` knob) — registered with HELP strings in
 #: telemetry/counters.py DESCRIPTIONS and asserted zero in tp=1 runs
-#: by ``python bench.py gate``'s tp section
+#: by tests/test_telemetry.py test_feature_off_counters_stay_zero
 TP_COUNTERS = (
     "veles_tp_engines_total",
     "veles_tp_dispatches_total",
@@ -126,7 +127,7 @@ TP_COUNTERS = (
 #: preempt-and-resume + AIMD admission + brownout ladder + retry
 #: storm control, serving/overload.py) — registered with HELP strings
 #: in telemetry/counters.py DESCRIPTIONS and asserted zero in QoS-off
-#: runs by ``python bench.py gate``'s overload section
+#: runs by tests/test_telemetry.py test_feature_off_counters_stay_zero
 QOS_COUNTERS = (
     "veles_qos_preemptions_total",
     "veles_qos_preempted_tokens_total",
@@ -140,8 +141,8 @@ QOS_COUNTERS = (
 #: every latency histogram the request-plane SLO layer records
 #: (serving/scheduler.py Ticket terminal accounting) — registered
 #: with HELP + bucket bounds in telemetry/counters.py HISTOGRAMS and
-#: asserted ZERO samples in non-serving runs by ``python bench.py
-#: gate``'s serving section
+#: asserted ZERO samples after a training-only run by
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
 SERVING_HISTOGRAMS = (
     "veles_serving_queue_wait_seconds",
     "veles_serving_ttft_seconds",

@@ -182,7 +182,7 @@ def _same_leaves(a: Dict, b: Dict) -> bool:
 def make_request(prompt, n_new, temperature=0.0, seed=0, eos_id=None,
                  mode="greedy", gamma=4, beam=4) -> Dict:
     """Normalized request dict (the subset of GenerationAPI's parsed
-    request the engine consumes) — for tests and bench harnesses."""
+    request the engine consumes) — for tests and harnesses."""
     return {"prompt": [int(t) for t in prompt], "n_new": int(n_new),
             "temperature": float(temperature), "seed": int(seed),
             "eos_id": eos_id, "mode": str(mode), "gamma": int(gamma),
@@ -447,10 +447,6 @@ class ContinuousEngine(Logger):
         self.admitted = 0
         self.retired = 0
         self.peak_slots = 0
-        #: per-program dispatch tally keyed like ``_progs`` — what the
-        #: bench prefix gate multiplies CostModel program costs by to
-        #: price a load's actual prefill FLOPs
-        self.prog_calls: Dict = {}
         #: chunked-prefill stall gauges: seconds of prefill work in
         #: the most recent tick that had co-tenant decodes in flight,
         #: and the worst such tick — THE "bounded TPOT jitter" number
@@ -458,7 +454,7 @@ class ContinuousEngine(Logger):
         self.prefill_stall_last = 0.0
         self.prefill_stall_max = 0.0
         #: requests that adopted at least one shared prefix block /
-        #: chunk dispatches run (bench + stats surface)
+        #: chunk dispatches run (the stats surface)
         self.prefix_requests = 0
         self.chunk_dispatches = 0
 
@@ -643,7 +639,7 @@ class ContinuousEngine(Logger):
 
     def serve(self, reqs: List[Dict], timeout: float = 300.0
               ) -> List[List[int]]:
-        """Synchronous convenience (tests / bench): submit every
+        """Synchronous convenience (tests): submit every
         request, wait, return each token list; raises on any error."""
         from .scheduler import Ticket
         tickets = [Ticket() for _ in reqs]
@@ -1997,8 +1993,7 @@ class ContinuousEngine(Logger):
             jitted = (builders[kind](bucket)
                       if kind in ("prefill", "dprefill")
                       else builders[kind]())
-            prog = self._progs[key] = self._instrument_live(jitted,
-                                                            key)
+            prog = self._progs[key] = self._instrument_live(jitted)
         return prog
 
     @staticmethod
@@ -2014,7 +2009,7 @@ class ContinuousEngine(Logger):
             return call(*args, **kwargs)
         return counted
 
-    def _instrument_live(self, jitted, key=None):
+    def _instrument_live(self, jitted):
         """Wrap a live jitted program: every call counts one
         ``veles_decode_dispatches_total`` (the round-5 regression
         lock's counter — same contract as
@@ -2034,13 +2029,9 @@ class ContinuousEngine(Logger):
             inc("veles_decode_dispatches_total")
             if tp_on:
                 # the TP observability seam: every dispatch that ran
-                # through a shard_mapped program (gate_tp's zero-
-                # leakage check asserts this NEVER moves solo)
+                # through a shard_mapped program (it never moves solo:
+                # test_feature_off_counters_stay_zero)
                 inc("veles_tp_dispatches_total")
-            if key is not None:
-                # per-program tally: the bench prefix gate prices a
-                # load's prefill FLOPs as sum(cost(program) x calls)
-                self.prog_calls[key] = self.prog_calls.get(key, 0) + 1
             exe = box.get("exe")
             if exe is None:
                 try:
@@ -2057,9 +2048,7 @@ class ContinuousEngine(Logger):
             return exe(*args)
 
         dispatch._jitted = jitted
-        # the compiled executable, once built — bench's lossless gate
-        # reads Compiled.cost_analysis() off it to prove a resumed
-        # decode costs fewer FLOPs than a full redo
+        # the compiled executable, once built (tests read its HLO)
         dispatch.compiled = lambda: box.get("exe")
         return dispatch
 

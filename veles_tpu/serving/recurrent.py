@@ -22,8 +22,7 @@ request plane as :class:`~veles_tpu.serving.engine.ContinuousEngine`:
   reserves pages, decode can never shed on page exhaustion, and the
   pool's HBM is ``max_slots x state_bytes_per_slot``, constant in
   sequence length. At equal HBM this serves a multiple of the paged
-  transformer pool's concurrent slots (the bench ``o1state`` gate
-  stamps the multiplier);
+  transformer pool's concurrent slots;
 - **state-checkpoint prefix cache**: the prefix-cache analog for a
   lane with no pages. Prefill snapshots the slot's state at every
   ``page_size``-token block boundary into a radix
@@ -192,7 +191,6 @@ class RecurrentEngine(Logger):
         self.admitted = 0
         self.retired = 0
         self.peak_slots = 0
-        self.prog_calls: Dict = {}
         #: requests that adopted a state checkpoint / chunk dispatches
         #: run / lane counters mirrored as gauges for stats()
         self.prefix_requests = 0
@@ -282,7 +280,7 @@ class RecurrentEngine(Logger):
 
     def serve(self, reqs: List[Dict], timeout: float = 300.0
               ) -> List[List[int]]:
-        """Synchronous convenience (tests / bench): submit every
+        """Synchronous convenience (tests): submit every
         request, wait, return each token list; raises on any error."""
         from .scheduler import Ticket
         tickets = [Ticket() for _ in reqs]
@@ -301,8 +299,8 @@ class RecurrentEngine(Logger):
     # -- observability -------------------------------------------------------
     def state_bytes_per_slot(self) -> int:
         """HBM one slot's recurrent state occupies — CONSTANT in
-        sequence length (the lane's whole point; the bench o1state
-        gate proves it flat vs token count)."""
+        sequence length (the lane's whole point;
+        tests/test_o1_serving.py holds it flat vs token count)."""
         if self._states is not None:
             return sum(int(leaf.nbytes) for st in self._states
                        for leaf in st.values()) // self.max_slots
@@ -820,10 +818,10 @@ class RecurrentEngine(Logger):
             builders = {"scan": self._build_scan_chunk,
                         "step": self._build_decode}
             prog = self._progs[key] = self._instrument_live(
-                builders[kind](), key)
+                builders[kind]())
         return prog
 
-    def _instrument_live(self, jitted, key=None):
+    def _instrument_live(self, jitted):
         """Identical wrapper to the paged engine's: one dispatch
         counter per call, one explicit lower+compile on the first —
         ``veles_serving_compile_seconds_total`` brackets ONLY the
@@ -832,8 +830,6 @@ class RecurrentEngine(Logger):
 
         def dispatch(*args):
             inc("veles_decode_dispatches_total")
-            if key is not None:
-                self.prog_calls[key] = self.prog_calls.get(key, 0) + 1
             exe = box.get("exe")
             if exe is None:
                 try:

@@ -89,7 +89,8 @@ from ..telemetry.spans import emit as emit_span
 
 #: every counter the fleet router increments — registered with HELP
 #: strings in telemetry/counters.py DESCRIPTIONS and asserted zero in
-#: non-fleet runs by ``python bench.py gate``'s fleet section
+#: non-fleet runs by
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
 ROUTER_COUNTERS = (
     "veles_router_requests_total",
     "veles_router_attempts_total",

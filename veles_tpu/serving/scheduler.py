@@ -292,8 +292,8 @@ class Ticket:
         emission under the tracing switch. Never raises: a broken
         observer must not lose the request's answer. Deliberately
         runs INSIDE the terminal lock, before ``event.set()``:
-        answered must imply accounted (the bench SLO proof and the
-        tests read the histograms the moment ``serve()`` returns),
+        answered must imply accounted (the tests read the
+        histograms the moment ``serve()`` returns),
         and the cost is bounded — once per REQUEST at a step
         boundary (≤ 4 small JSONL lines when a trace sink is open),
         never on the per-token path."""

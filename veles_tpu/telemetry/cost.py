@@ -11,9 +11,9 @@ cost model), so the ops that own kernels publish an ``analytic_cost``
 :class:`CostModel` merges both sources into one per-unit ledger.
 
 MFU here is the standard quotient: analytic/compiler model FLOPs per
-second over the chip's nominal dense bf16 peak — the same numerator
-convention bench.py has always used (2·spatial·weights per conv
-position, ×3 for training), now computed and reported by the framework.
+second over the chip's nominal dense bf16 peak (numerator convention:
+2·spatial·weights per conv position, ×3 for training), computed and
+reported by the framework.
 """
 
 from __future__ import annotations
@@ -23,7 +23,7 @@ from typing import Any, Callable, Dict, Optional
 
 #: nominal dense bf16 peak FLOP/s per chip by device kind (public
 #: numbers; substring-matched against jax device_kind, first hit wins).
-#: THE one copy — bench.py imports it from here. A device_kind with no
+#: THE one copy in the program. A device_kind with no
 #: row is an error (``UnknownDevice``), never a default: a utilization
 #: graded against another chip's peak is not a measurement.
 #: ``"TPU v5 lite"`` (the v5e, as jax names it) matches the ``v5`` row:
@@ -54,7 +54,7 @@ class UnknownDevice(LookupError):
 #: assumed aggregate ICI bandwidth per chip, bytes/s (public nominal
 #: numbers, substring-matched like PEAK_BF16; first hit wins). This is
 #: the STATED input of the elastic scaling model
-#: (resilience/elastic.py predict_step_time → SCALING.json): change a
+#: (resilience/elastic.py predict_step_time): change a
 #: value here and every prediction re-anchors — the point is that the
 #: assumption is written down where one measurement can refute it.
 ICI_BW_BYTES = [
@@ -107,7 +107,7 @@ def peak_flops_entry(dtype=None, device_kind: Optional[str] = None):
     priced at the f32 table as the optimistic bound) resolves through
     PEAK_F32, everything else (bf16/f16/int8-ish mixed precision)
     through PEAK_BF16. The label names the exact table entry used so
-    bench sections can stamp the peak they were graded against. Raises
+    a report can stamp the peak it was graded against. Raises
     ``UnknownDevice`` for a device_kind neither table lists."""
     if dtype is None:
         name = "bfloat16"
@@ -264,7 +264,7 @@ def cost_of_fn(fn: Callable, *args: Any, **kwargs: Any) -> Cost:
 class CostModel:
     """Per-unit cost ledger: the framework's own measured-MFU source.
 
-    Units (or bench sections) record the cost of their compiled
+    Units record the cost of their compiled
     programs under a name; :meth:`report` divides accumulated FLOPs by
     measured seconds and the chip's nominal peak — MFU as a framework
     output, not a hand calculation. Thread-safe (serving counters and

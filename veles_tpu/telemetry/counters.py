@@ -54,8 +54,10 @@ DESCRIPTIONS = {
     "veles_spans_total":
         "Telemetry spans recorded",
     # resilience subsystem (veles_tpu/resilience/): these exist so
-    # chaos runs are countable; bench.py's gate asserts they read 0 in
-    # clean (no fault spec) runs
+    # chaos runs are countable; tests/test_telemetry.py
+    # test_feature_off_counters_stay_zero asserts they read 0 in clean
+    # (no fault spec) runs, as it does for every family below that is
+    # said to read 0 with its subsystem off
     "veles_faults_injected_total":
         "Faults fired by the deterministic injection plane",
     "veles_retries_total":
@@ -67,8 +69,7 @@ DESCRIPTIONS = {
     "veles_snapshots_quarantined_total":
         "Corrupt snapshots renamed *.corrupt during chain restore",
     # elastic training plane (veles_tpu/resilience/elastic.py):
-    # bench.py's gate asserts the generation counters read 0 in
-    # non-elastic runs and bounds the per-handoff reshard time
+    # the generation counters read 0 in non-elastic runs
     "veles_elastic_generations_total":
         "Elastic training generations started (first generation "
         "included)",
@@ -83,8 +84,8 @@ DESCRIPTIONS = {
     "veles_manifest_cursor_defaults_total":
         "Snapshot manifests read without an {epoch, step, world_size} "
         "cursor (pre-elastic manifests; defaulted, never a crash)",
-    # overlap subsystem (veles_tpu/overlap/): bench.py's gate asserts
-    # the side-plane/prefetch counters read 0 in overlap-off runs
+    # overlap subsystem (veles_tpu/overlap/): the side-plane/prefetch
+    # counters read 0 in overlap-off runs
     "veles_sideplane_tasks_total":
         "Tasks executed by side-plane lane workers",
     "veles_sideplane_errors_total":
@@ -101,7 +102,7 @@ DESCRIPTIONS = {
     "veles_prefetch_stall_seconds_total":
         "Seconds consumers waited on the prefetch queue",
     # continuous-batching serving engine (veles_tpu/serving/):
-    # bench.py's gate asserts these read 0 in non-serving runs
+    # these read 0 in non-serving runs
     "veles_serving_admitted_total":
         "Requests admitted into continuous-batching KV-cache slots",
     "veles_serving_retired_total":
@@ -133,9 +134,8 @@ DESCRIPTIONS = {
     "veles_serving_compile_seconds_total":
         "Seconds the serving engine spent jit-tracing/compiling its "
         "live decode/prefill programs (0 in AOT-artifact mode)",
-    # quantization subsystem (veles_tpu/quant/): bench.py's gate
-    # asserts the quant/artifact counters read 0 in quant-off,
-    # artifact-off runs
+    # quantization subsystem (veles_tpu/quant/): the quant/artifact
+    # counters read 0 in quant-off, artifact-off runs
     "veles_quant_params_total":
         "Parameter tensors quantized to int8 (per-channel symmetric)",
     "veles_quant_bytes_saved_total":
@@ -149,8 +149,7 @@ DESCRIPTIONS = {
         "AOT serve-artifact loads that failed and fell back to "
         "live jit (corrupt/mismatched/injected)",
     # tensor-parallel serving (serving/engine.py tp= knob): shard_map
-    # over the ("model",) mesh slice — bench.py's gate asserts these
-    # read 0 in tp=1 runs
+    # over the ("model",) mesh slice — these read 0 in tp=1 runs
     "veles_tp_engines_total":
         "Serving engines started in tensor-parallel mode (one per "
         "mesh slice, however many chips the slice spans)",
@@ -164,22 +163,9 @@ DESCRIPTIONS = {
         "kernel_tuning.json hits whose recorded jax version differs "
         "from (or predates) the running toolchain — reused, but due "
         "a re-sweep",
-    # device-time measurement plane (telemetry/devtime.py): how each
-    # bench section's device_time_s was obtained — profiler capture
-    # vs the counted host-sync fallback — and how many gate sections
-    # had to fall back to wall-clock (legacy pre-devtime documents)
-    "veles_devtime_captures_total":
-        "Profiler trace captures that yielded device-stream "
-        "self-time",
-    "veles_devtime_fallbacks_total":
-        "Device-time measurements served by the host-sync wall-clock "
-        "fallback (profiler unavailable or no device streams)",
-    "veles_bench_legacy_sections_total":
-        "Gate sections compared on wall-clock because a legacy bench "
-        "document carries no device_time_s fields",
     # model-health observability (telemetry/tensormon.py +
-    # telemetry/recorder.py): bench.py's gate asserts the sample/NaN
-    # counters read 0 in tensormon-off runs
+    # telemetry/recorder.py): the sample/NaN counters read 0 in
+    # tensormon-off runs
     "veles_tensormon_samples_total":
         "Tensor-statistics samples drained from the jitted train step",
     "veles_model_nan_total":
@@ -195,8 +181,8 @@ DESCRIPTIONS = {
         "Caller-supplied /metrics gauges dropped because their name "
         "shadowed an already-rendered counter/histogram series "
         "(duplicate names are invalid Prometheus exposition)",
-    # serving fleet router (serving/router.py): bench.py's gate
-    # asserts these read 0 in non-fleet runs
+    # serving fleet router (serving/router.py): these read 0 in
+    # non-fleet runs
     "veles_router_requests_total":
         "Requests admitted by the fleet router's HTTP front",
     "veles_router_attempts_total":
@@ -218,8 +204,8 @@ DESCRIPTIONS = {
     "veles_router_respawns_total":
         "Dead serving replicas respawned by the ReplicaSupervisor",
     # lossless request plane (serving/journal.py + token-level
-    # failover resume + drain-by-handoff): bench.py's gate asserts
-    # these read 0 in non-fleet runs
+    # failover resume + drain-by-handoff): these read 0 in non-fleet
+    # runs
     "veles_journal_appends_total":
         "Records durably appended to the router's request journal "
         "(admissions + terminals, fsync'd before dispatch/reply)",
@@ -244,8 +230,7 @@ DESCRIPTIONS = {
         "progress (503 + resume) instead of aborting or riding out "
         "the full generation",
     # prefix-sharing paged KV cache (serving/pages.py PrefixCache +
-    # engine adoption/COW): bench.py's gate asserts these read 0 in
-    # non-serving runs
+    # engine adoption/COW): these read 0 in prefix-cache-off runs
     "veles_prefix_hits_total":
         "Admissions that adopted at least one shared prefix block "
         "from the radix prefix cache (prefill covers only the "
@@ -264,8 +249,8 @@ DESCRIPTIONS = {
         "Prefix-cache blocks dropped by LRU leaf eviction (allocator "
         "pressure or the soft block budget)",
     # O(1)-state serving lane (serving/recurrent.py RecurrentEngine +
-    # serving/pages.py StateCache): bench.py's gate asserts these read
-    # 0 in non-recurrent runs
+    # serving/pages.py StateCache): these read 0 in non-recurrent
+    # runs
     "veles_o1_state_checkpoints_total":
         "Recurrent state snapshots cached at page_size-token block "
         "boundaries after a prefill scan (the state lane's prefix-"
@@ -284,8 +269,8 @@ DESCRIPTIONS = {
         "State-cache checkpoint blocks dropped by LRU leaf eviction "
         "(the soft max_blocks budget)",
     # fleet-wide distributed tracing (telemetry/spans.py ring pulls +
-    # telemetry/fleet.py cross-process assembly): bench.py's gate
-    # asserts these read 0 in non-fleet runs
+    # telemetry/fleet.py cross-process assembly): these read 0 in
+    # non-fleet runs
     "veles_trace_rotations_total":
         "JSONL --trace-file rotations (the sink grew past "
         "root.common.trace.rotate_bytes; the previous segment is "
@@ -297,8 +282,8 @@ DESCRIPTIONS = {
         "Cross-process fleet traces assembled (span pulls merged "
         "onto one clock, one Chrome-trace lane per process)",
     # overload-hardened request plane (serving/overload.py QoS +
-    # brownout governor, engine preempt-and-resume): bench.py's gate
-    # asserts these read 0 in QoS-off runs
+    # brownout governor, engine preempt-and-resume): these read 0 in
+    # QoS-off runs
     "veles_qos_preemptions_total":
         "Batch decode rows preempted at a step boundary to free "
         "slots for waiting interactive requests (the row requeues "
@@ -325,8 +310,8 @@ DESCRIPTIONS = {
         "Failover retries denied by the router-wide retry token "
         "bucket (storm control: failed first attempts still answer, "
         "they just do not amplify)",
-    # load/chaos harness (veles_tpu/loadgen/): bench.py's gate
-    # asserts these read 0 in non-loadgen runs
+    # load/chaos harness (veles_tpu/loadgen/): these read 0 in
+    # non-loadgen runs
     "veles_loadgen_requests_total":
         "Requests dispatched open-loop by the load harness",
     "veles_loadgen_shed_total":
@@ -338,8 +323,8 @@ DESCRIPTIONS = {
     "veles_loadgen_storms_total":
         "Timed chaos storms armed on the fault plane by the load "
         "harness (one per storm clause per run)",
-    # distributed linear-algebra family (veles_tpu/linalg/): bench.py's
-    # gate asserts these read 0 in non-linalg runs
+    # distributed linear-algebra family (veles_tpu/linalg/): these
+    # read 0 in non-linalg runs
     "veles_linalg_block_ops_total":
         "Host-side blocked linear-algebra dispatches (k-panel dots, "
         "potrf/trsm panels, SUMMA launches) — the linalg.block_op "
@@ -361,8 +346,8 @@ DESCRIPTIONS = {
         "Residual checks FAILED — the solve raised instead of "
         "returning a silently-wrong answer (chaos corrupt lands here)",
     # watchtower plane (telemetry/timeseries.py + telemetry/
-    # alerts.py): bench.py's gate asserts these read 0 in watch-off
-    # runs — the sampler thread and rule engine must not exist at all
+    # alerts.py): these read 0 in watch-off runs — the sampler
+    # thread and rule engine must not exist at all
     # unless root.common.telemetry.watch.enabled
     "veles_watch_samples_total":
         "Metric time-series samples taken by the watchtower "
@@ -402,8 +387,8 @@ SPAN_BUCKETS = (0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
 #: quantile sketches would not be.
 HISTOGRAMS = {
     # request-plane serving SLOs (serving/scheduler.py Ticket
-    # accounting): bench.py's gate asserts ZERO samples in
-    # non-serving runs
+    # accounting): ZERO samples in non-serving runs
+    # (tests/test_telemetry.py test_feature_off_counters_stay_zero)
     "veles_serving_queue_wait_seconds": {
         "help": "Seconds a serving request waited in the queue "
                 "before admission (deadline-shed/expired requests "
@@ -530,8 +515,8 @@ class HistogramRegistry:
     """Thread-safe fixed-bucket histograms (the latency twin of
     :class:`CounterRegistry`): flat name → (bucket counts, sum).
     Entries appear on first ``observe`` — an idle process renders no
-    histogram rows at all, so non-serving /metrics pages (and the
-    bench gate's zero-leakage sections) stay exactly as before."""
+    histogram rows at all, so non-serving /metrics pages stay
+    exactly as before."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -581,7 +566,7 @@ class HistogramRegistry:
         return histogram_quantile(bounds, counts, q)
 
     def reset(self) -> None:
-        """Zero everything — tests and bench section boundaries only
+        """Zero everything — tests only
         (same contract as :meth:`CounterRegistry.reset`)."""
         with self._lock:
             self._counts.clear()
@@ -688,7 +673,7 @@ class CounterRegistry:
         return out
 
     def reset(self) -> None:
-        """Zero everything — tests and bench section boundaries only
+        """Zero everything — tests only
         (production counters are monotonic for the life of the
         process, as Prometheus scraping expects)."""
         with self._lock:

@@ -1,40 +1,21 @@
-"""Device self-time: the measurement plane behind the perf gates.
+"""Device self-time: what a profiler capture says the chip did.
 
-Every perf claim before this module keyed off wall-clock medians,
-which were measured swinging up to 7.6× between windows on a shared
-machine. Device *self-time* — the seconds the compute stream actually
-spent executing programs — is immune to host scheduling, queue depth
-and noisy neighbours, so ``bench.py`` stamps it
-per section and ``bench.py gate`` compares IT, with wall-clock only as
-a counted legacy fallback. Two sources, in preference order:
-
-1. **Profiler capture** (``jax.profiler.start_trace``/``stop_trace``):
-   the profiler writes a Chrome trace-event stream
-   (``plugins/profile/<run>/<host>.trace.json.gz``) whose *processes*
-   include one per device (``/device:TPU:0`` …) with per-stream
-   threads ("XLA Ops"). :func:`device_self_time` interval-unions those
-   device-stream events — nested/overlapping events never double
-   count — and :func:`attribute_spans` maps the device intervals onto
-   the telemetry span records (:mod:`~veles_tpu.telemetry.spans`) by
-   time overlap, so the operator view (``veles-tpu trace self-time``)
-   and the gate read the same numbers.
-2. **Host-sync fallback**: on backends where the capture yields no
-   device streams (the CPU CI backend traces only ``/host:CPU``), or
-   where the profiler is unavailable, the fallback times the caller's
-   ``lax``-loop harness (the fused epoch/decode programs — one
-   dispatch each) bracketed by the caller's sync (``bench.py
-   host_sync``: ``jax.block_until_ready``). Sync-to-sync wall time
-   of a single-dispatch program is device time plus one host round
-   trip — an upper bound, stamped ``source="host_sync"`` and counted
-   (``veles_devtime_fallbacks_total``) so a gate reading fallback
-   numbers knows it.
-
-The comparison arithmetic (:func:`compare_sections`) lives here too so
-the gate's tolerance math is a pure, testable function: device-time
-medians may grow ``DEVTIME_TOLERANCE`` (noise), legacy wall-clock
-sections (pre-devtime ``BENCH_*.json``) are compared at
-``LEGACY_TOLERANCE`` (the measured wall-clock swing) with a counted
-``veles_bench_legacy_sections_total`` warning instead of a crash.
+Device *self-time* — the seconds the compute stream actually spent
+executing programs — is immune to host scheduling, queue depth and
+noisy neighbours. A profiler capture
+(``jax.profiler.start_trace``/``stop_trace``) writes a Chrome
+trace-event stream (``plugins/profile/<run>/<host>.trace.json.gz``)
+whose *processes* include one per device (``/device:TPU:0`` …) with
+per-stream threads ("XLA Ops"). :func:`device_self_time`
+interval-unions those device-stream events — nested/overlapping events
+never double count — and :func:`attribute_spans` maps the device
+intervals onto the telemetry span records
+(:mod:`~veles_tpu.telemetry.spans`) by time overlap; the capture's
+``.xplane.pb`` is read by :func:`load_capture` and cut into the three
+tables of :func:`summarize_capture`. Both are the operator's view,
+``veles-tpu trace self-time``. A backend whose capture yields no device
+streams (the CPU traces only ``/host:CPU``) has no device time: the
+tables are empty, and no host clock stands in for it.
 """
 
 from __future__ import annotations
@@ -43,39 +24,9 @@ import gzip
 import json
 import logging
 import os
-import shutil
-import tempfile
-import time
-from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
-
-from .counters import inc
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 log = logging.getLogger("veles_tpu.telemetry")
-
-#: the measurement plane's counters — registered with HELP strings in
-#: counters.DESCRIPTIONS; capture/fallback counts surface on both
-#: /metrics surfaces through the shared registry renderer
-DEVTIME_COUNTERS = (
-    "veles_devtime_captures_total",
-    "veles_devtime_fallbacks_total",
-    "veles_bench_legacy_sections_total",
-)
-
-#: max allowed growth of device_time_per_epoch between two bench
-#: documents — the stated noise tolerance of the device-time gate.
-#: Device self-time is host-noise-immune but not jitter-free (compiler
-#: autotuning, HBM refresh alignment); measured drift on repeated
-#: chip sections sits well under 10 %, so 25 % headroom never flaps
-#: while a real regression (a lost fusion, an extra pass) is a ≥2×
-#: move.
-DEVTIME_TOLERANCE = 1.25
-
-#: wall-clock fallback tolerance for LEGACY sections (documents
-#: stamped before the device-time format): wall clock was measured
-#: swinging up to 7.6× between windows on a shared machine, so anything
-#: tighter would flap — this bound only catches collapse, and every
-#: legacy comparison is counted so the format migration is visible.
-LEGACY_TOLERANCE = 8.0
 
 
 # -- trace-event stream parsing ---------------------------------------------
@@ -647,201 +598,3 @@ def format_capture(summary: Dict[str, Any], top: int = 12) -> List[str]:
                    % (span, count, secs,
                       100.0 * secs / idle if idle else 0.0))
     return out
-
-
-# -- capture ------------------------------------------------------------------
-
-#: process-wide profiler state: "auto" probes once and remembers — a
-#: backend whose captures carry no device streams (CPU CI) or whose
-#: profiler errors must not pay capture overhead on every window.
-_prof_state = {"disabled": False, "reason": None}
-
-
-def _profiler_mode() -> str:
-    """``root.common.telemetry.devtime.profiler``: "auto" (default —
-    try once, remember failure), "on" (always try), "off"."""
-    try:
-        from ..config import root
-        mode = root.common.telemetry.devtime.get("profiler", "auto")
-        return str(mode) if mode else "auto"
-    except Exception:            # noqa: BLE001 — config not importable
-        return "auto"
-
-
-def _disable_profiler(reason: str) -> None:
-    if not _prof_state["disabled"]:
-        _prof_state.update(disabled=True, reason=reason)
-        log.info("devtime: profiler capture disabled for this process "
-                 "(%s) — falling back to host-sync timing", reason)
-
-
-def profiler_usable() -> bool:
-    mode = _profiler_mode()
-    if mode == "off":
-        return False
-    if mode == "on":
-        return True
-    return not _prof_state["disabled"]
-
-
-def measure(fn: Callable[[], Any], sync: Callable[[], Any],
-            calls: int = 1) -> Dict[str, Any]:
-    """ONE device-time measurement: run ``fn`` ``calls`` times between
-    scalar-fetch syncs. Returns::
-
-        {"device_time_s", "wall_time_s", "calls",
-         "device_time_per_call", "source": "profiler" | "host_sync"
-         [, "by_stream"] [, "spans"]}
-
-    Profiler path (when usable): the run is captured with
-    ``jax.profiler``, the trace-event stream parsed for device-stream
-    self-time (``veles_devtime_captures_total``) and attributed onto
-    the telemetry spans the capture itself holds
-    (:func:`annotation_spans`) under ``out["spans"]``. A capture with no
-    device streams disables the profiler for the process and falls
-    back. Fallback: the synced wall time IS the device-time estimate
-    (upper bound by one host round trip per call —
-    ``fn`` is expected to be a ``lax``-loop harness dispatching one
-    fused program per call), counted
-    ``veles_devtime_fallbacks_total``."""
-    sync()
-    started = False
-    tmpdir = None
-    if profiler_usable():
-        import jax
-        tmpdir = tempfile.mkdtemp(prefix="veles_devtime_")
-        try:
-            jax.profiler.start_trace(tmpdir)
-            started = True
-        except Exception as e:           # noqa: BLE001 — any profiler
-            _disable_profiler("start_trace failed: %s" % e)
-            shutil.rmtree(tmpdir, ignore_errors=True)
-            tmpdir = None
-    t0 = time.time()
-    try:
-        for _ in range(max(1, int(calls))):
-            fn()
-        sync()
-    finally:
-        wall = time.time() - t0
-        parsed = None
-        if started:
-            import jax
-            try:
-                jax.profiler.stop_trace()
-                events = load_profile_dir(tmpdir)
-                parsed = device_self_time(events)
-            except Exception as e:       # noqa: BLE001
-                _disable_profiler("capture parse failed: %s" % e)
-                events = None
-            if tmpdir:
-                shutil.rmtree(tmpdir, ignore_errors=True)
-    calls = max(1, int(calls))
-    if parsed is not None and parsed["device_time_s"] > 0:
-        inc("veles_devtime_captures_total")
-        out = {"device_time_s": parsed["device_time_s"],
-               "wall_time_s": wall, "calls": calls,
-               "device_time_per_call": parsed["device_time_s"] / calls,
-               "source": "profiler",
-               "by_stream": parsed["by_stream"]}
-        # the telemetry span names are the section vocabulary the gate
-        # and `trace self-time` share
-        spans = attribute_spans(events)
-        if spans:
-            out["spans"] = spans
-        return out
-    if started:
-        _disable_profiler("capture carried no device-stream events "
-                          "(host-only backend)")
-    inc("veles_devtime_fallbacks_total")
-    return {"device_time_s": wall, "wall_time_s": wall, "calls": calls,
-            "device_time_per_call": wall / calls,
-            "source": "host_sync"}
-
-
-# -- gate arithmetic ----------------------------------------------------------
-
-def section_invariants(name: str, sec: Dict[str, Any]) -> List[str]:
-    """Harness invariants every devtime section record must satisfy —
-    what the gate proves on CPU CI, where timing ratios are
-    meaningless: fields present, positive device time, wall ≥ device
-    (minus float slack), a known source."""
-    failures = []
-    for key in ("device_time_s", "wall_time_s", "source",
-                "device_time_per_epoch"):
-        if key not in sec:
-            failures.append("%s: devtime record lacks %s" % (name, key))
-    if failures:
-        return failures
-    if not sec["device_time_s"] > 0:
-        failures.append("%s: device_time_s = %r (must be > 0)"
-                        % (name, sec["device_time_s"]))
-    if sec["wall_time_s"] < sec["device_time_s"] * 0.999:
-        failures.append(
-            "%s: wall_time_s %.6f < device_time_s %.6f — device "
-            "self-time cannot exceed the synced wall window"
-            % (name, sec["wall_time_s"], sec["device_time_s"]))
-    if sec["source"] not in ("profiler", "host_sync"):
-        failures.append("%s: unknown devtime source %r"
-                        % (name, sec["source"]))
-    return failures
-
-
-def compare_sections(name: str, base: Optional[Dict[str, Any]],
-                     cur: Optional[Dict[str, Any]],
-                     base_rate: Optional[float] = None,
-                     cur_rate: Optional[float] = None,
-                     timing: bool = True,
-                     tolerance: float = DEVTIME_TOLERANCE) -> List[str]:
-    """The device-time gate for one section pair; returns failure
-    strings (empty = pass).
-
-    - both carry devtime records → harness invariants always; the
-      ``device_time_per_epoch`` ratio may not exceed ``tolerance``
-      when ``timing`` (False on CPU/smoke documents, where the gate
-      proves invariants only);
-    - the CURRENT doc lost the record while the baseline has it →
-      fail (format regression);
-    - a LEGACY side (pre-devtime ``BENCH_*.json``) → counted
-      ``veles_bench_legacy_sections_total`` warning and a wall-clock
-      rate comparison at :data:`LEGACY_TOLERANCE` (throughput may not
-      collapse below baseline/tolerance), so old baselines neither
-      crash the gate nor silently stop gating."""
-    failures: List[str] = []
-    if cur is not None:
-        failures += section_invariants(name, cur)
-    if base is None or cur is None:
-        if base is not None and cur is None:
-            failures.append(
-                "%s: current document lost its devtime record while "
-                "the baseline has one — the device-time format must "
-                "not regress" % name)
-            return failures
-        # legacy pairing: count + wall-clock fallback
-        inc("veles_bench_legacy_sections_total")
-        log.warning(
-            "devtime gate: section %s compared on wall-clock only "
-            "(legacy document without device_time_s)", name)
-        if base_rate and cur_rate is not None \
-                and cur_rate < base_rate / tolerance_legacy():
-            failures.append(
-                "%s: legacy wall-clock rate collapsed %.1f -> %.1f "
-                "(> %.1fx, beyond any measured wall-clock swing)"
-                % (name, base_rate, cur_rate, tolerance_legacy()))
-        return failures
-    if failures or not timing:
-        return failures
-    b = base.get("device_time_per_epoch")
-    c = cur.get("device_time_per_epoch")
-    if not b or c is None:
-        return failures
-    ratio = float(c) / float(b)
-    if ratio > tolerance + 1e-9:
-        failures.append(
-            "%s: device_time_per_epoch regressed %.6fs -> %.6fs "
-            "(%.3fx > %.2fx tolerance)" % (name, b, c, ratio, tolerance))
-    return failures
-
-
-def tolerance_legacy() -> float:
-    return LEGACY_TOLERANCE

@@ -493,7 +493,7 @@ def _group_processes(payloads: Sequence[Dict]) -> Dict:
     per-host, so two hosts CAN hold distinct processes with one
     pid): ``{key: {"pid", "names", "spans"}}``, deduplicated within
     a process by the records' pull cursor — an in-process fleet
-    (N replicas + router sharing one python process, the test/bench
+    (N replicas + router sharing one python process, the tests'
     topology) pulls the SAME process-global ring through every
     endpoint, and triple-counting it would triple every lane."""
     procs: Dict = {}

@@ -107,7 +107,7 @@ class FlightRecorder:
         self._sigterm_installed = False
         #: True only on the process-global instance: tracks the
         #: root.common.telemetry.recorder.capacity knob (explicit
-        #: capacities — tests, bench proofs — stay fixed)
+        #: capacities — tests — stay fixed)
         self._follow_config = follow_config
 
     # -- recording -----------------------------------------------------------
