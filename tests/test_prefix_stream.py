@@ -426,6 +426,47 @@ def test_http_stream_sse_id_exact_and_first_event_early(served,
     assert events[-1]["tokens"] == exp_s
 
 
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+@pytest.mark.parametrize("finish", ["n_new", "eos_id", "prefill"])
+def test_stream_equals_buffered_and_terminal_comes_last(
+        served, api_served, finish, sampled):
+    """A streamed request's token events, put together by their ``i``
+    offsets, are the buffered answer; the terminal event is the last
+    and the only one, however the row finishes: by its ``n_new``, by
+    ``eos_id`` in the middle of a decode, or in its prefill."""
+    lm, wf = served
+    url = "http://127.0.0.1:%d/generate" % api_served.port
+    body = {"prompt": _corpus(lm, 50 + sampled, 7),
+            "n_new": 1 if finish == "prefill" else 14}
+    if sampled:
+        body.update(mode="sample", temperature=0.8, seed=13)
+
+    def buffered():
+        req = urllib.request.Request(
+            url, data=json.dumps(body).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=60) as r:
+            return json.loads(r.read())["tokens"]
+    answer = buffered()
+    if finish == "eos_id":
+        # a token that the answer first shows in its middle
+        cut = next(j for j in range(3, 14) if answer[j] not in answer[:j])
+        body["eos_id"] = answer[cut]
+        answer = buffered()
+        assert len(answer) == cut + 1
+    _ct, events, _tf, _tt = _post_stream(url, dict(body, stream=True))
+    assert [bool(ev.get("done")) for ev in events] \
+        == [False] * (len(events) - 1) + [True]
+    streamed = []
+    for ev in events[:-1]:
+        assert ev["i"] == len(streamed) and ev["tokens"]
+        streamed.extend(ev["tokens"])
+    assert streamed == answer == events[-1]["tokens"]
+    # one event a step boundary, the prefill's first token its own
+    assert len(events) - 1 == len(answer)
+
+
 def test_stream_knob_off_answers_buffered(served, api_served):
     from veles_tpu.config import root
     lm, wf = served

@@ -8,7 +8,9 @@ riding out long co-tenants, the jit cache is bounded by
 ``len(buckets) + 1`` programs, and tickets older than their deadline
 are answered 503 + Retry-After instead of rotting in the queue."""
 import json
+import queue
 import re
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -842,3 +844,238 @@ def test_float_step_scatters_rows_not_views(served, decode_block):
     assert "page_writeback/scatter" in lowered.as_text(debug_info=True)
     assert re.search(r'op_name="[^"]*page_writeback[^"]*"',
                      lowered.compile().as_text())
+
+
+# -- a step's tokens reach the streams under the next dispatch ------------------
+
+class _Blocked:
+    """A program's first result that keeps ``numpy.asarray`` waiting
+    until the test lets go: while it waits, the dispatch is in flight
+    and the tick thread is where it would sleep for the device."""
+
+    def __init__(self, value, in_flight):
+        self.value, self.release = value, threading.Event()
+        self.in_flight = in_flight
+
+    def __array__(self, dtype=None, copy=None):
+        self.in_flight.put(self)
+        assert self.release.wait(60)
+        return numpy.asarray(self.value)
+
+
+def _drain(ticket):
+    """What a handler would have read by now: (tokens, terminal?)."""
+    tokens, ended = [], False
+    while True:
+        try:
+            item = ticket._stream_q.get_nowait()
+        except queue.Empty:
+            return tokens, ended
+        if item is None:
+            ended = True
+        else:
+            assert not ended, "tokens behind the terminal"
+            tokens.extend(item)
+
+
+@pytest.mark.parametrize("plane", ["float32", "int8", "speculative"])
+def test_step_tokens_reach_streams_under_the_next_dispatch(pooled, plane):
+    """Step n's tokens are not in the streams' queues when step n+1 is
+    dispatched, and are there while it is in flight: the handlers write
+    while the tick thread waits for the device."""
+    lm, wf, draft, pool_engine = pooled
+    spec = plane == "speculative"
+    engine = ContinuousEngine(
+        wf, max_slots=2, buckets=(8, 16), max_context=48, page_size=8,
+        quant_kv=plane == "int8", spec_gamma=3,
+        draft=draft if spec else None, name="eng_push_" + plane)
+    mode = "speculative" if spec else "greedy"
+    reqs = [make_request(_prompt(lm, 300 + i, 6 + i), 16, mode=mode,
+                         gamma=3) for i in range(2)]
+    from veles_tpu.nn import sampling
+    expected = (pool_engine.serve([dict(r) for r in reqs]) if spec else
+                [sampling.generate(wf, r["prompt"], 16, temperature=0)
+                 for r in reqs])
+    tickets = [Ticket(stream=True, mode=mode) for _ in reqs]
+    for req, ticket in zip(reqs, tickets):
+        assert engine.submit(req, ticket)
+    in_flight, at_dispatch = queue.Queue(), []
+    kind = "spec" if spec else "step"
+    engine._tick_params()          # the pool, which the program closes over
+    real = engine._program(kind)
+
+    def program(*args):
+        at_dispatch.append([t._stream_q.qsize() for t in tickets])
+        out = real(*args)
+        return (_Blocked(out[0], in_flight),) + tuple(out[1:])
+    engine._progs[(kind, None)] = program
+    engine.start()
+    try:
+        for n in range(1, 5):
+            gate = in_flight.get(timeout=120)
+            now = [t._stream_q.qsize() for t in tickets]
+            # prefill's first token at once; then one event a step,
+            # each handed over under the dispatch after its own
+            assert at_dispatch[-1] == [max(1, n - 1)] * 2, (n, at_dispatch)
+            assert now == [n] * 2, (n, now)
+            assert engine.token_pushes == 2 * (n - 1)
+            assert engine.token_pushes_overlapped == 2 * (n - 1)
+            gate.release.set()
+        while not all(t.event.is_set() for t in tickets):
+            try:
+                in_flight.get(timeout=0.05).release.set()
+            except queue.Empty:
+                pass
+    finally:
+        engine.stop()
+    for ticket, answer in zip(tickets, expected):
+        assert ticket.error is None
+        got, ended = _drain(ticket)
+        assert ended and got == ticket.result["tokens"] == answer
+
+
+def _mid_decode(engine, ticket, at_least=3):
+    """Tick the never-started engine until ``ticket``'s row has made
+    ``at_least`` tokens and its last step's are still kept."""
+    for _ in range(200):
+        engine._tick()
+        active = engine.scheduler.active()
+        if active and len(active[0].tokens) >= at_least and engine._held:
+            return active[0]
+    raise AssertionError("the row did not get going")
+
+
+@pytest.mark.parametrize("ending", ["idle", "abort", "handoff",
+                                    "decode_fault", "stop"])
+def test_nothing_is_held_at_an_ending(served, monkeypatch, ending):
+    """Wherever no dispatch follows or a terminal is set, what was kept
+    is pushed first: the stream ends with all its tokens, or with an
+    error payload whose ``resume`` holds exactly what it streamed."""
+    lm, wf, _ = served
+    engine = ContinuousEngine(wf, max_slots=2, buckets=(8, 16),
+                              max_context=48, name="eng_end_" + ending)
+    ticket = Ticket(stream=True)
+    assert engine.submit(make_request(_prompt(lm, 320, 7), 12), ticket)
+    slot = _mid_decode(engine, ticket)
+    made = list(slot.tokens)
+    before, ended = _drain(ticket)
+    assert not ended and before == made[:-1]     # the last step's: kept
+    if ending == "idle":
+        for _ in range(40):
+            engine._tick()
+        assert ticket.error is None
+        made = ticket.result["tokens"]
+        assert len(made) == 12
+    elif ending == "abort":
+        engine._abort_active("internal serving error", code=500)
+    elif ending == "handoff":
+        done = threading.Event()
+        engine._handoff = ("draining", done, {"count": 0})
+        engine._tick()
+        assert done.is_set()
+    elif ending == "decode_fault":
+        monkeypatch.setenv("VELES_FAULTS",
+                           "serve.decode_step:raise:times=1")
+        engine._tick()
+        monkeypatch.setenv("VELES_FAULTS", "")
+    else:
+        engine.stop()
+    assert ticket.event.is_set() and engine._held == []
+    after, ended = _drain(ticket)
+    assert ended and before + after == made
+    if ending != "idle":
+        assert ticket.error_payload()["resume"] == {
+            "tokens": made, "tokens_done": len(made)}
+    engine.stop()
+
+
+def test_preempted_row_still_gets_its_last_steps_tokens(served):
+    """QoS preempts a batch row between its step and the next
+    dispatch: the step's tokens reach the stream before the ticket
+    goes back to the queue, and the whole stream is the uninterrupted
+    answer, each token once."""
+    from veles_tpu.config import root
+    from veles_tpu.nn import sampling
+    lm, wf, _ = served
+    root.common.serving.qos = True
+    try:
+        engine = ContinuousEngine(wf, max_slots=1, buckets=(8, 24),
+                                  max_context=48, name="eng_push_qos")
+        req = make_request(_prompt(lm, 330, 6), 12, temperature=0.9,
+                           seed=5, mode="sample")
+        req["priority"] = "batch"
+        t_b, t_i = Ticket(stream=True, mode="sample"), Ticket()
+        assert engine.submit(req, t_b)
+        slot = _mid_decode(engine, t_b)
+        made = list(slot.tokens)
+        got, _ = _drain(t_b)
+        assert got == made[:-1]
+        urgent = make_request(_prompt(lm, 331, 5), 3)
+        urgent["priority"] = "interactive"
+        assert engine.submit(urgent, t_i)
+        engine._tick()
+        assert engine.preemptions == 1 and not t_b.event.is_set()
+        got += _drain(t_b)[0]
+        assert got == made == t_b.progress
+        for _ in range(200):
+            if t_b.event.is_set() and t_i.event.is_set():
+                break
+            engine._tick()
+        assert t_b.error is None and t_i.error is None
+        tail, ended = _drain(t_b)
+        # the resumed attempt streams its own first token onward
+        assert ended and got + tail == t_b.result["tokens"]
+        assert t_b.result["tokens"] == sampling.generate(
+            wf, req["prompt"], 12, temperature=0.9, seed=5)
+        engine.stop()
+    finally:
+        root.common.serving.qos = False
+
+
+def _sse(url, payload, timeout=120.0):
+    """One streamed request's events, in wire order."""
+    req = urllib.request.Request(
+        url, data=json.dumps(dict(payload, stream=True)).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        return [json.loads(line[5:]) for line in r
+                if line.startswith(b"data:")]
+
+
+def test_push_overlap_share_on_a_live_run(served, api_served):
+    """Six streams of 24 tokens: nearly every event of decode-step
+    tokens is queued while a dispatch is in flight (a finishing row's
+    is not), the two counters are on /metrics and their ratio on
+    /stats."""
+    lm, wf, api, url = api_served
+    before = counters.snapshot()
+    answers = {}
+
+    def ask(i):
+        answers[i] = _sse(url, {"prompt": _prompt(lm, 340 + i, 6 + i),
+                                "n_new": 24})
+    threads = [threading.Thread(target=ask, args=(i,), daemon=True)
+               for i in range(6)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+        assert not t.is_alive()
+    for events in answers.values():
+        assert events[-1]["done"] and len(events[-1]["tokens"]) == 24
+        assert [t for e in events[:-1] for t in e["tokens"]] \
+            == events[-1]["tokens"]
+    delta = counters.delta(before)
+    pushes = delta["veles_serving_token_pushes_total"]
+    overlapped = delta["veles_serving_token_pushes_overlapped_total"]
+    assert pushes == 6 * 23                 # the 24th is the prefill's
+    assert overlapped >= 0.9 * pushes, (overlapped, pushes)
+    with urllib.request.urlopen(url + "/stats", timeout=30) as r:
+        share = json.loads(r.read())["continuous"]["push_overlap_share"]
+    assert 0.9 <= share <= 1.0
+    with urllib.request.urlopen(
+            "http://127.0.0.1:%d/metrics" % api.port, timeout=30) as r:
+        text = r.read().decode()
+    for name in ("veles_serving_token_pushes_total",
+                 "veles_serving_token_pushes_overlapped_total"):
+        assert re.search(r"^%s \d+" % name, text, re.M), name

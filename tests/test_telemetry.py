@@ -423,6 +423,7 @@ OFF_FAMILIES = {
     "TP_COUNTERS": (serving.TP_COUNTERS, "served"),
     "QOS_COUNTERS": (serving.QOS_COUNTERS, "served"),
     "O1_COUNTERS": (serving.O1_COUNTERS, "served"),
+    "STREAM_COUNTERS": (serving.STREAM_COUNTERS, "served"),
     "ROUTER_COUNTERS": (serving.ROUTER_COUNTERS, "served"),
     "QUANT_COUNTERS": (quant.QUANT_COUNTERS, "served"),
     "OVERLAP_COUNTERS": (overlap.OVERLAP_COUNTERS, "served"),

@@ -4,7 +4,8 @@ What is pinned here:
 - every phase of ``ContinuousEngine._tick`` is a span nested under
   ``serving.tick`` and an unlabelled histogram on ``/metrics``
   (``veles_serving_tick_<phase>_seconds``), the SSE write of a handler
-  thread likewise; the phases cover the tick; with
+  thread likewise; the phases cover the tick and none lies in another
+  (``emit`` has two halves: the push under the next dispatch); with
   ``root.common.trace.spans`` false none is observed and the served
   tokens are the same;
 - the three program names the benchmark finds programs by
@@ -224,6 +225,36 @@ def test_span_nested_under_the_tick(served, name):
         # the step's own span stays their parent, in every mode
         assert all(by_sid[r["parent"]]["name"] == "serving.decode_step"
                    for r in mine)
+
+
+def test_emit_has_two_halves_and_no_second_is_in_two_sums(served):
+    """``serving.tick.emit`` is entered twice in a tick that steps: the
+    push of the last step's tokens lies under the step's span between
+    its dispatch and its device wait, record-and-finish after it under
+    the tick. No phase's span lies inside another phase's."""
+    _, records, _ = served
+    by_sid = {r["sid"]: r for r in records}
+    for r in records:
+        if r["name"] in TICK_SPANS:
+            up = r
+            while up.get("parent") is not None:
+                up = by_sid[up["parent"]]
+                assert up["name"] not in TICK_SPANS, (r["name"], up["name"])
+    emits = [r for r in records if r["name"] == "serving.tick.emit"]
+    pushes = [r for r in emits
+              if by_sid[r["parent"]]["name"] == "serving.decode_step"]
+    after = [r for r in emits
+             if by_sid[r["parent"]]["name"] == "serving.tick"]
+    assert len(pushes) + len(after) == len(emits)
+    # 6 requests of 16 tokens in chunks of 4: a row's first three
+    # steps go on decoding, so their tokens are pushed under the next
+    assert len(pushes) >= 6 and len(after) >= len(pushes)
+    for push in pushes:
+        phases = {r["name"]: r for r in records
+                  if r.get("parent") == push["parent"]}
+        sent = phases["serving.tick.dispatch"]
+        wait = phases["serving.tick.device"]
+        assert sent["ts"] <= push["ts"] <= wait["ts"]
 
 
 def test_tick_and_write_spans_carry_what_a_reader_needs(served):

@@ -100,6 +100,16 @@ SERVING_COUNTERS = (
     "veles_serving_beam_steps_total",
 )
 
+#: the counters of token streaming (serving/engine.py ``_push_tokens``:
+#: how often a step's tokens reach the streams under the next
+#: dispatch) — registered with HELP strings in telemetry/counters.py
+#: DESCRIPTIONS and asserted zero with no streaming request by
+#: tests/test_telemetry.py test_feature_off_counters_stay_zero
+STREAM_COUNTERS = (
+    "veles_serving_token_pushes_total",
+    "veles_serving_token_pushes_overlapped_total",
+)
+
 #: every counter the O(1)-state serving lane increments (recurrent
 #: slot pool + state-checkpoint prefix cache, serving/recurrent.py) —
 #: registered with HELP strings in telemetry/counters.py DESCRIPTIONS

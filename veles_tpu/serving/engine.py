@@ -92,7 +92,14 @@ float-pool only:
 - **token streaming**: rows whose ticket carries ``stream=True`` push
   emitted tokens at every step boundary (``Ticket.push_tokens``);
   the GenerationAPI drains them onto the wire as SSE events, so TTFT
-  becomes a client-visible measurement.
+  becomes a client-visible measurement. Still one event a step
+  boundary; WHEN within the tick it is handed over: a prefill's first
+  token and the last tokens of a row that finishes at once (then the
+  terminal), a step's tokens for a row that goes on decoding right
+  after the tick's NEXT dispatch has been issued
+  (:meth:`ContinuousEngine._push_held`), so that the handler threads
+  serialise and write while the tick thread sleeps on the device
+  without the interpreter lock, not while it prepares the dispatch.
 """
 
 from __future__ import annotations
@@ -444,6 +451,15 @@ class ContinuousEngine(Logger):
         #: the in-flight tickets are settled with their resume
         #: progress — the dying gasp a failover retry continues from
         self.on_death = None
+        #: (ticket, tokens) of the last step's rows that go on
+        #: decoding, handed to the streams under the next dispatch
+        #: (:meth:`_push_held`); the tick thread's alone
+        self._held: List[Tuple] = []
+        #: stream events of decode-step tokens queued, and those of
+        #: them queued while a dispatch was in flight (the stats
+        #: surface's ``push_overlap_share``)
+        self.token_pushes = 0
+        self.token_pushes_overlapped = 0
         self.admitted = 0
         self.retired = 0
         self.peak_slots = 0
@@ -722,6 +738,12 @@ class ContinuousEngine(Logger):
             "chunk_dispatches": self.chunk_dispatches,
             "prefilling": prefilling,
             "prefill_stall_seconds": round(self.prefill_stall_max, 6),
+            # the share of decode-step stream events handed over while
+            # a dispatch was in flight (the handlers write while the
+            # chip works); 0 with no streaming request
+            "push_overlap_share": round(
+                self.token_pushes_overlapped
+                / max(1, self.token_pushes), 4),
             # quantization/AOT plane (veles_tpu/quant/): what the
             # /metrics mode gauges render on both surfaces
             "artifact_mode": int(self.artifact_mode),
@@ -803,6 +825,7 @@ class ContinuousEngine(Logger):
                            and self.scheduler.busy_count() == 0
                            and self._handoff is None
                            and not self._closing):
+                        self._push_held()   # no dispatch follows a wait
                         with span("serving.loop.wait"):
                             self.scheduler.cv.wait(timeout=5.0)
                         if not self._closing:
@@ -873,15 +896,23 @@ class ContinuousEngine(Logger):
                 if death is not None:
                     death()
                 return
+        carried = self._held
         with span("serving.tick", active=self.scheduler.busy_count()):
             self._tick_phases()
+            if self._held is carried:
+                # no dispatch of this tick took them along and left a
+                # new list behind (every row chunk-prefilling, shed or
+                # preempted): none follows before the next tick's
+                self._push_held(phase=True)
 
     def _tick_phases(self) -> None:
         """The tick proper, one span a phase under ``serving.tick``
         (``serving.tick.{admit,prefill,prepare,dispatch,device,emit}``,
         each also a histogram: telemetry/spans.py SPAN_HISTOGRAMS), so
         that a profiler capture and ``/metrics`` both say where the
-        host's time between two dispatches goes."""
+        host's time between two dispatches goes. ``emit`` is entered
+        twice a tick: after the device wait (record, finish) and under
+        the next dispatch (:meth:`_push_held`)."""
         with span("serving.tick.prepare"):
             params = self._tick_params()
         from .scheduler import shed_expired
@@ -1030,7 +1061,12 @@ class ContinuousEngine(Logger):
             free = len(self.scheduler._free)
         if waiting <= free:
             return
-        for slot in self._preempt_victims(waiting - free):
+        victims = self._preempt_victims(waiting - free)
+        if victims:
+            # a requeued ticket may meet its deadline in the queue:
+            # its last step's tokens go out before it goes back there
+            self._push_held()
+        for slot in victims:
             emitted = self._emitted(slot)
             resumed = fold_resume(slot.req, slot.tokens)
             # chained folds accumulate: the PRNG must advance one
@@ -1344,6 +1380,7 @@ class ContinuousEngine(Logger):
                 params, ids_dev, numpy.int32(t_p),
                 numpy.int32(slot.idx), numpy.float32(slot.temperature),
                 seed_key, table_row, self._keys, self._caches)
+            self._push_held(overlapped=True)
         inc("veles_serving_prefill_dispatches_total")
         self._pos[slot.idx] = t_p
         self._temp[slot.idx] = slot.temperature
@@ -1574,6 +1611,7 @@ class ContinuousEngine(Logger):
                         numpy.float32(slot.temperature), seed_key,
                         table_row, numpy.int32(1 if final else 0),
                         self._keys, self._caches)
+                self._push_held(overlapped=True)
             inc("veles_serving_prefill_dispatches_total")
             self.chunk_dispatches += 1
             work = True
@@ -1635,11 +1673,58 @@ class ContinuousEngine(Logger):
             if slot.mode in _STEP_MODES:
                 victims[0].ticket.set_progress(
                     self._emitted(victims[0]))
+            self._push_held()
             if victims[0].ticket.fail(
                     "serving page pool exhausted mid-decode",
                     code=503, retry_after=1.0):
                 inc("veles_shed_requests_total")
         return alive
+
+    # -- a step's tokens, to the streams ---------------------------------------
+    def _push_tokens(self, handed, overlapped: bool = False) -> None:
+        """Queue each ``(ticket, tokens)`` of a decode step on its
+        stream and count the events queued (buffered tickets have no
+        queue and count nothing)."""
+        pushed = sum(ticket.push_tokens(tokens)
+                     for ticket, tokens in handed)
+        if not pushed:
+            return
+        self.token_pushes += pushed
+        inc("veles_serving_token_pushes_total", pushed)
+        if overlapped:
+            self.token_pushes_overlapped += pushed
+            inc("veles_serving_token_pushes_overlapped_total", pushed)
+
+    def _push_held(self, overlapped: bool = False,
+                   phase: bool = False) -> None:
+        """Hand the last step's tokens, kept by its ``emit`` for the
+        rows that went on decoding, to their streams. Called with
+        ``overlapped`` right after a dispatch to the device has been
+        issued and before the host waits for it: every push wakes an
+        HTTP handler thread that serialises and writes its SSE event
+        under the interpreter lock, and there the tick thread is about
+        to sleep without the lock for the whole program, where after
+        ``emit`` it would queue for the lock behind all of them at
+        every upload of the next ``prepare``. Called plainly wherever
+        no dispatch follows or a terminal is about to be set (a tick
+        that dispatches nothing, a shed, an abort, a hand-off, a
+        preemption, the idle wait), so that a stream's order stays
+        first token, each step's tokens, terminal; a row that is
+        admitted or still prefilling has nothing kept (a preempted
+        ticket's went out before it was queued again). What is kept
+        belongs to the ticket, not to the slot. ``phase`` where no
+        other phase's span is open: the push is then the second half
+        of ``serving.tick.emit``, and no second is in two sums. Tick
+        thread only; always leaves a NEW list behind (:meth:`_tick`
+        tells by that whether a dispatch took the old one along)."""
+        held, self._held = self._held, []
+        if not held:
+            return
+        if phase:
+            with span("serving.tick.emit"):
+                self._push_tokens(held, overlapped)
+        else:
+            self._push_tokens(held, overlapped)
 
     # -- the decode chunk ------------------------------------------------------
     def _decode(self, params) -> None:
@@ -1670,6 +1755,7 @@ class ContinuousEngine(Logger):
                 # device array yields the interpreter lock, and after
                 # the emit phase every handler thread is waiting for it
                 del host
+            self._push_held(overlapped=True, phase=True)
             with span("serving.tick.device"):
                 toks = numpy.asarray(toks)      # (decode_block, S)
         inc("veles_serving_decode_dispatches_total")
@@ -1686,12 +1772,19 @@ class ContinuousEngine(Logger):
                     if slot.record(token):
                         finished.append(slot)
             for slot in active:
-                # streaming rows hand this chunk's tokens to their
-                # drain loop at the step boundary — before _finish's
-                # terminal sentinel, so the wire order is
-                # tokens-then-done
-                slot.ticket.push_tokens(slot.tokens[base_len[id(slot)]:])
+                # a streaming row that goes on decoding hands this
+                # chunk's tokens to its drain loop under the next
+                # dispatch (_push_held) ...
+                if slot.ticket.stream and slot not in finished:
+                    self._held.append(
+                        (slot.ticket, slot.tokens[base_len[id(slot)]:]))
             for slot in finished:
+                # ... one that finishes, at once — before _finish's
+                # terminal sentinel, so the wire order is
+                # tokens-then-done and its slot is free for the next
+                # admission
+                self._push_tokens(
+                    [(slot.ticket, slot.tokens[base_len[id(slot)]:])])
                 self._finish(slot)
 
     # -- the speculative round -------------------------------------------------
@@ -1727,6 +1820,7 @@ class ContinuousEngine(Logger):
                     params, self._draft_params, *host, self._keys,
                     self._caches, self._draft_caches)
                 del host            # as in _decode
+            self._push_held(overlapped=True, phase=True)
             with span("serving.tick.device"):
                 out_vec = numpy.asarray(out_vec)     # (S, gamma)
                 n_emit = numpy.asarray(n_emit)
@@ -1748,9 +1842,11 @@ class ContinuousEngine(Logger):
                     if slot.record(int(t)):
                         done = True
                         break
-                slot.ticket.push_tokens(slot.tokens[base:])
-                if done:
+                if done:                # as in _decode
+                    self._push_tokens([(slot.ticket, slot.tokens[base:])])
                     self._finish(slot)
+                elif slot.ticket.stream:
+                    self._held.append((slot.ticket, slot.tokens[base:]))
 
     # -- the beam step ---------------------------------------------------------
     def _beam_tick(self, params) -> None:
@@ -1887,6 +1983,7 @@ class ContinuousEngine(Logger):
     def _abort_active(self, reason: str, code: int = 500,
                       retry_after: Optional[float] = None,
                       count_shed: bool = True) -> None:
+        self._push_held()
         answered = set()
         for slot in self.scheduler.active():
             # aborted rows hand their emitted-token prefix back on the
@@ -1943,6 +2040,7 @@ class ContinuousEngine(Logger):
         fault point fires once per in-flight ticket: an injected raise
         degrades THAT ticket to a plain 503 shed (no resume progress —
         its retry re-decodes from scratch), never blocks the drain."""
+        self._push_held()
         handed = 0
         answered = set()
         for slot in self.scheduler.active():

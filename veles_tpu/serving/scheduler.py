@@ -192,20 +192,22 @@ class Ticket:
             self.progress = [int(t) for t in tokens]
 
     # -- token streaming ------------------------------------------------------
-    def push_tokens(self, tokens) -> None:
+    def push_tokens(self, tokens) -> bool:
         """Hand freshly emitted tokens to the streaming drain loop (a
-        no-op for buffered tickets). Stamps first-token time: the
+        no-op for buffered tickets); True when an event was queued.
+        Stamps first-token time: the
         moment a token enters this queue it is one queue hop from the
         client's socket, so the TTFT histogram now measures a real
         client-visible first token — not an internal prefill sync a
         buffered response would sit on for the whole generation."""
         if self._stream_q is None:
-            return
+            return False
         toks = [int(t) for t in tokens]
         if not toks:
-            return
+            return False
         self.mark_first_token()
         self._stream_q.put(toks)
+        return True
 
     def next_stream_item(self, timeout: float):
         """Blocking drain step for the HTTP streaming handler: a token

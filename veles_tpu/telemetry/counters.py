@@ -114,6 +114,12 @@ DESCRIPTIONS = {
         "engine",
     "veles_serving_tokens_total":
         "Tokens emitted by the continuous-batching engine",
+    "veles_serving_token_pushes_total":
+        "Stream events of decode-step tokens the serving engine "
+        "queued for the HTTP handler threads",
+    "veles_serving_token_pushes_overlapped_total":
+        "Those of them queued while a dispatch was in flight, so the "
+        "handlers write while the device works",
     "veles_serving_expired_total":
         "Queued generation requests answered 503 past their deadline",
     "veles_serving_pages_alloc_total":
