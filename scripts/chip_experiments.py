@@ -10,7 +10,9 @@ Sections:
   attn_2048, attn_8192 (``attn``: both) — flash vs fused-XLA, fwd and
              train mode, sweeping Pallas block shapes;
              attn_d128: the 4k training cell's call (head size 128,
-             GQA, float32 operands), each kernel timed on the device
+             GQA, float32 operands), each kernel timed on the device;
+             attn_d256: the 8k training cell's (head size 256, 16 on 2,
+             bfloat16 operands)
   generation — KV-cached, speculative, beam and batched decode tokens/s
 
 Run:  python scripts/chip_experiments.py [--sections attn_d128,...]
@@ -327,6 +329,19 @@ def sec_attn_d128(dev, n):
     from veles_tpu.ops.autotune import CANDIDATES_WIDE
     return sec_attn(dev, n, pairs=((2048, 1), (4096, 1)),
                     h=16, kv=8, d=128, dtype="float32",
+                    candidates=CANDIDATES_WIDE, modes=(True,),
+                    extras=False)
+
+
+def sec_attn_d256(dev, n):
+    """The call of the 8k training cell (chipbench qwen3next_train8k: one
+    sequence, 16 query heads on 2 KV heads of 256, bfloat16 operands as
+    nn/hybrid.py hands them over), forward plus the custom-VJP backward,
+    at the cell's length and at half of it for the crossover. Train mode
+    alone, as ``sec_attn_d128``."""
+    from veles_tpu.ops.autotune import CANDIDATES_WIDE
+    return sec_attn(dev, n, pairs=((4096, 1), (8192, 1)),
+                    h=16, kv=2, d=256, dtype="bfloat16",
                     candidates=CANDIDATES_WIDE, modes=(True,),
                     extras=False)
 
@@ -750,7 +765,7 @@ def sec_generation(dev, n):
 
 SECTIONS = [("pallas_compile", sec_pallas_compile),
             ("attn_2048", sec_attn_2048), ("attn_8192", sec_attn_8192),
-            ("attn_d128", sec_attn_d128),
+            ("attn_d128", sec_attn_d128), ("attn_d256", sec_attn_d256),
             ("generation", sec_generation)]
 
 

@@ -179,7 +179,7 @@ def test_chip_experiments_sections_are_the_kernels_and_generation():
     ce = _load_chip_experiments()
     assert [name for name, _ in ce.SECTIONS] == [
         "pallas_compile", "attn_2048", "attn_8192", "attn_d128",
-        "generation"]
+        "attn_d256", "generation"]
     for _, fn in ce.SECTIONS:
         assert list(inspect.signature(fn).parameters) == ["dev", "n"]
 

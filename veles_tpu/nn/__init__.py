@@ -42,6 +42,7 @@ from . import speculative  # noqa
 from . import beam  # noqa
 from .transformer import (TransformerBlock, MeanPool,  # noqa
                           PositionalEmbedding, Embedding, LMHead)
+from .hybrid import HybridBlock, GDHybridBlock  # noqa
 from .evaluator import EvaluatorSoftmaxSeq  # noqa
 from .variants import (All2AllRProp, GDRProp,
                        ResizableAll2All)  # noqa

@@ -131,6 +131,11 @@ class TrainStep(AcceleratedUnit):
         #: feature (bit-identical state trees, same dispatch count,
         #: locked by tests/test_tensormon.py)
         self._tensormon = None
+        #: accumulator keys of what the forward units count inside the
+        #: step (telemetry/steptaps.py; ``step_taps()`` of a unit), set at
+        #: initialize; empty where no unit counts or the chain is not a
+        #: plain one (pipelined, or gradients accumulated over chunks)
+        self._step_taps = ()
         #: (stacked device accums, H) from the last block dispatch —
         #: converted to per-epoch dicts lazily in drain_epoch_blocks
         self._block_metrics = None
@@ -232,6 +237,11 @@ class TrainStep(AcceleratedUnit):
         self._setup_shardings()
         self._setup_fused_fc()
         self._setup_epilogue()
+        if self._pp is None and self._pp_hetero is None \
+                and self.grad_accumulation == 1:
+            self._step_taps = tuple(sorted({
+                k for f in self.forwards
+                for k in getattr(f, "step_taps", tuple)()}))
         return None
 
     def _setup_epilogue(self) -> None:
@@ -747,6 +757,35 @@ class TrainStep(AcceleratedUnit):
                     else a)
         return jax.tree_util.tree_map(cast, tree)
 
+    def _amp_cast_params(self, params):
+        """``_amp_cast`` of the parameters, but for the leaves a unit
+        names in ``AMP_FLOAT32`` (a router's scores, a decay's rate, the
+        matrices a unit casts itself a block at a time): those reach the
+        unit in float32."""
+        keep = {f.name: f.AMP_FLOAT32 for f in self.forwards
+                if getattr(f, "AMP_FLOAT32", ())}
+        if not keep:
+            return self._amp_cast(params)
+        return {name: ({k: v if k in keep[name] else self._amp_cast(v)
+                        for k, v in p.items()}
+                       if name in keep else self._amp_cast(p))
+                for name, p in params.items()}
+
+    def _forward_taps(self, p, batch, rng):
+        """The training forward pass and what its units counted on the
+        way (telemetry/steptaps.py): (out, {tap key: scalar}), the second
+        empty where no unit of a plain chain counts anything."""
+        import jax
+        from ..telemetry import steptaps
+
+        def fwd(pp, bb):
+            if not self._step_taps:
+                return self._forward_pure(pp, bb, True, rng), {}
+            with steptaps.collecting() as got:
+                out = self._forward_pure(pp, bb, True, rng)
+            return out, dict(got)
+        return jax.checkpoint(fwd)(p, batch) if self.remat else fwd(p, batch)
+
     def _target_for(self, batch, labels, targets, indices):
         if self.target_mode == "labels":
             return self._gather(labels, indices)
@@ -782,18 +821,13 @@ class TrainStep(AcceleratedUnit):
         def loss_fn(p):
             with jax.named_scope("forward"):
                 if self.mixed_precision:
-                    p = self._amp_cast(p)
-                if self.remat:
-                    out = jax.checkpoint(
-                        lambda pp, bb: self._forward_pure(
-                            pp, bb, True, rng))(p, batch)
-                else:
-                    out = self._forward_pure(p, batch, True, rng)
+                    p = self._amp_cast_params(p)
+                out, taps = self._forward_taps(p, batch, rng)
             with jax.named_scope("loss"):
-                return self.evaluator.loss(out, tgt, mask), out
+                return self.evaluator.loss(out, tgt, mask), (out, taps)
 
-        (loss, out), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params)
+        (loss, (out, taps)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params)
         valid = mask.sum() > 0  # all-padded plan rows must not decay params
         new_params, new_opt = self._apply_updates(params, grads,
                                                   opt_state, lr_scale,
@@ -802,6 +836,7 @@ class TrainStep(AcceleratedUnit):
             metrics = self.evaluator.metrics_fn(out, tgt, mask)
             metrics["sum_loss"] = loss * self.evaluator.sum_loss_weight(
                 out, mask)
+            metrics.update(taps)
         if self._tensormon is not None:
             # auxiliary tensor taps (telemetry/tensormon.py): pure
             # scalars over values this step already computed — extra
@@ -884,7 +919,7 @@ class TrainStep(AcceleratedUnit):
 
             def loss_fn(p):
                 if self.mixed_precision:
-                    p = self._amp_cast(p)
+                    p = self._amp_cast_params(p)
                 chunk_rng = jax.random.fold_in(rng, ci)
                 if self.remat:
                     out = jax.checkpoint(
@@ -959,7 +994,7 @@ class TrainStep(AcceleratedUnit):
         tgt = self._target_for(batch, labels, targets, indices)
         if self.mixed_precision:
             batch = self._amp_cast(batch)
-            params = self._amp_cast(params)
+            params = self._amp_cast_params(params)
         out = self._forward_pure(params, batch, False, None)
         metrics = self.evaluator.metrics_fn(out, tgt, mask)
         metrics["sum_loss"] = (self.evaluator.loss(out, tgt, mask)
@@ -995,6 +1030,9 @@ class TrainStep(AcceleratedUnit):
         if mon and self._tensormon is not None:
             from ..telemetry import tensormon
             zeros.update(tensormon.zero_stats(sorted(self.params)))
+        if mon:
+            zeros.update({k: jnp.zeros((), jnp.float32)
+                          for k in self._step_taps})
         return zeros
 
     # -- execution -----------------------------------------------------------
@@ -1221,6 +1259,9 @@ class TrainStep(AcceleratedUnit):
             from ..telemetry import tensormon
             for mon in tensormon.extract_mon(entries, TRAIN):
                 tensormon.monitor.observe(self, mon)
+        if self._step_taps:
+            from ..telemetry import steptaps
+            steptaps.publish(steptaps.extract(entries, TRAIN))
         return entries
 
     def cost_report(self):

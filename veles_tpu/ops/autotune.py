@@ -11,7 +11,7 @@ One DB: ``veles_tpu/devices/kernel_tuning.json``, committed. The model
 path only ever READS it (a memoized dict lookup, safe at trace time),
 so one commit compiles the same kernels on every machine and in every
 process of an SPMD job. Measuring is an explicit command on a chip —
-``scripts/chip_experiments.py --sections attn_2048,attn_8192,attn_d128``
+``scripts/chip_experiments.py --sections attn_2048,...,attn_d256``
 — whose ``record()`` rewrites that file for the next commit; a trace
 never sweeps.
 

@@ -51,6 +51,13 @@ DESCRIPTIONS = {
         "row in kernel_tuning.json and fell back to the 128x128 default "
         "tiles (each about a microsecond of grid overhead a tile; "
         "0 once the shape is swept)",
+    "veles_moe_assignments_total":
+        "Token-to-expert assignments the sparse-expert layers' routers "
+        "made in training (tokens x experts a token, a layer a step), "
+        "counted inside the step and drained with the epoch's metrics",
+    "veles_moe_assignments_held_total":
+        "Of those, assignments to an expert this process holds: the "
+        "rows its grouped expert products multiply",
     "veles_spans_total":
         "Telemetry spans recorded",
     # resilience subsystem (veles_tpu/resilience/): these exist so
@@ -465,6 +472,13 @@ HISTOGRAMS = {
                 "between ticks (zero when saturated)",
         "buckets": SPAN_BUCKETS,
     },
+    "veles_moe_peak_load_tokens": {
+        "help": "Assignments of the fullest held expert of a "
+                "sparse-expert layer in one train step (one sample a "
+                "layer a step), bucketed inside the step",
+        "buckets": (16, 32, 64, 96, 128, 160, 192, 224, 256, 320, 384,
+                    512, 768, 1024, 2048, 4096, 16384, 65536),
+    },
     "veles_serving_stream_write_seconds": {
         "help": "Serialising and writing one SSE event of a streamed "
                 "reply, per event, all handler threads together",
@@ -543,6 +557,25 @@ class HistogramRegistry:
                 self._sums[name] = 0.0
             counts[bisect.bisect_left(self._bounds[name], value)] += 1
             self._sums[name] += value
+
+    def add(self, name: str, bucket_counts: Dict[int, int],
+            total: float) -> None:
+        """Samples that were bucketed elsewhere (telemetry/steptaps.py:
+        inside the train step, by ``observe``'s own rule):
+        ``bucket_counts`` is {bucket index: samples}, ``total`` their
+        sum of values."""
+        if not bucket_counts:
+            return
+        with self._lock:
+            counts = self._counts.get(name)
+            if counts is None:
+                bounds = histogram_buckets(name)
+                self._bounds[name] = bounds
+                counts = self._counts[name] = [0] * (len(bounds) + 1)
+                self._sums[name] = 0.0
+            for i, n in bucket_counts.items():
+                counts[i] += n
+            self._sums[name] += float(total)
 
     def count(self, name: str) -> int:
         with self._lock:
