@@ -112,13 +112,49 @@ def test_artifact_serves_id_exact_with_zero_compiles(served_artifact):
         st = engine.stats()
         assert st["artifact_mode"] == 1
         assert st["compiled_live"] == 0
-        assert engine.programs_built <= len(engine.buckets) + 1
+        assert engine.programs_built <= engine.programs_bound()
+        assert engine.programs_bound() == len(engine.buckets) + 1
     finally:
         engine.stop()
     assert counters.get("veles_artifact_loads_total") == loads0 + 1
     assert counters.get("veles_compiles_total") == compiles0
     assert counters.get("veles_serving_compile_seconds_total") == \
         compile_s0
+
+
+def test_artifact_keeps_the_whole_view_and_compiles_nothing(
+        served_artifact, tmp_path):
+    """Where a live engine has a ladder of two, the artifact holds the
+    step at the whole view alone: the engine that loads it keeps that
+    one rung, serves the same tokens and compiles nothing."""
+    lm, wf, _ = served_artifact
+    knobs = dict(max_slots=3, buckets=(8, 16), max_context=64,
+                 page_size=4)
+    art = str(tmp_path / "artifact")
+    export_serve_artifact(wf, art, **knobs)
+    reqs = [make_request(_prompt(lm, 90, 5), 40, seed=1),
+            make_request(_prompt(lm, 91, 9), 12, temperature=0.7,
+                         seed=2)]
+    live = ContinuousEngine(wf, name="aot_lad_live", **knobs).start()
+    try:
+        ref = live.serve(list(reqs))
+        assert live.view_ladder == (16, 8)
+        assert live.programs_bound() == 2 + 2
+        assert live.stats()["view_share"] < 1.0
+    finally:
+        live.stop()
+    engine = ContinuousEngine(wf, artifact=art, name="aot_lad",
+                              **knobs).start()
+    try:
+        assert engine.artifact_mode
+        assert engine.view_ladder == (16,)
+        assert engine.programs_bound() == 2 + 1
+        assert engine.serve(list(reqs)) == ref
+        assert engine.compiled_live == 0
+        assert engine.stats()["view_share"] == 1.0
+        assert engine.programs_built <= engine.programs_bound()
+    finally:
+        engine.stop()
 
 
 # -- fallback paths ------------------------------------------------------------

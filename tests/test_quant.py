@@ -190,9 +190,36 @@ def test_quantized_greedy_and_sampled_token_exact(trained, qw, qkv):
         # quantization — the noise derives from seeds, not weights)
         solo = [engine.serve([r])[0] for r in reqs]
         assert solo == ref
-        assert engine.programs_built <= len(engine.buckets) + 1
+        assert engine.programs_built <= engine.programs_bound()
     finally:
         engine.stop()
+
+
+@pytest.mark.parametrize("qw", [False, True], ids=["f32w", "int8w"])
+@pytest.mark.parametrize("decode_block", [1, 4])
+def test_int8_pool_ladder_serves_what_the_whole_view_serves(
+        trained, decode_block, qw):
+    """The int8 pool's decode step gathers and writes through the same
+    table: at the shortest view that holds every active row it serves,
+    token for token, greedy and sampled, what it serves at the whole
+    view (tests/test_serving_engine.py holds the float pool to the
+    same drill)."""
+    import ladder_drill
+    lm, wf = trained
+    ladder, whole = ladder_drill.twins(
+        wf, "q_lad%d%d" % (decode_block, qw), quant_kv=True,
+        quant_weights=qw, decode_block=decode_block)
+    assert ladder.view_ladder == (16, 8)
+    seen = ladder_drill.record_rungs(ladder)
+    reqs = ladder_drill.requests(lambda seed, n: _prompt(lm, seed, n))
+    got = ladder_drill.serve_by_ticks(ladder, reqs)
+    assert got == ladder_drill.serve_by_ticks(whole, reqs)
+    assert [len(t) for t in got] == [r["n_new"] for r in reqs]
+    ladder_drill.assert_shortest_rungs(ladder, seen)
+    assert {pages for pages, _ in seen} == {8, 16}
+    assert whole.stats()["view_share"] == 1.0
+    assert ladder.programs_bound() == len(ladder.buckets) + 2
+    assert ladder.page_pool.in_use() == whole.page_pool.in_use() == 0
 
 
 def test_int8_pool_halves_hbm(trained):

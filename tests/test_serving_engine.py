@@ -181,16 +181,19 @@ def test_concurrent_rows_id_exact_vs_solo_greedy_and_sampled(served):
 
 def test_jit_program_cache_bounded_by_buckets(served):
     """After everything this module served, the engine holds at most
-    len(buckets)+1 jitted programs (the bucketed prefills + the ONE
-    fixed-shape decode step) — never one per distinct prompt length."""
+    ``programs_bound()`` jitted programs (the bucketed prefills + the
+    fixed-shape decode step at each rung of its view ladder) — never
+    one per distinct prompt length."""
     lm, wf, engine = served
-    assert engine.programs_built <= len(engine.buckets) + 1
+    assert engine.programs_built <= engine.programs_bound()
     # and the dispatch counter rides _count_decode_dispatches, so the
     # decode plane stays visible to the round-5 regression lock
     before = counters.get("veles_decode_dispatches_total")
     engine.serve([make_request(_prompt(lm, 30, 7), 4)])
     assert counters.get("veles_decode_dispatches_total") > before
-    assert engine.programs_built <= len(engine.buckets) + 1
+    assert engine.programs_built <= engine.programs_bound()
+    # this engine's three pages a slot do not halve: one rung
+    assert engine.programs_bound() == len(engine.buckets) + 1
 
 
 def test_early_eos_retirement_frees_slot_for_queue(served):
@@ -655,10 +658,11 @@ def test_program_count_bounded_with_spec_and_beam(pooled):
     function of traffic."""
     lm, wf, draft, engine = pooled
     assert engine.programs_built <= engine.programs_bound()
-    # the base greedy/sample plane alone stays within len(buckets)+1
+    # the base greedy/sample plane alone stays within the bucketed
+    # prefills and the step's rungs
     base = [k for k in engine._progs
             if k[0] in ("prefill", "step")]
-    assert len(base) <= len(engine.buckets) + 1
+    assert len(base) <= len(engine.buckets) + len(engine.view_ladder)
 
 
 # -- the float decode step's pool writes ----------------------------------------
@@ -901,14 +905,19 @@ def test_step_tokens_reach_streams_under_the_next_dispatch(pooled, plane):
         assert engine.submit(req, ticket)
     in_flight, at_dispatch = queue.Queue(), []
     kind = "spec" if spec else "step"
-    engine._tick_params()          # the pool, which the program closes over
-    real = engine._program(kind)
+    build = engine._program
 
-    def program(*args):
-        at_dispatch.append([t._stream_q.qsize() for t in tickets])
-        out = real(*args)
-        return (_Blocked(out[0], in_flight),) + tuple(out[1:])
-    engine._progs[(kind, None)] = program
+    def program(which, bucket=None):
+        real = build(which, bucket)
+        if which != kind:
+            return real
+
+        def blocked(*args):
+            at_dispatch.append([t._stream_q.qsize() for t in tickets])
+            out = real(*args)
+            return (_Blocked(out[0], in_flight),) + tuple(out[1:])
+        return blocked
+    engine._program = program
     engine.start()
     try:
         for n in range(1, 5):
@@ -1079,3 +1088,184 @@ def test_push_overlap_share_on_a_live_run(served, api_served):
     for name in ("veles_serving_token_pushes_total",
                  "veles_serving_token_pushes_overlapped_total"):
         assert re.search(r"^%s \d+" % name, text, re.M), name
+
+
+# -- the decode step's view ladder -----------------------------------------------
+
+@pytest.mark.parametrize("pages_per_slot,page_size,min_bucket,ladder", [
+    (128, 16, 128, (128, 64)),            # the decode cell
+    (16, 4, 8, (16, 8)),
+    (64, 16, 512, (64, 32)),              # the half is the bucket
+    (6, 8, 8, (6, 3)),
+    (3, 16, 8, (3,)),                     # no whole pages: a ladder of one
+    (4, 16, 64, (4,)),                    # 32 positions < a bucket of 64
+    (64, 16, 1024, (64,)),
+    (1, 16, 8, (1,)),
+])
+def test_view_ladder_geometry(pages_per_slot, page_size, min_bucket,
+                              ladder):
+    from veles_tpu.serving.pages import view_ladder
+    assert view_ladder(pages_per_slot, page_size, min_bucket) == ladder
+
+
+@pytest.mark.parametrize("knobs,ladder", [
+    (dict(buckets=(8, 16), max_context=64, page_size=4), (16, 8)),
+    (dict(buckets=(8, 16), max_context=48), (3,)),
+    (dict(buckets=(16, 32), max_context=64, page_size=8), (8, 4)),
+    (dict(buckets=(32,), max_context=48, page_size=8), (6,)),
+])
+def test_engine_ladder_and_programs_bound(served, knobs, ladder):
+    """The ladder follows from ``max_context``, ``page_size`` and
+    ``buckets``; ``programs_bound()`` holds a step program a rung and
+    is a constant of the engine."""
+    _, wf, _ = served
+    engine = ContinuousEngine(wf, max_slots=2, name="eng_geom", **knobs)
+    assert engine.view_ladder == ladder
+    assert engine.programs_bound() == len(engine.buckets) + len(ladder)
+    assert engine.stats()["view_share"] == 1.0     # nothing dispatched
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_view_rung_is_the_shortest_that_holds_every_row(seed):
+    """Over random geometries and random active rows: the rung chosen
+    is never shorter than any row's need, and no shorter rung of the
+    ladder would do."""
+    from veles_tpu.serving.pages import pages_for, view_ladder, view_rung
+    rng = numpy.random.RandomState(seed)
+    for _ in range(300):
+        size = int(rng.choice([1, 4, 8, 16]))
+        context = int(rng.randint(size, 64 * size + 1))
+        per_slot = pages_for(context, size)
+        ladder = view_ladder(per_slot, size,
+                             int(rng.randint(1, context + 1)))
+        assert ladder[0] == per_slot and len(ladder) <= 2
+        block = int(rng.choice([d for d in (1, 2, 4) if size % d == 0]))
+        needs = [min(int(rng.randint(1, context + 1)),
+                     int(rng.randint(0, context)) + block)
+                 for _ in range(rng.randint(1, 9))]
+        rung = view_rung(ladder, max(needs), size)
+        assert rung in ladder
+        assert all(rung * size >= n for n in needs)
+        assert not any(r < rung and r * size >= max(needs)
+                       for r in ladder)
+
+
+@pytest.mark.parametrize("decode_block", [1, 4])
+def test_ladder_serves_what_the_whole_view_serves(served, decode_block):
+    """Token for token, greedy and sampled, an engine with the ladder
+    serves what the same engine held to the whole view serves (and the
+    scan decoder): a shorter view drops only positions that the causal
+    mask weighs nought."""
+    import ladder_drill
+    from veles_tpu.nn import sampling
+    lm, wf, _ = served
+    ladder, whole = ladder_drill.twins(wf, "eng_blk%d" % decode_block,
+                                       decode_block=decode_block)
+    assert ladder.view_ladder == (16, 8)
+    seen = ladder_drill.record_rungs(ladder)
+    reqs = ladder_drill.requests(lambda seed, n: _prompt(lm, seed, n))
+    before = counters.snapshot()
+    got = ladder_drill.serve_by_ticks(ladder, reqs)
+    delta = counters.delta(before)
+    assert got == ladder_drill.serve_by_ticks(whole, reqs)
+    for req, toks in zip(reqs, got):
+        assert toks == sampling.generate(
+            wf, req["prompt"], req["n_new"],
+            temperature=req["temperature"], seed=req["seed"])
+    ladder_drill.assert_shortest_rungs(ladder, seen)
+    assert {pages for pages, _ in seen} == {8, 16}
+    if decode_block == 4:
+        # a chunk that ends exactly at its rung's last position
+        assert any(need == pages * 4 for pages, need in seen)
+    assert delta["veles_serving_view_positions_total"] == \
+        4 * sum(pages for pages, _ in seen)
+    assert delta["veles_serving_decode_dispatches_total"] == len(seen)
+    assert whole.stats()["view_share"] == 1.0
+    assert sorted(k for k in whole._progs if k[0] == "step") == \
+        [("step", 16)]
+    assert ladder.page_pool.in_use() == whole.page_pool.in_use() == 0
+
+
+def test_ladder_with_prefix_shared_leading_pages(served):
+    """Two rows that adopted the same leading pages read them through
+    every rung: the shared pages are the table's first entries, which
+    every rung keeps."""
+    import ladder_drill
+    from veles_tpu.nn import sampling
+    lm, wf, _ = served
+    ladder, whole = ladder_drill.twins(wf, "eng_pfx", prefix_cache=True)
+    seen = ladder_drill.record_rungs(ladder)
+    stem = _prompt(lm, 410, 12)
+    first = [make_request(stem + _prompt(lm, 411, 3), 4, seed=1)]
+    reqs = [make_request(stem + _prompt(lm, 412, 4), 40, seed=2),
+            make_request(stem + _prompt(lm, 413, 2), 9, temperature=0.8,
+                         seed=3)]
+    for engine in (ladder, whole):
+        ladder_drill.serve_by_ticks(engine, first)   # fills the index
+    got = ladder_drill.serve_by_ticks(ladder, reqs)
+    assert ladder.prefix_requests >= 2
+    assert got == ladder_drill.serve_by_ticks(whole, reqs)
+    for req, toks in zip(reqs, got):
+        assert toks == sampling.generate(
+            wf, req["prompt"], req["n_new"],
+            temperature=req["temperature"], seed=req["seed"])
+    ladder_drill.assert_shortest_rungs(ladder, seen)
+
+
+@pytest.mark.parametrize("mode,temp", [("greedy", 0.0), ("sample", 0.9)])
+def test_ladder_after_a_preemption_and_resume(served, mode, temp):
+    """A batch row preempted beyond the first rung comes back through
+    a longer prefill bucket and goes on at the rung its position asks
+    for; the interactive row between runs at the shorter."""
+    import ladder_drill
+    from veles_tpu.config import root
+    from veles_tpu.nn import sampling
+    lm, wf, _ = served
+    root.common.serving.qos = True
+    try:
+        engine, _ = ladder_drill.twins(wf, "eng_qos_" + mode, max_slots=1,
+                                       buckets=(8, 48))
+        seen = ladder_drill.record_rungs(engine)
+        req = make_request(_prompt(lm, 420, 6), 50, temperature=temp,
+                           seed=7, mode=mode)
+        req["priority"] = "batch"
+        urgent = make_request(_prompt(lm, 421, 5), 3)
+        urgent["priority"] = "interactive"
+        t_b, t_i = Ticket(), Ticket()
+        assert engine.submit(req, t_b)
+        ladder_drill.tick_until(
+            engine, lambda: any(len(s.tokens) >= 30
+                                for s in engine.scheduler.active()))
+        assert seen[-1][0] == 16                # beyond the first rung
+        at = len(seen)
+        assert engine.submit(urgent, t_i)
+        ladder_drill.tick_until(
+            engine, lambda: t_b.event.is_set() and t_i.event.is_set())
+        assert engine.preemptions == 1
+        assert t_b.error is None and t_i.error is None
+        assert seen[at][0] == 8                 # the interactive row's
+        assert t_b.result["tokens"] == sampling.generate(
+            wf, req["prompt"], 50, temperature=temp, seed=7)
+        assert t_i.result["tokens"] == sampling.generate(
+            wf, urgent["prompt"], 3, temperature=0)
+        ladder_drill.assert_shortest_rungs(engine, seen)
+        assert engine.page_pool.in_use() == 0
+    finally:
+        root.common.serving.qos = False
+
+
+def test_view_share_on_stats_and_metrics(served, api_served):
+    """``view_share`` is on ``/generate/stats`` (1.0 on this engine,
+    whose ladder has one rung) and the counter on ``/metrics``."""
+    lm, wf, api, url = api_served
+    _post(url, {"prompt": _prompt(lm, 430, 6), "n_new": 4})
+    with urllib.request.urlopen(url + "/stats", timeout=30) as r:
+        stats = json.loads(r.read())["continuous"]
+    assert api._engine.view_ladder == (api._engine.pages_per_slot,)
+    assert stats["view_share"] == 1.0
+    with urllib.request.urlopen(
+            "http://127.0.0.1:%d/metrics" % api.port, timeout=30) as r:
+        text = r.read().decode()
+    found = re.search(r"^veles_serving_view_positions_total (\d+)", text,
+                      re.M)
+    assert found and int(found.group(1)) > 0

@@ -130,7 +130,8 @@ def make_parser() -> argparse.ArgumentParser:
                         "pool (root.common.serving.max_slots)")
     p.add_argument("--serve-buckets", default=None, metavar="L1,L2,...",
                    help="prefill pad-to lengths; the serving jit cache "
-                        "is bounded by len(buckets)+1 programs "
+                        "is bounded by len(buckets) programs and "
+                        "the decode step's 1 or 2 view lengths "
                         "(root.common.serving.buckets)")
     p.add_argument("--serve-max-context", type=int, default=None,
                    metavar="T",

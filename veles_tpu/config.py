@@ -260,7 +260,8 @@ def _default_root() -> Config:
             # KV-cache slot rows decoded by the one fixed-shape step
             "max_slots": 8,
             # prefill pad-to lengths: jit cache is bounded by
-            # len(buckets)+1 programs, not by distinct prompt lengths
+            # len(buckets) + the decode step's 1 or 2 view lengths
+            # (programs_bound()), not by distinct prompt lengths
             "buckets": [16, 32, 64, 128],
             # per-row KV capacity; admission requires
             # len(prompt) + n_new <= max_context (else the request

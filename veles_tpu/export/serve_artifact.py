@@ -273,7 +273,9 @@ def load_serve_programs(path: str, expect_signature: Dict
             raise VelesError("serve-artifact program %s corrupt: %s: %s"
                              % (fname, type(e).__name__, e)) from e
         if label == "decode":
-            key = ("step", None)
+            # the paged step is keyed by its view length in pages,
+            # and the artifact holds the whole view alone
+            key = ("step", expect_signature["pages_per_slot"])
         elif label.startswith("prefill_"):
             key = ("prefill", int(label[len("prefill_"):]))
         elif label == "rscan":
@@ -290,7 +292,7 @@ def load_serve_programs(path: str, expect_signature: Dict
     else:
         want = {("prefill", b)
                 for b in expect_signature.get("buckets", ())}
-        want.add(("step", None))
+        want.add(("step", expect_signature["pages_per_slot"]))
     missing = want - set(programs)
     if missing:
         raise VelesError("serve-artifact %s is missing programs: %s"

@@ -286,7 +286,8 @@ class GenerationAPI(Unit):
       ride the continuous-batching engine (``veles_tpu/serving/``) — a
       persistent ``max_slots``-row KV-cache pool with ONE fixed-shape
       jitted decode step, prefill padded to ``buckets`` (jit cache
-      bounded by len(buckets)+1 programs), iteration-level admission
+      bounded by ``programs_bound()``: len(buckets) and the step's 1
+      or 2 view lengths), iteration-level admission
       into free slots and per-row retirement at ``eos_id`` / own
       ``n_new``. Per-slot PRNG streams keep every row id-exact vs its
       solo decode, so batching never changes answers — stochastic

@@ -14,8 +14,9 @@ Caching for Inference"):
   ONE fixed-shape jitted decode step over a ``max_slots``-row KV-cache
   pool (``nn/sampling.py``'s ``_block_prefill``/``_block_step`` cache
   layout, padded to ``max_context``), prefill padded to a small set of
-  length buckets so the jit cache is bounded by ``len(buckets) + 1``
-  programs — not by distinct prompt lengths;
+  length buckets so the jit cache is bounded by ``len(buckets)`` and
+  the step's 1 or 2 view lengths (``programs_bound()``) — not by
+  distinct prompt lengths;
 - :mod:`scheduler` — :class:`~veles_tpu.serving.scheduler.SlotScheduler`:
   admits queued requests into free slots at step boundaries, retires a
   row the moment it emits ``eos_id`` or reaches its own ``n_new``, and
@@ -90,6 +91,7 @@ SERVING_COUNTERS = (
     "veles_serving_retired_total",
     "veles_serving_prefill_dispatches_total",
     "veles_serving_decode_dispatches_total",
+    "veles_serving_view_positions_total",
     "veles_serving_tokens_total",
     "veles_serving_expired_total",
     "veles_serving_compile_seconds_total",

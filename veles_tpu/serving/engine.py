@@ -25,10 +25,15 @@ Duality and Portable O(1) Autoregressive Caching for Inference"):
   accounting safety net, and a row it cannot cover — or an injected
   ``serve.page_alloc`` fault — is shed 503 + Retry-After while
   everyone else keeps decoding;
-- the decode step's shapes never change — page tables are data, not
-  shape — so it still compiles exactly once; prefill pads prompts to
-  a small set of length ``buckets``, so the greedy/sample plane holds
-  ``len(buckets) + 1`` programs, never one per prompt length;
+- the decode step's shapes do not follow the traffic — page tables
+  are data, not shape — and its view is one of a short ladder of
+  lengths (``view_ladder``: ``pages_per_slot`` and its half;
+  ``_decode`` hands it, each tick, the table's leading columns for
+  the shortest that holds every active row, since what lies beyond
+  a row's position is masked to nought anyway); prefill
+  pads prompts to a small set of length ``buckets``, so the
+  greedy/sample plane holds ``len(buckets) + len(view_ladder)``
+  programs, never one per prompt length;
 - ALL decode modes ride the pool: ``speculative`` rows advance by
   on-device draft/verify rounds (a second fixed-shape program sharing
   the page tables; the draft model's K/V pages ride the same
@@ -120,6 +125,7 @@ from ..resilience import health
 from ..resilience.faults import FaultInjected, fire as fire_fault
 from ..telemetry.counters import inc
 from ..telemetry.spans import span
+from .pages import view_ladder, view_rung
 
 #: floor for the temperature divisor inside the one shared decode
 #: program (greedy rows carry temperature 0; their categorical lane is
@@ -311,6 +317,11 @@ class ContinuousEngine(Logger):
             raise ValueError("beam_width must be >= 1")
         from . import parse_buckets
         self.buckets = parse_buckets(buckets)
+        #: the decode step's view lengths in pages, longest first
+        #: (pages.view_ladder): ``_decode`` takes, each tick, the
+        #: shortest that holds every active row
+        self.view_ladder = view_ladder(self.pages_per_slot,
+                                       self.page_size, self.buckets[0])
         self.page_pool = PagePool(self.pages, self.page_size)
         # heavy-traffic request plane knobs (root.common.serving.*,
         # CLI --serve-prefix-cache/--serve-prefill-chunk); both off =
@@ -460,6 +471,11 @@ class ContinuousEngine(Logger):
         #: surface's ``push_overlap_share``)
         self.token_pushes = 0
         self.token_pushes_overlapped = 0
+        #: decode dispatches (step, speculative round, beam step) and
+        #: the positions a slot that they gathered, summed (the stats
+        #: surface's ``view_share``)
+        self.decode_dispatches = 0
+        self.view_positions = 0
         self.admitted = 0
         self.retired = 0
         self.peak_slots = 0
@@ -744,6 +760,14 @@ class ContinuousEngine(Logger):
             "push_overlap_share": round(
                 self.token_pushes_overlapped
                 / max(1, self.token_pushes), 4),
+            # the mean view a decode dispatch gathered a slot, over the
+            # whole view (pages_per_slot pages); 1.0 = the ladder
+            # never engaged (or has one rung)
+            "view_share": round(
+                self.view_positions
+                / (self.decode_dispatches * self.pages_per_slot
+                   * self.page_size), 4)
+            if self.decode_dispatches else 1.0,
             # quantization/AOT plane (veles_tpu/quant/): what the
             # /metrics mode gauges render on both surfaces
             "artifact_mode": int(self.artifact_mode),
@@ -778,18 +802,18 @@ class ContinuousEngine(Logger):
     @property
     def programs_built(self) -> int:
         """Jitted programs this engine ever built. The greedy/sample
-        plane is bounded by ``len(buckets) + 1``; speculation adds its
-        draft prefills + one round program, beam one step program —
-        see :meth:`programs_bound`."""
+        plane is bounded by ``len(buckets) + len(view_ladder)``;
+        speculation adds its draft prefills + one round program, beam
+        one step program — see :meth:`programs_bound`."""
         return len(self._progs)
 
     def programs_bound(self) -> int:
         """The hard ceiling on :attr:`programs_built`: bucketed
-        prefills + the decode step, plus (draft configured) the draft
-        prefills + the spec round, plus (beam servable) the beam step
-        and the sibling page-copy — a CONSTANT per engine, never a
-        function of traffic."""
-        bound = len(self.buckets) + 1
+        prefills + the decode step at each rung of ``view_ladder``,
+        plus (draft configured) the draft prefills + the spec round,
+        plus (beam servable) the beam step and the sibling page-copy —
+        a CONSTANT per engine, never a function of traffic."""
+        bound = len(self.buckets) + len(self.view_ladder)
         if self.draft is not None:
             bound += len(self.buckets) + 1
         has_pagecopy = False
@@ -1727,25 +1751,44 @@ class ContinuousEngine(Logger):
             self._push_tokens(held, overlapped)
 
     # -- the decode chunk ------------------------------------------------------
+    def _count_decode_dispatch(self, pages: int) -> None:
+        """One decode dispatch (the step, a speculative round or a
+        beam step) whose views were ``pages`` pages long."""
+        positions = pages * self.page_size
+        self.decode_dispatches += 1
+        self.view_positions += positions
+        inc("veles_serving_decode_dispatches_total")
+        inc("veles_serving_view_positions_total", positions)
+
     def _decode(self, params) -> None:
         import jax.numpy as jnp
+
+        def need(s):
+            return min(s.t_p + s.n_new,
+                       int(self._pos[s.idx]) + self.decode_block)
+
         with span("serving.tick.prepare"):
-            active = self._grow_or_shed(
-                self._decodable(),
-                lambda s: min(s.t_p + s.n_new,
-                              int(self._pos[s.idx]) + self.decode_block))
+            active = self._grow_or_shed(self._decodable(), need)
             if not active:
                 return
+            # the step runs at the shortest view that holds every
+            # active row: the table's width IS the view's length, and
+            # what lies beyond a row's ``pos`` weighs nought anyway.
+            # A masked-out row may lie beyond it (result discarded,
+            # write to the sink page)
+            pages = view_rung(self.view_ladder,
+                              max(need(s) for s in active),
+                              self.page_size)
             mask = numpy.zeros(self.max_slots, numpy.int32)
             for slot in active:
                 mask[slot.idx] = 1
             base_len = {id(s): len(s.tokens) for s in active}
             fire_fault("serve.decode_step")
-            step = self._program("step")
             host = (jnp.asarray(self._tok), jnp.asarray(self._pos),
                     jnp.asarray(self._temp), jnp.asarray(mask),
-                    jnp.asarray(self._page_table),
+                    jnp.asarray(self._page_table[:, :pages]),
                     jnp.asarray(self._shared))
+            step = self._program("step", pages)
         with span("serving.decode_step", active=len(active),
                   chunk=self.decode_block):
             with span("serving.tick.dispatch"):
@@ -1758,7 +1801,7 @@ class ContinuousEngine(Logger):
             self._push_held(overlapped=True, phase=True)
             with span("serving.tick.device"):
                 toks = numpy.asarray(toks)      # (decode_block, S)
-        inc("veles_serving_decode_dispatches_total")
+        self._count_decode_dispatch(pages)
         with span("serving.tick.emit"):
             finished: List = []
             for h in range(toks.shape[0]):
@@ -1826,7 +1869,7 @@ class ContinuousEngine(Logger):
                 n_emit = numpy.asarray(n_emit)
                 acc = numpy.asarray(acc)
                 new_tok = numpy.asarray(new_tok)
-        inc("veles_serving_decode_dispatches_total")
+        self._count_decode_dispatch(self.pages_per_slot)
         inc("veles_serving_spec_rounds_total", len(active))
         with span("serving.tick.emit"):
             for slot in active:
@@ -1904,7 +1947,7 @@ class ContinuousEngine(Logger):
                 parent = numpy.asarray(parent)
                 new_scores = numpy.asarray(new_scores)
                 new_fin = numpy.asarray(new_fin)
-        inc("veles_serving_decode_dispatches_total")
+        self._count_decode_dispatch(self.pages_per_slot)
         inc("veles_serving_beam_steps_total", len(groups))
         with span("serving.tick.emit"):
             for gi, group in enumerate(groups):
@@ -2076,6 +2119,11 @@ class ContinuousEngine(Logger):
 
     # -- jitted programs -------------------------------------------------------
     def _program(self, kind: str, bucket: Optional[int] = None):
+        """The program of ``kind``, built at the first call that
+        needs it. ``bucket`` is a prefill's padded prompt length or
+        the step's view length in pages (``view_ladder``): the step's
+        builder takes no length, its program's shapes follow the
+        table it is first called with, one executable a key."""
         key = (kind, bucket)
         prog = self._progs.get(key)
         if prog is None:
@@ -2227,6 +2275,9 @@ class ContinuousEngine(Logger):
                 type(e).__name__, e)
             return False
         tp_on = self.tp > 1
+        # the artifact holds the step at the whole view and no other
+        # length: the ladder is that one rung, nothing compiles
+        self.view_ladder = (self.pages_per_slot,)
         for key, call in programs.items():
             counted = _count_decode_dispatches(call)
             if tp_on:
@@ -2438,7 +2489,9 @@ class ContinuousEngine(Logger):
     def _build_decode(self):
         """THE decode step: ``decode_block`` scan iterations of the
         vmapped single-row ``_block_step`` over every slot's gathered
-        page view — one fixed shape, compiled exactly once; page
+        page view — one fixed shape, compiled exactly once a view
+        length (``view_ladder``; the view is as long as the table
+        handed in is wide); page
         tables arrive as DATA. The float pool gathers each row's view
         ONCE per chunk, carries it through the scan (the inner step
         runs at dense-pool cost) and writes into the pool only the

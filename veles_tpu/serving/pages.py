@@ -50,6 +50,31 @@ def pages_for(positions: int, page_size: int) -> int:
     return max(0, (int(positions) + page_size - 1) // page_size)
 
 
+def view_ladder(pages_per_slot: int, page_size: int,
+                min_bucket: int) -> Tuple[int, ...]:
+    """The view lengths THE decode step runs at, in pages, longest
+    first: ``pages_per_slot`` and its half, where the half is whole
+    pages and no shorter than the smallest prefill bucket;
+    ``(pages_per_slot,)`` otherwise. A length is a compiled program
+    that its first dispatch builds, and a server's warm-up reaches
+    what it has been given: two, so that traffic under half of
+    ``max_context`` warms the rung it will run at."""
+    whole = int(pages_per_slot)
+    half = whole // 2
+    if whole % 2 == 0 and half * page_size >= min_bucket:
+        return whole, half
+    return (whole,)
+
+
+def view_rung(ladder: Sequence[int], positions: int,
+              page_size: int) -> int:
+    """The shortest rung of ``ladder`` (pages) whose view holds
+    ``positions`` cache rows; the longest (its first) where none
+    does."""
+    need = pages_for(positions, page_size)
+    return min((r for r in ladder if r >= need), default=ladder[0])
+
+
 def per_shard_kv_heads(n_kv_heads: int, tp: int = 1) -> int:
     """K/V heads each mesh shard STORES per logical page under
     tensor-parallel serving (``serving/engine.py`` ``tp=`` knob).

@@ -119,6 +119,9 @@ DESCRIPTIONS = {
     "veles_serving_decode_dispatches_total":
         "Pooled fixed-shape decode steps dispatched by the serving "
         "engine",
+    "veles_serving_view_positions_total":
+        "Cache positions a slot that those dispatches gathered (the "
+        "view's length); over the dispatches, the mean view",
     "veles_serving_tokens_total":
         "Tokens emitted by the continuous-batching engine",
     "veles_serving_token_pushes_total":
