@@ -412,7 +412,10 @@ def serve_phase(leg, tp):
                           answers["dup"][1]["tokens"]), log)
         status, stats = _http(port, "/generate/stats")
         pool = stats.get("continuous", {}) if status == 200 else {}
-        programs = len(used) + 1          # one prefill per bucket + decode
+        # one prefill per bucket, and the decode step at both rungs of
+        # its view ladder (PR 35): "exact" passes half of max_context,
+        # the other requests stay under it
+        programs = len(used) + 2
         if (pool.get("slot_kind") != "paged" or pool.get("tp") != tp
                 or pool.get("compiled_live") != programs):
             _fail("%s: /generate/stats wants slot_kind paged, tp %d, "

@@ -1404,7 +1404,9 @@ def _drive(launcher: Launcher, workflow, args):
         # not a 500 on the first request.
         from .nn.sampling import split_stack
         from .restful_api import GenerationAPI
-        split_stack(list(workflow.forwards))
+        # (the continuous engine serves a hybrid_block through the
+        # block's own programs; the draft's consumer does not)
+        split_stack(list(workflow.forwards), hybrid=True)
         draft = None
         if args.serve_draft:
             draft_mod = import_file_as_module(args.serve_draft)

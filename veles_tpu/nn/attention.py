@@ -119,6 +119,94 @@ def attention_core(q, k, v, *, causal=False, mesh=None, n_heads=1,
                                window=window)
 
 
+def masked_attention(q, k, v, mask, sink=None, scale=None):
+    """softmax(q k^T / sqrt(Dk) under ``mask``) v where the key and the
+    value widths may differ and the keys carry fewer heads (read through
+    a (kv, group) view of the query heads, nothing expanded). ``scale``
+    in place of ``1 / sqrt(Dk)`` where q and k come padded with noughts
+    beyond their width.
+    q (B, Tq, H, Dk), k (B, Tk, KV, Dk), v (B, Tk, KV, Dv), ``mask``
+    (B or 1, Tq, Tk) bool -> (B, Tq, H, Dv) in v's type. Scores, softmax
+    and the sink in float32, the two products on the operands' own type
+    with float32 accumulation. ``sink`` (H,): one learned score a head
+    that joins the softmax's denominator and no value's weight
+    (``p_ij = exp(s_ij) / (sum_j exp(s_ij) + exp(sink_h))``)."""
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    b, tq, h, dk = q.shape
+    kv = k.shape[2]
+    g = h // kv
+    s = jnp.einsum("bqkgd,btkd->bkgqt", q.reshape(b, tq, kv, g, dk), k,
+                   preferred_element_type=f32) * (
+                       1.0 / numpy.sqrt(dk) if scale is None else scale)
+    s = jnp.where(mask[:, None, None], s, -1e30)
+    m = s.max(axis=-1, keepdims=True)
+    if sink is not None:
+        sk = sink.astype(f32).reshape(1, kv, g, 1, 1)
+        m = jnp.maximum(m, sk)
+    w = jnp.exp(s - m)
+    den = w.sum(axis=-1, keepdims=True)
+    if sink is not None:
+        den = den + jnp.exp(sk - m)
+    w = (w / den).astype(v.dtype)
+    o = jnp.einsum("bkgqt,btkd->bqkgd", w, v, preferred_element_type=f32)
+    return o.astype(v.dtype).reshape(b, tq, h, v.shape[-1])
+
+
+def blocked_causal_attention(q, k, v, *, window=None, sink=None,
+                             block=256):
+    """Causal :func:`masked_attention` of a whole sequence onto itself,
+    by blocks of queries, so that no (H, T, T) score tensor exists: a
+    full layer's block of ``block`` queries reads the keys up to its own
+    end; a window layer's blocks of ``window`` queries each read their
+    own keys and the block's before, all blocks in one batched product.
+    A query sees the keys j with i - window < j <= i. Plain XLA, any
+    pair of key and value widths."""
+    import jax.numpy as jnp
+    b, t = q.shape[:2]
+    if window and t > 2 * window:
+        w = int(window)
+        n = -(-t // w)
+        pad = n * w - t
+
+        def blocks(x, lead):
+            x = jnp.pad(x, ((0, 0), (lead * w, pad), (0, 0), (0, 0)))
+            return x.reshape((b, n + lead, w) + x.shape[2:])
+
+        qb = blocks(q, 0)
+        kb, vb = blocks(k, 1), blocks(v, 1)
+        # block n's keys: the block before it (zeros before the first,
+        # masked) and its own
+        k2 = jnp.concatenate([kb[:, :-1], kb[:, 1:]], axis=2)
+        v2 = jnp.concatenate([vb[:, :-1], vb[:, 1:]], axis=2)
+        i = numpy.arange(w)[:, None]
+        j = numpy.arange(2 * w)[None, :] - w      # relative to the block
+        seen = (j <= i) & (j > i - w)
+        first = seen & (j >= 0)
+        mask = numpy.broadcast_to(seen, (n, w, 2 * w)).copy()
+        mask[0] = first
+
+        def fold(x):
+            return x.reshape((b * n,) + x.shape[2:])
+        o = masked_attention(fold(qb), fold(k2), fold(v2),
+                             jnp.asarray(numpy.tile(mask, (b, 1, 1))),
+                             sink)
+        return o.reshape((b, n * w) + o.shape[2:])[:, :t]
+    outs = []
+    for start in range(0, t, block):
+        end = min(start + block, t)
+        lo = max(0, start - window + 1) if window else 0
+        i = numpy.arange(start, end)[:, None]
+        j = numpy.arange(lo, end)[None, :]
+        mask = j <= i
+        if window:
+            mask = mask & (j > i - window)
+        outs.append(masked_attention(q[:, start:end], k[:, lo:end],
+                                     v[:, lo:end], jnp.asarray(mask[None]),
+                                     sink))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
 class MultiHeadAttention(ForwardBase):
     """(B, T, D) → (B, T, D); params wq/wk/wv/wo each (D, D)."""
 

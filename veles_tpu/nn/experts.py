@@ -2,8 +2,10 @@
 
 The router scores every token over ALL the experts of the layer (softmax
 in float32), keeps the ``top_k`` largest and divides their weights by
-their sum. The layer is told which experts it holds (``held``: their
-ids; all of them by default) and computes
+their sum; or (``route_sigmoid``) scores them by a sigmoid, chooses by the
+score plus a learned correction an expert and weighs by the score alone.
+The shared expert may be left out. The layer is told which experts it
+holds (``held``: their ids; all of them by default) and computes
 
     y = sum over the chosen experts held here of
             w_e down_e(silu(gate_e x) * up_e x)
@@ -52,6 +54,21 @@ def route(x, router, top_k):
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return idx, w / jnp.sum(w, axis=-1, keepdims=True)
+
+
+def route_sigmoid(x, router, bias, top_k):
+    """As :func:`route` with sigmoid scores: the ``top_k`` largest of
+    ``sigmoid(x . router) + bias`` are chosen (``bias`` (E,): the
+    selection's correction, which no weight carries) and weigh by their
+    sigmoid alone over the sum of the chosen."""
+    import jax
+    import jax.numpy as jnp
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
     return idx, w / jnp.sum(w, axis=-1, keepdims=True)
 
 
@@ -202,6 +219,9 @@ def shared_expert(p, x, precision):
         jnp.float32)
 
 
+TOUCHED = "veles_moe_experts_touched_total"
+
+
 def tap_keys():
     """The accumulator keys of ``load_taps``: two counters, and the
     histogram's sum and one key a bucket (the last is +Inf)."""
@@ -213,9 +233,11 @@ def tap_keys():
             + [steptaps.histogram_key(PEAK_LOAD, i) for i in range(buckets)])
 
 
-def load_taps(counts, n_assigned):
+def load_taps(counts, n_assigned, touched=False):
     """What the layer counts of one step: assignments made, assignments
-    held, and the fullest held expert's load in its histogram bucket."""
+    held, and the fullest held expert's load in its histogram bucket;
+    with ``touched`` (a served step, whose collector takes any key) also
+    the held experts that got a row."""
     import jax.numpy as jnp
     from ..telemetry import steptaps
     from ..telemetry.counters import histogram_buckets
@@ -224,31 +246,53 @@ def load_taps(counts, n_assigned):
     # bisect_left over the bounds, as HistogramRegistry.observe
     bucket = jnp.sum(peak > jnp.asarray(histogram_buckets(PEAK_LOAD), f32))
     assigned, held, total, *in_bucket = tap_keys()
-    steptaps.emit(assigned, jnp.asarray(float(n_assigned), f32))
+    steptaps.emit(assigned, jnp.asarray(n_assigned, f32))
     steptaps.emit(held, jnp.sum(counts).astype(f32))
     steptaps.emit(total, peak)
     for i, key in enumerate(in_bucket):
         steptaps.emit(key, (bucket == i).astype(f32))
+    if touched:
+        steptaps.emit(steptaps.counter_key(TOUCHED),
+                      jnp.sum(counts > 0).astype(f32))
 
 
 def sparse_experts(p, x, *, top_k, local_of, n_held, precision, scope,
-                   block=BLOCK_ROWS):
+                   block=BLOCK_ROWS, router="softmax", shared=True,
+                   live=None):
     """(B, T, D) -> (B, T, D) on the leaves ``router`` (D, E), ``e_gate``,
-    ``e_up`` (held, D, F), ``e_down`` (held, F, D), ``s_gate``, ``s_up``,
-    ``s_down`` and ``s_mix``. ``scope(part)`` opens a part's scope."""
+    ``e_up`` (held, D, F), ``e_down`` (held, F, D), with ``shared``
+    ``s_gate``, ``s_up``, ``s_down`` and ``s_mix``, with
+    ``router="sigmoid"`` ``router_bias`` (E,). ``scope(part)`` opens a
+    part's scope. ``live`` (B x T,) bool, a served program's: the rows
+    that are somebody's; the others are assigned to no expert, and the
+    layer counts the live rows' assignments alone."""
     import jax.numpy as jnp
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     with scope("router"):
-        idx, w = route(x2, p["router"], top_k)
+        if router == "sigmoid":
+            idx, w = route_sigmoid(x2, p["router"], p["router_bias"],
+                                   top_k)
+        else:
+            idx, w = route(x2, p["router"], top_k)
     with scope("dispatch"):
-        plan = plan_blocks(idx, jnp.asarray(local_of, jnp.int32), n_held,
-                           block)
-        load_taps(plan["counts"], idx.size)
+        local_of = jnp.asarray(local_of, jnp.int32)
+        n_assigned = idx.size
+        if live is not None:
+            # a row that is nobody's chooses an expert past the router's
+            # width, which nobody holds
+            idx = jnp.where(live[:, None], idx, local_of.shape[0])
+            local_of = jnp.concatenate(
+                [local_of, jnp.full((1,), -1, jnp.int32)])
+            n_assigned = jnp.sum(live) * top_k
+        plan = plan_blocks(idx, local_of, n_held, block)
+        load_taps(plan["counts"], n_assigned, touched=live is not None)
     with scope("experts"):
         y = grouped_experts(x2, w.reshape(-1), p["e_gate"], p["e_up"],
                             p["e_down"], plan, block, top_k)
-    with scope("shared_expert"):
-        s = shared_expert(p, x2, precision)
+    s = None
+    if shared:
+        with scope("shared_expert"):
+            s = shared_expert(p, x2, precision)
     with scope("combine"):
-        return (y + s).astype(x.dtype).reshape(shape)
+        return (y if s is None else y + s).astype(x.dtype).reshape(shape)

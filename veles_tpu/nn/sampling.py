@@ -71,9 +71,15 @@ def _rope_at(np_mod, x, pos, base=10000.0):
     return np_mod.concatenate([rot1, rot2, x[..., 2 * half:]], axis=-1)
 
 
-def split_stack(forwards) -> Dict[str, object]:
+def split_stack(forwards, hybrid: bool = False) -> Dict[str, object]:
     """Stem / block-list / head decomposition of a generation-capable
-    forward chain; raises for anything else."""
+    forward chain; raises for anything else. ``hybrid``: the caller
+    serves a ``HybridBlock`` through the block's own ``serve_prefill``
+    and ``serve_step`` (the paged engine); every other consumer (the
+    scan sampler, speculation, beam search) runs ``_block_prefill`` and
+    ``_block_step``, which are ``TransformerBlock``'s, and refuses the
+    block by name."""
+    from .hybrid import HybridBlock
     stem = pos_emb = head = None
     blocks: List[TransformerBlock] = []
     for f in forwards:
@@ -82,6 +88,15 @@ def split_stack(forwards) -> Dict[str, object]:
         elif isinstance(f, PositionalEmbedding):
             pos_emb = f
         elif isinstance(f, TransformerBlock):
+            blocks.append(f)
+        elif isinstance(f, HybridBlock):
+            if not hybrid:
+                raise VelesError(
+                    "%s is a hybrid_block: it is served by the continuous "
+                    "engine's greedy and sampled decode step only (--serve-"
+                    "generate with the paged slot pool); the scan sampler, "
+                    "speculative and beam decoding take transformer_block "
+                    "stacks" % f.name)
             blocks.append(f)
         elif isinstance(f, LMHead):
             head = f
@@ -274,17 +289,24 @@ def _embed_prompt(stem, pos_emb, params, ids, pos0=0, tp=1,
 
 
 def _prefill_blocks(blocks, params, x, cache_len, dim, tp=1,
-                    tp_axis=None):
+                    tp_axis=None, live=None):
     """Run every transformer block's ``_block_prefill`` over fresh
     zero K/V caches of ``cache_len`` rows → (x, [(ck, cv), ...]) —
     the shared prompt forward. Each block shapes its OWN cache (the
     layers config allows heterogeneous n_heads; with GQA the cache
     holds the unrepeated n_kv_heads rows; under ``tp`` each shard
-    caches its own ``n_kv_heads/tp`` slice)."""
+    caches its own ``n_kv_heads/tp`` slice). A block that states its
+    own ``serve_prefill`` (``HybridBlock``) runs that and hands back its
+    K and V rows at its own widths; ``live`` (T,) bool tells it which
+    rows are the prompt's."""
     import jax.numpy as jnp
     b = x.shape[0]
     caches = []
     for blk in blocks:
+        if hasattr(blk, "serve_prefill"):
+            x, ck, cv = blk.serve_prefill(params[blk.name], x, live=live)
+            caches.append((ck, cv))
+            continue
         bkv = getattr(blk, "n_kv_heads", blk.n_heads) // tp
         hd = dim // blk.n_heads
         ck = jnp.zeros((b, cache_len, bkv, hd), x.dtype)

@@ -91,22 +91,30 @@ def block_ffn(np_mod, block, p, x, prec=None, tp_axis=None):
     return out + p["b2"]
 
 
-def _rope(np_mod, x, base=10000.0):
+def _rope(np_mod, x, base=10000.0, positions=None):
     """Rotary position embedding on (B, T, H, Dh), HALF-SPLIT pairing
     (GPT-NeoX convention: feature j rotates with j+half — NOT the
     interleaved even/odd RoFormer layout; the two are not weight-
     compatible). Relative by construction, so it needs no learned table
     and no length cap; applied to the GLOBAL q/k before attention_core,
     it stays correct under every attention path (single-chip, flash,
-    ring, Ulysses)."""
+    ring, Ulysses). ``positions`` (B, T), traced or not: each row's own
+    positions (a decode step's rows stand at different ones); None is
+    0..T-1 for every row."""
     t, hd = x.shape[1], x.shape[-1]
     half = hd // 2
     inv = (base ** (-numpy.arange(half, dtype="float32") / half))
-    ang = np_mod.asarray(
-        numpy.arange(t, dtype="float32")[:, None] * inv[None, :])
-    cos, sin = np_mod.cos(ang), np_mod.sin(ang)
-    cos = cos[None, :, None, :]
-    sin = sin[None, :, None, :]
+    if positions is None:
+        ang = np_mod.asarray(
+            numpy.arange(t, dtype="float32")[:, None] * inv[None, :])
+        cos, sin = np_mod.cos(ang), np_mod.sin(ang)
+        cos = cos[None, :, None, :]
+        sin = sin[None, :, None, :]
+    else:
+        ang = (np_mod.asarray(positions).astype("float32")[..., None]
+               * np_mod.asarray(inv))
+        cos = np_mod.cos(ang)[:, :, None, :]
+        sin = np_mod.sin(ang)[:, :, None, :]
     x1, x2 = x[..., :half], x[..., half:2 * half]
     rot1 = x1 * cos - x2 * sin
     rot2 = x1 * sin + x2 * cos

@@ -742,6 +742,7 @@ class GenerationAPI(Unit):
                 "veles_quant_weights_mode": st["quant_weights"],
                 "veles_quant_kv_mode": st["quant_kv"],
                 "veles_serving_kv_pool_bytes": st["kv_pool_bytes"],
+                "veles_serving_kv_ring_bytes": st.get("kv_ring_bytes", 0),
                 # prefix sharing & chunked prefill (docs/services.md
                 # "Prefix sharing & streaming"): index occupancy and
                 # the per-tick decode stall chunking bounds
@@ -986,6 +987,15 @@ class GenerationAPI(Unit):
                 reject = (None if engine is None
                           else engine.accepts(req))
                 via_engine = engine is not None and reject is None
+                if reject is not None and not getattr(
+                        engine, "window_fallback", True):
+                    # the stack has a block the window plane cannot
+                    # run: what its engine refuses is refused, with
+                    # the engine's own line
+                    json_reply(self, 400, {
+                        "error": reject,
+                        "request_id": ticket.request_id})
+                    return
                 if req.get("resume_k") and not via_engine \
                         and req["mode"] != "greedy":
                     # a sampled resume re-enters a per-slot PRNG

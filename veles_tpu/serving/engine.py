@@ -123,6 +123,7 @@ from ..nn.sampling import (_block_step, _count_decode_dispatches,
                            split_stack)
 from ..resilience import health
 from ..resilience.faults import FaultInjected, fire as fire_fault
+from ..telemetry import steptaps
 from ..telemetry.counters import inc
 from ..telemetry.spans import span
 from .pages import view_ladder, view_rung
@@ -277,7 +278,25 @@ class ContinuousEngine(Logger):
         self.compiled_live = 0
         # raises VelesError on anything but a generation stack (a bare
         # workflow has no forwards at all — same rejection)
-        self.stack = split_stack(list(getattr(wf, "forwards", ()) or ()))
+        self.stack = split_stack(list(getattr(wf, "forwards", ()) or ()),
+                                 hybrid=True)
+        #: blocks that state their own served programs and cache
+        #: geometry (nn/hybrid.py HybridBlock); a stack that has one is
+        #: served by the greedy/sample decode step alone, and what it
+        #: refuses is never handed to the window plane, which runs
+        #: TransformerBlock's equations (``window_fallback``)
+        self._own_blocks = [b for b in self.stack["blocks"]
+                            if hasattr(b, "serve_step")]
+        self.window_fallback = not self._own_blocks
+        #: the keys of what the step's sparse-expert layers count inside
+        #: the program (telemetry/steptaps.py), in the order of the
+        #: columns that carry them to the host after the slots' tokens;
+        #: empty for a stack without such a layer
+        self._tap_names: List[str] = []
+        if any(getattr(b, "ffn", None) == "experts"
+               for b in self._own_blocks):
+            from ..nn.experts import TOUCHED, tap_keys
+            self._tap_names = tap_keys() + [steptaps.counter_key(TOUCHED)]
         self.max_slots = int(max_slots)
         self.max_context = int(max_context)
         self.decode_block = max(1, int(decode_block))
@@ -355,6 +374,8 @@ class ContinuousEngine(Logger):
             # allocator pressure reclaims cached prefixes LRU-first
             # before any admission is refused or shed
             self.page_pool.evictor = self.prefix_cache.evict
+        if self._own_blocks:
+            self._refuse_for_own_blocks(want_prefix, draft, tp, mesh)
         self.scheduler = SlotScheduler(self.max_slots, self.buckets,
                                        self.max_context,
                                        page_pool=self.page_pool,
@@ -490,6 +511,48 @@ class ContinuousEngine(Logger):
         self.prefix_requests = 0
         self.chunk_dispatches = 0
 
+    def _refuse_for_own_blocks(self, want_prefix, draft, tp, mesh) -> None:
+        """A stack with a ``HybridBlock`` is served by the float pool's
+        monolithic prefill and decode step on one device; every other
+        plane is refused at construction with a line that names the
+        block (a ValueError: the operator's knobs do not fit the model,
+        and GenerationAPI lets that propagate instead of degrading to
+        the window worker). Also checks that every such block keeps K/V
+        rows (``cache_geometry`` raises for one that does not)."""
+        from ..config import root
+        blk = self._own_blocks[0]
+        d = self.stack["stem"].dim
+        for b in self._own_blocks:
+            b.cache_geometry(d, self.page_size)
+        asked = [what for what, on in (
+            ("int8 weights (--quant-weights)", self.quant_weights),
+            ("the int8 KV pool (--quant-kv)", self.quant_kv),
+            ("an AOT serve-artifact", bool(self.artifact)),
+            ("the prefix cache", want_prefix),
+            ("chunked prefill", bool(self.prefill_chunk)),
+            ("a draft model for speculation", draft is not None),
+            ("tensor-parallel serving (--serve-tp)",
+             (int(root.common.serving.get("tp", 1) if tp is None
+                  else tp) > 1) or mesh is not None),
+        ) if on]
+        if asked:
+            raise ValueError(
+                "%s (%s) is served by the float page pool's monolithic "
+                "prefill and its greedy/sample decode step on one device; "
+                "it does not serve %s"
+                % (blk.name, type(blk).__name__, ", ".join(asked)))
+
+    def _geometry(self, blk):
+        """(kv heads, key width, value width, ring positions) of what a
+        slot holds of ``blk``: the block's own statement, or the
+        standing block's ``d // n_heads`` rows in pages (ring 0)."""
+        d = self.stack["stem"].dim
+        if hasattr(blk, "cache_geometry"):
+            g = blk.cache_geometry(d, self.page_size)
+            return g["kv_heads"], g["k_dim"], g["v_dim"], g["ring"]
+        hd = d // blk.n_heads
+        return getattr(blk, "n_kv_heads", blk.n_heads), hd, hd, 0
+
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "ContinuousEngine":
         if self._thread is not None:
@@ -560,6 +623,11 @@ class ContinuousEngine(Logger):
             return "unknown decode mode %r" % mode
         if t_p < 1:
             return "empty prompt"
+        if self._own_blocks and mode not in _STEP_MODES:
+            blk = self._own_blocks[0]
+            return ("%s (%s) is served by the greedy/sample decode step "
+                    "only; mode=%s is refused for this stack"
+                    % (blk.name, type(blk).__name__, mode))
         if int(req.get("resume_k", 0) or 0) and mode not in _STEP_MODES:
             # resume re-enters the per-slot PRNG stream mid-decode —
             # a contract only the plain decode step owns (docs/
@@ -639,6 +707,8 @@ class ContinuousEngine(Logger):
         from ..ops.flash_attention import choose_flash
         d = stack["stem"].dim
         for blk in stack["blocks"]:
+            if hasattr(blk, "serve_prefill"):
+                continue        # its prefill has one kernel at any length
             hd = d // blk.n_heads
             if choose_flash(bucket, hd) != choose_flash(t_p, hd):
                 return True
@@ -783,6 +853,15 @@ class ContinuousEngine(Logger):
             "tp": self.tp,
             "kv_pool_bytes": pool_nbytes(self._caches)
             + pool_nbytes(self._draft_caches),
+            # of those, what the window layers' rings hold: max_slots x
+            # ring positions a layer, whatever the slots' contexts (0
+            # for a stack without a window layer that keeps a ring)
+            "kv_ring_bytes": pool_nbytes(
+                [c for b, c in zip(self.stack["blocks"],
+                                   self._caches or ())
+                 if self._geometry(b)[3]]),
+            "kv_ring_positions": max(
+                [self._geometry(b)[3] for b in self._own_blocks] or [0]),
             # what ONE chip of the slice actually holds: the kv-head
             # axis shards tp ways (pages.per_shard_kv_heads), so the
             # per-chip HBM is the logical pool over tp — the number
@@ -1177,6 +1256,15 @@ class ContinuousEngine(Logger):
             d = stack["stem"].dim
             out = []
             for blk in stack["blocks"]:
+                if hasattr(blk, "cache_geometry"):
+                    # the block's own heads and widths; a window layer
+                    # keeps a ring a slot and no page
+                    kv, kd, vd, ring = self._geometry(blk)
+                    lead = ((self.max_slots, ring) if ring
+                            else (rows, self.page_size))
+                    out.append((jnp.zeros(lead + (kv, kd), dtype),
+                                jnp.zeros(lead + (kv, vd), dtype)))
+                    continue
                 bkv = getattr(blk, "n_kv_heads", blk.n_heads)
                 hd = d // blk.n_heads
                 out.append(block_page_pool(rows, self.page_size, bkv,
@@ -1801,6 +1889,13 @@ class ContinuousEngine(Logger):
             self._push_held(overlapped=True, phase=True)
             with span("serving.tick.device"):
                 toks = numpy.asarray(toks)      # (decode_block, S)
+        if self._tap_names:
+            # the expert layers' counts came with the tokens: no
+            # further dispatch, no further sync
+            counts = toks[:, self.max_slots:].sum(axis=0)
+            toks = toks[:, :self.max_slots]
+            steptaps.publish({k: float(v)
+                              for k, v in zip(self._tap_names, counts)})
         self._count_decode_dispatch(pages)
         with span("serving.tick.emit"):
             finished: List = []
@@ -2225,6 +2320,9 @@ class ContinuousEngine(Logger):
         d = stem.dim
         pools = []
         for blk in blocks:
+            if hasattr(blk, "cache_geometry"):
+                pools.append(list(self._geometry(blk)))
+                continue
             bkv = getattr(blk, "n_kv_heads", blk.n_heads)
             pools.append([bkv, d // blk.n_heads])
         return {
@@ -2363,6 +2461,25 @@ class ContinuousEngine(Logger):
                 scales = jnp.pad(scales, ((0, pad),))
             return pool, scales.reshape(n_pages, self.page_size)
 
+    def _ring_prompt(self, ring, rows, t_p, slot):
+        """Write a prompt's last rows into ``slot``'s ring of a window
+        layer: ``ring`` (slots, R, kv, hd), ``rows`` (bucket, kv, hd).
+        Index j takes the newest position under ``t_p`` that is
+        congruent to j modulo R (the step reads and writes a position at
+        its value modulo R); where the prompt is shorter than the ring
+        the indices past it take row 0 and stay unseen, since the step's
+        mask knows which position an index can hold."""
+        import jax
+        import jax.numpy as jnp
+        length = ring.shape[1]
+        with jax.named_scope("ring_writeback"):
+            last = t_p - 1
+            held = last - (last - jnp.arange(length)) % length
+            kept = jnp.take(rows, jnp.clip(held, 0, rows.shape[0] - 1),
+                            axis=0)
+            return jax.lax.dynamic_update_slice(
+                ring, kept[None].astype(ring.dtype), (slot, 0, 0, 0))
+
     # -- program builders ------------------------------------------------------
     def _build_prefill(self, bucket: int):
         """One program per bucket: pad-to-``bucket`` full-window pass
@@ -2385,6 +2502,8 @@ class ContinuousEngine(Logger):
         d = stem.dim
         quant_w, quant_kv = self.quant_weights, self.quant_kv
         tp, tp_axis = self.tp, self._tp_axis
+        own = bool(self._own_blocks)
+        rings = {b.name: self._geometry(b)[3] for b in blocks}
 
         def prefill(params, ids, t_p, slot, temp, seed_key, table_row,
                     keys, caches):
@@ -2398,11 +2517,16 @@ class ContinuousEngine(Logger):
                     params, dtype=params[stem.name]["table"].dtype)
             x = _embed_prompt(stem, pos_emb, params, ids, tp=tp,
                               tp_axis=tp_axis)
-            x, blk_caches = _prefill_blocks(blocks, params, x,
-                                            bucket, d, tp=tp,
-                                            tp_axis=tp_axis)
+            x, blk_caches = _prefill_blocks(
+                blocks, params, x, bucket, d, tp=tp, tp_axis=tp_axis,
+                live=(jnp.arange(bucket) < t_p) if own else None)
             new_caches = []
-            for (ck, cv), pool in zip(blk_caches, caches):
+            for blk, (ck, cv), pool in zip(blocks, blk_caches, caches):
+                if rings[blk.name]:
+                    new_caches.append(tuple(
+                        self._ring_prompt(ring, rows[0], t_p, slot)
+                        for ring, rows in zip(pool, (ck, cv))))
+                    continue
                 # pad rows land in the pages too; they are causal-
                 # masked for every real position and the decode steps
                 # rewrite position p before the read mask reaches it
@@ -2518,6 +2642,8 @@ class ContinuousEngine(Logger):
         prec = matmul_precision()
         quant_w, quant_kv = self.quant_weights, self.quant_kv
         tp, tp_axis = self.tp, self._tp_axis
+        rings = {b.name: self._geometry(b)[3] for b in blocks}
+        tap_names = self._tap_names
 
         def embed_rows(params, tok, pos):
             from ..nn.sampling import _embed_ids
@@ -2564,7 +2690,12 @@ class ContinuousEngine(Logger):
                 # rows are scattered into the pool at chunk end — the
                 # pages a step did not write are never rewritten.
                 views = []
-                for kp, vp in caches:
+                for blk, (kp, vp) in zip(blocks, caches):
+                    if rings[blk.name]:
+                        # a window layer's ring IS every slot's view:
+                        # no table, no gather; the step writes into it
+                        views.append((kp, vp))
+                        continue
                     views.append((
                         jax.vmap(lambda t, kp=kp: self._view(kp, t))(
                             tables),
@@ -2575,30 +2706,49 @@ class ContinuousEngine(Logger):
                     tok, pos, keys, vws = carry
                     x = embed_rows(params, tok, pos)
                     new_vws, rows = [], []
-                    for blk, (ck, cv) in zip(blocks, vws):
-                        p = params[blk.name]
+                    # what the blocks' expert layers count of this
+                    # step (nothing, for a stack without one)
+                    with steptaps.collecting() as counted:
+                        for blk, (ck, cv) in zip(blocks, vws):
+                            p = params[blk.name]
+                            if hasattr(blk, "serve_step"):
+                                # the block's own one-position step, every
+                                # row at once (its experts route the batch)
+                                x, ck, cv, kn, vn = blk.serve_step(
+                                    p, x, ck, cv, pos, mask > 0)
+                                new_vws.append((ck, cv))
+                                rows.append((kn, vn))
+                                continue
 
-                        def row(x_row, ck_row, cv_row, pos_row,
-                                blk=blk, p=p):
-                            y, ck2, cv2 = _block_step(
-                                blk, p, x_row[None, None, :],
-                                ck_row[None], cv_row[None], pos_row,
-                                tp=tp, tp_axis=tp_axis)
-                            return (y[0, 0], ck2[0], cv2[0],
-                                    jnp.take(ck2[0], pos_row, axis=0,
-                                             mode="clip"),
-                                    jnp.take(cv2[0], pos_row, axis=0,
-                                             mode="clip"))
+                            def row(x_row, ck_row, cv_row, pos_row,
+                                    blk=blk, p=p):
+                                y, ck2, cv2 = _block_step(
+                                    blk, p, x_row[None, None, :],
+                                    ck_row[None], cv_row[None], pos_row,
+                                    tp=tp, tp_axis=tp_axis)
+                                return (y[0, 0], ck2[0], cv2[0],
+                                        jnp.take(ck2[0], pos_row, axis=0,
+                                                 mode="clip"),
+                                        jnp.take(cv2[0], pos_row, axis=0,
+                                                 mode="clip"))
 
-                        x, ck, cv, kn, vn = jax.vmap(row)(
-                            x, ck, cv, pos)
-                        new_vws.append((ck, cv))
-                        rows.append((kn, vn))       # each (S, kv, hd)
+                            x, ck, cv, kn, vn = jax.vmap(row)(
+                                x, ck, cv, pos)
+                            new_vws.append((ck, cv))
+                            rows.append((kn, vn))       # each (S, kv, hd)
                     nxt, pos2, keys = sample_next(tok, pos, keys, x)
+                    out = nxt
+                    if tap_names:
+                        # the expert layers' counts ride the tokens' own
+                        # array to the host: whole numbers, one column
+                        # each after the slots'
+                        out = jnp.concatenate([nxt, jnp.round(jnp.stack(
+                            [counted[k] for k in tap_names])).astype(
+                                jnp.int32)])
                     return ((nxt, pos2, keys, tuple(new_vws)),
-                            (nxt, pos, tuple(rows)))
+                            (out, pos, tuple(rows)))
 
-                (_, _, keys, _), (toks, wpos, rows) = jax.lax.scan(
+                (_, _, keys, vws), (toks, wpos, rows) = jax.lax.scan(
                     body, (tok, pos, keys, tuple(views)), None,
                     length=self.decode_block)
                 # row write-back, (decode_block, S) targets at once.
@@ -2618,7 +2768,12 @@ class ContinuousEngine(Logger):
                         self._row_targets, in_axes=(None, 0, 0))(
                             tables, wpos, ok)
                     new_caches = []
-                    for (kp, vp), (kn, vn) in zip(caches, rows):
+                    for blk, (kp, vp), (kn, vn), view in zip(
+                            blocks, caches, rows, vws):
+                        if rings[blk.name]:
+                            # the ring the scan carried has the rows
+                            new_caches.append(view)
+                            continue
                         new_caches.append((kp.at[pg, off].set(kn),
                                            vp.at[pg, off].set(vn)))
                 return toks, keys, tuple(new_caches)

@@ -53,11 +53,18 @@ DESCRIPTIONS = {
         "0 once the shape is swept)",
     "veles_moe_assignments_total":
         "Token-to-expert assignments the sparse-expert layers' routers "
-        "made in training (tokens x experts a token, a layer a step), "
-        "counted inside the step and drained with the epoch's metrics",
+        "made (tokens x experts a token, a layer a step): in training "
+        "counted inside the step and drained with the epoch's metrics, "
+        "in a served decode step the live rows' alone, read with the "
+        "step's tokens",
     "veles_moe_assignments_held_total":
         "Of those, assignments to an expert this process holds: the "
         "rows its grouped expert products multiply",
+    "veles_moe_experts_touched_total":
+        "Held experts that got at least one row, summed over the "
+        "sparse-expert layers of a served decode step (live rows only; "
+        "prefill's routing is not counted): the experts whose matrices "
+        "the step had to read",
     "veles_spans_total":
         "Telemetry spans recorded",
     # resilience subsystem (veles_tpu/resilience/): these exist so
@@ -477,8 +484,9 @@ HISTOGRAMS = {
     },
     "veles_moe_peak_load_tokens": {
         "help": "Assignments of the fullest held expert of a "
-                "sparse-expert layer in one train step (one sample a "
-                "layer a step), bucketed inside the step",
+                "sparse-expert layer in one train step or one served "
+                "decode step (one sample a layer a step), bucketed "
+                "inside the step",
         "buckets": (16, 32, 64, 96, 128, 160, 192, 224, 256, 320, 384,
                     512, 768, 1024, 2048, 4096, 16384, 65536),
     },
