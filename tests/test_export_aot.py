@@ -66,9 +66,10 @@ def test_artifact_is_a_v3_package_with_serving_block(served_artifact):
         contents = json.load(fin)
     assert contents["format_version"] == 3
     serving = contents["serving"]
-    # v5: the tensor-parallel mesh geometry ("tp"/"mesh") joined the
-    # signature; unsharded artifacts are unchanged otherwise
-    assert serving["artifact_version"] == 5
+    # v6: the decode step takes the tokens of the step before back as it
+    # gave them ("step_tokens" in the signature)
+    assert serving["artifact_version"] == 6
+    assert serving["signature"]["step_tokens"] == "device"
     assert sorted(serving["programs"]) == ["decode", "prefill_16",
                                            "prefill_8"]
     for fname in serving["programs"].values():
@@ -157,6 +158,41 @@ def test_artifact_keeps_the_whole_view_and_compiles_nothing(
         engine.stop()
 
 
+def test_artifact_step_runs_ahead_and_compiles_nothing(served_artifact):
+    """The exported step has the live step's signature (the tokens of
+    the step before among its inputs): an engine on the artifact
+    dispatches step n+1 before it reads step n, serves what its twin
+    held to the serial
+    order serves and what the scan decoder gives, a row ending on an
+    ``eos_id`` among them, and compiles nothing."""
+    import ahead_drill
+    lm, wf, art = served_artifact
+    reqs = _reqs(lm)
+    ender, short = ahead_drill.ender(
+        lambda req: ahead_drill.solo(wf, req),
+        lambda i: _prompt(lm, 700 + 10 * i, 6), 6, 0.7, seed=5, earliest=2)
+    reqs.append(ender)
+    compiles0 = counters.get("veles_compiles_total")
+    answers = []
+    for engine in (
+            ContinuousEngine(wf, artifact=art, name="aot_ahead", **KNOBS),
+            ahead_drill.hold_serial(ContinuousEngine(
+                wf, artifact=art, name="aot_serial", **KNOBS))):
+        engine._load_artifact()
+        assert engine.artifact_mode
+        events = ahead_drill.record_order(engine)
+        got = ahead_drill.serve_by_ticks(engine, reqs)
+        assert got[-1] == short
+        for req, toks in zip(reqs, got):
+            assert toks == ahead_drill.solo(wf, req)
+        answers.append((got, ahead_drill.ahead_of(events)))
+        assert engine.compiled_live == 0
+        engine.stop()
+    assert answers[0][0] == answers[1][0]
+    assert answers[0][1] > 0 and answers[1][1] == 0
+    assert counters.get("veles_compiles_total") == compiles0
+
+
 # -- fallback paths ------------------------------------------------------------
 
 def _fallback_engine(wf, art, name):
@@ -212,30 +248,34 @@ def test_missing_and_mismatched_artifacts_fall_back(served_artifact,
         load_serve_programs(art, {"buckets": [8, 32]})
 
 
+@pytest.mark.parametrize("version,lacks", [
+    (4, ("tp", "mesh", "step_tokens")), (5, ("step_tokens",))])
 def test_v4_artifact_refused_with_counted_live_fallback(
-        served_artifact, tmp_path):
-    """Format-migration contract (v4 -> v5): a v4 artifact — exported
-    before the mesh geometry ("tp"/"mesh") joined the signature — is
+        served_artifact, tmp_path, version, lacks):
+    """Format-migration contract (v4 -> v5 -> v6): an artifact exported
+    before the mesh geometry ("tp"/"mesh") joined the signature, or
+    before the decode step kept its tokens on the device
+    ("step_tokens": its step has another calling convention), is
     REFUSED, counted in veles_artifact_load_failures_total, and the
     engine serves correct tokens via live jit instead of running
-    programs whose sharding commitments are unknown."""
+    programs whose commitments are unknown."""
     import shutil
     lm, wf, art = served_artifact
     from veles_tpu.nn import sampling
-    old = str(tmp_path / "v4_art")
+    old = str(tmp_path / ("v%d_art" % version))
     shutil.copytree(art, old)
     cpath = os.path.join(old, "contents.json")
     with open(cpath) as fin:
         contents = json.load(fin)
-    contents["serving"]["artifact_version"] = 4
-    for key in ("tp", "mesh"):
+    contents["serving"]["artifact_version"] = version
+    for key in lacks:
         contents["serving"]["signature"].pop(key, None)
     with open(cpath, "w") as fout:
         json.dump(contents, fout)
     with pytest.raises(VelesError, match="different"):
         load_serve_programs(old, ContinuousEngine(
-            wf, name="aot_v4_sig", **KNOBS).stack_signature())
-    engine = _fallback_engine(wf, old, "aot_v4")
+            wf, name="aot_v%d_sig" % version, **KNOBS).stack_signature())
+    engine = _fallback_engine(wf, old, "aot_v%d" % version)
     try:
         req = make_request(_prompt(lm, 93), 5)
         assert engine.serve([req])[0] == sampling.generate(

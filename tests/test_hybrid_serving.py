@@ -250,6 +250,61 @@ def test_the_step_counts_its_experts(engine, served):
     assert rise("peak_count") == steps * expert_layers
 
 
+def test_ahead_serves_what_the_serial_order_serves_with_its_counts(wf, ref):
+    """The hybrid step keeps its tokens on the device and its experts'
+    counts ride them: with step n+1 dispatched before step n is read the
+    engine serves, greedy and sampled, what its twin held to the serial
+    order serves and what the reference puts first; the counts are read
+    one step later with the tokens and are the twin's, but for the one
+    row-step dropped behind the row that ends on its ``eos_id``."""
+    import ahead_drill
+    from veles_tpu.serving.engine import make_request
+    rng = numpy.random.default_rng(3)
+
+    def prompt(n):
+        return rng.integers(0, CFG["vocab_size"], n).tolist()
+    ahead, serial = ahead_drill.twins(
+        wf, "hybrid_ah", max_slots=3, buckets=(16, 32), max_context=80,
+        page_size=16)
+    ender, short = ahead_drill.ender(
+        lambda req: ahead_drill.serve_by_ticks(serial, [req])[0],
+        lambda i: prompt(9), 24, 0.0)
+    reqs = [make_request(prompt(5), 30),
+            ender,
+            make_request(prompt(30), 20, temperature=0.8, seed=4),
+            make_request(prompt(16), 12),
+            make_request(prompt(9), 8, temperature=0.7, seed=5)]
+    expert_layers = sum(1 for b in ahead.stack["blocks"]
+                        if b.ffn == "experts")
+    got, rises = {}, {}
+    for engine in (ahead, serial):
+        events = ahead_drill.record_order(engine)
+        before = series()
+        got[engine] = ahead_drill.serve_by_ticks(engine, reqs)
+        after = series()
+        rises[engine] = {k: after.get(k, 0) - before.get(k, 0)
+                         for k in after}
+        steps = sum(1 for e in events if e[0] == "dispatch")
+        ran_ahead = ahead_drill.ahead_of(events)
+        assert (ran_ahead >= 0.8 * steps) if engine is ahead \
+            else ran_ahead == 0
+        decoded = sum(len(o) - 1 for o in got[engine])
+        dropped = 1 if engine is ahead else 0
+        assert ahead_drill.row_steps(events, 1) == decoded + dropped
+        assert rises[engine]["veles_moe_assignments_total"] == (
+            (decoded + dropped) * CFG["num_experts_per_tok"]
+            * expert_layers)
+        assert rises[engine]["peak_count"] == steps * expert_layers
+        assert engine._flying is None
+        assert engine.page_pool.in_use() == 0
+    assert got[ahead] == got[serial] and got[ahead][1] == short
+    for case in (0, 1):
+        gaps, first = ref.served_gaps(CFG, SEED, reqs[case]["prompt"],
+                                      got[ahead][case], pad=96)
+        assert list(first) == list(got[ahead][case])
+        assert float(gaps.max()) <= TOL
+
+
 def test_the_step_names_its_scopes(engine, served):
     """(``combine`` is a cast and a reshape, which the compiler folds into
     its neighbours: nothing of it is left to name.)"""

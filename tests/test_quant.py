@@ -222,6 +222,43 @@ def test_int8_pool_ladder_serves_what_the_whole_view_serves(
     assert ladder.page_pool.in_use() == whole.page_pool.in_use() == 0
 
 
+@pytest.mark.parametrize("qw", [False, True], ids=["f32w", "int8w"])
+@pytest.mark.parametrize("decode_block", [1, 4])
+def test_int8_pool_ahead_serves_what_the_serial_order_serves(
+        trained, decode_block, qw):
+    """The int8 pool's step keeps its tokens on the device too: with
+    step n+1 dispatched before step n is read it serves, token for
+    token, greedy and sampled, what it serves in the serial order; a
+    row that ends on an ``eos_id`` costs one dropped row-step and
+    poisons nobody (tests/test_serving_engine.py holds the float pool
+    to the same drill)."""
+    import ahead_drill
+    import ladder_drill
+    lm, wf = trained
+    ahead, serial = ahead_drill.twins(
+        wf, "q_ah%d%d" % (decode_block, qw), quant_kv=True,
+        quant_weights=qw, decode_block=decode_block)
+    ender, short = ahead_drill.ender(
+        lambda req: ahead_drill.serve_by_ticks(serial, [req])[0],
+        lambda i: _prompt(lm, 600 + 10 * i, 6), 18, 0.8, seed=9)
+    reqs = ladder_drill.requests(lambda seed, n: _prompt(lm, seed, n))
+    reqs.insert(1, ender)
+    events, events_s = (ahead_drill.record_order(e)
+                        for e in (ahead, serial))
+    got = ahead_drill.serve_by_ticks(ahead, reqs)
+    assert got == ahead_drill.serve_by_ticks(serial, reqs)
+    assert got[1] == short
+    assert [len(t) for i, t in enumerate(got) if i != 1] == [
+        r["n_new"] for i, r in enumerate(reqs) if i != 1]
+    steps = sum(1 for e in events if e[0] == "dispatch")
+    assert ahead_drill.ahead_of(events) >= 0.8 * steps
+    assert ahead_drill.ahead_of(events_s) == 0
+    assert (ahead_drill.row_steps(events, decode_block)
+            - ahead_drill.row_steps(events_s, decode_block)) == decode_block
+    assert ahead.programs_built <= ahead.programs_bound()
+    assert ahead.page_pool.in_use() == serial.page_pool.in_use() == 0
+
+
 def test_int8_pool_halves_hbm(trained):
     lm, wf = trained
     sizes = {}

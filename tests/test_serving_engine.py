@@ -772,13 +772,18 @@ def test_float_step_pool_equals_whole_view_oracle(served, decode_block,
     what the last one stored), with half the batch masked out, slots
     holding shared pages, a row landing inside a shared page and a row
     past the view's end. The sink page (0) is the one page whose
-    content is nobody's."""
+    content is nobody's. From the second tick on the step takes its
+    tokens from what it gave in the call before (the host's row says
+    -1), as :meth:`_decode` runs it; the oracle is handed them by the
+    host."""
+    import jax.numpy as jnp
     _, wf, _ = served
     engine, params, caches, st = _step_fixture(wf, decode_block)
     before = [[numpy.asarray(a) for a in pool] for pool in caches]
     new, old = engine._build_decode(), _whole_view_step(engine)
     got = want = caches
     tok_g, tok_w = st["tok"], st["tok"]
+    last_g = jnp.zeros((decode_block, len(_STEP_SLOTS)), jnp.int32)
     keys_g = keys_w = st["keys"]
     pos = st["pos"].copy()
     rest = (st["temp"], st["mask"], st["tables"], st["shared"])
@@ -787,13 +792,14 @@ def test_float_step_pool_equals_whole_view_oracle(served, decode_block,
                                    want)
         # THE step donates its keys and pool: hand it copies
         toks_g, keys_g, got = new(
-            params, tok_g, pos, *rest, keys_g + 0,
+            params, tok_g, pos, *rest, last_g, keys_g + 0,
             tuple(tuple(a + 0 for a in pool) for pool in got))
         numpy.testing.assert_array_equal(numpy.asarray(toks_g),
                                          numpy.asarray(toks_w))
         numpy.testing.assert_array_equal(numpy.asarray(keys_g),
                                          numpy.asarray(keys_w))
-        tok_g = tok_w = numpy.asarray(toks_w)[-1]
+        tok_w = numpy.asarray(toks_w)[-1]
+        last_g, tok_g = toks_g, numpy.full_like(tok_w, -1)
         pos = pos + decode_block * (st["mask"] > 0)
     frozen = sorted({p for m, _, pages, _ in _STEP_SLOTS if not m
                      for p in pages if p} | {5} | set(range(17, 25)))
@@ -826,7 +832,9 @@ def test_float_step_scatters_rows_not_views(served, decode_block):
     engine, params, caches, st = _step_fixture(wf, decode_block)
     step = engine._build_decode()
     args = (params, st["tok"], st["pos"], st["temp"], st["mask"],
-            st["tables"], st["shared"], st["keys"], caches)
+            st["tables"], st["shared"],
+            numpy.zeros((decode_block, len(_STEP_SLOTS)), numpy.int32),
+            st["keys"], caches)
     kp = caches[0][0]
     limit = decode_block * len(_STEP_SLOTS) * kp.shape[2] * kp.shape[3]
 
@@ -914,7 +922,9 @@ def test_step_tokens_reach_streams_under_the_next_dispatch(pooled, plane):
 
         def blocked(*args):
             at_dispatch.append([t._stream_q.qsize() for t in tickets])
-            out = real(*args)
+            # the step's tokens go back into it as they were returned
+            out = real(*(a.value if isinstance(a, _Blocked) else a
+                         for a in args))
             return (_Blocked(out[0], in_flight),) + tuple(out[1:])
         return blocked
     engine._program = program
@@ -957,9 +967,10 @@ def _mid_decode(engine, ticket, at_least=3):
 @pytest.mark.parametrize("ending", ["idle", "abort", "handoff",
                                     "decode_fault", "stop"])
 def test_nothing_is_held_at_an_ending(served, monkeypatch, ending):
-    """Wherever no dispatch follows or a terminal is set, what was kept
-    is pushed first: the stream ends with all its tokens, or with an
-    error payload whose ``resume`` holds exactly what it streamed."""
+    """Wherever no dispatch follows or a terminal is set, the step in
+    flight is read and what was kept is pushed first: the stream ends
+    with all its tokens, or with an error payload whose ``resume``
+    holds exactly what it streamed, the step in flight's token too."""
     lm, wf, _ = served
     engine = ContinuousEngine(wf, max_slots=2, buckets=(8, 16),
                               max_context=48, name="eng_end_" + ending)
@@ -990,6 +1001,12 @@ def test_nothing_is_held_at_an_ending(served, monkeypatch, ending):
     else:
         engine.stop()
     assert ticket.event.is_set() and engine._held == []
+    assert engine._flying is None
+    if ending != "idle":
+        # one step was in flight beyond the one whose tokens were kept
+        assert slot.tokens[:len(made)] == made
+        assert len(slot.tokens) == len(made) + 1
+        made = list(slot.tokens)
     after, ended = _drain(ticket)
     assert ended and before + after == made
     if ending != "idle":
@@ -999,10 +1016,10 @@ def test_nothing_is_held_at_an_ending(served, monkeypatch, ending):
 
 
 def test_preempted_row_still_gets_its_last_steps_tokens(served):
-    """QoS preempts a batch row between its step and the next
-    dispatch: the step's tokens reach the stream before the ticket
-    goes back to the queue, and the whole stream is the uninterrupted
-    answer, each token once."""
+    """QoS preempts a batch row with a step in flight: that step is
+    read, and its tokens and the kept ones reach the stream before the
+    ticket goes back to the queue; the whole stream is the
+    uninterrupted answer, each token once."""
     from veles_tpu.config import root
     from veles_tpu.nn import sampling
     lm, wf, _ = served
@@ -1025,7 +1042,9 @@ def test_preempted_row_still_gets_its_last_steps_tokens(served):
         engine._tick()
         assert engine.preemptions == 1 and not t_b.event.is_set()
         got += _drain(t_b)[0]
-        assert got == made == t_b.progress
+        assert len(slot.tokens) == len(made) + 1    # the step in flight
+        assert slot.tokens[:len(made)] == made
+        assert got == slot.tokens == t_b.progress
         for _ in range(200):
             if t_b.event.is_set() and t_i.event.is_set():
                 break
@@ -1269,3 +1288,406 @@ def test_view_share_on_stats_and_metrics(served, api_served):
     found = re.search(r"^veles_serving_view_positions_total (\d+)", text,
                       re.M)
     assert found and int(found.group(1)) > 0
+
+
+# -- step n+1 is dispatched before step n's tokens are read ----------------------
+
+def _assert_solo(wf, reqs, got):
+    import ahead_drill
+    for req, toks in zip(reqs, got):
+        assert toks == ahead_drill.solo(wf, req), req
+
+
+@pytest.mark.parametrize("decode_block", [1, 4])
+def test_ahead_serves_what_the_serial_order_serves(served, decode_block):
+    """Greedy and sampled rows, one that ends by length while the others
+    go on, slots used again: the engine gives, token for token, what its
+    twin held to the serial order gives and what the scan decoder
+    gives; nearly every step of it was dispatched with the one before
+    unread, none of the twin's was, and the counters say so."""
+    import ahead_drill
+    import ladder_drill
+    lm, wf, _ = served
+    ahead, serial = ahead_drill.twins(wf, "eng_ah%d" % decode_block,
+                                      decode_block=decode_block)
+    events, events_s = (ahead_drill.record_order(e)
+                        for e in (ahead, serial))
+    reqs = ladder_drill.requests(lambda seed, n: _prompt(lm, seed, n))
+    before = counters.snapshot()
+    got = ahead_drill.serve_by_ticks(ahead, reqs)
+    delta = counters.delta(before)
+    assert got == ahead_drill.serve_by_ticks(serial, reqs)
+    _assert_solo(wf, reqs, got)
+    steps = sum(1 for e in events if e[0] == "dispatch")
+    ran_ahead = ahead_drill.ahead_of(events)
+    assert ahead_drill.ahead_of(events_s) == 0
+    # only a round's first step (nothing before it) and the step after
+    # a tick that had to drain have nothing unread before them
+    assert 0.8 * steps <= ran_ahead < steps
+    assert ahead.steps_ahead == ran_ahead and serial.steps_ahead == 0
+    assert delta["veles_serving_steps_ahead_total"] == ran_ahead
+    assert delta["veles_serving_decode_dispatches_total"] == steps
+    assert ahead.stats()["steps_ahead_share"] == round(ran_ahead / steps, 4)
+    assert serial.stats()["steps_ahead_share"] == 0.0
+    # no row ends on an eos_id here: the same row-steps in both orders
+    assert ahead_drill.row_steps(events, decode_block) == \
+        ahead_drill.row_steps(events_s, decode_block)
+    assert ahead._flying is None and ahead._held == []
+    assert ahead.programs_built <= ahead.programs_bound()
+    assert ahead.page_pool.in_use() == serial.page_pool.in_use() == 0
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.8], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("decode_block", [1, 4])
+def test_ahead_row_that_ends_on_eos_and_its_slot_used_again(
+        served, decode_block, temp):
+    """A row ends on its ``eos_id`` with its next step already in
+    flight: that row-step is dropped (the one row-step more than the
+    serial order runs), and the request admitted next, into the very
+    slot and pages, gives its own tokens, as does the co-tenant that
+    went on through it all."""
+    import ahead_drill
+    from veles_tpu.nn import sampling
+    lm, wf, _ = served
+    ender, short = ahead_drill.ender(
+        lambda req: ahead_drill.solo(wf, req),
+        lambda i: _prompt(lm, 440 + 10 * i, 6), 20, temp, seed=11)
+    assert 3 < len(short) < 20
+    reqs = [ender,
+            make_request(_prompt(lm, 441, 5), 30, temperature=0.7, seed=12),
+            make_request(_prompt(lm, 442, 7), 10, temperature=temp,
+                         seed=13)]
+    dropped = {}
+    for engine in ahead_drill.twins(
+            wf, "eng_eos%d%d" % (decode_block, temp > 0), max_slots=2,
+            pages=16, decode_block=decode_block):
+        events = ahead_drill.record_order(engine)
+        tickets = ahead_drill.submit_all(engine, reqs)
+        engine._tick()
+        first = {s.ticket: (s.idx, set(s.pages))
+                 for s in engine.scheduler.active()}
+        assert set(first) == set(tickets[:2])       # the third waits
+        ahead_drill.tick_until(
+            engine, lambda: tickets[0].event.is_set() and len(
+                engine.scheduler.active()) == 2)
+        (later,) = [s for s in engine.scheduler.active()
+                    if s.ticket is tickets[2]]
+        idx, pages = first[tickets[0]]
+        assert later.idx == idx and set(later.pages) <= pages
+        ahead_drill.tick_until(
+            engine, lambda: all(t.event.is_set() for t in tickets))
+        got = [t.result["tokens"] for t in tickets]
+        assert got[0] == short
+        _assert_solo(wf, reqs, got)
+        ahead_drill.ahead_of(events)
+        dropped[engine.name] = (
+            ahead_drill.row_steps(events, decode_block)
+            - sum(-(-(len(toks) - 1) // decode_block) * decode_block
+                  for toks in got))
+        assert engine._flying is None
+        assert engine.page_pool.in_use() == 0
+    name = "eng_eos%d%d" % (decode_block, temp > 0)
+    assert dropped[name + "_serial"] == 0
+    assert dropped[name + "_ahead"] == decode_block
+
+
+@pytest.mark.parametrize("ending", ["handoff", "abort", "replica_death",
+                                    "decode_fault", "shed", "stop"])
+def test_ahead_an_ending_reads_the_step_in_flight(served, monkeypatch,
+                                                  ending):
+    """With a step in flight, a hand-off, an abort, a shed and ``stop``
+    read it first: the error payload's ``resume`` holds every token the
+    row was ever dispatched for, they are the scan decoder's, and the
+    twin held to the serial order answers the same."""
+    import ahead_drill
+    lm, wf, _ = served
+    reqs = [make_request(_prompt(lm, 450, 6), 24, temperature=0.8, seed=21),
+            make_request(_prompt(lm, 451, 5), 24, seed=22)]
+    answers = []
+    for engine in ahead_drill.twins(wf, "eng_end2_" + ending, max_slots=2):
+        tickets = ahead_drill.submit_all(engine, reqs, stream=True)
+        ahead_drill.tick_until(
+            engine, lambda: all(len(s.tokens) >= 6
+                                for s in engine.scheduler.active())
+            and len(engine.scheduler.active()) == 2)
+        slots = {s.ticket: s for s in engine.scheduler.active()}
+        recorded = [len(slots[t].tokens) for t in tickets]
+        in_flight = engine._flying is not None
+        if ending == "handoff":
+            done = threading.Event()
+            engine._handoff = ("draining", done, {"count": 0})
+            engine._tick()
+            assert done.is_set()
+        elif ending == "abort":
+            engine._abort_active("internal serving error", code=500)
+        elif ending == "replica_death":
+            monkeypatch.setenv("VELES_FAULTS",
+                               "serve.replica_death:raise:times=1")
+            engine._tick()
+        elif ending == "decode_fault":
+            monkeypatch.setenv("VELES_FAULTS",
+                               "serve.decode_step:raise:times=1")
+            engine._tick()
+        elif ending == "shed":
+            # the ledger has drifted: the rows hold no page beyond their
+            # position, and the allocator refuses the next
+            for s in slots.values():
+                keep = -(-int(engine._pos[s.idx]) // engine.page_size)
+                engine.page_pool.free(s.pages[keep:])
+                del s.pages[keep:]
+            monkeypatch.setenv("VELES_FAULTS", "serve.page_alloc:raise")
+            ahead_drill.tick_until(
+                engine, lambda: all(t.event.is_set() for t in tickets))
+        else:
+            engine.stop()
+        monkeypatch.setenv("VELES_FAULTS", "")
+        assert engine._flying is None and engine._held == []
+        for t, req, had in zip(tickets, reqs, recorded):
+            assert t.event.is_set() and t.code in (500, 503)
+            resume = t.error_payload()["resume"]["tokens"]
+            assert resume == ahead_drill.solo(wf, req)[:len(resume)]
+            streamed, ended = _drain(t)
+            assert ended and streamed == resume
+            if ending != "shed":
+                # the step in flight, and no more, beyond what was
+                # recorded when the ending came
+                assert len(resume) == had + in_flight
+        answers.append([t.error_payload()["resume"]["tokens"]
+                        for t in tickets])
+        assert engine.page_pool.in_use() == 0
+        engine.stop()
+    if ending != "shed":
+        assert in_flight is False       # the twin's, the last one built
+        # both were ticked until six tokens were recorded: the engine's
+        # answer is the twin's and the step it had in flight
+        for mine, twin in zip(*answers):
+            assert mine[:-1] == twin
+
+
+@pytest.mark.parametrize("mode,temp", [("greedy", 0.0), ("sample", 0.9)])
+def test_ahead_a_preemption_and_resume(served, mode, temp):
+    """QoS preempts a batch row whose next step is in flight: it is read
+    first, the row comes back through a longer prefill and goes on; both
+    requests' tokens are the scan decoder's, in either order of the
+    tick."""
+    import ahead_drill
+    from veles_tpu.config import root
+    lm, wf, _ = served
+    root.common.serving.qos = True
+    try:
+        req = make_request(_prompt(lm, 460, 6), 30, temperature=temp,
+                           seed=7, mode=mode)
+        req["priority"] = "batch"
+        urgent = make_request(_prompt(lm, 461, 5), 3)
+        urgent["priority"] = "interactive"
+        for engine in ahead_drill.twins(wf, "eng_ahq_" + mode, max_slots=1,
+                                        buckets=(8, 48)):
+            events = ahead_drill.record_order(engine)
+            (t_b,) = ahead_drill.submit_all(engine, [req])
+            ahead_drill.tick_until(
+                engine, lambda: any(len(s.tokens) >= 12
+                                    for s in engine.scheduler.active()))
+            flying = engine._flying is not None
+            (t_i,) = ahead_drill.submit_all(engine, [urgent])
+            engine._tick()
+            assert engine.preemptions == 1
+            # the preempted progress holds the step that was in flight
+            assert len(t_b.progress) == 12 + flying
+            ahead_drill.tick_until(
+                engine, lambda: t_b.event.is_set() and t_i.event.is_set())
+            assert t_b.error is None and t_i.error is None
+            _assert_solo(wf, [req, urgent], [t_b.result["tokens"],
+                                             t_i.result["tokens"]])
+            ahead_drill.ahead_of(events)
+            assert engine.page_pool.in_use() == 0
+        assert not flying                       # the twin's
+    finally:
+        root.common.serving.qos = False
+
+
+def test_ahead_a_change_of_weights_waits_for_the_step_in_flight(served):
+    """A row ends on its ``eos_id`` and leaves the pool idle with its
+    dropped step in flight; the weights change; the next request is
+    served on the new weights, by either order of the tick, and the step
+    in flight was read before they were taken."""
+    import ahead_drill
+    from veles_tpu.nn import sampling
+    lm, wf, _ = served
+    ender, short = ahead_drill.ender(
+        lambda req: ahead_drill.solo(wf, req),
+        lambda i: _prompt(lm, 470 + 10 * i, 6), 16, seed=31)
+    after = make_request(_prompt(lm, 471, 7), 8, temperature=0.8, seed=32)
+    head = wf.forwards[-1]
+    bias = head.param_arrays()["bias"]
+    kept = numpy.array(bias.mem)
+    want = {}
+    try:
+        for engine in ahead_drill.twins(wf, "eng_swap", max_slots=2):
+            events = ahead_drill.record_order(engine)
+            assert ahead_drill.serve_by_ticks(engine, [ender]) == [short]
+            flying = engine._flying is not None
+            bias.map_write()
+            bias.mem[...] = kept + numpy.linspace(-2, 2, kept.size).astype(
+                kept.dtype).reshape(kept.shape)
+            wf._sampler_cache = {}
+            want[engine.name] = ahead_drill.solo(wf, after)
+            n_events = len(events)
+            got = ahead_drill.serve_by_ticks(engine, [after])
+            assert got == [want[engine.name]]
+            if flying:
+                # read before the first dispatch on the new weights
+                assert events[n_events][0] == "read"
+            ahead_drill.ahead_of(events)
+            bias.map_write()
+            bias.mem[...] = kept
+            wf._sampler_cache = {}
+        assert want["eng_swap_ahead"] == want["eng_swap_serial"]
+        assert want["eng_swap_ahead"] != sampling.generate(
+            wf, after["prompt"], 8, temperature=0.8, seed=32)
+    finally:
+        bias.map_write()
+        bias.mem[...] = kept
+        wf._sampler_cache = {}
+
+
+def test_ahead_with_prefix_shared_leading_pages(served):
+    """Rows that adopted the same leading pages, one of them ending on
+    an ``eos_id`` with its next step in flight: the dropped row-step
+    writes no shared page (the cached blocks still serve the next
+    request its exact tokens) and all tokens are the scan decoder's."""
+    import ahead_drill
+    from veles_tpu.nn import sampling
+    lm, wf, _ = served
+    stem = _prompt(lm, 480, 12)
+    first = [make_request(stem + _prompt(lm, 481, 3), 4, seed=1)]
+    ender, short = ahead_drill.ender(
+        lambda req: ahead_drill.solo(wf, req),
+        lambda i: stem + _prompt(lm, 482 + 10 * i, 2), 14, 0.8, seed=3)
+    reqs = [make_request(stem + _prompt(lm, 483, 4), 30, seed=2), ender]
+    last = [make_request(stem + _prompt(lm, 484, 3), 6, temperature=0.6,
+                         seed=4)]
+    results = []
+    for engine in ahead_drill.twins(wf, "eng_ahpfx", prefix_cache=True):
+        events = ahead_drill.record_order(engine)
+        ahead_drill.serve_by_ticks(engine, first)     # fills the index
+        cached = numpy.array(sorted(engine.prefix_cache.cached_pages()))
+        held = [[numpy.array(a[cached]) for a in pool]
+                for pool in engine._caches]
+        got = ahead_drill.serve_by_ticks(engine, reqs)
+        assert engine.prefix_requests >= 2 and got[1] == short
+        got += ahead_drill.serve_by_ticks(engine, last)
+        _assert_solo(wf, reqs + last, got)
+        for pool, before in zip(engine._caches, held):
+            for a, b in zip(pool, before):
+                numpy.testing.assert_array_equal(numpy.asarray(a)[cached], b)
+        results.append((got, ahead_drill.ahead_of(events)))
+        engine.stop()
+    assert results[0][0] == results[1][0]
+    assert results[0][1] > 0 and results[1][1] == 0
+
+
+@pytest.mark.parametrize("pool", ["plain", "speculative_beside"])
+def test_step_dispatch_precedes_the_read_of_the_step_before(pooled, pool):
+    """On a started engine with streaming clients: a plain tick
+    dispatches step n+1 and only then reads step n's tokens; a pool that
+    also holds a speculative row reads every plain step before anything
+    else is dispatched. Either way each stream is first token, each
+    step's tokens in order, terminal; and ``steps_ahead_share`` is what
+    the recorded order implies."""
+    import ahead_drill
+    from veles_tpu.nn import sampling
+    lm, wf, draft, pool_engine = pooled
+    spec = pool == "speculative_beside"
+    engine = ContinuousEngine(
+        wf, max_slots=3, buckets=(8, 16), max_context=48, page_size=8,
+        spec_gamma=3, draft=draft if spec else None,
+        name="eng_order_" + pool)
+    reqs = [make_request(_prompt(lm, 490 + i, 6 + i), 14,
+                         temperature=0.8 * (i % 2), seed=40 + i)
+            for i in range(2)]
+    tickets = [Ticket(stream=True) for _ in reqs]
+    if spec:
+        reqs.append(make_request(_prompt(lm, 495, 6), 14,
+                                 mode="speculative", gamma=3))
+        tickets.append(Ticket(stream=True, mode="speculative"))
+    events = ahead_drill.record_order(engine)
+    for req, ticket in zip(reqs, tickets):
+        assert engine.submit(req, ticket)
+    engine.start()
+    try:
+        for ticket in tickets:
+            assert ticket.event.wait(120) and ticket.error is None
+    finally:
+        engine.stop()
+    for req, ticket in zip(reqs[:2], tickets):
+        got, ended = _drain(ticket)
+        assert ended and got == ticket.result["tokens"]
+        assert got == sampling.generate(
+            wf, req["prompt"], 14, temperature=req["temperature"],
+            seed=req["seed"])
+    if spec:
+        assert tickets[2].result["tokens"] == pool_engine.serve(
+            [dict(reqs[2])])[0]
+    steps = sum(1 for e in events if e[0] == "dispatch")
+    ran_ahead = ahead_drill.ahead_of(events)
+    assert steps >= 13
+    if spec:
+        # while the speculative row lives, each plain step is read
+        # before the round is dispatched: none ahead but after it ended
+        first = [i for i, e in enumerate(events) if e[0] == "dispatch"]
+        assert all(events[i + 1] == ("read", events[i][1])
+                   for i in first[:4])
+        assert ran_ahead < steps - 4
+    else:
+        # dispatch 2, read 1, dispatch 3, read 2, ...
+        assert [e[:2] for e in events[:5]] == [
+            ("dispatch", 1), ("dispatch", 2), ("read", 1),
+            ("dispatch", 3), ("read", 2)]
+        assert ran_ahead == steps - 1
+    assert engine.steps_ahead == ran_ahead
+    assert engine.decode_dispatches >= steps    # the rounds among them
+    assert engine.stats()["steps_ahead_share"] == round(
+        ran_ahead / engine.decode_dispatches, 4)
+
+
+def test_steps_ahead_share_on_stats_and_metrics(served, api_served):
+    """Three streamed requests over HTTP: the counter on ``/metrics``
+    and the share on ``/generate/stats`` rise by what the ticks
+    imply (every plain step but a round's first ran ahead)."""
+    lm, wf, api, url = api_served
+    engine = api._engine
+    before = counters.snapshot()
+    dispatches0, ahead0 = engine.decode_dispatches, engine.steps_ahead
+    answers = {}
+
+    def ask(i):
+        answers[i] = _sse(url, {"prompt": _prompt(lm, 500 + i, 6 + i),
+                                "n_new": 20})
+    threads = [threading.Thread(target=ask, args=(i,), daemon=True)
+               for i in range(3)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+        assert not t.is_alive()
+    for events in answers.values():
+        assert events[-1]["done"] and len(events[-1]["tokens"]) == 20
+        assert [t for e in events[:-1] for t in e["tokens"]] \
+            == events[-1]["tokens"]
+    delta = counters.delta(before)
+    steps = delta["veles_serving_decode_dispatches_total"]
+    ran_ahead = delta["veles_serving_steps_ahead_total"]
+    assert steps == engine.decode_dispatches - dispatches0 >= 19
+    assert ran_ahead == engine.steps_ahead - ahead0
+    # a step is not ahead only when nothing was in flight before it: a
+    # burst's first, and the one after a tick whose rows all ended
+    assert steps - 4 <= ran_ahead < steps
+    with urllib.request.urlopen(url + "/stats", timeout=30) as r:
+        share = json.loads(r.read())["continuous"]["steps_ahead_share"]
+    assert share == round(engine.steps_ahead / engine.decode_dispatches, 4)
+    assert share > 0.5
+    with urllib.request.urlopen(
+            "http://127.0.0.1:%d/metrics" % api.port, timeout=30) as r:
+        text = r.read().decode()
+    found = re.search(r"^veles_serving_steps_ahead_total (\d+)", text, re.M)
+    assert found and int(found.group(1)) >= ran_ahead

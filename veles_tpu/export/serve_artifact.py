@@ -56,7 +56,12 @@ from ..error import VelesError
 #: the same device count). Every v4 artifact lacks the tp keys, so it
 #: refuses on the signature check and falls back counted to live jit
 #: — never an outage
-ARTIFACT_VERSION = 5
+#: v6: the decode step keeps its tokens on the device — it takes the
+#: tokens it gave in the call before back as they are (and a host row
+#: that says -1 wherever their last row holds; signature key
+#: "step_tokens"); every v5 artifact lacks the key, refuses on the
+#: signature check and falls back counted to live jit
+ARTIFACT_VERSION = 6
 
 
 def _specs_of(tree):
@@ -144,7 +149,8 @@ def export_serve_artifact(workflow, path: str,
     exported = jexport.export(engine._build_decode())(
         params_spec, svec, svec,
         jax.ShapeDtypeStruct((slots,), jnp.float32),
-        svec, tables_spec, svec, keys_spec, caches_spec)
+        svec, tables_spec, svec, _specs_of(engine._last), keys_spec,
+        caches_spec)
     with open(os.path.join(path, "serve_decode.bin"), "wb") as fout:
         fout.write(exported.serialize())
     programs["decode"] = "serve_decode.bin"

@@ -45,7 +45,20 @@ Duality and Portable O(1) Autoregressive Caching for Inference"):
   request's ``seed``, so a request's tokens are id-exact vs its solo
   decode whatever strangers share the batch — greedy, sampled,
   speculative and beam rows co-tenant in one pool without changing
-  each other's answers.
+  each other's answers;
+- the plain decode step's tokens stay on the device as the next
+  step's input, so the tick DISPATCHES STEP n+1 BEFORE IT READS STEP
+  n's TOKENS (:meth:`ContinuousEngine._decode`): the host reckons
+  positions, pages, the view's rung and the mask for a step from what
+  it can know beforehand, and reads, records and emits the step
+  before while the chip runs this one; at most one step is in flight
+  beyond the one being read. A row that ends on its ``eos_id`` costs
+  one dropped row-step; a hand-off, an abort, a preemption, a shed, a
+  change of weights, a speculative round or a beam step on the same
+  pool, ``stop()`` and the idle wait read the step in flight first
+  (:meth:`ContinuousEngine._drain`), decided from the engine's own
+  state each tick, so a pool that must drain every tick runs in the
+  serial order it always had. Same tokens either way.
 
 The per-block cache math is ``nn/sampling.py``'s ``_block_prefill`` /
 ``_block_step`` (and ``nn/speculative.py``'s ``_block_span`` for the
@@ -126,7 +139,7 @@ from ..resilience.faults import FaultInjected, fire as fire_fault
 from ..telemetry import steptaps
 from ..telemetry.counters import inc
 from ..telemetry.spans import span
-from .pages import view_ladder, view_rung
+from .pages import pages_for, view_ladder, view_rung
 
 #: floor for the temperature divisor inside the one shared decode
 #: program (greedy rows carry temperature 0; their categorical lane is
@@ -256,7 +269,7 @@ class ContinuousEngine(Logger):
                  name: str = "serving") -> None:
         super().__init__()
         from ..config import root
-        from .pages import PagePool, PrefixCache, pages_for
+        from .pages import PagePool, PrefixCache
         from .scheduler import SlotScheduler
         self.wf = wf
         self.name = name
@@ -465,7 +478,22 @@ class ContinuousEngine(Logger):
         self._keys = None
         self._page_table = numpy.zeros(
             (self.max_slots, self.pages_per_slot), numpy.int32)
+        #: the last token the HOST wrote a slot (a prefill's first, a
+        #: speculative round's), and which of them it has written since
+        #: the last step dispatch (:meth:`_set_tok`): the plain step
+        #: takes those from the host and every other slot's from
+        #: ``_last``, the tokens of the step before as they lie on the
+        #: device, so a step never waits for the host to have read the
+        #: one before it
         self._tok = numpy.zeros(self.max_slots, numpy.int32)
+        self._fresh = numpy.ones(self.max_slots, bool)
+        self._last = None
+        #: the plain step dispatched and not yet read, ``(its tokens
+        #: on the device, the rows it advances)``, or None: at most one
+        #: (:meth:`_decode` leaves it, :meth:`_drain` reads it)
+        self._flying: Optional[Tuple] = None
+        #: a slot's next cache position; a masked-in row's advances at
+        #: the step's dispatch, as the device's does
         self._pos = numpy.zeros(self.max_slots, numpy.int32)
         self._temp = numpy.zeros(self.max_slots, numpy.float32)
         #: per-slot count of leading READ-ONLY page-table entries
@@ -494,9 +522,11 @@ class ContinuousEngine(Logger):
         self.token_pushes_overlapped = 0
         #: decode dispatches (step, speculative round, beam step) and
         #: the positions a slot that they gathered, summed (the stats
-        #: surface's ``view_share``)
+        #: surface's ``view_share``); those of them issued while the
+        #: step before was still unread (``steps_ahead_share``)
         self.decode_dispatches = 0
         self.view_positions = 0
+        self.steps_ahead = 0
         self.admitted = 0
         self.retired = 0
         self.peak_slots = 0
@@ -838,6 +868,12 @@ class ContinuousEngine(Logger):
                 / (self.decode_dispatches * self.pages_per_slot
                    * self.page_size), 4)
             if self.decode_dispatches else 1.0,
+            # the share of decode dispatches issued while the step
+            # before was still unread (the chip did not wait for the
+            # host between the two); 0 for a pool that drains every
+            # tick (speculative or beam rows beside the plain ones)
+            "steps_ahead_share": round(
+                self.steps_ahead / max(1, self.decode_dispatches), 4),
             # quantization/AOT plane (veles_tpu/quant/): what the
             # /metrics mode gauges render on both surfaces
             "artifact_mode": int(self.artifact_mode),
@@ -928,7 +964,7 @@ class ContinuousEngine(Logger):
                            and self.scheduler.busy_count() == 0
                            and self._handoff is None
                            and not self._closing):
-                        self._push_held()   # no dispatch follows a wait
+                        self._flush()       # no dispatch follows a wait
                         with span("serving.loop.wait"):
                             self.scheduler.cv.wait(timeout=5.0)
                         if not self._closing:
@@ -942,10 +978,12 @@ class ContinuousEngine(Logger):
                 except Exception:     # noqa: BLE001 — serve, don't die
                     fail_streak += 1
                     self.exception("%s: serving tick failed", self.name)
+                    # donated buffers may be gone — rebuild lazily; a
+                    # step in flight goes with them unread, and its rows
+                    # answer with what they had recorded
+                    self._reset_pool()
                     self._abort_active("internal serving error",
                                        code=500, count_shed=False)
-                    # donated buffers may be gone — rebuild lazily
-                    self._reset_pool()
                     # a tick that dies before take_admissions never
                     # reaches the deadline check there: sweep the queue
                     # so waiting callers still get their 503 instead of
@@ -960,6 +998,7 @@ class ContinuousEngine(Logger):
 
     def _reset_pool(self) -> None:
         self._caches = self._draft_caches = self._keys = None
+        self._last = self._flying = None
         self._params = self._draft_params = None
 
     def _active(self, modes: Tuple[str, ...]) -> List:
@@ -1013,9 +1052,16 @@ class ContinuousEngine(Logger):
         (``serving.tick.{admit,prefill,prepare,dispatch,device,emit}``,
         each also a histogram: telemetry/spans.py SPAN_HISTOGRAMS), so
         that a profiler capture and ``/metrics`` both say where the
-        host's time between two dispatches goes. ``emit`` is entered
-        twice a tick: after the device wait (record, finish) and under
-        the next dispatch (:meth:`_push_held`)."""
+        host's time goes. A plain tick's order is admit, prefill,
+        prepare and dispatch step n+1, hand step n-1's tokens to the
+        streams (``emit``, :meth:`_push_held`), wait for step n's
+        tokens (``device``), record them and finish rows (``emit``
+        again): ``prepare``, ``dispatch`` and both halves of ``emit``
+        run while the chip runs a step, and ``device`` is the wait for
+        the step BEFORE the one just dispatched, short by what the host
+        did meanwhile. Where the pipeline is drained (:meth:`_drain`)
+        the wait is for the step just dispatched, as it always was
+        before."""
         with span("serving.tick.prepare"):
             params = self._tick_params()
         from .scheduler import shed_expired
@@ -1049,6 +1095,8 @@ class ContinuousEngine(Logger):
         try:
             if self._decodable():
                 self._decode(params)
+            elif self._flying is not None:
+                self._drain()       # no plain step follows it this tick
             if self._active(("speculative",)):
                 self._spec_tick(params)
             if self.scheduler.active_beams():
@@ -1071,6 +1119,9 @@ class ContinuousEngine(Logger):
         # (weights are frozen while serving, as everywhere in serving).
         params = self._params
         if params is None or self.scheduler.busy_count() == 0:
+            # a step still in flight here holds only rows that ended on
+            # an eos_id: it is read before the weights may change
+            self._flush()
             params = self._params = self._prepare_params()
             if self.draft is not None:
                 self._draft_params = self._prepare_draft_params()
@@ -1108,10 +1159,10 @@ class ContinuousEngine(Logger):
                 # pool rather than decode on possibly-dead buffers.
                 self.exception("%s: admission failed; resetting the "
                                "slot pool", self.name)
+                self._reset_pool()      # a step in flight too, unread
                 self._abort_active("serving pool reset after a failed "
                                    "admission", code=503,
                                    retry_after=1.0)
-                self._reset_pool()
                 return False
         return True
 
@@ -1166,9 +1217,12 @@ class ContinuousEngine(Logger):
             return
         victims = self._preempt_victims(waiting - free)
         if victims:
-            # a requeued ticket may meet its deadline in the queue:
-            # its last step's tokens go out before it goes back there
-            self._push_held()
+            # a victim's progress is what it has recorded, so a step in
+            # flight is read first (it may end a row: choose again);
+            # and a requeued ticket may meet its deadline in the queue,
+            # so its last step's tokens go out before it goes back there
+            self._flush()
+            victims = self._preempt_victims(waiting - free)
         for slot in victims:
             emitted = self._emitted(slot)
             resumed = fold_resume(slot.req, slot.tokens)
@@ -1280,6 +1334,14 @@ class ContinuousEngine(Logger):
             # quant_kv makes
             self._draft_caches = pools(self.draft_stack, False)
         self._keys = jnp.zeros((self.max_slots, 2), jnp.uint32)
+        # what the step before gave (its tokens, the experts' counts
+        # beside them); until a step has run, every slot's token is
+        # the host's. Uploaded, not made on the device: a shape of its
+        # own there would be one more program to build in set-up
+        self._last = jnp.asarray(numpy.zeros(
+            (self.decode_block, self.max_slots + len(self._tap_names)),
+            numpy.int32))
+        self._fresh[:] = True
         if self.tp > 1:
             # pools shard over the kv-head axis (each chip holds every
             # logical page's heads/tp slice — pages.py per_shard_kv);
@@ -1294,8 +1356,8 @@ class ContinuousEngine(Logger):
                 self._draft_caches = self._tp_place(
                     self._draft_caches,
                     self._caches_pspec(self.draft_stack))
-            self._keys = jax.device_put(
-                self._keys, NamedSharding(mesh, P()))
+            self._keys, self._last = jax.device_put(
+                (self._keys, self._last), NamedSharding(mesh, P()))
 
     def _pool_dtype(self, params):
         """Float dtype of the activation path (the stem table's —
@@ -1442,6 +1504,14 @@ class ContinuousEngine(Logger):
         self._refresh_table_row(slot)
         return jnp.asarray(self._page_table[slot.idx])
 
+    def _set_tok(self, idx: int, token: int) -> None:
+        """The host writes slot ``idx``'s last token (a prefill's
+        first, a speculative round's, a retired row's nought): the next
+        plain step takes it from the host, not from the device's row of
+        the step before."""
+        self._tok[idx] = token
+        self._fresh[idx] = True
+
     def _admit(self, params, slot) -> None:
         import jax
         import jax.numpy as jnp
@@ -1515,7 +1585,7 @@ class ContinuousEngine(Logger):
             # stamps only — no device work rides on tracing)
             slot.ticket.mark_prefill_done()
             slot.ticket.mark_first_token()
-            self._tok[slot.idx] = first
+            self._set_tok(slot.idx, first)
             if slot.mode in _STEP_MODES:
                 self._prefix_insert(slot)
             done = slot.record(first)
@@ -1736,7 +1806,7 @@ class ContinuousEngine(Logger):
             first = int(first)          # syncs the chunk dispatch
             slot.ticket.mark_prefill_done()
             slot.ticket.mark_first_token()
-            self._tok[slot.idx] = first
+            self._set_tok(slot.idx, first)
             self._prefix_insert(slot)
             done = slot.record(first)
             slot.ticket.push_tokens([first])
@@ -1839,56 +1909,133 @@ class ContinuousEngine(Logger):
             self._push_tokens(held, overlapped)
 
     # -- the decode chunk ------------------------------------------------------
-    def _count_decode_dispatch(self, pages: int) -> None:
+    def _count_decode_dispatch(self, pages: int,
+                               ahead: bool = False) -> None:
         """One decode dispatch (the step, a speculative round or a
-        beam step) whose views were ``pages`` pages long."""
+        beam step) whose views were ``pages`` pages long; ``ahead``
+        where the step before it was still unread."""
         positions = pages * self.page_size
         self.decode_dispatches += 1
         self.view_positions += positions
         inc("veles_serving_decode_dispatches_total")
         inc("veles_serving_view_positions_total", positions)
+        if ahead:
+            self.steps_ahead += 1
+            inc("veles_serving_steps_ahead_total")
 
     def _decode(self, params) -> None:
+        """Dispatch the plain step n+1, THEN read, record and emit step
+        n: the chip runs a step while the host does all of that and
+        prepares the next, and never waits for the host between two
+        plain steps. Step n+1 needs nothing of step n that the host
+        cannot know beforehand:
+
+        - its tokens stay on the device (``_last``: what step n gave
+          goes back in as it is, and its last row is merged inside the
+          program with the rows the host has written since:
+          :meth:`_set_tok`);
+        - a masked-in row's position advances by ``decode_block`` at the
+          dispatch, here as on the device, so the pages it needs, the
+          view's rung and the table follow as they always did;
+        - a row whose step in flight reaches its ``n_new`` is masked out
+          of this one; it finishes when that step is read.
+
+        A row that ends on its ``eos_id`` cannot be foreseen: it is in
+        step n+1 too, and that row-step is DROPPED when read (the slot
+        is no longer the row's: never recorded, never pushed). Its key
+        advanced once more, and the slot's next prefill sets the key
+        anew from its request's seed; its K/V row landed in a page the
+        slot held when the step was dispatched, past the row's last
+        position, which the next owner of the page (a prefill, a chunk,
+        a copy: each dispatched later, so run later) writes before any
+        read of it, as it does every position it comes to own. A page
+        the prefix cache adopted holds whole blocks of a PROMPT: a
+        decode row lies past its prompt, and the write-back sends a row
+        inside a slot's shared pages to the sink besides, so no such
+        page is ever written.
+
+        The step dispatched here stays in flight until the next
+        :meth:`_decode` or a :meth:`_drain`; at most one does."""
         import jax.numpy as jnp
+        block = self.decode_block
 
         def need(s):
-            return min(s.t_p + s.n_new,
-                       int(self._pos[s.idx]) + self.decode_block)
+            return min(s.t_p + s.n_new, int(self._pos[s.idx]) + block)
+
+        def going():
+            # a row whose step in flight reaches its n_new is done but
+            # for the reading
+            ahead = {id(s) for s in self._flying[1]} if self._flying else ()
+            return [s for s in self._decodable()
+                    if len(s.tokens) + (block if id(s) in ahead else 0)
+                    < s.n_new]
 
         with span("serving.tick.prepare"):
-            active = self._grow_or_shed(self._decodable(), need)
-            if not active:
-                return
-            # the step runs at the shortest view that holds every
-            # active row: the table's width IS the view's length, and
-            # what lies beyond a row's ``pos`` weighs nought anyway.
-            # A masked-out row may lie beyond it (result discarded,
-            # write to the sink page)
-            pages = view_rung(self.view_ladder,
-                              max(need(s) for s in active),
-                              self.page_size)
-            mask = numpy.zeros(self.max_slots, numpy.int32)
-            for slot in active:
-                mask[slot.idx] = 1
-            base_len = {id(s): len(s.tokens) for s in active}
-            fire_fault("serve.decode_step")
-            host = (jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    jnp.asarray(self._temp), jnp.asarray(mask),
-                    jnp.asarray(self._page_table[:, :pages]),
-                    jnp.asarray(self._shared))
-            step = self._program("step", pages)
+            rows = going()
+            grows = self._flying is not None and any(
+                pages_for(need(s), self.page_size) > len(s.pages)
+                for s in rows)
+        if grows:
+            # growth past a reservation may shed the row, with what it
+            # has recorded: in the serial order (admission reserved
+            # each row's worst case, so this is the safety net's path)
+            self._drain()
+        flying = self._flying
+        with span("serving.tick.prepare"):
+            active = self._grow_or_shed(going() if grows else rows, need)
+            if active:
+                # the step runs at the shortest view that holds every
+                # active row: the table's width IS the view's length,
+                # and what lies beyond a row's ``pos`` weighs nought
+                # anyway. A masked-out row may lie beyond it (result
+                # discarded, write to the sink page)
+                pages = view_rung(self.view_ladder,
+                                  max(need(s) for s in active),
+                                  self.page_size)
+                mask = numpy.zeros(self.max_slots, numpy.int32)
+                for slot in active:
+                    mask[slot.idx] = 1
+                fire_fault("serve.decode_step")
+                # -1: the slot's token is the device's own (``_last``).
+                # Copies, each: the host writes these arrays again
+                # while the step is in flight, and an upload may alias
+                # the memory it was given (the CPU backend's does)
+                host = tuple(jnp.asarray(a) for a in (
+                    numpy.where(self._fresh, self._tok, -1)
+                    .astype(numpy.int32),
+                    self._pos.copy(), self._temp.copy(), mask,
+                    self._page_table[:, :pages].copy(),
+                    self._shared.copy()))
+                step = self._program("step", pages)
+        if not active:
+            self._drain()           # every row ends in the step in flight
+            return
         with span("serving.decode_step", active=len(active),
-                  chunk=self.decode_block):
+                  chunk=block):
             with span("serving.tick.dispatch"):
                 toks, self._keys, self._caches = step(
-                    params, *host, self._keys, self._caches)
+                    params, *host, self._last, self._keys, self._caches)
+                self._last = toks
                 # dropped here, not at the function's end: freeing a
                 # device array yields the interpreter lock, and after
                 # the emit phase every handler thread is waiting for it
                 del host
+            self._flying = (toks, active)
+            self._fresh[:] = False
+            for slot in active:
+                self._pos[slot.idx] += block
+            self._count_decode_dispatch(pages, ahead=flying is not None)
             self._push_held(overlapped=True, phase=True)
-            with span("serving.tick.device"):
-                toks = numpy.asarray(toks)      # (decode_block, S)
+            if flying is not None:
+                self._land(flying)
+
+    def _land(self, flying) -> None:
+        """Wait for a dispatched step's tokens, record them and finish
+        the rows they end; ``flying`` is the ``(tokens on the device,
+        rows)`` that :meth:`_decode` left."""
+        toks, rows = flying
+        with span("serving.tick.device"):
+            toks = numpy.asarray(toks)          # (decode_block, S)
         if self._tap_names:
             # the expert layers' counts came with the tokens: no
             # further dispatch, no further sync
@@ -1896,20 +2043,21 @@ class ContinuousEngine(Logger):
             toks = toks[:, :self.max_slots]
             steptaps.publish({k: float(v)
                               for k, v in zip(self._tap_names, counts)})
-        self._count_decode_dispatch(pages)
         with span("serving.tick.emit"):
+            # a row that ended on its eos_id in the step before was
+            # retired when that was read: this row-step of it is dropped
+            rows = [s for s in rows
+                    if self.scheduler.slots[s.idx] is s]
+            base_len = {id(s): len(s.tokens) for s in rows}
             finished: List = []
             for h in range(toks.shape[0]):
-                still = [s for s in active if s not in finished]
+                still = [s for s in rows if s not in finished]
                 if not still:
                     break
                 for slot in still:
-                    token = int(toks[h, slot.idx])
-                    self._tok[slot.idx] = token
-                    self._pos[slot.idx] += 1
-                    if slot.record(token):
+                    if slot.record(int(toks[h, slot.idx])):
                         finished.append(slot)
-            for slot in active:
+            for slot in rows:
                 # a streaming row that goes on decoding hands this
                 # chunk's tokens to its drain loop under the next
                 # dispatch (_push_held) ...
@@ -1925,6 +2073,31 @@ class ContinuousEngine(Logger):
                     [(slot.ticket, slot.tokens[base_len[id(slot)]:])])
                 self._finish(slot)
 
+    def _drain(self) -> None:
+        """Read, record and emit the step in flight, if there is one:
+        the serial order, restored wherever something other than a
+        plain step (or an admission and its prefill, which queue behind
+        it on the device) comes next: a speculative round or a beam
+        step on the same pool, a tick with no plain row left, and
+        every ending (:meth:`_flush`). Decided from the engine's own
+        state where it is called: a pool that drains every tick behaves
+        as it did before a step was ever left in flight."""
+        flying, self._flying = self._flying, None
+        if flying is not None:
+            with span("serving.decode_step", active=len(flying[1]),
+                      chunk=self.decode_block):
+                self._push_held(phase=True)     # an earlier step's first
+                self._land(flying)
+
+    def _flush(self) -> None:
+        """Nothing in flight and nothing kept: before a hand-off, an
+        abort, a preemption, a shed, a change of weights, :meth:`stop`
+        and the idle wait. A row's progress is then all it was ever
+        dispatched for, and a stream has every token before its
+        terminal is set."""
+        self._drain()
+        self._push_held()
+
     # -- the speculative round -------------------------------------------------
     def _spec_tick(self, params) -> None:
         """One on-device draft/verify round for every speculative row:
@@ -1935,6 +2108,7 @@ class ContinuousEngine(Logger):
         own accepted lengths inside one fixed-shape dispatch."""
         import jax.numpy as jnp
         gamma = self.spec_gamma
+        self._drain()       # a plain step of this tick is read first
         with span("serving.tick.prepare"):
             active = self._grow_or_shed(
                 self._active(("speculative",)),
@@ -1973,7 +2147,7 @@ class ContinuousEngine(Logger):
                 slot.rounds += 1
                 slot.acc += int(acc[i])
                 self._pos[i] += emitted
-                self._tok[i] = int(new_tok[i])
+                self._set_tok(i, int(new_tok[i]))
                 done = False
                 base = len(slot.tokens)
                 for t in out_vec[i, :emitted]:
@@ -1997,6 +2171,7 @@ class ContinuousEngine(Logger):
         frozen-eos lanes, flat top_k), so a pooled beam request's
         tokens equal its solo ``beam_generate`` exactly."""
         import jax.numpy as jnp
+        self._drain()       # a plain step of this tick is read first
         with span("serving.tick.prepare"):
             groups = self.scheduler.active_beams()
             hyps = [s for g in groups for s in g.slots]
@@ -2063,7 +2238,7 @@ class ContinuousEngine(Logger):
         """Clear a row's host state and free its slot + pages. The
         page-table row is zeroed so a retired row's stale view can
         never alias pages the allocator hands to the next admission."""
-        self._tok[slot.idx] = 0
+        self._set_tok(slot.idx, 0)
         self._pos[slot.idx] = 0
         self._temp[slot.idx] = 0.0
         self._shared[slot.idx] = 0
@@ -2121,7 +2296,7 @@ class ContinuousEngine(Logger):
     def _abort_active(self, reason: str, code: int = 500,
                       retry_after: Optional[float] = None,
                       count_shed: bool = True) -> None:
-        self._push_held()
+        self._flush()
         answered = set()
         for slot in self.scheduler.active():
             # aborted rows hand their emitted-token prefix back on the
@@ -2178,7 +2353,7 @@ class ContinuousEngine(Logger):
         fault point fires once per in-flight ticket: an injected raise
         degrades THAT ticket to a plain 503 shed (no resume progress —
         its retry re-decodes from scratch), never blocks the drain."""
-        self._push_held()
+        self._flush()
         handed = 0
         answered = set()
         for slot in self.scheduler.active():
@@ -2351,6 +2526,10 @@ class ContinuousEngine(Logger):
             # (and every v4 artifact, lacking the key, refuses too)
             "tp": int(self.tp),
             "mesh": ([["model", self.tp]] if self.tp > 1 else []),
+            # v6: the decode step takes the tokens of the step before
+            # back as it gave them (the host's row says -1 where they
+            # hold); an artifact without the key has the older step
+            "step_tokens": "device",
         }
 
     def _load_artifact(self) -> bool:
@@ -2632,7 +2811,12 @@ class ContinuousEngine(Logger):
         the SAME ``_block_step``, then quantizes only the one newly
         written position with its own fresh scale — previously
         written rows are never re-scaled, so there is no error
-        accumulation across steps."""
+        accumulation across steps. The chunk's tokens, which the host
+        reads, go back into the NEXT call of the program as they are
+        (``last``; never donated), and it takes a slot's token from
+        their last row wherever the host's row says -1
+        (:meth:`_decode`): one program a tick, and no step waits for
+        the host to have read the step before it."""
         import jax
         import jax.numpy as jnp
         from ..ops import matmul_precision
@@ -2653,12 +2837,18 @@ class ContinuousEngine(Logger):
                                  axis=0, mode="clip")
             return x                            # (S, D)
 
-        def step(params, tok, pos, temp, mask, tables, shared, keys,
-                 caches):
+        def step(params, tok, pos, temp, mask, tables, shared, last,
+                 keys, caches):
             if quant_w:
                 from ..quant import dequantize_params
                 params = dequantize_params(
                     params, dtype=params[stem.name]["table"].dtype)
+            # a slot's token is the host's where the host has written
+            # one since the last step (it sends -1 elsewhere), else the
+            # last row this program gave in the step before: ``last`` is
+            # that step's ``toks`` as they were returned, a masked-out
+            # slot's token carried through them
+            tok = jnp.where(tok >= 0, tok, last[-1, :tok.shape[0]])
 
             def sample_next(tok, pos, keys, x):
                 logits = _head_logits(head, params, x, prec,
@@ -2833,13 +3023,13 @@ class ContinuousEngine(Logger):
             return toks, keys, caches            # toks (chunk, S)
 
         if tp <= 1:
-            return self._finalize(step, donate=(7, 8))
+            return self._finalize(step, donate=(8, 9))
         from jax.sharding import PartitionSpec as P
         cs = self._caches_pspec(self.stack)
         pspec = self._params_pspec(self.stack, params_of(self.wf))
         return self._finalize(
-            step, donate=(7, 8),
-            in_specs=(pspec, P(), P(), P(), P(), P(), P(), P(), cs),
+            step, donate=(8, 9),
+            in_specs=(pspec, P(), P(), P(), P(), P(), P(), P(), P(), cs),
             out_specs=(P(), P(), cs))
 
     def _build_spec_round(self):

@@ -129,6 +129,10 @@ DESCRIPTIONS = {
     "veles_serving_view_positions_total":
         "Cache positions a slot that those dispatches gathered (the "
         "view's length); over the dispatches, the mean view",
+    "veles_serving_steps_ahead_total":
+        "Those of the decode dispatches issued while the step before "
+        "was still unread: the device did not wait for the host "
+        "between the two",
     "veles_serving_tokens_total":
         "Tokens emitted by the continuous-batching engine",
     "veles_serving_token_pushes_total":
