@@ -168,7 +168,9 @@ def test_deadline_shed_accounted_exactly_once():
         - exp_before == 1
     assert counters.get("veles_shed_requests_total") \
         - shed_before == 1
-    assert histograms.count("veles_serving_queue_wait_seconds") == 1
+    # one sample at ``busy``'s admission (observed where the wait ends,
+    # though that ticket has not ended), one at ``old``'s expiry
+    assert histograms.count("veles_serving_queue_wait_seconds") == 2
     assert old.outcome == "expired"
     done = [r for r in flight.records(kind="request")
             if r.get("request_id") == old.request_id

@@ -333,7 +333,7 @@ def test_begin_reads_four_counters_not_the_registry(monkeypatch):
     counters.inc("veles_dispatches_total", 0)
     with spans.span("t.outer") as outer:
         counters.inc("veles_dispatches_total", 3)
-        counters.inc("veles_unit_runs_total", 5)
+        counters.inc("veles_decode_tokens_total", 5)
     assert outer.record["counters"] == {"veles_dispatches_total": 3}
 
 

@@ -107,9 +107,7 @@ class AcceleratedUnit(Unit):
         counts one ``veles_dispatches_total``; a call that grows the
         jit's trace cache counts one ``veles_compiles_total``
         (recompiles are a deterministic regression signal that no
-        clock can see); lookups
-        served from the per-unit cache count
-        ``veles_jit_cache_hits_total``."""
+        clock can see)."""
         cached = self._jit_cache.get(key)
         if cached is None:
             import jax
@@ -140,9 +138,6 @@ class AcceleratedUnit(Unit):
 
             dispatch._jitted = jitted
             cached = self._jit_cache[key] = dispatch
-        else:
-            from .telemetry.counters import inc
-            inc("veles_jit_cache_hits_total")
         return cached
 
     def program_cost(self, key: str):

@@ -911,6 +911,7 @@ class GenerationAPI(Unit):
                 json_reply(self, 200, stats)
 
             def do_POST(self):
+                arrived = time.perf_counter()
                 if self.path == api.path + "/drain":
                     # admin face of the SIGTERM drain: flip /readyz
                     # to draining, stop admission, reply immediately —
@@ -972,7 +973,8 @@ class GenerationAPI(Unit):
                     mode=req.get("mode", "greedy"),
                     trace_id=req.get("trace_id"),
                     attempt=req.get("attempt", 1),
-                    stream=bool(req.get("stream")))
+                    stream=bool(req.get("stream")),
+                    arrived=arrived)
                 if api._draining:
                     health.shed(self, retry_after=5.0,
                                 reason="server draining",
@@ -1200,6 +1202,10 @@ class GenerationAPI(Unit):
                             break
                         event({"tokens": item, "i": sent,
                                "request_id": ticket.request_id})
+                        if not sent:
+                            # the first token is on the wire: the last
+                            # of a request's waits ends here
+                            ticket.mark_first_write()
                         sent += len(item)
                     # /stats parity with the buffered path: count
                     # every via-engine terminal the batch actually

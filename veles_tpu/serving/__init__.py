@@ -90,6 +90,8 @@ SERVING_COUNTERS = (
     "veles_serving_admitted_total",
     "veles_serving_retired_total",
     "veles_serving_prefill_dispatches_total",
+    "veles_serving_prefill_positions_total",
+    "veles_serving_unfed_late_reads_total",
     "veles_serving_decode_dispatches_total",
     "veles_serving_view_positions_total",
     "veles_serving_steps_ahead_total",
@@ -152,15 +154,20 @@ QOS_COUNTERS = (
 )
 
 #: every latency histogram the request-plane SLO layer records
-#: (serving/scheduler.py Ticket terminal accounting) — registered
+#: (serving/scheduler.py Ticket: each wait where it ends) and the
+#: engine's account of the chip unfed — registered
 #: with HELP + bucket bounds in telemetry/counters.py HISTOGRAMS and
 #: asserted ZERO samples after a training-only run by
 #: tests/test_telemetry.py test_feature_off_counters_stay_zero
 SERVING_HISTOGRAMS = (
     "veles_serving_queue_wait_seconds",
+    "veles_serving_prefill_wait_seconds",
     "veles_serving_ttft_seconds",
+    "veles_serving_first_write_seconds",
     "veles_serving_tpot_seconds",
     "veles_serving_e2e_seconds",
+    "veles_serving_unfed_first_token_seconds",
+    "veles_serving_unfed_drain_seconds",
 )
 
 #: process-global registry of live engines (web_status /metrics renders

@@ -137,14 +137,23 @@ from ..nn.sampling import (_block_step, _count_decode_dispatches,
 from ..resilience import health
 from ..resilience.faults import FaultInjected, fire as fire_fault
 from ..telemetry import steptaps
-from ..telemetry.counters import inc
-from ..telemetry.spans import span
+from ..telemetry.counters import inc, observe
+from ..telemetry.spans import emit, span
 from .pages import pages_for, view_ladder, view_rung
 
 #: floor for the temperature divisor inside the one shared decode
 #: program (greedy rows carry temperature 0; their categorical lane is
 #: computed-and-discarded, so the clamp only has to keep it finite)
 _TEMP_EPS = 1e-3
+
+#: the two syncs that leave the chip with nothing queued
+#: (:meth:`ContinuousEngine._emptied`), and the histogram that takes
+#: the seconds until the next program has been called, each
+_UNFED = {"first_token": "veles_serving_unfed_first_token_seconds",
+          "drain": "veles_serving_unfed_drain_seconds"}
+
+#: a blocking read that returns sooner found its result ready
+_LATE_READ_S = 50e-6
 
 #: slot modes the plain decode step advances — also the only modes
 #: that RESUME (scheduler.RESUME_MODES is the single source: their
@@ -489,9 +498,21 @@ class ContinuousEngine(Logger):
         self._fresh = numpy.ones(self.max_slots, bool)
         self._last = None
         #: the plain step dispatched and not yet read, ``(its tokens
-        #: on the device, the rows it advances)``, or None: at most one
-        #: (:meth:`_decode` leaves it, :meth:`_drain` reads it)
+        #: on the device, the rows it advances, its number among the
+        #: dispatches)``, or None: at most one (:meth:`_decode` leaves
+        #: it, :meth:`_drain` reads it)
         self._flying: Optional[Tuple] = None
+        #: calls of compiled programs so far (:meth:`_fed`): a blocking
+        #: read that returns on the NEWEST one leaves the device with
+        #: nothing queued
+        self._dispatched = 0
+        #: ``(perf_counter when the chip was found with nothing queued,
+        #: the cause)`` until the next call of a program has returned:
+        #: the chip known to be unfed (:meth:`_emptied`); tick thread
+        #: only
+        self._unfed: Optional[Tuple[float, str]] = None
+        #: seconds observed so far, a cause (``/stats`` ``unfed_s``)
+        self.unfed_s = {cause: 0.0 for cause in _UNFED}
         #: a slot's next cache position; a masked-in row's advances at
         #: the step's dispatch, as the device's does
         self._pos = numpy.zeros(self.max_slots, numpy.int32)
@@ -874,6 +895,11 @@ class ContinuousEngine(Logger):
             # tick (speculative or beam rows beside the plain ones)
             "steps_ahead_share": round(
                 self.steps_ahead / max(1, self.decode_dispatches), 4),
+            # seconds the chip was KNOWN to have nothing queued before
+            # the engine's next program, by the sync that emptied it
+            # (the two veles_serving_unfed_* histograms' sums)
+            "unfed_s": {cause: round(s, 6)
+                        for cause, s in self.unfed_s.items()},
             # quantization/AOT plane (veles_tpu/quant/): what the
             # /metrics mode gauges render on both surfaces
             "artifact_mode": int(self.artifact_mode),
@@ -965,6 +991,9 @@ class ContinuousEngine(Logger):
                            and self._handoff is None
                            and not self._closing):
                         self._flush()       # no dispatch follows a wait
+                        # no traffic is not the host's lateness (and
+                        # the wait has a histogram of its own)
+                        self._unfed = None
                         with span("serving.loop.wait"):
                             self.scheduler.cv.wait(timeout=5.0)
                         if not self._closing:
@@ -998,7 +1027,7 @@ class ContinuousEngine(Logger):
 
     def _reset_pool(self) -> None:
         self._caches = self._draft_caches = self._keys = None
-        self._last = self._flying = None
+        self._last = self._flying = self._unfed = None
         self._params = self._draft_params = None
 
     def _active(self, modes: Tuple[str, ...]) -> List:
@@ -1562,8 +1591,10 @@ class ContinuousEngine(Logger):
                 params, ids_dev, numpy.int32(t_p),
                 numpy.int32(slot.idx), numpy.float32(slot.temperature),
                 seed_key, table_row, self._keys, self._caches)
+            prefill = self._dispatched
             self._push_held(overlapped=True)
         inc("veles_serving_prefill_dispatches_total")
+        inc("veles_serving_prefill_positions_total", bucket)
         self._pos[slot.idx] = t_p
         self._temp[slot.idx] = slot.temperature
         if slot.mode == "speculative":
@@ -1579,10 +1610,12 @@ class ContinuousEngine(Logger):
                 # ticket's, veles_serving_queue_wait_seconds)
                 inc("veles_serving_admitted_total")
                 self.admitted += 1
+            asked = time.perf_counter()
             first = int(first)
             # the int() above synced the prefill dispatch: this step
             # boundary IS prefill-done and first-token time (host-side
             # stamps only — no device work rides on tracing)
+            self._emptied("first_token", prefill, asked)
             slot.ticket.mark_prefill_done()
             slot.ticket.mark_first_token()
             self._set_tok(slot.idx, first)
@@ -1602,8 +1635,10 @@ class ContinuousEngine(Logger):
             logp0 = jax.nn.log_softmax(
                 jnp.asarray(logits).astype(jnp.float32))
             top0, tok0 = jax.lax.top_k(logp0, self.beam_width)
+            asked = time.perf_counter()
             group.cur = numpy.asarray(tok0, numpy.int32)
             group.scores = numpy.asarray(top0, numpy.float32)
+            self._emptied("first_token", prefill, asked)
             eos = slot.eos_id
             group.finished = (group.cur == (-1 if eos is None
                                             else int(eos)))
@@ -1793,8 +1828,10 @@ class ContinuousEngine(Logger):
                         numpy.float32(slot.temperature), seed_key,
                         table_row, numpy.int32(1 if final else 0),
                         self._keys, self._caches)
+                chunk = self._dispatched
                 self._push_held(overlapped=True)
             inc("veles_serving_prefill_dispatches_total")
+            inc("veles_serving_prefill_positions_total", C)
             self.chunk_dispatches += 1
             work = True
             if not final:
@@ -1803,7 +1840,9 @@ class ContinuousEngine(Logger):
                 continue
             slot.prefilled = None
             self._pos[slot.idx] = t_p
+            asked = time.perf_counter()
             first = int(first)          # syncs the chunk dispatch
+            self._emptied("first_token", chunk, asked)
             slot.ticket.mark_prefill_done()
             slot.ticket.mark_first_token()
             self._set_tok(slot.idx, first)
@@ -1861,6 +1900,44 @@ class ContinuousEngine(Logger):
                     code=503, retry_after=1.0):
                 inc("veles_shed_requests_total")
         return alive
+
+    # -- the chip known to be unfed ---------------------------------------------
+    def _emptied(self, cause: str, number: int, asked: float) -> None:
+        """A blocking read of what dispatch ``number`` gave, begun at
+        ``asked`` (``perf_counter``), has just returned. Where that was
+        the newest dispatch the device now has nothing queued: stamp
+        the moment and the ``cause`` (a key of ``_UNFED``: the sync
+        that emptied it); :meth:`_fed` observes the interval when the
+        engine has next called a program. A read that returned at once
+        found the result ready: the chip may have stood idle before
+        the host looked, the interval is then a lower bound, and
+        ``veles_serving_unfed_late_reads_total`` says how often."""
+        if number != self._dispatched:
+            return              # a later program is still queued
+        now = time.perf_counter()
+        if now - asked < _LATE_READ_S:
+            inc("veles_serving_unfed_late_reads_total")
+        if self._unfed is None:     # else known empty since earlier
+            self._unfed = (now, cause)
+
+    def _fed(self) -> None:
+        """The engine's call of a compiled program (whatever
+        :meth:`_program` returned) has returned, so the device has work
+        again: number the dispatch and, where the chip was known to be
+        unfed, observe for how long (the call's own length is the
+        host's too: a program's arguments are a thousand leaves). On a
+        plain tick that is one comparison: the step before is unread,
+        so no stamp is set."""
+        self._dispatched += 1
+        if self._unfed is not None:
+            (since, cause), self._unfed = self._unfed, None
+            unfed = time.perf_counter() - since
+            observe(_UNFED[cause], unfed)
+            self.unfed_s[cause] += unfed
+            # a finished interval for the ring and ``trace export``
+            # (where spans are recorded); no live span, which would
+            # cross ``serving.tick.prefill`` and ``.dispatch``
+            emit("serving.unfed", time.time() - unfed, unfed, cause=cause)
 
     # -- a step's tokens, to the streams ---------------------------------------
     def _push_tokens(self, handed, overlapped: bool = False) -> None:
@@ -2020,7 +2097,7 @@ class ContinuousEngine(Logger):
                 # device array yields the interpreter lock, and after
                 # the emit phase every handler thread is waiting for it
                 del host
-            self._flying = (toks, active)
+            self._flying = (toks, active, self._dispatched)
             self._fresh[:] = False
             for slot in active:
                 self._pos[slot.idx] += block
@@ -2029,13 +2106,18 @@ class ContinuousEngine(Logger):
             if flying is not None:
                 self._land(flying)
 
-    def _land(self, flying) -> None:
+    def _land(self, flying, drained: bool = False) -> None:
         """Wait for a dispatched step's tokens, record them and finish
-        the rows they end; ``flying`` is the ``(tokens on the device,
-        rows)`` that :meth:`_decode` left."""
-        toks, rows = flying
+        the rows they end; ``flying`` is what :meth:`_decode` left.
+        ``drained`` where no step was dispatched after it: its read
+        may then leave the chip with nothing queued."""
+        toks, rows, number = flying
+        if drained:
+            asked = time.perf_counter()
         with span("serving.tick.device"):
             toks = numpy.asarray(toks)          # (decode_block, S)
+        if drained:
+            self._emptied("drain", number, asked)
         if self._tap_names:
             # the expert layers' counts came with the tokens: no
             # further dispatch, no further sync
@@ -2087,7 +2169,7 @@ class ContinuousEngine(Logger):
             with span("serving.decode_step", active=len(flying[1]),
                       chunk=self.decode_block):
                 self._push_held(phase=True)     # an earlier step's first
-                self._land(flying)
+                self._land(flying, drained=True)
 
     def _flush(self) -> None:
         """Nothing in flight and nothing kept: before a hand-off, an
@@ -2133,11 +2215,14 @@ class ContinuousEngine(Logger):
                     self._caches, self._draft_caches)
                 del host            # as in _decode
             self._push_held(overlapped=True, phase=True)
+            asked = time.perf_counter()
             with span("serving.tick.device"):
                 out_vec = numpy.asarray(out_vec)     # (S, gamma)
                 n_emit = numpy.asarray(n_emit)
                 acc = numpy.asarray(acc)
                 new_tok = numpy.asarray(new_tok)
+            # the serial order: the round just dispatched is read
+            self._emptied("drain", self._dispatched, asked)
         self._count_decode_dispatch(self.pages_per_slot)
         inc("veles_serving_spec_rounds_total", len(active))
         with span("serving.tick.emit"):
@@ -2212,11 +2297,13 @@ class ContinuousEngine(Logger):
                 tok, parent, new_scores, new_fin, self._caches = \
                     beam(params, *host, self._caches)
                 del host            # as in _decode
+            asked = time.perf_counter()
             with span("serving.tick.device"):
                 tok = numpy.asarray(tok)
                 parent = numpy.asarray(parent)
                 new_scores = numpy.asarray(new_scores)
                 new_fin = numpy.asarray(new_fin)
+            self._emptied("drain", self._dispatched, asked)
         self._count_decode_dispatch(self.pages_per_slot)
         inc("veles_serving_beam_steps_total", len(groups))
         with span("serving.tick.emit"):
@@ -2412,18 +2499,22 @@ class ContinuousEngine(Logger):
             prog = self._progs[key] = self._instrument_live(jitted)
         return prog
 
-    @staticmethod
-    def _count_tp_dispatch(call):
-        """Count one ``veles_tp_dispatches_total`` per invocation —
-        the TP observability seam for artifact-installed programs
-        (the live path counts inside ``_instrument_live``)."""
+    def _installed(self, call):
+        """An artifact-installed program as the engine calls it: every
+        call is a dispatch to :meth:`_fed` and, on a sharded engine,
+        one ``veles_tp_dispatches_total`` — the TP observability seam
+        (the live path does both inside ``_instrument_live``)."""
         import functools
+        tp_on = self.tp > 1
 
         @functools.wraps(call)
-        def counted(*args, **kwargs):
-            inc("veles_tp_dispatches_total")
-            return call(*args, **kwargs)
-        return counted
+        def fed(*args, **kwargs):
+            if tp_on:
+                inc("veles_tp_dispatches_total")
+            out = call(*args, **kwargs)
+            self._fed()
+            return out
+        return fed
 
     def _instrument_live(self, jitted):
         """Wrap a live jitted program: every call counts one
@@ -2461,7 +2552,11 @@ class ContinuousEngine(Logger):
                     inc("veles_serving_compile_seconds_total",
                         time.time() - t0)
                 box["exe"] = exe
-            return exe(*args)
+                # building a program is set-up, not the host's lateness
+                self._unfed = None
+            out = exe(*args)
+            self._fed()
+            return out
 
         dispatch._jitted = jitted
         # the compiled executable, once built (tests read its HLO)
@@ -2551,19 +2646,16 @@ class ContinuousEngine(Logger):
                 "live jit", self.name, self.artifact,
                 type(e).__name__, e)
             return False
-        tp_on = self.tp > 1
         # the artifact holds the step at the whole view and no other
         # length: the ladder is that one rung, nothing compiles
         self.view_ladder = (self.pages_per_slot,)
         for key, call in programs.items():
-            counted = _count_decode_dispatches(call)
-            if tp_on:
-                # artifact-installed programs are the same shard_mapped
-                # executables the live path builds, so they feed the TP
-                # dispatch seam too — otherwise a sharded engine serving
-                # from an artifact under-reports veles_tp_dispatches_total
-                counted = self._count_tp_dispatch(counted)
-            self._progs[key] = counted
+            # artifact-installed programs are the same shard_mapped
+            # executables the live path builds, so they feed the TP
+            # dispatch seam too — otherwise a sharded engine serving
+            # from an artifact under-reports veles_tp_dispatches_total
+            self._progs[key] = self._installed(
+                _count_decode_dispatches(call))
         self.artifact_mode = True
         inc("veles_artifact_loads_total")
         self.info("%s: AOT artifact loaded from %s (%d programs; zero "

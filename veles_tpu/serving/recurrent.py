@@ -631,6 +631,7 @@ class RecurrentEngine(Logger):
                         numpy.int32(1 if final else 0),
                         self._keys, self._states)
                 inc("veles_serving_prefill_dispatches_total")
+                inc("veles_serving_prefill_positions_total", C)
                 self.chunk_dispatches += 1
                 boundary = p0 + n_real
                 if n_real == C and self.state_cache is not None:

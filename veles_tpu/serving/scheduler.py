@@ -96,18 +96,27 @@ class Ticket:
     process-unique ``request_id`` and host-side lifecycle timestamps
     (``enqueued`` → ``admitted`` → ``prefill_done`` → ``first_token``
     → terminal), stamped by the planes at step boundaries only.
+    Each wait is observed WHERE IT ENDS, once a request: the queue
+    wait at the first :meth:`mark_admitted`, TTFT and the prefill wait
+    at the first :meth:`mark_first_token`, the first token's way out
+    at :meth:`mark_first_write` (``telemetry/counters.py`` HISTOGRAMS),
+    so a scrape, an alert and the overload governor see a slow first
+    token when it comes and not a generation later. Their durations
+    come from ``perf_counter`` stamps taken beside the epoch ones
+    (which stay: the lifecycle spans' ``ts`` and the deadline's clock).
     :meth:`succeed`/:meth:`fail` are EXACTLY-ONCE: the first terminal
-    call records the per-request histograms (queue wait, TTFT, TPOT,
-    end-to-end — ``telemetry/counters.py`` HISTOGRAMS), emits the
-    request's lifecycle spans tagged with its id, and notes a
-    terminal flight-recorder event; any later call is a no-op
-    returning False — a ticket swept by both the tick path and the
-    failure path can never double-count."""
+    call records what ends there (TPOT, end-to-end, the queue wait of
+    a ticket that died in the queue), emits the request's lifecycle
+    spans tagged with its id, and notes a terminal flight-recorder
+    event; any later call is a no-op returning False — a ticket swept
+    by both the tick path and the failure path can never
+    double-count."""
 
     __slots__ = ("event", "result", "error", "code", "retry_after",
                  "deadline", "enqueued", "request_id", "trace_id",
                  "attempt", "mode",
                  "admitted", "prefill_done", "first_token",
+                 "_p_enqueued", "_p_admitted", "_p_first_token",
                  "n_tokens", "outcome", "progress", "_terminal_lock",
                  "stream", "_stream_q")
 
@@ -116,7 +125,8 @@ class Ticket:
                  mode: str = "greedy",
                  trace_id: Optional[str] = None,
                  attempt: int = 1,
-                 stream: bool = False) -> None:
+                 stream: bool = False,
+                 arrived: Optional[float] = None) -> None:
         self._terminal_lock = threading.Lock()
         self.event = threading.Event()
         self.result = None
@@ -124,7 +134,13 @@ class Ticket:
         self.code: int = 500
         self.retry_after: Optional[float] = None
         self.deadline = deadline
-        self.enqueued = time.time()
+        # ``arrived``: the handler's ``perf_counter()`` when it had the
+        # request's headers. Reading and parsing a long prompt's body
+        # under the interpreter lock the tick thread holds is part of
+        # what the client waits: the request's clock starts there
+        now = time.perf_counter()
+        self._p_enqueued = now if arrived is None else arrived
+        self.enqueued = time.time() - (now - self._p_enqueued)
         self.request_id = request_id or new_request_id()
         #: fleet-wide correlation key: adopted from the router's body
         #: when one arrives, else the request's own id — every
@@ -138,6 +154,10 @@ class Ticket:
         self.admitted: Optional[float] = None
         self.prefill_done: Optional[float] = None
         self.first_token: Optional[float] = None
+        self._p_admitted: Optional[float] = None
+        #: cleared by :meth:`mark_first_write`: the flag that makes
+        #: the first token's way out one observation a request
+        self._p_first_token: Optional[float] = None
         self.n_tokens = 0
         self.outcome: Optional[str] = None
         #: tokens emitted before a mid-decode failure/handoff — the
@@ -154,11 +174,16 @@ class Ticket:
 
     # -- lifecycle stamps (host-side, step boundaries only) ------------------
     def mark_admitted(self) -> None:
-        """Stamp queue exit (slot admission / window-batch pop); first
-        stamp wins — a beam group's sibling slots share one ticket."""
+        """Stamp queue exit (slot admission / window-batch pop) and
+        observe the queue wait that ends here; first stamp wins — a
+        beam group's sibling slots share one ticket, and a preempted
+        and requeued one is neither stamped nor observed again."""
         if self.admitted is not None:
             return
         self.admitted = time.time()
+        self._p_admitted = time.perf_counter()
+        observe("veles_serving_queue_wait_seconds",
+                self._p_admitted - self._p_enqueued)
         if request_tracing_enabled():
             try:
                 from ..telemetry.recorder import flight
@@ -174,8 +199,31 @@ class Ticket:
             self.prefill_done = time.time()
 
     def mark_first_token(self) -> None:
-        if self.first_token is None:
-            self.first_token = time.time()
+        """Stamp the host's read of the first token and observe the
+        two waits that end here: TTFT (from arrival) and the prefill
+        wait (from admission: the step in flight that the prefill
+        queues behind, the prefills ahead of it in the same tick, its
+        own program). First stamp wins."""
+        if self.first_token is not None:
+            return
+        self.first_token = time.time()
+        self._p_first_token = now = time.perf_counter()
+        observe("veles_serving_ttft_seconds", now - self._p_enqueued)
+        if self._p_admitted is not None:
+            observe("veles_serving_prefill_wait_seconds",
+                    now - self._p_admitted)
+
+    def mark_first_write(self) -> None:
+        """The handler thread has written the first SSE event that
+        carries a token: observe the first token's way out, from the
+        host's read of it (:meth:`mark_first_token`) through the push,
+        the handler's wake under the interpreter lock and the write.
+        Once a request (the stamp is the flag); a ticket whose tokens
+        burst at completion (the window plane) has none to clear."""
+        p0, self._p_first_token = self._p_first_token, None
+        if p0 is not None:
+            observe("veles_serving_first_write_seconds",
+                    time.perf_counter() - p0)
 
     def set_progress(self, tokens) -> None:
         """Attach the emitted-token prefix BEFORE a terminal
@@ -290,8 +338,10 @@ class Ticket:
         return dynamic_retry_after(self.retry_after)
 
     def _account(self, outcome: str) -> None:
-        """Terminal SLO accounting — histograms always, span/flight
-        emission under the tracing switch. Never raises: a broken
+        """Terminal SLO accounting — the histograms of what ends here
+        always (TPOT, end-to-end, a queue wait that no admission
+        ended), span/flight emission under the tracing switch. Never
+        raises: a broken
         observer must not lose the request's answer. Deliberately
         runs INSIDE the terminal lock, before ``event.set()``:
         answered must imply accounted (the tests read the
@@ -302,20 +352,16 @@ class Ticket:
         now = time.time()
         self.outcome = outcome
         try:
-            if self.admitted is not None:
+            if self.admitted is None and outcome in ("expired", "shed"):
+                # died in the queue: its whole life WAS queue wait (an
+                # admitted ticket's was observed at its admission)
                 observe("veles_serving_queue_wait_seconds",
-                        max(0.0, self.admitted - self.enqueued))
-            elif outcome in ("expired", "shed"):
-                # died in the queue: its whole life WAS queue wait
-                observe("veles_serving_queue_wait_seconds",
-                        max(0.0, now - self.enqueued))
-            if self.first_token is not None:
-                observe("veles_serving_ttft_seconds",
-                        max(0.0, self.first_token - self.enqueued))
-                if outcome == "retired" and self.n_tokens > 1:
-                    observe("veles_serving_tpot_seconds",
-                            max(0.0, now - self.first_token)
-                            / (self.n_tokens - 1))
+                        time.perf_counter() - self._p_enqueued)
+            if self.first_token is not None \
+                    and outcome == "retired" and self.n_tokens > 1:
+                observe("veles_serving_tpot_seconds",
+                        max(0.0, now - self.first_token)
+                        / (self.n_tokens - 1))
             if outcome == "retired":
                 observe("veles_serving_e2e_seconds",
                         max(0.0, now - self.enqueued))

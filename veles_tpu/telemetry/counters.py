@@ -29,14 +29,10 @@ DESCRIPTIONS = {
         "Jitted device program executions (one per jitted call)",
     "veles_compiles_total":
         "XLA compilations observed (jit cache misses at call time)",
-    "veles_jit_cache_hits_total":
-        "Unit-level jit lookups served from the per-unit cache",
     "veles_h2d_bytes_total":
         "Bytes explicitly transferred host to device",
     "veles_d2h_bytes_total":
         "Bytes explicitly fetched device to host",
-    "veles_unit_runs_total":
-        "Unit.run invocations through the workflow scheduler",
     "veles_decode_tokens_total":
         "Tokens emitted by the generation stack",
     "veles_decode_dispatches_total":
@@ -65,8 +61,6 @@ DESCRIPTIONS = {
         "sparse-expert layers of a served decode step (live rows only; "
         "prefill's routing is not counted): the experts whose matrices "
         "the step had to read",
-    "veles_spans_total":
-        "Telemetry spans recorded",
     # resilience subsystem (veles_tpu/resilience/): these exist so
     # chaos runs are countable; tests/test_telemetry.py
     # test_feature_off_counters_stay_zero asserts they read 0 in clean
@@ -123,6 +117,16 @@ DESCRIPTIONS = {
         "Slot rows retired (eos_id emitted or own n_new reached)",
     "veles_serving_prefill_dispatches_total":
         "Bucketed prefill programs dispatched by the serving engine",
+    "veles_serving_prefill_positions_total":
+        "Prompt positions those dispatches of the target model ran "
+        "(a prefill's bucket, a chunk's length; the draft's prefill "
+        "counts none): over the decode dispatches, the prefill work "
+        "that a tick carries beside its step",
+    "veles_serving_unfed_late_reads_total":
+        "Blocking reads that opened an unfed interval (the two "
+        "veles_serving_unfed_* histograms) and returned within 50 us: "
+        "the result was ready, so the chip may have stood idle before "
+        "the host looked and that interval is a lower bound",
     "veles_serving_decode_dispatches_total":
         "Pooled fixed-shape decode steps dispatched by the serving "
         "engine",
@@ -417,17 +421,37 @@ HISTOGRAMS = {
     # accounting): ZERO samples in non-serving runs
     # (tests/test_telemetry.py test_feature_off_counters_stay_zero)
     "veles_serving_queue_wait_seconds": {
-        "help": "Seconds a serving request waited in the queue "
-                "before admission (deadline-shed/expired requests "
-                "record their full wait)",
+        "help": "Seconds a serving request waited from its arrival, "
+                "through its body's parse and the queue, to its "
+                "admission, observed at the admission "
+                "(deadline-shed/expired requests record their full "
+                "wait at the terminal)",
         "buckets": (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                     0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
     },
     "veles_serving_ttft_seconds": {
-        "help": "Time to first token: request enqueue to the first "
-                "generated token (prefill output), per request",
+        "help": "Time to first token: a request's arrival (the "
+                "handler has its headers) to the host's read of the "
+                "first generated token (prefill output), per request, "
+                "observed at that read",
         "buckets": (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                     0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
+    },
+    "veles_serving_prefill_wait_seconds": {
+        "help": "Admission to the host's read of the first token, "
+                "per request: the step in flight that the prefill "
+                "queues behind, the prefills ahead of it in the same "
+                "tick, its own program (queue wait + this = TTFT)",
+        "buckets": (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+                    0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
+    },
+    "veles_serving_first_write_seconds": {
+        "help": "The first token's way out, per streamed request: "
+                "the host's read of it to the end of the handler "
+                "thread's write of the first SSE event that carries "
+                "a token (the push, the handler's wake under the "
+                "interpreter lock, the write)",
+        "buckets": SPAN_BUCKETS,
     },
     "veles_serving_tpot_seconds": {
         "help": "Time per output token after the first (decode "
@@ -479,6 +503,27 @@ HISTOGRAMS = {
         "help": "Serving tick, emission: recording the step's tokens "
                 "per slot, pushing them to the streams, retiring "
                 "finished requests",
+        "buckets": SPAN_BUCKETS,
+    },
+    # the chip known to be unfed (serving/engine.py _unfed): from a
+    # blocking read that returned on the NEWEST program dispatched, so
+    # that the device has nothing queued, until the engine's next call
+    # of a compiled program has returned; by the sync that emptied it.
+    # Not named veles_serving_tick_*: these seconds lie inside the
+    # phases above
+    "veles_serving_unfed_first_token_seconds": {
+        "help": "Chip unfed after a first token's read: the prefill "
+                "was the newest dispatch, and the rest of the "
+                "admission, further admissions' preparation and the "
+                "decode step's prepare ran with nothing queued",
+        "buckets": SPAN_BUCKETS,
+    },
+    "veles_serving_unfed_drain_seconds": {
+        "help": "Chip unfed after a drained step's read: the step "
+                "landed was the newest dispatch (growth past a "
+                "reservation, no plain row left, a speculative or "
+                "beam pool, a change of weights); the loop's idle "
+                "wait closes the interval unobserved",
         "buckets": SPAN_BUCKETS,
     },
     "veles_serving_loop_wait_seconds": {

@@ -279,7 +279,6 @@ class SpanRecorder:
         cursor, honor the ring-capacity knob, append, stream to the
         sink (rotating past ``root.common.trace.rotate_bytes``), then
         run the close hooks outside the lock."""
-        counters.inc("veles_spans_total")
         rotated = False
         with self._lock:
             if self._follow_config:

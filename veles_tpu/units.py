@@ -191,9 +191,7 @@ class Unit(Logger, metaclass=UnitRegistry):
         t0 = time.time()
         if root.common.trace.run:
             self.debug("running %s", self.name)
-        from .telemetry.counters import inc
         from .telemetry.spans import span
-        inc("veles_unit_runs_total")
         # telemetry span: nesting + per-run dispatch/transfer
         # counter deltas. The root.common.trace.spans switch is
         # honored centrally by the recorder — one knob, every site
