@@ -94,3 +94,43 @@ def assert_shortest_rungs(engine, seen):
     assert abs(share - sum(p for p, _ in seen)
                / (len(seen) * engine.pages_per_slot)) < 1e-4
     assert share < 1.0
+
+
+def force_paged(engine):
+    """``engine`` with its float step reading every standing block
+    through the page table (the kernel, interpreted off the chip), as
+    its predicate decides on a TPU: the predicate forced for this one
+    engine, the way tests/test_flash_attention.py forces flash."""
+    engine._paged_read = lambda blk: not hasattr(blk, "serve_step")
+    return engine
+
+
+def record_reads(engine):
+    """Every later step dispatch of ``engine`` appends ``(positions a
+    slot of its view, the positions its masked-in rows could see)``,
+    reckoned here from the step's own inputs: a row at ``p`` sees ``p +
+    j + 1`` at the chunk's iteration ``j``."""
+    seen, program = [], engine._program
+    block = engine.decode_block
+
+    def recording(kind, bucket=None):
+        prog = program(kind, bucket)
+        if kind != "step":
+            return prog
+
+        def step(params, tok, pos, temp, mask, tables, *rest):
+            live = [int(p) for p, m in zip(pos, mask) if m]
+            seen.append((tables.shape[1] * engine.page_size,
+                         sum(p * block + block * (block + 1) // 2
+                             for p in live)))
+            return prog(params, tok, pos, temp, mask, tables, *rest)
+        return step
+    engine._program = recording
+    return seen
+
+
+def live_share(engine, seen):
+    """What ``live_share`` must read after the dispatches in ``seen``."""
+    view = sum(positions for positions, _ in seen)
+    return round(sum(rows for _, rows in seen)
+                 / (view * engine.max_slots * engine.decode_block), 4)

@@ -1,8 +1,9 @@
-"""The flash kernels compiled by the TPU's own compiler for a v5e that
+"""The Pallas kernels compiled by the TPU's own compiler for a v5e that
 is described, not attached: every tile pair devices/kernel_tuning.json
 commits for "TPU v5 lite" lowers, forward and custom-VJP backward, at its
 own length, head size, grouping and operand dtype, with the VMEM limit
-the kernels compute for themselves. What interpret mode cannot see (VMEM,
+the kernels compute for themselves; and the paged decode read at the
+dense serving cell's widths. What interpret mode cannot see (VMEM,
 tiling) and a chip run costs minutes to see.
 
 All in this one file, the topology described inside a fixture: only one
@@ -78,3 +79,40 @@ def test_committed_tiles_lower_on_a_described_v5e(key, row, one_chip,
                             argnums=(0, 1, 2))).lower(
         q, k, k).compile().as_text()
     assert "veles_flash_bwd_dkv" in text and "veles_flash_bwd_dq" in text
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_read_lowers_on_a_described_v5e(dtype, one_chip,
+                                              no_compile_cache):
+    """ops/paged_attention.py at mistral7b_decode_sat's widths (32 slots,
+    32 query heads on 8 KV heads of 128, pages of 16, a table of 128
+    pages) with its write-back beside it: the kernel lowers, and the
+    pools are read and written where they lie, with no copy of either."""
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu.ops import paged_attention as pa
+    slots, heads, kv, hd, size, width = 32, 32, 8, 128, 16, 128
+    pages = slots * width + 1
+    dtype = jnp.dtype(dtype)
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(q, k_pool, v_pool, tables, pos, k_new):
+        o = pa.paged_decode_attention(
+            q, k_pool, v_pool, tables, jnp.zeros_like(pos), pos,
+            k_new[:, None], k_new[:, None], jnp.ones((slots, 1), bool),
+            interpret=False)
+        page = jnp.take_along_axis(tables, (pos // size)[:, None], 1)[:, 0]
+        return (o, k_pool.at[page, pos % size].set(k_new),
+                v_pool.at[page, pos % size].set(k_new))
+
+    pool = spec((pages, size, kv, hd), dtype)
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        spec((slots, heads, hd), jnp.float32), pool, pool,
+        spec((slots, width), jnp.int32), spec((slots,), jnp.int32),
+        spec((slots, kv, hd), dtype)).compile()
+    text = compiled.as_text()
+    assert "veles_paged_attn" in text and "tpu_custom_call" in text
+    # temporaries of a few (S, H, hd) states, nothing of a pool's size
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20

@@ -16,6 +16,7 @@ layernorm/gelu/rope math so cached and full paths cannot drift.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List
 
 import numpy
@@ -35,7 +36,6 @@ def _count_decode_dispatches(program):
     per-token jitted steps reads as n_new dispatches — the round-5
     dispatch-count regression lock measures, it does not assert. Any
     new jitted decode program MUST wear this decorator."""
-    import functools
     from ..telemetry.counters import inc
 
     @functools.wraps(program)
@@ -54,15 +54,15 @@ def params_of(wf):
 
 
 def _rope_at(np_mod, x, pos, base=10000.0):
-    """RoPE for a SINGLE position: x (B, 1, H, Dh), pos scalar (traced
-    ok). Same half-split pairing as transformer._rope."""
+    """RoPE for a SINGLE position a row: x (B, 1, H, Dh), pos a scalar
+    or (B,) (traced ok). Same half-split pairing as transformer._rope."""
     hd = x.shape[-1]
     half = hd // 2
     inv = np_mod.asarray(
         (base ** (-numpy.arange(half, dtype="float32") / half)))
-    ang = pos.astype("float32") * inv           # (half,)
-    cos = np_mod.cos(ang)[None, None, None, :]
-    sin = np_mod.sin(ang)[None, None, None, :]
+    ang = np_mod.reshape(pos, (-1, 1, 1, 1)).astype("float32") * inv
+    cos = np_mod.cos(ang)                       # (1 or B, 1, 1, half)
+    sin = np_mod.sin(ang)
     x1, x2 = x[..., :half], x[..., half:2 * half]
     rot1 = x1 * cos - x2 * sin
     rot2 = x1 * sin + x2 * cos
@@ -173,12 +173,48 @@ def _block_prefill(block, p, x, cache_k, cache_v, tp=1, tp_axis=None):
                                  tp_axis=tp_axis), cache_k, cache_v
 
 
+def _dense_read(q, k, v, cache_k, cache_v, pos, window=None):
+    """The one-token step's attention read of a dense cache: q (B, 1,
+    H, Dh), the new rows k, v (B, 1, KV, Dh), caches (B, T_max, KV,
+    Dh), pos scalar. Writes the rows at ``pos`` and reads the rows <=
+    pos (the last ``window`` of them, where given) -> (o (B, 1, KV,
+    H/KV, Dh) float32, cache_k, cache_v)."""
+    import jax.numpy as jnp
+    b, _, h, hd = q.shape
+    kv = cache_k.shape[2]
+    cache_k = jnp.asarray(cache_k).at[:, pos].set(k[:, 0])
+    cache_v = jnp.asarray(cache_v).at[:, pos].set(v[:, 0])
+    t_max = cache_k.shape[1]
+    # single-row attention over the cache; scores/softmax in f32 like
+    # attention_reference so the step matches the full-window forward.
+    # GQA reads the unrepeated cache through a (kv, group) view of the
+    # query heads — no (B, T, H, Dh) materialization.
+    q5 = q.reshape(b, 1, kv, h // kv, hd).astype(jnp.float32)
+    s = jnp.einsum("bqkgd,btkd->bkgqt", q5,
+                   cache_k.astype(jnp.float32)) / numpy.sqrt(hd)
+    valid = jnp.arange(t_max) <= pos
+    if window:
+        # sliding window: only the last `window` cached rows are visible
+        valid = valid & (jnp.arange(t_max) > pos - window)
+    valid = valid[None, None, None, None, :]
+    s = jnp.where(valid, s, -1e30)
+    w = jnp.exp(s - s.max(axis=-1, keepdims=True))
+    w = w / w.sum(axis=-1, keepdims=True)
+    o = jnp.einsum("bkgqt,btkd->bqkgd", w, cache_v.astype(jnp.float32))
+    return o, cache_k, cache_v
+
+
 def _block_step(block, p, x_t, cache_k, cache_v, pos, tp=1,
-                tp_axis=None):
+                tp_axis=None, attend=None):
     """One-token pass: x_t (B, 1, D), caches (B, T_max, H, Dh), pos =
     tokens already cached. Attention reads the cache rows <= pos.
     ``tp``/``tp_axis``: head-sharded weights + ``kv/tp``-head caches
-    inside a shard_map, exactly as :func:`_block_prefill`."""
+    inside a shard_map, exactly as :func:`_block_prefill`.
+    ``attend(q, k, v, cache_k, cache_v, pos)`` is the attention read
+    and what it keeps (:func:`_dense_read`'s contract, the default): the
+    serving engine's float step passes one that reads its page pool
+    through the page table, with a (B,) ``pos``; the block's norms,
+    projections, rotary and FFN are this one body either way."""
     import jax.numpy as jnp
     from jax import named_scope
     from ..ops import matmul_precision
@@ -186,8 +222,10 @@ def _block_step(block, p, x_t, cache_k, cache_v, pos, tp=1,
     b, _, d = x_t.shape
     h = block.n_heads // tp
     kv = getattr(block, "n_kv_heads", block.n_heads) // tp
-    g = h // kv
     hd = d // block.n_heads
+    if attend is None:
+        attend = functools.partial(_dense_read,
+                                   window=getattr(block, "window", None))
 
     with named_scope(block.name):
         with named_scope("norm1"):
@@ -205,31 +243,8 @@ def _block_step(block, p, x_t, cache_k, cache_v, pos, tp=1,
                 q = _rope_at(jnp, q, pos, base)
                 k = _rope_at(jnp, k, pos, base)
         with named_scope("attn"):
-            cache_k = jnp.asarray(cache_k).at[:, pos].set(k[:, 0])
-            cache_v = jnp.asarray(cache_v).at[:, pos].set(v[:, 0])
-            t_max = cache_k.shape[1]
-            # single-row attention over the cache; scores/softmax in
-            # f32 like attention_reference so the step matches the
-            # full-window forward. GQA reads the unrepeated cache
-            # through a (kv, group) view of the query heads — no
-            # (B, T, H, Dh) materialization.
-            q5 = q.reshape(b, 1, kv, g, hd).astype(jnp.float32)
-            s = jnp.einsum("bqkgd,btkd->bkgqt", q5,
-                           cache_k.astype(jnp.float32)) / numpy.sqrt(hd)
-            valid = jnp.arange(t_max) <= pos
-            win = getattr(block, "window", None)
-            if win:
-                # sliding window: only the last `win` cached rows are
-                # visible
-                valid = valid & (jnp.arange(t_max) > pos - win)
-            valid = valid[None, None, None, None, :]
-            s = jnp.where(valid, s, -1e30)
-            w = jnp.exp(s - s.max(axis=-1, keepdims=True))
-            w = w / w.sum(axis=-1, keepdims=True)
-            o = jnp.einsum("bkgqt,btkd->bqkgd", w,
-                           cache_v.astype(jnp.float32)
-                           ).astype(x_t.dtype)
-            o = o.reshape(b, 1, h * hd)
+            o, cache_k, cache_v = attend(q, k, v, cache_k, cache_v, pos)
+            o = o.astype(x_t.dtype).reshape(b, 1, h * hd)
         with named_scope("attn_out"):
             proj = jnp.dot(o, p["wo"], precision=prec)
             if tp_axis is not None:

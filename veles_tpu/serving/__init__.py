@@ -95,6 +95,8 @@ SERVING_COUNTERS = (
     "veles_serving_decode_dispatches_total",
     "veles_serving_view_positions_total",
     "veles_serving_steps_ahead_total",
+    "veles_serving_paged_reads_total",
+    "veles_serving_paged_positions_total",
     "veles_serving_tokens_total",
     "veles_serving_expired_total",
     "veles_serving_compile_seconds_total",

@@ -548,6 +548,15 @@ class ContinuousEngine(Logger):
         self.decode_dispatches = 0
         self.view_positions = 0
         self.steps_ahead = 0
+        #: of the decode dispatches, those whose attention read the
+        #: float pool through the page table (``paged_share``); the
+        #: positions their live rows could see, and the positions the
+        #: gather would have read for them (``live_share``)
+        self.paged_reads = 0
+        self.paged_positions = 0
+        self.paged_view_positions = 0
+        #: :meth:`_paged_read` of each block, once the pool is there
+        self._paged: Optional[Tuple[bool, ...]] = None
         self.admitted = 0
         self.retired = 0
         self.peak_slots = 0
@@ -895,6 +904,16 @@ class ContinuousEngine(Logger):
             # tick (speculative or beam rows beside the plain ones)
             "steps_ahead_share": round(
                 self.steps_ahead / max(1, self.decode_dispatches), 4),
+            # the share of decode dispatches whose attention read the
+            # float pool through the page table, and of the positions
+            # the gather would have read for them, those their live
+            # rows could see (docs/services.md "How the decode step
+            # reads the cache"); 0 where no step read so
+            "paged_share": round(
+                self.paged_reads / max(1, self.decode_dispatches), 4),
+            "live_share": round(
+                self.paged_positions / max(1, self.paged_view_positions),
+                4),
             # seconds the chip was KNOWN to have nothing queued before
             # the engine's next program, by the sync that emptied it
             # (the two veles_serving_unfed_* histograms' sums)
@@ -1986,11 +2005,13 @@ class ContinuousEngine(Logger):
             self._push_tokens(held, overlapped)
 
     # -- the decode chunk ------------------------------------------------------
-    def _count_decode_dispatch(self, pages: int,
-                               ahead: bool = False) -> None:
+    def _count_decode_dispatch(self, pages: int, ahead: bool = False,
+                               seen: Optional[int] = None) -> None:
         """One decode dispatch (the step, a speculative round or a
         beam step) whose views were ``pages`` pages long; ``ahead``
-        where the step before it was still unread."""
+        where the step before it was still unread; ``seen``, for a step
+        that read through the page table, the positions its live rows
+        could see, summed over rows and the chunk's iterations."""
         positions = pages * self.page_size
         self.decode_dispatches += 1
         self.view_positions += positions
@@ -1999,6 +2020,26 @@ class ContinuousEngine(Logger):
         if ahead:
             self.steps_ahead += 1
             inc("veles_serving_steps_ahead_total")
+        if seen is not None:
+            self.paged_reads += 1
+            self.paged_positions += seen
+            self.paged_view_positions += (positions * self.max_slots
+                                          * self.decode_block)
+            inc("veles_serving_paged_reads_total")
+            inc("veles_serving_paged_positions_total", seen)
+
+    def _seen(self, mask) -> int:
+        """The positions the masked-in rows of a step can see, reckoned
+        from where each row stands (``_pos``): a row at ``p`` sees ``p +
+        j + 1`` at the chunk's iteration ``j``, or the last ``window``
+        of them where every paged block keeps one (the least)."""
+        pos = self._pos[mask > 0].astype(numpy.int64)
+        seen = pos[:, None] + numpy.arange(1, self.decode_block + 1)
+        windows = [getattr(b, "window", None) or 0 for b, on in
+                   zip(self.stack["blocks"], self._paged_blocks()) if on]
+        if min(windows, default=0):
+            seen = numpy.minimum(seen, min(windows))
+        return int(seen.sum())
 
     def _decode(self, params) -> None:
         """Dispatch the plain step n+1, THEN read, record and emit step
@@ -2099,9 +2140,12 @@ class ContinuousEngine(Logger):
                 del host
             self._flying = (toks, active, self._dispatched)
             self._fresh[:] = False
+            seen = (self._seen(mask) if any(self._paged_blocks())
+                    else None)
             for slot in active:
                 self._pos[slot.idx] += block
-            self._count_decode_dispatch(pages, ahead=flying is not None)
+            self._count_decode_dispatch(pages, ahead=flying is not None,
+                                        seen=seen)
             self._push_held(overlapped=True, phase=True)
             if flying is not None:
                 self._land(flying)
@@ -2709,6 +2753,66 @@ class ContinuousEngine(Logger):
 
         return row
 
+    def _paged_read(self, blk) -> bool:
+        """THE predicate of the float step's attention read for ``blk``:
+        through the page table, in one Pallas call that reads the live
+        pages alone (``ops/paged_attention.py``), where the chip is a
+        TPU, the pool is a float pool, the block has no ``serve_step`` of
+        its own and the kernel compiles for its rows; elsewhere through
+        a gathered view. Under ``--serve-tp`` each shard's kernel reads
+        its own KV heads of the pool."""
+        import jax
+        from ..ops import paged_attention
+        if self.quant_kv or hasattr(blk, "serve_step"):
+            return False
+        kv, hd, _, _ = self._geometry(blk)
+        dtype = self._caches[self.stack["blocks"].index(blk)][0].dtype
+        return (jax.default_backend() == "tpu"
+                and paged_attention.supported(hd, self.page_size,
+                                              kv // self.tp, dtype))
+
+    def _paged_blocks(self) -> Tuple[bool, ...]:
+        """:meth:`_paged_read` of each block, decided once the pool is
+        there (the step's builder and the dispatch's counters ask)."""
+        if self._paged is None:
+            self._paged = tuple(self._paged_read(b)
+                                for b in self.stack["blocks"])
+        return self._paged
+
+    def _paged_attend(self, blk, kp, vp, tables, chunk_pos, j):
+        """``_block_step``'s attention read for ``blk`` at the chunk's
+        iteration ``j``, every row at once: the pool's positions before
+        the chunk's first (``chunk_pos``), through ``tables``, and the
+        rows that the chunk has made so far, the row's own among them,
+        which its carry holds (S, decode_block, kv, hd) until the chunk
+        is written back. A masked-out row's result is discarded."""
+        import jax.numpy as jnp
+        from ..ops import matmul_precision
+        from ..ops.paged_attention import paged_decode_attention
+        from jax import lax
+        window = getattr(blk, "window", None)
+        made = jnp.arange(self.decode_block)
+        end = jnp.minimum(chunk_pos, tables.shape[1] * self.page_size)
+        # the products' operands as the configuration states them:
+        # Precision.DEFAULT's bfloat16 on the chip (None), else float32
+        operand = (None if matmul_precision() == lax.Precision.DEFAULT
+                   else jnp.float32)
+
+        def attend(q, k, v, rows_k, rows_v, pos):
+            rows_k = rows_k.at[:, j].set(k[:, 0].astype(rows_k.dtype))
+            rows_v = rows_v.at[:, j].set(v[:, 0].astype(rows_v.dtype))
+            ok = jnp.broadcast_to(made <= j, rows_k.shape[:2])
+            start = jnp.zeros_like(pos)
+            if window:
+                ok = ok & (chunk_pos[:, None] + made > pos[:, None] - window)
+                start = jnp.maximum(pos - window + 1, 0)
+            o = paged_decode_attention(q[:, 0], kp, vp, tables, start, end,
+                                       rows_k, rows_v, ok,
+                                       operand_dtype=operand)
+            return o[:, None], rows_k, rows_v
+
+        return attend
+
     def _scatter_prompt(self, pool, rows, table_row, bucket, scales=None):
         """Write a bucket's prefill K or V rows page-wise into the
         pool: (bucket, kv, hd) padded up to whole pages and scattered
@@ -2895,7 +2999,11 @@ class ContinuousEngine(Logger):
         through the same ``_row_targets`` the int8 pool and the
         speculative round write through (a masked row, a row inside a
         shared page and a row past the view's end target the sink
-        page). Per-row sampling draws from each slot's private
+        page). Where :meth:`_paged_read` says so (a TPU, a standing
+        block), a block gets no view: its attention reads the pool
+        through the tables in one Pallas call, beside the rows the
+        chunk has made so far, which the scan carries until that same
+        write-back. Per-row sampling draws from each slot's private
         key stream, advanced ONLY for masked-in rows, so a row's
         noise is a pure function of its request's seed whatever other
         modes share the pool. Under ``quant_kv`` each scan iteration
@@ -2920,6 +3028,7 @@ class ContinuousEngine(Logger):
         tp, tp_axis = self.tp, self._tp_axis
         rings = {b.name: self._geometry(b)[3] for b in blocks}
         tap_names = self._tap_names
+        paged = self._paged_blocks()
 
         def embed_rows(params, tok, pos):
             from ..nn.sampling import _embed_ids
@@ -2970,13 +3079,21 @@ class ContinuousEngine(Logger):
                 # are scratch: each iteration also yields the ONE row
                 # it wrote per slot, block and tensor, and only those
                 # rows are scattered into the pool at chunk end — the
-                # pages a step did not write are never rewritten.
+                # pages a step did not write are never rewritten. A
+                # block read through the page table (``paged``) gets
+                # no view: the scan carries the chunk's rows it made,
+                # which the pool holds only once they are written back
                 views = []
-                for blk, (kp, vp) in zip(blocks, caches):
+                for blk, (kp, vp), on in zip(blocks, caches, paged):
                     if rings[blk.name]:
                         # a window layer's ring IS every slot's view:
                         # no table, no gather; the step writes into it
                         views.append((kp, vp))
+                        continue
+                    if on:
+                        rows0 = jnp.zeros((tok.shape[0], self.decode_block)
+                                          + kp.shape[2:], kp.dtype)
+                        views.append((rows0, rows0))
                         continue
                     views.append((
                         jax.vmap(lambda t, kp=kp: self._view(kp, t))(
@@ -2984,14 +3101,15 @@ class ContinuousEngine(Logger):
                         jax.vmap(lambda t, vp=vp: self._view(vp, t))(
                             tables)))         # each (S, T, kv, hd)
 
-                def body(carry, _):
+                def body(carry, j):
                     tok, pos, keys, vws = carry
                     x = embed_rows(params, tok, pos)
                     new_vws, rows = [], []
                     # what the blocks' expert layers count of this
                     # step (nothing, for a stack without one)
                     with steptaps.collecting() as counted:
-                        for blk, (ck, cv) in zip(blocks, vws):
+                        for blk, (ck, cv), (kp, vp), on in zip(
+                                blocks, vws, caches, paged):
                             p = params[blk.name]
                             if hasattr(blk, "serve_step"):
                                 # the block's own one-position step, every
@@ -3000,6 +3118,16 @@ class ContinuousEngine(Logger):
                                     p, x, ck, cv, pos, mask > 0)
                                 new_vws.append((ck, cv))
                                 rows.append((kn, vn))
+                                continue
+                            if on:
+                                y, ck, cv = _block_step(
+                                    blk, p, x[:, None, :], ck, cv, pos,
+                                    tp=tp, tp_axis=tp_axis,
+                                    attend=self._paged_attend(
+                                        blk, kp, vp, tables, chunk_pos, j))
+                                x = y[:, 0]
+                                new_vws.append((ck, cv))
+                                rows.append((ck[:, j], cv[:, j]))
                                 continue
 
                             def row(x_row, ck_row, cv_row, pos_row,
@@ -3030,9 +3158,10 @@ class ContinuousEngine(Logger):
                     return ((nxt, pos2, keys, tuple(new_vws)),
                             (out, pos, tuple(rows)))
 
+                chunk_pos = pos
                 (_, _, keys, vws), (toks, wpos, rows) = jax.lax.scan(
-                    body, (tok, pos, keys, tuple(views)), None,
-                    length=self.decode_block)
+                    body, (tok, pos, keys, tuple(views)),
+                    jnp.arange(self.decode_block))
                 # row write-back, (decode_block, S) targets at once.
                 # Sent to the sink page instead of the pool: a masked
                 # row; a row inside one of its slot's leading SHARED

@@ -137,6 +137,15 @@ DESCRIPTIONS = {
         "Those of the decode dispatches issued while the step before "
         "was still unread: the device did not wait for the host "
         "between the two",
+    "veles_serving_paged_reads_total":
+        "Those of the decode dispatches whose attention read the float "
+        "pool through the page table (the veles_paged_attn kernel), no "
+        "view gathered; over the dispatches, the stats' paged_share",
+    "veles_serving_paged_positions_total":
+        "Positions the live rows of those dispatches could see, summed "
+        "over rows and a chunk's iterations (reckoned on the host from "
+        "each row's position); over the view positions the gather would "
+        "have read for every slot, the stats' live_share",
     "veles_serving_tokens_total":
         "Tokens emitted by the continuous-batching engine",
     "veles_serving_token_pushes_total":
