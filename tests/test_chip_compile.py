@@ -116,3 +116,80 @@ def test_paged_read_lowers_on_a_described_v5e(dtype, one_chip,
     assert "veles_paged_attn" in text and "tpu_custom_call" in text
     # temporaries of a few (S, H, hd) states, nothing of a pool's size
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+def test_the_hybrid_state_step_copies_no_pool(one_chip, no_compile_cache):
+    """The continuous engine's decode step for ``olmo-hybrid-7b`` at its
+    widths and the cell's 64 slots and 1,536 positions, one period of its
+    layers (three delta-rule layers, one full layer; the vocabulary cut,
+    which only the head sees): the delta-rule states (64, 30, 96, 192) in
+    float32 and the full layer's pages (30 KV heads stored as 32,
+    ``nn/hybrid.stored_heads``) are read and written where they lie, with
+    no copy of any of them (at 30 heads XLA converted both page pools
+    whole, to and fro, every step)."""
+    import sys
+    import jax
+    import jax.numpy as jnp
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench import modules
+    from veles_tpu import nn
+    from veles_tpu.config import root as config
+    from veles_tpu.serving import ContinuousEngine
+    with open(os.path.join(root, "chipbench", "configs",
+                           "olmo-hybrid-7b.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=4, vocab_size=2048)
+    ref = modules.reference_of(cfg, serving=True)
+    kinds = {"embedding": nn.Embedding, "hybrid_block": nn.HybridBlock,
+             "final_norm": nn.FinalNorm, "lm_head": nn.LMHead}
+    units = [kinds[layer["type"]](None, **{k: v for k, v in layer.items()
+                                           if k != "type"})
+             for layer in ref.layer_list(cfg)]
+
+    class Stack:
+        forwards = units
+
+    slots = 64
+    keep = config.common.engine.precision_type
+    config.common.engine.precision_type = "bfloat16"
+    try:
+        eng = ContinuousEngine(Stack, max_slots=slots,
+                               buckets=(256, 512, 1024), max_context=1536,
+                               name="olmo_compile")
+
+        def spec(shape, dt):
+            return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
+                                        sharding=one_chip)
+        params = {u: {k: spec(s, "bfloat16") for k, (s, _) in
+                      ref.unit_shapes(cfg, u).items()}
+                  for u in ref.unit_names(cfg)}
+        caches, big = [], set()
+        for blk in eng.stack["blocks"]:
+            state = eng._state(blk)
+            if state:
+                pair = (spec((slots,) + tuple(state[0]), "float32"),
+                        spec((slots,) + tuple(state[1]), "bfloat16"))
+            else:
+                kv, kd, vd, _ = eng._geometry(blk)
+                assert kv == 32
+                rows = eng.page_pool.device_rows
+                pair = (spec((rows, 16, kv, kd), "bfloat16"),
+                        spec((rows, 16, kv, vd), "bfloat16"))
+            caches.append(pair)
+            big.add(",".join(map(str, pair[0].shape)))
+        width = eng.view_ladder[0]
+        ints = [spec((slots,), "int32")] * 2
+        compiled = eng._build_decode().lower(
+            params, *ints, spec((slots,), "float32"), ints[0],
+            spec((slots, width), "int32"), ints[0],
+            spec((1, slots + len(eng._tap_names)), "int32"),
+            spec((slots, 2), "uint32"), tuple(caches)).compile()
+    finally:
+        config.common.engine.precision_type = keep
+    copies = re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(",
+                        compiled.as_text())
+    assert big == {"64,30,96,192", "6145,16,32,128"}
+    assert not big & set(copies), copies
+    # beside the pools: a view of a full layer's K and V and smaller
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

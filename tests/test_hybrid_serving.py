@@ -485,8 +485,8 @@ def test_a_refused_mode_is_a_400_and_not_the_window_plane(wf):
 def test_a_mixer_without_rows_is_not_served():
     from veles_tpu.error import VelesError
     from veles_tpu.nn.hybrid import HybridBlock
-    blk = HybridBlock(None, mixer="delta_rule", name="blk9")
+    blk = HybridBlock(None, mixer="attention", name="blk9")
     with pytest.raises(VelesError) as refused:
         blk.cache_geometry(64, 16)
-    assert "blk9" in str(refused.value) and "delta_rule" in str(
+    assert "blk9" in str(refused.value) and "'attention'" in str(
         refused.value)

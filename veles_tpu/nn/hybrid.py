@@ -1,7 +1,11 @@
-"""HybridBlock: a pre-norm residual block that is told its mixer.
+"""HybridBlock: a residual block that is told its mixer and where its
+norms stand.
 
-    h   = x + mixer(norm1(x))
+    h   = x + mixer(norm1(x))              (norm="pre")
     out = h + ffn(norm2(h))
+
+    h   = x + norm1(mixer(x))              (norm="post", Olmo's)
+    out = h + norm2(ffn(h))
 
 ``mixer`` is ``"attention"`` (gated softmax attention: a sigmoid output
 gate beside the query, RMS norms on q and k, rotary on a leading part of
@@ -12,8 +16,13 @@ heads, its rotary part and base, its window, a learned sink a head and a
 scale on the values). The feed-forward is the sparse experts of
 ``nn/experts.py`` (``ffn="experts"``: told which experts it holds, its
 router softmax or sigmoid, with or without a shared expert) or a dense
-SwiGLU (``ffn="dense"``). The norms are zero-centred:
-``x / rms(x) * (1 + w)``, ``w`` nought at the start.
+SwiGLU (``ffn="dense"``). Before the sublayers the norms are
+zero-centred, ``x / rms(x) * (1 + w)`` with ``w`` nought at the start;
+after them plain, ``x / rms(x) * w`` with ``w`` one at the start. The
+softmax mixer may also normalise its whole q and its whole k projection
+before they are split into heads (``qk_norm``) and may leave the rotary
+out (``rotary_factor`` 0); the delta rule's ``beta`` may reach 2
+(``beta_scale``). ``FinalNorm`` is the plain norm before the head.
 
 One chip's share of a layer: with ``experts_held`` the unit holds some of
 the ``n_experts`` that its router scores, and what the others would add
@@ -22,13 +31,15 @@ loss's.
 
 Training: the unit has ``apply`` (traced by ``TrainStep`` and
 differentiated by ``jax.grad`` like every unit). Serving: a block whose
-mixer is ``"softmax"`` also states its own ``serve_prefill`` and its own
-one-position ``serve_step`` with a cache argument, on the same
-``softmax_mixer`` and the same feed-forward as ``apply`` (one definition
-of the equations); ``serving/engine.py`` asks ``cache_geometry`` what a
-slot holds of the layer: pages of K and V rows, or, with a window, a ring
-of ``ring_positions`` rows addressed by position modulo its length. The
-other two mixers keep no per-slot state yet and are not served.
+mixer is ``"softmax"`` or ``"delta_rule"`` also states its own
+``serve_prefill`` and its own one-position ``serve_step`` with a cache
+argument, on the same ``_block`` as ``apply`` (one definition of the
+equations); ``serving/engine.py`` asks ``cache_geometry`` what a slot
+holds of the layer: pages of K and V rows; with a window, a ring of
+``ring_positions`` rows addressed by position modulo its length; of a
+delta-rule layer, its state and the convolution's tail, a row a slot,
+whatever the context. The gated attention mixer keeps no per-slot state
+yet and is not served.
 
 Named scopes in ``apply`` go mechanism first, block second
 (``delta_rule/blk2``, ``experts/blk0``): in a train step jax puts
@@ -73,6 +84,32 @@ def stored_width(width):
     return width if width <= LANES else -(-width // LANES) * LANES
 
 
+#: KV heads of a page a cache keeps as they are; beyond, a multiple of this
+HEAD_TILE = 16
+
+
+def stored_heads(heads):
+    """The KV heads a page pool keeps rows of ``heads`` heads at: up to 8
+    as they are, beyond that the next multiple of ``HEAD_TILE``, padded
+    with noughts. A page of (16 positions, 30 heads, 128) gets a layout
+    on a TPU that puts the positions second-minor (30 heads fill no tile
+    of 16 rows): the step's gather wants the heads there, and XLA
+    converts both pools whole, to and fro, in every step (four 755 MB
+    copies at ``olmo-hybrid-7b``'s widths, compiled for a described
+    v5e, PR 40). At 32 the layout is the rows' own."""
+    if heads <= 8 or heads % HEAD_TILE == 0:
+        return heads
+    return -(-heads // HEAD_TILE) * HEAD_TILE
+
+
+def _pad_heads(x, heads):
+    """x (..., KV, D) padded with nought heads to ``heads``."""
+    import jax.numpy as jnp
+    pad = heads - x.shape[-2]
+    return x if pad <= 0 else jnp.pad(
+        x, ((0, 0),) * (x.ndim - 2) + ((0, pad), (0, 0)))
+
+
 def _pad_last(x, width):
     import jax.numpy as jnp
     pad = width - x.shape[-1]
@@ -87,6 +124,21 @@ def _scopes(*names):
     for name in names:
         stack.enter_context(named_scope(name))
     return stack
+
+
+#: what a served decode step counts of its delta-rule layers: live rows x
+#: such layers, the states it read and wrote (a ``steptaps`` counter)
+STATE_ROWS = "veles_serving_state_rows_total"
+
+
+def rms_norm(x, w, eps):
+    """``x / rms(x) * w`` in float32, returned in x's type."""
+    import jax
+    import jax.numpy as jnp
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
+                            + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
 
 
 def zero_centred_norm(x, w, eps):
@@ -146,31 +198,38 @@ def gated_attention_mixer(p, x, *, n_heads, n_kv_heads, head_dim,
 
 def softmax_mixer(p, x, attend, *, n_heads, n_kv_heads, head_dim,
                   v_head_dim, rope_base, rotary_dims, value_scale,
-                  precision, scope, attn_scope, positions=None):
+                  precision, scope, attn_scope, positions=None,
+                  qk_norm_eps=None):
     """(B, T, D) -> ((B, T, D), k, v) on ``wq`` (D, H x Dk), ``wk``
     (D, KV x Dk), ``wv`` (D, KV x Dv) and ``wo`` (H x Dv, D): the keys
-    rotated at ``positions`` and the values times ``value_scale``, as the
-    cache holds them. ``attend(q, k, v)`` is the softmax over whatever
-    the caller may see (the sequence itself, or a slot's cache with the
-    new row in it) and returns (B, T, H, Dv); it runs under the scope
-    ``attn_scope``."""
+    rotated at ``positions`` (none where ``rotary_dims`` is 0) and the
+    values times ``value_scale``, as the cache holds them. With
+    ``qk_norm_eps`` the whole q and the whole k projection are
+    normalised (``rms_norm`` on ``q_norm`` (H x Dk) and ``k_norm``
+    (KV x Dk)) before the split into heads. ``attend(q, k, v)`` is the
+    softmax over whatever the caller may see (the sequence itself, or a
+    slot's cache with the new row in it) and returns (B, T, H, Dv); it
+    runs under the scope ``attn_scope``."""
     import jax.numpy as jnp
     b, t, _ = x.shape
     h, kv = n_heads, n_kv_heads
     with scope("attn_qkv"):
-        q = jnp.dot(x, p["wq"], precision=precision).reshape(
-            b, t, h, head_dim)
-        k = jnp.dot(x, p["wk"], precision=precision).reshape(
-            b, t, kv, head_dim)
+        def projected(name, heads):
+            y = jnp.dot(x, p["w" + name], precision=precision)
+            if qk_norm_eps is not None:
+                y = rms_norm(y, p[name + "_norm"], qk_norm_eps)
+            return y.reshape(b, t, heads, head_dim)
+        q, k = projected("q", h), projected("k", kv)
         v = jnp.dot(x, p["wv"], precision=precision).reshape(
             b, t, kv, v_head_dim)
         if value_scale != 1.0:
             v = (v.astype(jnp.float32) * value_scale).astype(x.dtype)
-    with scope("rope"):
-        q = partial_rope(jnp, q.astype(jnp.float32), rope_base,
-                         rotary_dims, positions).astype(x.dtype)
-        k = partial_rope(jnp, k.astype(jnp.float32), rope_base,
-                         rotary_dims, positions).astype(x.dtype)
+    if rotary_dims:
+        with scope("rope"):
+            q = partial_rope(jnp, q.astype(jnp.float32), rope_base,
+                             rotary_dims, positions).astype(x.dtype)
+            k = partial_rope(jnp, k.astype(jnp.float32), rope_base,
+                             rotary_dims, positions).astype(x.dtype)
     with scope(attn_scope):
         o = attend(q, k, v).reshape(b, t, h * v_head_dim)
     with scope("attn_out"):
@@ -189,7 +248,8 @@ def dense_swiglu(p, x, precision):
 
 
 class HybridBlock(ForwardBase):
-    """(B, T, D) -> (B, T, D): ``mixer`` then the feed-forward, pre-norm."""
+    """(B, T, D) -> (B, T, D): ``mixer`` then the feed-forward, with the
+    norms before them (``norm="pre"``) or after them (``"post"``)."""
 
     MAPPING = "hybrid_block"
     PARAMETERIZED = True
@@ -222,7 +282,7 @@ class HybridBlock(ForwardBase):
                  shared_hidden=0, eps=1e-6, v_head_dim=0, window=0,
                  sink=False, value_scale=1.0, ffn="experts",
                  dense_hidden=0, router="softmax", shared_expert=True,
-                 **kwargs):
+                 norm="pre", qk_norm=False, beta_scale=1.0, **kwargs):
         super().__init__(workflow, **kwargs)
         if mixer not in ("attention", "delta_rule", "softmax"):
             raise ValueError("mixer must be 'attention', 'delta_rule' or "
@@ -231,6 +291,14 @@ class HybridBlock(ForwardBase):
             raise ValueError("ffn must be 'experts' or 'dense'")
         if router not in ("softmax", "sigmoid"):
             raise ValueError("router must be 'softmax' or 'sigmoid'")
+        if norm not in ("pre", "post"):
+            raise ValueError("norm must be 'pre' or 'post'")
+        #: where the norms stand, whether the softmax mixer normalises its
+        #: whole q and k projections, and what the delta rule's sigmoid
+        #: ``beta`` is multiplied by (2: it may reach 2)
+        self.norm_place = norm
+        self.qk_norm = bool(qk_norm)
+        self.beta_scale = float(beta_scale)
         # (``router`` and ``sink`` are leaves' names, and a leaf is an
         # attribute of its unit)
         self.mixer, self.ffn, self.router_kind = mixer, ffn, router
@@ -299,7 +367,9 @@ class HybridBlock(ForwardBase):
         f = self.expert_hidden or 4 * d
         sf = self.shared_hidden or f
         held = len(self.experts_held)
-        shapes = {"ln1_w": ((d,), "zeros"), "ln2_w": ((d,), "zeros")}
+        # a norm after a sublayer is plain, its weight one at the start
+        ln = "ones" if self.norm_place == "post" else "zeros"
+        shapes = {"ln1_w": ((d,), ln), "ln2_w": ((d,), ln)}
         if self.mixer == "attention":
             hd = self.head_dim or d // self.n_heads
             q_d, kv_d = self.n_heads * hd, self.n_kv_heads * hd
@@ -316,6 +386,9 @@ class HybridBlock(ForwardBase):
                           wo=((self.n_heads * vd, d), "normal"))
             if self.has_sink:
                 shapes.update(sink=((self.n_heads,), "normal"))
+            if self.qk_norm:
+                shapes.update(q_norm=((self.n_heads * hd,), "ones"),
+                              k_norm=((self.n_kv_heads * hd,), "ones"))
         else:
             kd = self.n_key_heads * self.key_dim
             vd = self.n_value_heads * self.value_dim
@@ -385,20 +458,24 @@ class HybridBlock(ForwardBase):
         return _scopes(self.name, part)
 
     def _block(self, params, x, scope, attend=None, positions=None,
-               live=None, block_rows=None):
+               live=None, block_rows=None, carry=None):
         """The block's equations, once: ``apply`` and the two served
         forms differ in what ``attend`` sees, in the rows' positions, in
-        which rows are ``live`` (the others reach no expert) and in the
-        scopes' order. Returns (out, k, v); k and v are None but for the
-        softmax mixer."""
+        which rows are ``live`` (the others reach no expert and leave a
+        delta-rule state alone), in the delta rule's ``carry`` (a slot's
+        state and tail, or (None, None) for a prompt) and in the scopes'
+        order. Returns (out, k, v); k and v are the softmax mixer's rows
+        or, served, the delta rule's state and tail, else None."""
         from ..ops import matmul_precision
         from .attention import blocked_causal_attention
         from .delta_rule import delta_rule_mixer
         from .experts import BLOCK_ROWS, sparse_experts
         prec = matmul_precision()
+        post = self.norm_place == "post"
         k = v = None
         with scope("norm1"):
-            a_in = zero_centred_norm(x, params["ln1_w"], self.eps)
+            a_in = x if post else zero_centred_norm(
+                x, params["ln1_w"], self.eps)
         if self.mixer == "attention":
             hd = self.head_dim or x.shape[-1] // self.n_heads
             mixed = gated_attention_mixer(
@@ -420,19 +497,32 @@ class HybridBlock(ForwardBase):
                 rope_base=self.rope_base, rotary_dims=rotary,
                 value_scale=self.value_scale, precision=prec, scope=scope,
                 attn_scope="window_attn" if self.window else "full_attn",
-                positions=positions)
+                positions=positions,
+                qk_norm_eps=self.eps if self.qk_norm else None)
         else:
             mixed = delta_rule_mixer(
                 params, a_in, n_k=self.n_key_heads, n_v=self.n_value_heads,
                 dk=self.key_dim, dv=self.value_dim, eps=self.eps,
-                precision=prec, scope=scope)
+                precision=prec, scope=scope, beta_scale=self.beta_scale,
+                carry=carry, live=None if carry is None
+                else live.reshape(x.shape[:2]))
+            if carry is not None:
+                mixed, k, v = mixed
+        if post:
+            with scope("norm1"):
+                mixed = rms_norm(mixed, params["ln1_w"], self.eps)
         with scope("mixer_out"):
             x = x + mixed.astype(x.dtype)
         with scope("norm2"):
-            f_in = zero_centred_norm(x, params["ln2_w"], self.eps)
+            f_in = x if post else zero_centred_norm(
+                x, params["ln2_w"], self.eps)
         if self.ffn == "dense":
             with scope("ffn"):
-                return x + dense_swiglu(params, f_in, prec), k, v
+                y = dense_swiglu(params, f_in, prec)
+                if not post:
+                    return x + y, k, v
+            with scope("norm2"):
+                return x + rms_norm(y, params["ln2_w"], self.eps), k, v
         local_of = numpy.full((self.n_experts,), -1, numpy.int32)
         local_of[list(self.experts_held)] = numpy.arange(
             len(self.experts_held), dtype=numpy.int32)
@@ -442,6 +532,9 @@ class HybridBlock(ForwardBase):
                            scope=scope, block=block_rows or BLOCK_ROWS,
                            router=self.router_kind, shared=self.shared_expert,
                            live=live)
+        if post:
+            with scope("norm2"):
+                y = rms_norm(y, params["ln2_w"], self.eps)
         with scope("experts_out"):
             return x + y, k, v
 
@@ -455,29 +548,46 @@ class HybridBlock(ForwardBase):
         ``stored_width``), and how many positions of
         them a slot keeps where the window bounds that (``ring``: the
         window's pages and one more, so that a prompt's last page can be
-        written whole; 0: all of them, in pages). Raises for a mixer
-        that keeps no such rows."""
+        written whole; 0: all of them, in pages). A delta-rule layer
+        keeps {"state", "tail"} instead, a row of each a slot: the state
+        (value heads, key width, value width) in float32 and the
+        convolution's last ``conv_taps - 1`` inputs (positions,
+        channels). Raises for a mixer that keeps neither."""
         from ..error import VelesError
+        if self.mixer == "delta_rule":
+            width = (2 * self.n_key_heads * self.key_dim
+                     + self.n_value_heads * self.value_dim)
+            return {"state": (self.n_value_heads, self.key_dim,
+                              self.value_dim),
+                    "tail": (self.conv_taps - 1, width)}
         if self.mixer != "softmax":
             raise VelesError(
                 "%s (%s): a hybrid_block is served with mixer='softmax' "
-                "only; mixer=%r keeps no K/V rows and has no per-slot "
-                "state for the engines yet" % (
+                "or 'delta_rule' only; mixer=%r keeps no K/V rows and "
+                "has no per-slot state for the engines yet" % (
                     self.name, type(self).__name__, self.mixer))
         hd, vd, _ = self._widths(d)
         pages = -(-self.window // page_size) + 1 if self.window else 0
-        return {"kv_heads": self.n_kv_heads, "k_dim": stored_width(hd),
-                "v_dim": stored_width(vd), "ring": pages * page_size}
+        return {"kv_heads": stored_heads(self.n_kv_heads),
+                "k_dim": stored_width(hd), "v_dim": stored_width(vd),
+                "ring": pages * page_size}
 
     def serve_prefill(self, params, x, live=None):
         """(1, T, D) -> (out, k (1, T, KV, Dk), v (1, T, KV, Dv)): the
         whole prompt at once, its K and V rows as the cache keeps them.
         ``live`` (T,) bool: the rows that are the prompt's (the bucket's
-        padding reaches no expert)."""
+        padding reaches no expert). A delta-rule layer returns (out,
+        state (1, H, K, V), tail (1, taps - 1, C)) at the prompt's last
+        position instead: the padding leaves both alone."""
+        if self.mixer == "delta_rule":
+            return self._block(params, x, self._serve_scope, live=live,
+                               carry=(None, None))
         out, k, v = self._block(params, x, self._serve_scope, live=live,
                                 block_rows=self.PREFILL_BLOCK_ROWS)
-        return (out, _pad_last(k, stored_width(k.shape[-1])),
-                _pad_last(v, stored_width(v.shape[-1])))
+        heads = stored_heads(k.shape[-2])
+        return (out, _pad_heads(_pad_last(k, stored_width(k.shape[-1])),
+                                heads),
+                _pad_heads(_pad_last(v, stored_width(v.shape[-1])), heads))
 
     def serve_step(self, params, x, cache_k, cache_v, pos, live):
         """One position a row. x (S, D); ``cache_k`` (S, Tc, KV, Dk) and
@@ -485,9 +595,21 @@ class HybridBlock(ForwardBase):
         pages in order, or its ring; ``pos`` (S,) the position each row
         stands at (its tokens already cached); ``live`` (S,) bool.
         Returns (out (S, D), cache_k, cache_v with the row written, k_new
-        (S, KV, Dk), v_new (S, KV, Dv))."""
+        (S, KV, Dk), v_new (S, KV, Dv)). A delta-rule layer takes every
+        slot's state (S, H, K, V) and tail (S, taps - 1, C) as
+        ``cache_k`` and ``cache_v`` and returns them advanced a token
+        where ``live`` and as they were elsewhere, with no new rows
+        (None, None); it counts its live rows (``STATE_ROWS``)."""
         import jax.numpy as jnp
         from .attention import masked_attention
+        if self.mixer == "delta_rule":
+            from ..telemetry import steptaps
+            out, state, tail = self._block(
+                params, x[:, None, :], self._serve_scope, live=live,
+                carry=(cache_k, cache_v))
+            steptaps.emit(steptaps.counter_key(STATE_ROWS),
+                          jnp.sum(live.astype(jnp.float32)))
+            return out[:, 0], state, tail, None, None
         s_rows, t_c = cache_k.shape[:2]
         ring = bool(self.window)
         at = pos % t_c if ring else jnp.clip(pos, 0, t_c - 1)
@@ -503,14 +625,20 @@ class HybridBlock(ForwardBase):
 
         def attend(q, k, v):
             # rows as the cache stores them: padded with noughts, which
-            # add nothing to a score or to a value's sum
+            # add nothing to a score or to a value's sum; the nought heads
+            # are no head's
             k_w, v_w = cache_k.shape[-1], cache_v.shape[-1]
-            box["kn"] = _pad_last(k[:, 0], k_w)
-            box["vn"] = _pad_last(v[:, 0], v_w)
+            heads = cache_k.shape[-2]
+            box["kn"] = _pad_heads(_pad_last(k[:, 0], k_w), heads)
+            box["vn"] = _pad_heads(_pad_last(v[:, 0], v_w), heads)
             box["k"] = cache_k.at[rows, at].set(box["kn"])
             box["v"] = cache_v.at[rows, at].set(box["vn"])
+            keys, values = box["k"], box["v"]
+            if heads != k.shape[-2]:
+                keys = keys[:, :, :k.shape[-2]]
+                values = values[:, :, :k.shape[-2]]
             o = masked_attention(
-                _pad_last(q, k_w), box["k"], box["v"], seen[:, None, :],
+                _pad_last(q, k_w), keys, values, seen[:, None, :],
                 params["sink"] if self.has_sink else None,
                 scale=1.0 / numpy.sqrt(q.shape[-1]))
             return o[..., :v.shape[-1]]
@@ -525,4 +653,40 @@ class HybridBlock(ForwardBase):
 @matches(HybridBlock)
 class GDHybridBlock(GradientDescentBase):
     MAPPING = "gd_hybrid_block"
+    hide_from_registry = False
+
+
+class FinalNorm(ForwardBase):
+    """(B, T, D) -> (B, T, D): the plain RMS norm before the head,
+    ``x / rms(x) * w`` with ``w`` one at the start. ``nn/sampling.py
+    split_stack`` gives it a place between the blocks and the head for
+    the engine that serves ``HybridBlock`` stacks (``stack["norm"]``);
+    the other consumers of a stack refuse it."""
+
+    MAPPING = "final_norm"
+    PARAMETERIZED = True
+    hide_from_registry = False
+    PARAM_NAMES = ("w",)
+
+    def __init__(self, workflow, eps=1e-6, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.eps = float(eps)
+
+    def output_shape_for(self, input_shape):
+        return tuple(input_shape)
+
+    def create_params(self, rng: prng.RandomGenerator) -> Dict[str, Array]:
+        d = self.input.shape[-1]
+        w = numpy.ones((d,), dtype=root.common.engine.precision_type)
+        return {"w": Array(w, name=self.name + ".w")}
+
+    def apply(self, params, x, *, train=False, rng=None):
+        from jax import named_scope
+        with named_scope(self.name):
+            return rms_norm(x, params["w"], self.eps)
+
+
+@matches(FinalNorm)
+class GDFinalNorm(GradientDescentBase):
+    MAPPING = "gd_final_norm"
     hide_from_registry = False

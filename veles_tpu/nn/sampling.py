@@ -78,9 +78,11 @@ def split_stack(forwards, hybrid: bool = False) -> Dict[str, object]:
     and ``serve_step`` (the paged engine); every other consumer (the
     scan sampler, speculation, beam search) runs ``_block_prefill`` and
     ``_block_step``, which are ``TransformerBlock``'s, and refuses the
-    block by name."""
-    from .hybrid import HybridBlock
-    stem = pos_emb = head = None
+    block by name. The same holds for a ``FinalNorm`` before the head
+    (``"norm"``; None for every other stack), which the engine's
+    ``_head_logits`` calls apply."""
+    from .hybrid import FinalNorm, HybridBlock
+    stem = pos_emb = head = norm = None
     blocks: List[TransformerBlock] = []
     for f in forwards:
         if isinstance(f, Embedding):
@@ -98,6 +100,14 @@ def split_stack(forwards, hybrid: bool = False) -> Dict[str, object]:
                     "speculative and beam decoding take transformer_block "
                     "stacks" % f.name)
             blocks.append(f)
+        elif isinstance(f, FinalNorm):
+            if not hybrid:
+                raise VelesError(
+                    "%s is a final_norm: it is served by the continuous "
+                    "engine's greedy and sampled decode step only; the "
+                    "scan sampler, speculative and beam decoding take "
+                    "stacks with no norm before the head" % f.name)
+            norm = f
         elif isinstance(f, LMHead):
             head = f
         else:
@@ -109,7 +119,7 @@ def split_stack(forwards, hybrid: bool = False) -> Dict[str, object]:
         raise VelesError("not a generation stack: stem=%r head=%r "
                          "blocks=%d" % (stem, head, len(blocks)))
     return {"stem": stem, "pos_emb": pos_emb, "blocks": blocks,
-            "head": head}
+            "norm": norm, "head": head}
 
 
 def _block_prefill(block, p, x, cache_k, cache_v, tp=1, tp_axis=None):
@@ -332,15 +342,18 @@ def _prefill_blocks(blocks, params, x, cache_len, dim, tp=1,
     return x, caches
 
 
-def _head_logits(head, params, x_last, prec, tp_axis=None):
+def _head_logits(head, params, x_last, prec, tp_axis=None, norm=None):
     """Vocabulary head projection, shared by the same three consumers
-    as :func:`_embed_prompt`. Under ``tp_axis`` weights/bias are
+    as :func:`_embed_prompt`, after the stack's ``FinalNorm`` where it
+    has one (``norm``). Under ``tp_axis`` weights/bias are
     vocab-column shards: each shard computes its own logit columns
     (bit-exact — every column is one full-depth dot), and a tiled
     all_gather rebuilds the full replicated (…, V) row so sampling
     runs identically on every shard."""
     import jax
     import jax.numpy as jnp
+    if norm is not None:
+        x_last = norm.apply(params[norm.name], x_last)
     with jax.named_scope(head.name), jax.named_scope("head"):
         out = (jnp.dot(x_last, params[head.name]["weights"],
                        precision=prec) + params[head.name]["bias"])

@@ -743,6 +743,7 @@ class GenerationAPI(Unit):
                 "veles_quant_kv_mode": st["quant_kv"],
                 "veles_serving_kv_pool_bytes": st["kv_pool_bytes"],
                 "veles_serving_kv_ring_bytes": st.get("kv_ring_bytes", 0),
+                "veles_serving_state_bytes": st.get("state_bytes", 0),
                 # prefix sharing & chunked prefill (docs/services.md
                 # "Prefix sharing & streaming"): index occupancy and
                 # the per-tick decode stall chunking bounds

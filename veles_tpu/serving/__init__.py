@@ -91,6 +91,7 @@ SERVING_COUNTERS = (
     "veles_serving_retired_total",
     "veles_serving_prefill_dispatches_total",
     "veles_serving_prefill_positions_total",
+    "veles_serving_state_rows_total",
     "veles_serving_unfed_late_reads_total",
     "veles_serving_decode_dispatches_total",
     "veles_serving_view_positions_total",

@@ -319,6 +319,11 @@ class ContinuousEngine(Logger):
                for b in self._own_blocks):
             from ..nn.experts import TOUCHED, tap_keys
             self._tap_names = tap_keys() + [steptaps.counter_key(TOUCHED)]
+        if any(getattr(b, "mixer", None) == "delta_rule"
+               for b in self._own_blocks):
+            # the delta-rule layers' live rows (nn/hybrid.py STATE_ROWS)
+            from ..nn.hybrid import STATE_ROWS
+            self._tap_names.append(steptaps.counter_key(STATE_ROWS))
         self.max_slots = int(max_slots)
         self.max_context = int(max_context)
         self.decode_block = max(1, int(decode_block))
@@ -578,7 +583,8 @@ class ContinuousEngine(Logger):
         block (a ValueError: the operator's knobs do not fit the model,
         and GenerationAPI lets that propagate instead of degrading to
         the window worker). Also checks that every such block keeps K/V
-        rows (``cache_geometry`` raises for one that does not)."""
+        rows or a state row (``cache_geometry`` raises for one that keeps
+        neither)."""
         from ..config import root
         blk = self._own_blocks[0]
         d = self.stack["stem"].dim
@@ -605,13 +611,33 @@ class ContinuousEngine(Logger):
     def _geometry(self, blk):
         """(kv heads, key width, value width, ring positions) of what a
         slot holds of ``blk``: the block's own statement, or the
-        standing block's ``d // n_heads`` rows in pages (ring 0)."""
+        standing block's ``d // n_heads`` rows in pages (ring 0). None
+        for a layer that keeps a state row instead (:meth:`_state`)."""
         d = self.stack["stem"].dim
         if hasattr(blk, "cache_geometry"):
             g = blk.cache_geometry(d, self.page_size)
+            if "state" in g:
+                return None
             return g["kv_heads"], g["k_dim"], g["v_dim"], g["ring"]
         hd = d // blk.n_heads
         return getattr(blk, "n_kv_heads", blk.n_heads), hd, hd, 0
+
+    def _state(self, blk):
+        """(state shape, tail shape) of the row a slot holds of a
+        delta-rule layer (``HybridBlock.cache_geometry``), or None for a
+        layer that keeps K/V rows."""
+        if not hasattr(blk, "cache_geometry"):
+            return None
+        g = blk.cache_geometry(self.stack["stem"].dim, self.page_size)
+        return (g["state"], g["tail"]) if "state" in g else None
+
+    def _slot_row(self, blk):
+        """What ``blk``'s pool holds a slot as one row: "ring" (a window
+        layer's), "state" (a delta-rule layer's) or None (pages, read
+        through the tables)."""
+        if self._state(blk) is not None:
+            return "state"
+        return "ring" if self._geometry(blk)[3] else None
 
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "ContinuousEngine":
@@ -940,9 +966,17 @@ class ContinuousEngine(Logger):
             "kv_ring_bytes": pool_nbytes(
                 [c for b, c in zip(self.stack["blocks"],
                                    self._caches or ())
-                 if self._geometry(b)[3]]),
+                 if self._slot_row(b) == "ring"]),
             "kv_ring_positions": max(
-                [self._geometry(b)[3] for b in self._own_blocks] or [0]),
+                [self._geometry(b)[3] for b in self._own_blocks
+                 if self._slot_row(b) != "state"] or [0]),
+            # of those, what the delta-rule layers' rows hold: a state in
+            # float32 and a convolution's tail a slot, whatever the
+            # slots' contexts (0 for a stack without such a layer)
+            "state_bytes": pool_nbytes(
+                [c for b, c in zip(self.stack["blocks"],
+                                   self._caches or ())
+                 if self._slot_row(b) == "state"]),
             # what ONE chip of the slice actually holds: the kv-head
             # axis shards tp ways (pages.per_shard_kv_heads), so the
             # per-chip HBM is the logical pool over tp — the number
@@ -1358,6 +1392,16 @@ class ContinuousEngine(Logger):
             d = stack["stem"].dim
             out = []
             for blk in stack["blocks"]:
+                state = self._state(blk)
+                if state is not None:
+                    # a delta-rule layer: a row a slot whatever its
+                    # context, the state in float32 and the tail of the
+                    # convolution's inputs in the activations' type
+                    out.append((jnp.zeros((self.max_slots,) + state[0],
+                                          jnp.float32),
+                                jnp.zeros((self.max_slots,) + state[1],
+                                          dtype)))
+                    continue
                 if hasattr(blk, "cache_geometry"):
                     # the block's own heads and widths; a window layer
                     # keeps a ring a slot and no page
@@ -2635,7 +2679,8 @@ class ContinuousEngine(Logger):
         pools = []
         for blk in blocks:
             if hasattr(blk, "cache_geometry"):
-                pools.append(list(self._geometry(blk)))
+                pools.append(list(self._geometry(blk)
+                                  or map(list, self._state(blk))))
                 continue
             bkv = getattr(blk, "n_kv_heads", blk.n_heads)
             pools.append([bkv, d // blk.n_heads])
@@ -2878,7 +2923,8 @@ class ContinuousEngine(Logger):
         quant_w, quant_kv = self.quant_weights, self.quant_kv
         tp, tp_axis = self.tp, self._tp_axis
         own = bool(self._own_blocks)
-        rings = {b.name: self._geometry(b)[3] for b in blocks}
+        held = {b.name: self._slot_row(b) for b in blocks}
+        norm = stack["norm"]
 
         def prefill(params, ids, t_p, slot, temp, seed_key, table_row,
                     keys, caches):
@@ -2897,7 +2943,17 @@ class ContinuousEngine(Logger):
                 live=(jnp.arange(bucket) < t_p) if own else None)
             new_caches = []
             for blk, (ck, cv), pool in zip(blocks, blk_caches, caches):
-                if rings[blk.name]:
+                if held[blk.name] == "state":
+                    # the slot's row whole: a reused slot keeps nothing
+                    with jax.named_scope(blk.name), \
+                            jax.named_scope("state_write"):
+                        new_caches.append(tuple(
+                            jax.lax.dynamic_update_slice(
+                                part, rows.astype(part.dtype),
+                                (slot,) + (0,) * (part.ndim - 1))
+                            for part, rows in zip(pool, (ck, cv))))
+                    continue
+                if held[blk.name]:
                     new_caches.append(tuple(
                         self._ring_prompt(ring, rows[0], t_p, slot)
                         for ring, rows in zip(pool, (ck, cv))))
@@ -2929,7 +2985,7 @@ class ContinuousEngine(Logger):
                     new_caches.append((kp, vp))
             x_last = jnp.take(x[0], t_p - 1, axis=0, mode="clip")
             logits = _head_logits(head, params, x_last, prec,
-                                  tp_axis=tp_axis)
+                                  tp_axis=tp_axis, norm=norm)
             with jax.named_scope("sample"):
                 k2 = jax.random.split(seed_key)
                 greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -3026,7 +3082,10 @@ class ContinuousEngine(Logger):
         prec = matmul_precision()
         quant_w, quant_kv = self.quant_weights, self.quant_kv
         tp, tp_axis = self.tp, self._tp_axis
-        rings = {b.name: self._geometry(b)[3] for b in blocks}
+        # a window layer's ring and a delta-rule layer's state are each
+        # every slot's row of the pool: no table, no gather
+        held = {b.name: self._slot_row(b) for b in blocks}
+        norm = stack["norm"]
         tap_names = self._tap_names
         paged = self._paged_blocks()
 
@@ -3053,7 +3112,7 @@ class ContinuousEngine(Logger):
 
             def sample_next(tok, pos, keys, x):
                 logits = _head_logits(head, params, x, prec,
-                                      tp_axis=tp_axis)        # (S, V)
+                                      tp_axis=tp_axis, norm=norm)  # (S, V)
                 # _split_rows IS the id-exactness contract: the same
                 # carry/subkey convention solo and batched generate
                 # use — advanced only for rows this step owns, so
@@ -3085,9 +3144,10 @@ class ContinuousEngine(Logger):
                 # which the pool holds only once they are written back
                 views = []
                 for blk, (kp, vp), on in zip(blocks, caches, paged):
-                    if rings[blk.name]:
-                        # a window layer's ring IS every slot's view:
-                        # no table, no gather; the step writes into it
+                    if held[blk.name]:
+                        # a window layer's ring (a delta-rule layer's
+                        # state) IS every slot's view: no table, no
+                        # gather; the step writes into it
                         views.append((kp, vp))
                         continue
                     if on:
@@ -3181,8 +3241,9 @@ class ContinuousEngine(Logger):
                     new_caches = []
                     for blk, (kp, vp), (kn, vn), view in zip(
                             blocks, caches, rows, vws):
-                        if rings[blk.name]:
-                            # the ring the scan carried has the rows
+                        if held[blk.name]:
+                            # the ring (the state) the scan carried has
+                            # the rows
                             new_caches.append(view)
                             continue
                         new_caches.append((kp.at[pg, off].set(kn),

@@ -117,6 +117,10 @@ DESCRIPTIONS = {
         "Slot rows retired (eos_id emitted or own n_new reached)",
     "veles_serving_prefill_dispatches_total":
         "Bucketed prefill programs dispatched by the serving engine",
+    "veles_serving_state_rows_total":
+        "Live rows x delta-rule layers of the served decode steps: the "
+        "slot states each step read and wrote (counted inside the step "
+        "and read with its tokens, a step later)",
     "veles_serving_prefill_positions_total":
         "Prompt positions those dispatches of the target model ran "
         "(a prefill's bucket, a chunk's length; the draft's prefill "
