@@ -14,6 +14,8 @@ Sections:
              attn_d256: the 8k training cell's (head size 256, 16 on 2,
              bfloat16 operands)
   generation — KV-cached, speculative, beam and batched decode tokens/s
+  paged_read (by name only) — ops/paged_attention.py alone at each
+             serving cell's widths: ms a call and GB/s of the pages it copies
 
 Run:  python scripts/chip_experiments.py [--sections attn_d128,...]
 Results: chiprun_out/chip_experiments.json (atomic incremental writes
@@ -763,6 +765,102 @@ def sec_generation(dev, n):
     return rows
 
 
+#: the paged decode read at each serving cell's full layers: (slots,
+#: query heads as stored, KV heads as stored, key width as stored, true
+#: key width, value width, pool dtype, table pages, prompt range, output
+#: range)
+PAGED_SHAPES = {
+    "olmo_hybrid_decode_sat": (64, 32, 32, 128, 128, 128, "bfloat16", 96,
+                               (128, 1024), (256, 512)),
+    "mimo_v25_decode_mixed": (64, 64, 4, 256, 192, 128, "bfloat16", 288,
+                              (256, 3840), (256, 512)),
+    "mistral7b_decode_sat": (32, 32, 8, 128, 128, 128, "float32", 64,
+                             (64, 256), (256, 512)),
+}
+
+
+def sec_paged_read(dev, n):
+    """ops/paged_attention.py alone at each serving cell's widths: the
+    kernel's device time a call (ten calls chained in one program, the
+    host's clock over it), the bytes of the pages it copies (the live
+    rows rounded up to pages) and GB/s, and the largest gap of its
+    attention from a float32 einsum over the same rows. A slot's end is
+    its prompt plus a uniform share of its output, both uniform over the
+    cell's ranges."""
+    import numpy
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu.ops import paged_attention as pa
+    reps, chained = 5, 10
+    out = {}
+    for cell, (slots, heads, kv, hd, true_hd, vd, dtype, width, prompts,
+               outputs) in PAGED_SHAPES.items():
+        rng = numpy.random.RandomState(41)
+        ends = (rng.randint(prompts[0], prompts[1] + 1, slots)
+                + (rng.rand(slots) * rng.randint(outputs[0], outputs[1] + 1,
+                                                 slots)).astype(int))
+        ends = numpy.minimum(ends, width * 16)
+        pages = slots * width + 1
+        tables = 1 + numpy.arange(slots * width, dtype=numpy.int32).reshape(
+            slots, width)
+        dt = jnp.dtype(dtype)
+        k_pool = jax.random.normal(jax.random.key(1), (pages, 16, kv, hd),
+                                   jnp.float32).astype(dt)
+        v_pool = jax.random.normal(jax.random.key(2), (pages, 16, kv, vd),
+                                   jnp.float32).astype(dt)
+        q = jax.random.normal(jax.random.key(3), (slots, heads, hd),
+                              jnp.float32)
+        copied = int(numpy.sum(-(-ends // 16))) * 16 * kv * (hd + vd) \
+            * dt.itemsize
+        row = {"slots": slots, "heads": heads, "kv": kv, "hd": hd,
+               "vd": vd, "dtype": dtype, "table_pages": width,
+               "live_mean": float(numpy.mean(ends)), "bytes_copied": copied}
+        ends_d = jnp.asarray(ends, jnp.int32)
+        tables_d = jnp.asarray(tables)
+        starts = jnp.zeros_like(ends_d)
+        scale = float(1 / numpy.sqrt(true_hd))
+
+        @jax.jit
+        def run(q, k_pool, v_pool, tables, starts, ends, scale=scale):
+            def one(i, q_i):
+                acc, m, l = pa.paged_attention(q_i, k_pool, v_pool, tables,
+                                               starts, ends, scale=scale)
+                # a dependency on the call before, no change to q
+                return q_i + l[:, :1, None] * 0.0
+            q = jax.lax.fori_loop(0, chained, one, q)
+            acc, m, l = pa.paged_attention(q, k_pool, v_pool, tables,
+                                           starts, ends, scale=scale)
+            return acc / l[..., None]
+        try:
+            got = jax.block_until_ready(
+                run(q, k_pool, v_pool, tables_d, starts, ends_d))
+            times = []
+            for _ in range(reps):
+                t0 = time.perf_counter()
+                jax.block_until_ready(
+                    run(q, k_pool, v_pool, tables_d, starts, ends_d))
+                times.append((time.perf_counter() - t0) / (chained + 1))
+            ms = 1e3 * float(numpy.median(times))
+            row.update(ms=round(ms, 4), gb_s=round(copied / ms / 1e6, 1),
+                       gb_s_of_peak=round(copied / ms / 1e6 / 819 * 100, 1))
+            # the reference, on a few slots' rows in float32
+            for s in range(4):
+                view_k = k_pool[tables[s]].reshape(-1, kv, hd)[:ends[s]]
+                view_v = v_pool[tables[s]].reshape(-1, kv, vd)[:ends[s]]
+                qs = q[s].reshape(kv, heads // kv, hd)
+                sc = jnp.einsum("kgd,tkd->kgt", qs,
+                                view_k.astype(jnp.float32)) * scale
+                want = jnp.einsum("kgt,tkd->kgd", jax.nn.softmax(sc, -1),
+                                  view_v.astype(jnp.float32))
+                row["max_gap"] = max(row.get("max_gap", 0.0), float(
+                    jnp.max(jnp.abs(want.reshape(heads, vd) - got[s]))))
+        except Exception as e:            # noqa: BLE001 — the next goes on
+            row["error"] = str(e)[-400:]
+        out[cell] = row
+        print("  paged_read %s: %s" % (cell, row), flush=True)
+    return out
+
+
 SECTIONS = [("pallas_compile", sec_pallas_compile),
             ("attn_2048", sec_attn_2048), ("attn_8192", sec_attn_8192),
             ("attn_d128", sec_attn_d128), ("attn_d256", sec_attn_d256),
@@ -778,6 +876,8 @@ def main():
     # manual alias outside the default batch: the split halves cover
     # both lengths, so the full sweep must not run twice by default
     by_name["attn"] = sec_attn
+    # asked for by name alone: it writes no tuning row
+    by_name["paged_read"] = sec_paged_read
     unknown = [name for name in want if name not in by_name]
     if unknown:
         print("unknown section(s) %s" % unknown, file=sys.stderr)

@@ -97,11 +97,13 @@ def assert_shortest_rungs(engine, seen):
 
 
 def force_paged(engine):
-    """``engine`` with its float step reading every standing block
-    through the page table (the kernel, interpreted off the chip), as
-    its predicate decides on a TPU: the predicate forced for this one
-    engine, the way tests/test_flash_attention.py forces flash."""
-    engine._paged_read = lambda blk: not hasattr(blk, "serve_step")
+    """``engine`` with its float step reading every block whose K and V
+    lie in pages through the page table (the kernel, interpreted off the
+    chip), as its predicate decides on a TPU: the predicate forced for
+    this one engine, the way tests/test_flash_attention.py forces flash.
+    A window layer's ring and a delta-rule layer's state keep their
+    rows, and a layer with a learned sink its gathered view."""
+    engine._paged_read = engine._kernel_fits
     return engine
 
 

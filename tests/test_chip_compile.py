@@ -118,18 +118,20 @@ def test_paged_read_lowers_on_a_described_v5e(dtype, one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
-def test_the_hybrid_state_step_copies_no_pool(one_chip, no_compile_cache):
+def _olmo_step(one_chip, layers, paged):
     """The continuous engine's decode step for ``olmo-hybrid-7b`` at its
-    widths and the cell's 64 slots and 1,536 positions, one period of its
-    layers (three delta-rule layers, one full layer; the vocabulary cut,
-    which only the head sees): the delta-rule states (64, 30, 96, 192) in
-    float32 and the full layer's pages (30 KV heads stored as 32,
-    ``nn/hybrid.stored_heads``) are read and written where they lie, with
-    no copy of any of them (at 30 heads XLA converted both page pools
-    whole, to and fro, every step)."""
+    widths and the cell's 64 slots and 1,536 positions, ``layers`` of it
+    (the vocabulary cut, which only the head sees), compiled for a
+    described v5e: (compiled, the pools' shapes as "a,b,c,d"). ``paged``:
+    the full layers read through the page table, as the engine's
+    predicate decides on a TPU (tests/ladder_drill.py ``force_paged``),
+    the kernel lowered for the chip as on a TPU, not interpreted as off
+    one."""
     import sys
+    from unittest import mock
     import jax
     import jax.numpy as jnp
+    from veles_tpu.ops import paged_attention as pa
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
@@ -139,7 +141,7 @@ def test_the_hybrid_state_step_copies_no_pool(one_chip, no_compile_cache):
     from veles_tpu.serving import ContinuousEngine
     with open(os.path.join(root, "chipbench", "configs",
                            "olmo-hybrid-7b.json")) as f:
-        cfg = dict(json.load(f), num_hidden_layers=4, vocab_size=2048)
+        cfg = dict(json.load(f), num_hidden_layers=layers, vocab_size=2048)
     ref = modules.reference_of(cfg, serving=True)
     kinds = {"embedding": nn.Embedding, "hybrid_block": nn.HybridBlock,
              "final_norm": nn.FinalNorm, "lm_head": nn.LMHead}
@@ -178,18 +180,104 @@ def test_the_hybrid_state_step_copies_no_pool(one_chip, no_compile_cache):
                         spec((rows, 16, kv, vd), "bfloat16"))
             caches.append(pair)
             big.add(",".join(map(str, pair[0].shape)))
+        eng._caches = caches
+        if paged:
+            import ladder_drill
+            ladder_drill.force_paged(eng)
         width = eng.view_ladder[0]
         ints = [spec((slots,), "int32")] * 2
-        compiled = eng._build_decode().lower(
-            params, *ints, spec((slots,), "float32"), ints[0],
-            spec((slots, width), "int32"), ints[0],
-            spec((1, slots + len(eng._tap_names)), "int32"),
-            spec((slots, 2), "uint32"), tuple(caches)).compile()
+        read = pa.paged_attention
+
+        def on_chip(*args, **kwargs):
+            return read(*args, **dict(kwargs, interpret=False))
+
+        with mock.patch.object(pa, "paged_attention", on_chip):
+            compiled = eng._build_decode().lower(
+                params, *ints, spec((slots,), "float32"), ints[0],
+                spec((slots, width), "int32"), ints[0],
+                spec((1, slots + len(eng._tap_names)), "int32"),
+                spec((slots, 2), "uint32"), tuple(caches)).compile()
     finally:
         config.common.engine.precision_type = keep
-    copies = re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(",
-                        compiled.as_text())
+    return compiled, big
+
+
+def _copies(compiled):
+    return re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(",
+                      compiled.as_text())
+
+
+def test_the_hybrid_state_step_copies_no_pool(one_chip, no_compile_cache):
+    """The continuous engine's decode step for ``olmo-hybrid-7b`` at its
+    widths and the cell's 64 slots and 1,536 positions, one period of its
+    layers (three delta-rule layers, one full layer; the vocabulary cut,
+    which only the head sees): the delta-rule states (64, 30, 96, 192) in
+    float32 and the full layer's pages (30 KV heads stored as 32,
+    ``nn/hybrid.stored_heads``) are read and written where they lie, with
+    no copy of any of them (at 30 heads XLA converted both page pools
+    whole, to and fro, every step)."""
+    compiled, big = _olmo_step(one_chip, 4, paged=False)
     assert big == {"64,30,96,192", "6145,16,32,128"}
-    assert not big & set(copies), copies
+    assert not big & set(_copies(compiled)), _copies(compiled)
     # beside the pools: a view of a full layer's K and V and smaller
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_the_hybrid_step_reads_its_full_layers_through_the_table(
+        one_chip, no_compile_cache):
+    """The same step at the cell's 8 layers, its two full layers read
+    through the page table as on a TPU: the kernel is in it, no gather of
+    a view is, and no state or page pool is copied; the views' gigabyte of
+    temporaries is gone with the gather. Each full layer's kernel is one
+    device operation named after the scope it runs in, ``full_attn``, the
+    name a profile files its time under (``scope_ms.full_attn``)."""
+    compiled, big = _olmo_step(one_chip, 8, paged=True)
+    text = compiled.as_text()
+    assert "page_gather" not in text
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert {re.match(r"\s*(?:ROOT )?%?([\w.-]+) = ", line).group(1)
+            .rsplit(".", 1)[0] for line in calls} == {"full_attn"}
+    assert sorted(re.search(r'op_name="[^"]*?/(blk\d+)/full_attn/', line)
+                  .group(1) for line in calls) == ["blk3", "blk7"]
+    assert big == {"64,30,96,192", "6145,16,32,128"}
+    assert not big & set(_copies(compiled)), _copies(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("slots,heads,kv,hd,true_hd,vd,width", [
+    (64, 32, 32, 128, 128, 128, 96),
+    (64, 64, 4, 256, 192, 128, 288),
+], ids=["olmo_full", "mimo_full"])
+def test_the_hybrid_paged_read_lowers_on_a_described_v5e(
+        slots, heads, kv, hd, true_hd, vd, width, one_chip,
+        no_compile_cache):
+    """ops/paged_attention.py at the hybrid cells' full layers, bfloat16
+    pools: Olmo's 30 heads stored as 32 on as many KV heads, mimo's 64
+    on 4 KV heads with keys of 192 at 256 lanes and values of 128 (the
+    pages handed over as they lie).
+    The kernel lowers and no pool is copied to reach it."""
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu.ops import paged_attention as pa
+    pages = slots * width + 1
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    def read(q, k_pool, v_pool, tables, pos, k_new, v_new):
+        return pa.paged_decode_attention(
+            q, k_pool, v_pool, tables, jnp.zeros_like(pos), pos,
+            k_new[:, None], v_new[:, None], jnp.ones((slots, 1), bool),
+            scale=1 / true_hd ** 0.5, interpret=False)
+
+    compiled = jax.jit(read).lower(
+        spec((slots, heads, hd), "bfloat16"),
+        spec((pages, 16, kv, hd), "bfloat16"),
+        spec((pages, 16, kv, vd), "bfloat16"),
+        spec((slots, width), "int32"), spec((slots,), "int32"),
+        spec((slots, kv, hd), "bfloat16"),
+        spec((slots, kv, vd), "bfloat16")).compile()
+    text = compiled.as_text()
+    assert "veles_paged_attn" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20

@@ -452,6 +452,11 @@ class HybridBlock(ForwardBase):
         why)."""
         return _scopes(part, self.name)
 
+    @property
+    def attn_scope(self) -> str:
+        """The named scope of a softmax layer's attention read."""
+        return "window_attn" if self.window else "full_attn"
+
     def _serve_scope(self, part):
         """The same in a served program: block first, as the served
         ``TransformerBlock`` names its parts."""
@@ -496,7 +501,7 @@ class HybridBlock(ForwardBase):
                 n_kv_heads=self.n_kv_heads, head_dim=hd, v_head_dim=vd,
                 rope_base=self.rope_base, rotary_dims=rotary,
                 value_scale=self.value_scale, precision=prec, scope=scope,
-                attn_scope="window_attn" if self.window else "full_attn",
+                attn_scope=self.attn_scope,
                 positions=positions,
                 qk_norm_eps=self.eps if self.qk_norm else None)
         else:
@@ -589,7 +594,8 @@ class HybridBlock(ForwardBase):
                                 heads),
                 _pad_heads(_pad_last(v, stored_width(v.shape[-1])), heads))
 
-    def serve_step(self, params, x, cache_k, cache_v, pos, live):
+    def serve_step(self, params, x, cache_k, cache_v, pos, live,
+                   attend=None):
         """One position a row. x (S, D); ``cache_k`` (S, Tc, KV, Dk) and
         ``cache_v`` (S, Tc, KV, Dv) each row's view of its cache: its
         pages in order, or its ring; ``pos`` (S,) the position each row
@@ -599,7 +605,19 @@ class HybridBlock(ForwardBase):
         slot's state (S, H, K, V) and tail (S, taps - 1, C) as
         ``cache_k`` and ``cache_v`` and returns them advanced a token
         where ``live`` and as they were elsewhere, with no new rows
-        (None, None); it counts its live rows (``STATE_ROWS``)."""
+        (None, None); it counts its live rows (``STATE_ROWS``).
+
+        ``attend``: the caller's read of a full layer's cache in place of
+        the view, the serving engine's read through the page table
+        (``ContinuousEngine._paged_attend``) for a layer with no sink;
+        ``cache_k`` and ``cache_v`` are then the rows the caller carries
+        beside its pool and returns with this row's written, and
+        ``attend(q, k, v, cache_k, cache_v, pos, scale=, name=)`` gets q
+        and the new row at the cache's widths and heads (q's heads padded
+        to as many groups as the cache keeps KV heads) and the name of the
+        scope it runs under, for its kernel's device operation (which a
+        profile files under that name and not under the scopes around
+        it), and returns (o (S, 1, heads, Dv), cache_k, cache_v)."""
         import jax.numpy as jnp
         from .attention import masked_attention
         if self.mixer == "delta_rule":
@@ -610,6 +628,30 @@ class HybridBlock(ForwardBase):
             steptaps.emit(steptaps.counter_key(STATE_ROWS),
                           jnp.sum(live.astype(jnp.float32)))
             return out[:, 0], state, tail, None, None
+        box = {}
+
+        def padded(q, k, v):
+            # rows as the cache stores them: padded with noughts, which
+            # add nothing to a score or to a value's sum; the nought heads
+            # are no head's
+            k_w, v_w = cache_k.shape[-1], cache_v.shape[-1]
+            heads = cache_k.shape[-2]
+            box["kn"] = _pad_heads(_pad_last(k[:, 0], k_w), heads)
+            box["vn"] = _pad_heads(_pad_last(v[:, 0], v_w), heads)
+            return _pad_last(q, k_w), heads
+
+        def read(q, k, v):
+            # through the caller, under the block's own scope, whose name
+            # the kernel takes: the padded query heads attend to the
+            # nought heads and are dropped with the padded lanes
+            q, heads = padded(q, k, v)
+            group = q.shape[-2] // k.shape[-2]
+            o, box["k"], box["v"] = attend(
+                _pad_heads(q, heads * group), box["kn"][:, None],
+                box["vn"][:, None], cache_k, cache_v, pos,
+                scale=1.0 / numpy.sqrt(k.shape[-1]), name=self.attn_scope)
+            return o[:, :, :q.shape[-2], :v.shape[-1]].astype(v.dtype)
+
         s_rows, t_c = cache_k.shape[:2]
         ring = bool(self.window)
         at = pos % t_c if ring else jnp.clip(pos, 0, t_c - 1)
@@ -621,16 +663,9 @@ class HybridBlock(ForwardBase):
             seen = (held >= 0) & (held > pos[:, None] - self.window)
         else:
             seen = idx <= pos[:, None]
-        box = {}
 
-        def attend(q, k, v):
-            # rows as the cache stores them: padded with noughts, which
-            # add nothing to a score or to a value's sum; the nought heads
-            # are no head's
-            k_w, v_w = cache_k.shape[-1], cache_v.shape[-1]
-            heads = cache_k.shape[-2]
-            box["kn"] = _pad_heads(_pad_last(k[:, 0], k_w), heads)
-            box["vn"] = _pad_heads(_pad_last(v[:, 0], v_w), heads)
+        def view(q, k, v):
+            q, heads = padded(q, k, v)
             box["k"] = cache_k.at[rows, at].set(box["kn"])
             box["v"] = cache_v.at[rows, at].set(box["vn"])
             keys, values = box["k"], box["v"]
@@ -638,13 +673,14 @@ class HybridBlock(ForwardBase):
                 keys = keys[:, :, :k.shape[-2]]
                 values = values[:, :, :k.shape[-2]]
             o = masked_attention(
-                _pad_last(q, k_w), keys, values, seen[:, None, :],
+                q, keys, values, seen[:, None, :],
                 params["sink"] if self.has_sink else None,
-                scale=1.0 / numpy.sqrt(q.shape[-1]))
+                scale=1.0 / numpy.sqrt(k.shape[-1]))
             return o[..., :v.shape[-1]]
 
         out, _, _ = self._block(
-            params, x[:, None, :], self._serve_scope, attend=attend,
+            params, x[:, None, :], self._serve_scope,
+            attend=view if attend is None else read,
             positions=pos[:, None], live=live,
             block_rows=self.STEP_BLOCK_ROWS)
         return out[:, 0], box["k"], box["v"], box["kn"], box["vn"]

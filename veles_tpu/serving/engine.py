@@ -2802,19 +2802,28 @@ class ContinuousEngine(Logger):
         """THE predicate of the float step's attention read for ``blk``:
         through the page table, in one Pallas call that reads the live
         pages alone (``ops/paged_attention.py``), where the chip is a
-        TPU, the pool is a float pool, the block has no ``serve_step`` of
-        its own and the kernel compiles for its rows; elsewhere through
-        a gathered view. Under ``--serve-tp`` each shard's kernel reads
-        its own KV heads of the pool."""
+        TPU, the pool is a float pool, the kernel fits the block
+        (:meth:`_kernel_fits`) and compiles for its rows; elsewhere
+        through a gathered view. Under ``--serve-tp`` each shard's
+        kernel reads its own KV heads of the pool."""
         import jax
         from ..ops import paged_attention
-        if self.quant_kv or hasattr(blk, "serve_step"):
+        if self.quant_kv or not self._kernel_fits(blk):
             return False
-        kv, hd, _, _ = self._geometry(blk)
+        kv, hd, vd, _ = self._geometry(blk)
         dtype = self._caches[self.stack["blocks"].index(blk)][0].dtype
         return (jax.default_backend() == "tpu"
                 and paged_attention.supported(hd, self.page_size,
-                                              kv // self.tp, dtype))
+                                              kv // self.tp, dtype, vd))
+
+    def _kernel_fits(self, blk) -> bool:
+        """Whether the paged read computes ``blk``'s attention: its K and
+        V lie in pages (a standing block, or a hybrid block's full layer:
+        not a window layer's ring, not a delta-rule layer's state, which
+        are a slot's row already) and it keeps no learned sink, which the
+        kernel's softmax does not merge."""
+        return (self._slot_row(blk) is None
+                and not getattr(blk, "has_sink", False))
 
     def _paged_blocks(self) -> Tuple[bool, ...]:
         """:meth:`_paged_read` of each block, decided once the pool is
@@ -2826,14 +2835,18 @@ class ContinuousEngine(Logger):
 
     def _paged_attend(self, blk, kp, vp, tables, chunk_pos, j):
         """``_block_step``'s attention read for ``blk`` at the chunk's
-        iteration ``j``, every row at once: the pool's positions before
-        the chunk's first (``chunk_pos``), through ``tables``, and the
-        rows that the chunk has made so far, the row's own among them,
-        which its carry holds (S, decode_block, kv, hd) until the chunk
-        is written back. A masked-out row's result is discarded."""
+        iteration ``j``, every row at once (a hybrid block's
+        ``serve_step`` takes the same): the pool's positions before the
+        chunk's first (``chunk_pos``), through ``tables``, and the rows
+        that the chunk has made so far, the row's own among them, which
+        its carry holds (S, decode_block, kv, width) until the chunk is
+        written back. ``scale`` is the block's, where its q and keys come
+        padded beyond their width; ``name`` the kernel's device operation's
+        (``ops/paged_attention.py``), the block's scope where the read is
+        to be timed under it. A masked-out row's result is discarded."""
         import jax.numpy as jnp
         from ..ops import matmul_precision
-        from ..ops.paged_attention import paged_decode_attention
+        from ..ops.paged_attention import KERNEL, paged_decode_attention
         from jax import lax
         window = getattr(blk, "window", None)
         made = jnp.arange(self.decode_block)
@@ -2843,7 +2856,7 @@ class ContinuousEngine(Logger):
         operand = (None if matmul_precision() == lax.Precision.DEFAULT
                    else jnp.float32)
 
-        def attend(q, k, v, rows_k, rows_v, pos):
+        def attend(q, k, v, rows_k, rows_v, pos, scale=None, name=KERNEL):
             rows_k = rows_k.at[:, j].set(k[:, 0].astype(rows_k.dtype))
             rows_v = rows_v.at[:, j].set(v[:, 0].astype(rows_v.dtype))
             ok = jnp.broadcast_to(made <= j, rows_k.shape[:2])
@@ -2852,8 +2865,8 @@ class ContinuousEngine(Logger):
                 ok = ok & (chunk_pos[:, None] + made > pos[:, None] - window)
                 start = jnp.maximum(pos - window + 1, 0)
             o = paged_decode_attention(q[:, 0], kp, vp, tables, start, end,
-                                       rows_k, rows_v, ok,
-                                       operand_dtype=operand)
+                                       rows_k, rows_v, ok, scale=scale,
+                                       operand_dtype=operand, name=name)
             return o[:, None], rows_k, rows_v
 
         return attend
@@ -3055,11 +3068,11 @@ class ContinuousEngine(Logger):
         through the same ``_row_targets`` the int8 pool and the
         speculative round write through (a masked row, a row inside a
         shared page and a row past the view's end target the sink
-        page). Where :meth:`_paged_read` says so (a TPU, a standing
-        block), a block gets no view: its attention reads the pool
-        through the tables in one Pallas call, beside the rows the
-        chunk has made so far, which the scan carries until that same
-        write-back. Per-row sampling draws from each slot's private
+        page). Where :meth:`_paged_read` says so (a TPU, a block whose
+        K and V lie in pages), a block gets no view: its attention reads
+        the pool through the tables in one Pallas call, beside the rows
+        the chunk has made so far, which the scan carries until that
+        same write-back. Per-row sampling draws from each slot's private
         key stream, advanced ONLY for masked-in rows, so a row's
         noise is a pure function of its request's seed whatever other
         modes share the pool. Under ``quant_kv`` each scan iteration
@@ -3151,9 +3164,10 @@ class ContinuousEngine(Logger):
                         views.append((kp, vp))
                         continue
                     if on:
-                        rows0 = jnp.zeros((tok.shape[0], self.decode_block)
-                                          + kp.shape[2:], kp.dtype)
-                        views.append((rows0, rows0))
+                        views.append(tuple(
+                            jnp.zeros((tok.shape[0], self.decode_block)
+                                      + pool.shape[2:], pool.dtype)
+                            for pool in (kp, vp)))
                         continue
                     views.append((
                         jax.vmap(lambda t, kp=kp: self._view(kp, t))(
@@ -3173,9 +3187,13 @@ class ContinuousEngine(Logger):
                             p = params[blk.name]
                             if hasattr(blk, "serve_step"):
                                 # the block's own one-position step, every
-                                # row at once (its experts route the batch)
+                                # row at once (its experts route the batch);
+                                # a paged one reads through the tables
                                 x, ck, cv, kn, vn = blk.serve_step(
-                                    p, x, ck, cv, pos, mask > 0)
+                                    p, x, ck, cv, pos, mask > 0,
+                                    attend=self._paged_attend(
+                                        blk, kp, vp, tables, chunk_pos, j)
+                                    if on else None)
                                 new_vws.append((ck, cv))
                                 rows.append((kn, vn))
                                 continue
