@@ -12,7 +12,9 @@ Sections:
              attn_d128: the 4k training cell's call (head size 128,
              GQA, float32 operands), each kernel timed on the device;
              attn_d256: the 8k training cell's (head size 256, 16 on 2,
-             bfloat16 operands)
+             bfloat16 operands); attn_mla (by name only): the
+             latent-attention cell's (16 heads, keys of 192 over values
+             of 128, bfloat16)
   generation — KV-cached, speculative, beam and batched decode tokens/s
   paged_read (by name only) — ops/paged_attention.py alone at each
              serving cell's widths: ms a call and GB/s of the pages it copies
@@ -249,8 +251,9 @@ def sec_pallas_compile(dev, n):
         r = numpy.random.RandomState(4)
         dtype = entry.get("dtype", "bfloat16")
         kv_db = 1 if entry.get("kv", 0) < entry.get("h", 0) else 2
-        q2, k2, v2 = (jnp.asarray(r.randn(1, t_db, heads, d_db), dtype)
-                      for heads in (2, kv_db, kv_db))
+        q2, k2, v2 = (jnp.asarray(r.randn(1, t_db, heads, width), dtype)
+                      for heads, width in ((2, d_db), (kv_db, d_db),
+                                           (kv_db, entry.get("dv", d_db))))
         qf2, kf2, vf2 = (jnp.repeat(x, 2 // x.shape[2], axis=2).astype(
             jnp.float32) for x in (q2, k2, v2))
 
@@ -281,7 +284,7 @@ def sec_pallas_compile(dev, n):
     from veles_tpu.ops import autotune
     kind = autotune.current_device_kind()
     for key, entry in sorted(autotune._device_db(kind).items()):
-        m = re.fullmatch(r"flash_t(\d+)_d(\d+)_causal", key)
+        m = re.fullmatch(r"flash_t(\d+)_d(\d+)(?:_v\d+)?_causal", key)
         if m and "block_q" in entry:
             name = "db_%s_%dx%d" % (key, entry["block_q"],
                                     entry["block_k"])
@@ -348,6 +351,18 @@ def sec_attn_d256(dev, n):
                     extras=False)
 
 
+def sec_attn_mla(dev, n):
+    """The call of the latent-attention training cell (chipbench
+    moonlight_train8k: one sequence, 16 heads with keys of 192 and values
+    of 128, bfloat16 operands as nn/hybrid.py hands them over), forward
+    plus the custom-VJP backward at the cell's length. Train mode alone,
+    as ``sec_attn_d128``."""
+    from veles_tpu.ops.autotune import CANDIDATES_WIDE
+    return sec_attn(dev, n, pairs=((8192, 1),), h=16, kv=16, d=192,
+                    dv=128, dtype="bfloat16", candidates=CANDIDATES_WIDE,
+                    modes=(True,), extras=False, fused=False)
+
+
 ATTN_SWEEP_H, ATTN_SWEEP_D = 8, 64   # the sweep's shape unless told
 
 #: the three Pallas calls of one flash forward + backward, by the name
@@ -389,10 +404,12 @@ def _kernel_ms(fn, args, iters=4):
 
 def _attn_measure(dev, n, pairs=None, h=ATTN_SWEEP_H, kv=None,
                   d=ATTN_SWEEP_D, dtype="bfloat16", candidates=None,
-                  modes=(False, True), extras=True):
+                  modes=(False, True), extras=True, dv=None, fused=True):
     """Fused XLA against the flash kernels at each candidate tile pair,
     at (T, B) ``pairs`` of ``h`` query heads on ``kv`` KV heads (None:
-    MHA) of size ``d``, operands of ``dtype``. A variant's ``ms`` is
+    MHA) of size ``d`` (values of ``dv``, where given), operands of
+    ``dtype``; ``fused``: whether the fused reference runs beside them.
+    A variant's ``ms`` is
     the host's clock over the whole call; ``fwd_ms``, ``bwd_dkv_ms``,
     ``bwd_dq_ms`` are each kernel's device time in a capture. Every row
     carries the shape it was measured at, for ``_attn_seed``.
@@ -409,7 +426,7 @@ def _attn_measure(dev, n, pairs=None, h=ATTN_SWEEP_H, kv=None,
     kv = kv or h
     results = []
 
-    def fused(q, k, v, causal=True):
+    def fused_core(q, k, v, causal=True):
         return attention_reference(q, expand_kv(None, k, h),
                                    expand_kv(None, v, h), causal=causal)
 
@@ -417,9 +434,9 @@ def _attn_measure(dev, n, pairs=None, h=ATTN_SWEEP_H, kv=None,
     for t, b in (pairs or ((2048, 16), (8192, 1))):
         import numpy
         rng = numpy.random.RandomState(0)
-        q, k, v = (jnp.asarray(rng.randn(b, t, heads, d), dtype)
-                   for heads in (h, kv, kv))
-        flops_fwd = 4.0 * b * h * t * t * d / 2     # causal half
+        q, k, v = (jnp.asarray(rng.randn(b, t, heads, width), dtype)
+                   for heads, width in ((h, d), (kv, d), (kv, dv or d)))
+        flops_fwd = 2.0 * b * h * t * t * (d + (dv or d)) / 2  # causal
         for train in modes:
             flops = flops_fwd * (3.5 if train else 1.0)
 
@@ -435,8 +452,13 @@ def _attn_measure(dev, n, pairs=None, h=ATTN_SWEEP_H, kv=None,
 
             row = {"t": t, "b": b, "train": train, "h": h, "kv": kv,
                    "d": d, "dtype": str(dtype), "variants": {}}
+            if dv:
+                row["dv"] = dv
             try:
-                dt = ba.time_fn(wrap(fused), q, k, v)
+                if not fused:
+                    raise RuntimeError("not run: no crossover is recorded "
+                                       "for keys and values apart")
+                dt = ba.time_fn(wrap(fused_core), q, k, v)
                 row["variants"]["fused_xla"] = {
                     "ms": round(dt * 1e3, 2),
                     "tflops": round(flops / dt / 1e12, 2)}
@@ -563,9 +585,10 @@ def _attn_seed(results):
     from veles_tpu.ops import autotune
     if not results:
         return
-    shape = {k: results[0][k] for k in ("h", "kv", "dtype")
+    shape = {k: results[0][k] for k in ("h", "kv", "dtype", "dv")
              if k in results[0]}
     d_swept = results[0].get("d", ATTN_SWEEP_D)
+    dv_swept = results[0].get("dv")
     crossover = {}          # t -> flash beat fused (train-preferred)
     for t in sorted({r["t"] for r in results}):
         cands = {}             # train_mode -> {(bq, bk): variant}
@@ -616,7 +639,7 @@ def _attn_seed(results):
         elif any("error" in f for f in fused_rows):
             crossover[t] = True     # the fused reference did not run
         autotune.record(
-            autotune.flash_key(t, d_swept, True),
+            autotune.flash_key(t, d_swept, True, dv=dv_swept),
             dict(entry, block_q=bq, block_k=bk, ms=ms))
         print("  autotune seeded t=%d d=%d -> %dx%d (%.2f ms)%s"
               % (t, d_swept, bq, bk, ms,
@@ -641,7 +664,7 @@ def _attn_seed(results):
     floor = max(losses) if losses else -1
     wins = sorted(t for t, won in merged.items()
                   if won and t > floor)
-    if crossover:
+    if crossover and dv_swept is None:
         min_t = wins[0] if wins else autotune.NEVER
         autotune.record(
             autotune.min_t_key(d_swept),
@@ -878,6 +901,8 @@ def main():
     by_name["attn"] = sec_attn
     # asked for by name alone: it writes no tuning row
     by_name["paged_read"] = sec_paged_read
+    # asked for by name alone: its row is the latent-attention cell's
+    by_name["attn_mla"] = sec_attn_mla
     unknown = [name for name in want if name not in by_name]
     if unknown:
         print("unknown section(s) %s" % unknown, file=sys.stderr)

@@ -421,7 +421,7 @@ def test_inherited_pair_is_probed_at_the_calls_dtype(tuned_env,
     probes = []
     monkeypatch.setattr(
         autotune, "_bwd_compiles",
-        lambda t, d, causal, blocks, dtype=None:
+        lambda t, d, causal, blocks, dtype=None, dv=None:
         probes.append((t, blocks, str(dtype))) or str(dtype) != "float32")
     # lowers at bfloat16, not at float32: one verdict per dtype, memoized
     assert autotune.flash_blocks(4096, 128, dtype="bfloat16") == (1024, 512)

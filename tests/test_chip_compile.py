@@ -1,7 +1,8 @@
 """The Pallas kernels compiled by the TPU's own compiler for a v5e that
 is described, not attached: every tile pair devices/kernel_tuning.json
 commits for "TPU v5 lite" lowers, forward and custom-VJP backward, at its
-own length, head size, grouping and operand dtype, with the VMEM limit
+own length, head size (and value width, where a row has one of its own),
+grouping and operand dtype, with the VMEM limit
 the kernels compute for themselves; and the paged decode read at the
 dense serving cell's widths. What interpret mode cannot see (VMEM,
 tiling) and a chip run costs minutes to see.
@@ -55,12 +56,14 @@ def test_committed_tiles_lower_on_a_described_v5e(key, row, one_chip,
     import jax
     import jax.numpy as jnp
     from veles_tpu.ops import flash_attention as fa
-    t, d = map(int, re.fullmatch(r"flash_t(\d+)_d(\d+)_causal",
-                                 key).groups())
+    t, d, dv = re.fullmatch(r"flash_t(\d+)_d(\d+)(?:_v(\d+))?_causal",
+                            key).groups()
+    t, d = int(t), int(d)
     h, kv = row.get("h", 8), row.get("kv", row.get("h", 8))
     dtype = jnp.dtype(row.get("dtype", "bfloat16"))
-    q, k = (jax.ShapeDtypeStruct((1, t, heads, d), dtype, sharding=one_chip)
-            for heads in (h, kv))
+    q, k, v = (jax.ShapeDtypeStruct((1, t, heads, width), dtype,
+                                    sharding=one_chip)
+               for heads, width in ((h, d), (kv, d), (kv, int(dv or d))))
     fwd = (row["block_q"], row["block_k"])
     bwd = (row.get("bwd_block_q", fwd[0]), row.get("bwd_block_k", fwd[1]))
 
@@ -73,11 +76,11 @@ def test_committed_tiles_lower_on_a_described_v5e(key, row, one_chip,
     # gradient's forward rides along at the backward's tiles: it lowers
     # wherever the larger backward working set does)
     text = jax.jit(lambda q, k, v: loss(q, k, v, fwd)).lower(
-        q, k, k).compile().as_text()
+        q, k, v).compile().as_text()
     assert "veles_flash_fwd" in text and "tpu_custom_call" in text
     text = jax.jit(jax.grad(lambda q, k, v: loss(q, k, v, bwd),
                             argnums=(0, 1, 2))).lower(
-        q, k, k).compile().as_text()
+        q, k, v).compile().as_text()
     assert "veles_flash_bwd_dkv" in text and "veles_flash_bwd_dq" in text
 
 

@@ -4,12 +4,14 @@ The router scores every token over ALL the experts of the layer (softmax
 in float32), keeps the ``top_k`` largest and divides their weights by
 their sum; or (``route_sigmoid``) scores them by a sigmoid, chooses by the
 score plus a learned correction an expert and weighs by the score alone.
-The shared expert may be left out. The layer is told which experts it
+The combine weights may be scaled (``routed_scale`` s: DeepSeek-V3's
+``routed_scaling_factor``); the shared expert may be left out, and its
+gate too (then it is added whole). The layer is told which experts it
 holds (``held``: their ids; all of them by default) and computes
 
     y = sum over the chosen experts held here of
-            w_e down_e(silu(gate_e x) * up_e x)
-        + sigmoid(x . s_mix) * shared(x)
+            s w_e down_e(silu(gate_e x) * up_e x)
+        + sigmoid(x . s_mix) * shared(x)      (or shared(x), ungated)
 
 What the chosen experts that are NOT held would add is left out: this is
 one chip's part of the layer's result, and the parts of all the chips
@@ -18,8 +20,9 @@ one chip's part of the layer's result, and the parts of all the chips
 is no capacity factor.
 
 The held assignments are sorted by expert and cut into blocks of
-``BLOCK_ROWS`` rows of one expert each (an expert's last block is padded
-with rows of weight nought); one loop runs over the blocks that hold
+``block`` rows of one expert each (an expert's last block is padded
+with rows of weight nought; in training ``block_rows_for`` sizes a block
+to an expert's expected rows); one loop runs over the blocks that hold
 anything, so the work follows the number of held assignments and no
 (tokens, experts, capacity) tensor exists. The loop gathers a block's
 token rows, multiplies them through that expert's three matrices and adds
@@ -31,8 +34,9 @@ expert's hidden activations recomputed.
 from __future__ import annotations
 
 import functools
+import math
 
-#: rows of one expert that one step of the loop multiplies
+#: rows of one expert that one step of the loop multiplies, at the least
 BLOCK_ROWS = 256
 #: bucket bounds (tokens) of the histogram of the fullest held expert's
 #: load a layer a step (telemetry/counters.py HISTOGRAMS)
@@ -97,6 +101,16 @@ def plan_blocks(idx, local_of, n_held, block):
             "first": first.astype(jnp.int32),
             "starts": starts.astype(jnp.int32)[expert],
             "n_blocks": ends[-1].astype(jnp.int32)}
+
+
+def block_rows_for(tokens, top_k, n_experts):
+    """Rows of a training block: the power of two nearest the rows that an
+    expert of the router's ``n_experts`` expects a step (``tokens x top_k
+    / n_experts``), at least ``BLOCK_ROWS``. A block reads its expert's
+    three matrices and writes their gradients' slices whatever its rows,
+    so an expert's expected rows take about one block."""
+    expected = max(tokens * top_k / n_experts, 1.0)
+    return max(BLOCK_ROWS, 1 << int(round(math.log2(expected))))
 
 
 def _block_rows(plan, b, block, top_k, weights):
@@ -205,18 +219,19 @@ def _grouped_bwd(block, top_k, res, dout):
             dd.astype(e_down.dtype), None)
 
 
-def shared_expert(p, x, precision):
+def shared_expert(p, x, precision, gated=True):
     import jax
     import jax.numpy as jnp
-    # one number a token decides how much of the shared expert it gets:
-    # summed and squashed in float32
-    mix = jax.nn.sigmoid(jnp.dot(
-        x, p["s_mix"], precision=precision,
-        preferred_element_type=jnp.float32))[..., None]
+    if gated:
+        # one number a token decides how much of the shared expert it
+        # gets: summed and squashed in float32
+        mix = jax.nn.sigmoid(jnp.dot(
+            x, p["s_mix"], precision=precision,
+            preferred_element_type=jnp.float32))[..., None]
     h = (_silu(jnp.dot(x, p["s_gate"], precision=precision))
          * jnp.dot(x, p["s_up"], precision=precision))
-    return mix * jnp.dot(h, p["s_down"], precision=precision).astype(
-        jnp.float32)
+    y = jnp.dot(h, p["s_down"], precision=precision).astype(jnp.float32)
+    return mix * y if gated else y
 
 
 TOUCHED = "veles_moe_experts_touched_total"
@@ -258,11 +273,12 @@ def load_taps(counts, n_assigned, touched=False):
 
 def sparse_experts(p, x, *, top_k, local_of, n_held, precision, scope,
                    block=BLOCK_ROWS, router="softmax", shared=True,
-                   live=None):
+                   live=None, routed_scale=1.0, shared_gate=True):
     """(B, T, D) -> (B, T, D) on the leaves ``router`` (D, E), ``e_gate``,
     ``e_up`` (held, D, F), ``e_down`` (held, F, D), with ``shared``
-    ``s_gate``, ``s_up``, ``s_down`` and ``s_mix``, with
-    ``router="sigmoid"`` ``router_bias`` (E,). ``scope(part)`` opens a
+    ``s_gate``, ``s_up``, ``s_down`` and, with ``shared_gate``, ``s_mix``,
+    with ``router="sigmoid"`` ``router_bias`` (E,). The combine weights
+    are multiplied by ``routed_scale``. ``scope(part)`` opens a
     part's scope. ``live`` (B x T,) bool, a served program's: the rows
     that are somebody's; the others are assigned to no expert, and the
     layer counts the live rows' assignments alone."""
@@ -275,6 +291,8 @@ def sparse_experts(p, x, *, top_k, local_of, n_held, precision, scope,
                                    top_k)
         else:
             idx, w = route(x2, p["router"], top_k)
+        if routed_scale != 1.0:
+            w = w * routed_scale
     with scope("dispatch"):
         local_of = jnp.asarray(local_of, jnp.int32)
         n_assigned = idx.size
@@ -293,6 +311,6 @@ def sparse_experts(p, x, *, top_k, local_of, n_held, precision, scope,
     s = None
     if shared:
         with scope("shared_expert"):
-            s = shared_expert(p, x2, precision)
+            s = shared_expert(p, x2, precision, gated=shared_gate)
     with scope("combine"):
         return (y if s is None else y + s).astype(x.dtype).reshape(shape)

@@ -10,14 +10,17 @@ norms stand.
 ``mixer`` is ``"attention"`` (gated softmax attention: a sigmoid output
 gate beside the query, RMS norms on q and k, rotary on a leading part of
 the head, any head size the flash kernel takes), ``"delta_rule"`` (the
-gated delta rule of ``nn/delta_rule.py``) or ``"softmax"`` (plain softmax
+gated delta rule of ``nn/delta_rule.py``), ``"softmax"`` (plain softmax
 attention told, layer by layer, its key and its value width, its KV
 heads, its rotary part and base, its window, a learned sink a head and a
-scale on the values). The feed-forward is the sparse experts of
-``nn/experts.py`` (``ffn="experts"``: told which experts it holds, its
-router softmax or sigmoid, with or without a shared expert) or a dense
-SwiGLU (``ffn="dense"``). Before the sublayers the norms are
-zero-centred, ``x / rms(x) * (1 + w)`` with ``w`` nought at the start;
+scale on the values) or ``"latent"`` (latent attention, DeepSeek-V3's
+MLA, trained in its decompressed form; ``latent_mixer``). The
+feed-forward is the sparse experts of ``nn/experts.py``
+(``ffn="experts"``: told which experts it holds, its router softmax or
+sigmoid, a scale on the routed experts' weights, with or without a
+shared expert, gated or not) or a dense SwiGLU (``ffn="dense"``).
+Before the sublayers the norms are zero-centred,
+``x / rms(x) * (1 + w)`` with ``w`` nought at the start;
 after them plain, ``x / rms(x) * w`` with ``w`` one at the start. The
 softmax mixer may also normalise its whole q and its whole k projection
 before they are split into heads (``qk_norm``) and may leave the rotary
@@ -42,7 +45,10 @@ whatever the context. The gated attention mixer keeps no per-slot state
 yet and is not served.
 
 Named scopes in ``apply`` go mechanism first, block second
-(``delta_rule/blk2``, ``experts/blk0``): in a train step jax puts
+(``delta_rule/blk2``, ``experts/blk0``; the latent mixer's are
+``latent_q``, ``latent_kv``, ``rope``, ``attn`` around the flash
+kernels, which run under their own ``veles_flash*`` names, and
+``attn_out``): in a train step jax puts
 ``forward`` or the backward pass's mark in front, and a reader that cuts
 the stack at two levels (``chipbench``'s ``scope_ms``) then sees
 ``forward/delta_rule`` and ``backward/delta_rule``, every block's
@@ -236,6 +242,51 @@ def softmax_mixer(p, x, attend, *, n_heads, n_kv_heads, head_dim,
         return jnp.dot(o, p["wo"], precision=precision), k, v
 
 
+def latent_mixer(p, x, *, n_heads, nope_dim, rope_dim, v_head_dim,
+                 kv_rank, rope_base, eps, precision, mesh, scope):
+    """(B, T, D) -> (B, T, D): latent attention (MLA) with no query
+    compression, decompressed per head as it is trained. On ``wq``
+    (D, H x (Dn + Dr)), ``wkv_a`` (D, R + Dr), ``kv_norm`` (R),
+    ``wkv_b`` (R, H x (Dn + Dv)) and ``wo`` (H x Dv, D):
+
+        q = x wq, each head's [q_nope (Dn) | q_pe (Dr)]
+        [c_kv (R) | k_pe (Dr)] = x wkv_a;  c_kv = norm(c_kv)
+        each head's [k_nope (Dn) | v (Dv)] = c_kv wkv_b
+        k = [k_nope | rope(k_pe)], one k_pe head broadcast to every head
+        o = softmax(q k^T / sqrt(Dn + Dr), causal) v;  out = o wo
+
+    with rotary on ``q_pe`` and ``k_pe`` alone. The keys are
+    ``Dn + Dr`` wide and the values ``Dv``: the flash kernel takes the
+    two widths apart."""
+    import jax.numpy as jnp
+    b, t, _ = x.shape
+    h, dn, dr, dv = n_heads, nope_dim, rope_dim, v_head_dim
+    with scope("latent_q"):
+        q = jnp.dot(x, p["wq"], precision=precision).reshape(b, t, h,
+                                                              dn + dr)
+    with scope("latent_kv"):
+        c = jnp.dot(x, p["wkv_a"], precision=precision)
+        c_kv = zero_centred_norm(c[..., :kv_rank], p["kv_norm"], eps)
+        kv = jnp.dot(c_kv, p["wkv_b"], precision=precision).reshape(
+            b, t, h, dn + dv)
+    with scope("rope"):
+        # the tables are float32; q and k go on in the type they came in
+        q_pe = _rope(jnp, q[..., dn:].astype(jnp.float32), rope_base)
+        k_pe = _rope(jnp, c[..., None, kv_rank:].astype(jnp.float32),
+                     rope_base)
+        q = jnp.concatenate([q[..., :dn], q_pe.astype(x.dtype)], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn],
+             jnp.broadcast_to(k_pe.astype(x.dtype), (b, t, h, dr))],
+            axis=-1)
+    with scope("attn"):
+        o = attention_core(q, k, kv[..., dn:], causal=True, mesh=mesh,
+                           n_heads=h)
+    with scope("attn_out"):
+        return jnp.dot(o.reshape(b, t, h * dv), p["wo"],
+                       precision=precision)
+
+
 def dense_swiglu(p, x, precision):
     """``down(silu(gate x) * up x)`` on ``d_gate``, ``d_up`` (D, F) and
     ``d_down`` (F, D)."""
@@ -255,20 +306,21 @@ class HybridBlock(ForwardBase):
     PARAMETERIZED = True
     hide_from_registry = False
     ATTENTION = ("wq", "wk", "wv", "wo", "q_norm", "k_norm", "sink")
+    LATENT = ("wkv_a", "kv_norm", "wkv_b")
     DELTA_RULE = ("in_qkvz", "in_ba", "conv", "A_log", "dt_bias",
                   "gnorm_w", "out_proj")
     EXPERTS = ("router", "router_bias", "e_gate", "e_up", "e_down",
                "s_gate", "s_up", "s_down", "s_mix")
     DENSE = ("d_gate", "d_up", "d_down")
-    PARAM_NAMES = (("ln1_w", "ln2_w") + ATTENTION + DELTA_RULE + EXPERTS
-                   + DENSE)
+    PARAM_NAMES = (("ln1_w", "ln2_w") + ATTENTION + LATENT + DELTA_RULE
+                   + EXPERTS + DENSE)
     #: leaves that ``--mixed-precision`` leaves in float32: the router
-    #: (its softmax decides which experts a token reaches), the decay's
-    #: two (``g`` is float32 as published), and the held experts'
-    #: matrices, which the grouped loop casts a block at a time and whose
-    #: gradients it sums in float32
-    AMP_FLOAT32 = ("router", "A_log", "dt_bias", "e_gate", "e_up",
-                   "e_down")
+    #: and the selection's correction (they decide which experts a token
+    #: reaches), the decay's two (``g`` is float32 as published), and the
+    #: held experts' matrices, which the grouped loop casts a block at a
+    #: time and whose gradients it sums in float32
+    AMP_FLOAT32 = ("router", "router_bias", "A_log", "dt_bias", "e_gate",
+                   "e_up", "e_down")
     #: rows of one expert that one step of the served loops multiplies: a
     #: decode step's rows choose a held expert a few times each, a
     #: prefill's some hundred times
@@ -282,11 +334,13 @@ class HybridBlock(ForwardBase):
                  shared_hidden=0, eps=1e-6, v_head_dim=0, window=0,
                  sink=False, value_scale=1.0, ffn="experts",
                  dense_hidden=0, router="softmax", shared_expert=True,
-                 norm="pre", qk_norm=False, beta_scale=1.0, **kwargs):
+                 norm="pre", qk_norm=False, beta_scale=1.0, kv_rank=0,
+                 qk_nope_dim=0, qk_rope_dim=0, routed_scale=1.0,
+                 shared_gate=True, **kwargs):
         super().__init__(workflow, **kwargs)
-        if mixer not in ("attention", "delta_rule", "softmax"):
-            raise ValueError("mixer must be 'attention', 'delta_rule' or "
-                             "'softmax'")
+        if mixer not in ("attention", "delta_rule", "softmax", "latent"):
+            raise ValueError("mixer must be 'attention', 'delta_rule', "
+                             "'softmax' or 'latent'")
         if ffn not in ("experts", "dense"):
             raise ValueError("ffn must be 'experts' or 'dense'")
         if router not in ("softmax", "sigmoid"):
@@ -342,6 +396,21 @@ class HybridBlock(ForwardBase):
         self.expert_hidden = int(expert_hidden)
         self.shared_hidden = int(shared_hidden)
         self.shared_expert = bool(shared_expert)
+        #: what the routed experts' combine weights are multiplied by, and
+        #: whether the shared expert is weighed by ``sigmoid(x . s_mix)``
+        #: (else it is added whole)
+        self.routed_scale = float(routed_scale)
+        self.shared_gate = bool(shared_gate)
+        #: the latent mixer's widths: the latent's rank, a head's key part
+        #: without rotary and with it (its values are ``v_head_dim``)
+        self.kv_rank = int(kv_rank)
+        self.qk_nope_dim, self.qk_rope_dim = int(qk_nope_dim), int(
+            qk_rope_dim)
+        if mixer == "latent" and not (self.kv_rank and self.qk_nope_dim
+                                      and self.qk_rope_dim
+                                      and self.v_head_dim):
+            raise ValueError("mixer='latent' needs kv_rank, qk_nope_dim, "
+                             "qk_rope_dim and v_head_dim")
         self.dense_hidden = int(dense_hidden)
         self.eps = float(eps)
         self.mesh = None
@@ -378,6 +447,14 @@ class HybridBlock(ForwardBase):
                           wv=((d, kv_d), "normal"),
                           wo=((q_d, d), "normal"),
                           q_norm=((hd,), "zeros"), k_norm=((hd,), "zeros"))
+        elif self.mixer == "latent":
+            h, r, dr = self.n_heads, self.kv_rank, self.qk_rope_dim
+            shapes.update(
+                wq=((d, h * (self.qk_nope_dim + dr)), "normal"),
+                wkv_a=((d, r + dr), "normal"), kv_norm=((r,), "zeros"),
+                wkv_b=((r, h * (self.qk_nope_dim + self.v_head_dim)),
+                       "normal"),
+                wo=((h * self.v_head_dim, d), "normal"))
         elif self.mixer == "softmax":
             hd, vd, _ = self._widths(d)
             shapes.update(wq=((d, self.n_heads * hd), "normal"),
@@ -417,7 +494,9 @@ class HybridBlock(ForwardBase):
         if self.shared_expert:
             shapes.update(
                 s_gate=((d, sf), "normal"), s_up=((d, sf), "normal"),
-                s_down=((sf, d), "normal"), s_mix=((d,), "normal"))
+                s_down=((sf, d), "normal"))
+            if self.shared_gate:
+                shapes.update(s_mix=((d,), "normal"))
         return shapes
 
     def create_params(self, rng: prng.RandomGenerator) -> Dict[str, Array]:
@@ -474,7 +553,7 @@ class HybridBlock(ForwardBase):
         from ..ops import matmul_precision
         from .attention import blocked_causal_attention
         from .delta_rule import delta_rule_mixer
-        from .experts import BLOCK_ROWS, sparse_experts
+        from .experts import block_rows_for, sparse_experts
         prec = matmul_precision()
         post = self.norm_place == "post"
         k = v = None
@@ -489,6 +568,13 @@ class HybridBlock(ForwardBase):
                 rope_base=self.rope_base,
                 rotary_dims=int(round(hd * self.rotary_factor)),
                 eps=self.eps, precision=prec, mesh=self.mesh, scope=scope)
+        elif self.mixer == "latent":
+            mixed = latent_mixer(
+                params, a_in, n_heads=self.n_heads,
+                nope_dim=self.qk_nope_dim, rope_dim=self.qk_rope_dim,
+                v_head_dim=self.v_head_dim, kv_rank=self.kv_rank,
+                rope_base=self.rope_base, eps=self.eps, precision=prec,
+                mesh=self.mesh, scope=scope)
         elif self.mixer == "softmax":
             hd, vd, rotary = self._widths(x.shape[-1])
             sink = params["sink"] if self.has_sink else None
@@ -531,12 +617,15 @@ class HybridBlock(ForwardBase):
         local_of = numpy.full((self.n_experts,), -1, numpy.int32)
         local_of[list(self.experts_held)] = numpy.arange(
             len(self.experts_held), dtype=numpy.int32)
+        block = block_rows or block_rows_for(
+            x.shape[0] * x.shape[1], self.top_k, self.n_experts)
         y = sparse_experts(params, f_in, top_k=self.top_k,
                            local_of=local_of,
                            n_held=len(self.experts_held), precision=prec,
-                           scope=scope, block=block_rows or BLOCK_ROWS,
+                           scope=scope, block=block,
                            router=self.router_kind, shared=self.shared_expert,
-                           live=live)
+                           live=live, routed_scale=self.routed_scale,
+                           shared_gate=self.shared_gate)
         if post:
             with scope("norm2"):
                 y = rms_norm(y, params["ln2_w"], self.eps)

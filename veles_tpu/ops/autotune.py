@@ -106,11 +106,18 @@ def current_device_kind() -> str:
         return "unknown"
 
 
-def flash_key(t: int, d: int, causal: bool, window: int = 0) -> str:
+def flash_key(t: int, d: int, causal: bool, window: int = 0,
+              dv: Optional[int] = None) -> str:
+    """The row of a call at length ``t``, key width ``d`` and, where it
+    differs, value width ``dv``: ``flash_t8192_d192_v128_causal``."""
     mode = "causal" if causal else "full"
     if window:
         mode += "_win"
-    return "flash_t%d_d%d_%s" % (t, d, mode)
+    return "flash_t%d_%s_%s" % (t, _widths(d, dv), mode)
+
+
+def _widths(d: int, dv: Optional[int]) -> str:
+    return "d%d" % d if dv in (None, d) else "d%d_v%d" % (d, dv)
 
 
 def lookup(key: str, device_kind: Optional[str] = None) -> Optional[dict]:
@@ -193,7 +200,8 @@ def resolved_min_t(d: int, device_kind: Optional[str] = None) -> int:
 
 
 def _bwd_compiles(t: int, d: int, causal: bool,
-                  blocks: Tuple[int, int], dtype=None) -> bool:
+                  blocks: Tuple[int, int], dtype=None,
+                  dv: Optional[int] = None) -> bool:
     """Whether the custom-VJP backward pair LOWERS at these blocks with
     operands of ``dtype`` (the dtype of the call being decided for;
     bfloat16 when the caller has none): a tile pair that lowers at two
@@ -204,20 +212,22 @@ def _bwd_compiles(t: int, d: int, causal: bool,
     import jax.numpy as jnp
     from .flash_attention import flash_attention
     x = jax.ShapeDtypeStruct((1, t, 1, d), jnp.dtype(dtype or jnp.bfloat16))
+    v = jax.ShapeDtypeStruct((1, t, 1, dv or d), x.dtype)
     try:
         jax.jit(jax.grad(
             lambda q, k, v: flash_attention(
                 q, k, v, causal=causal, block_q=blocks[0],
                 block_k=blocks[1],
                 interpret=False).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))).lower(x, x, x).compile()
+            argnums=(0, 1, 2))).lower(x, x, v).compile()
         return True
     except Exception:            # noqa: BLE001 — lowering/VMEM failure
         return False
 
 
-def _nearest_blocks(t: int, d: int, causal: bool,
-                    kind: str) -> Optional[Tuple[int, int]]:
+def _nearest_blocks(t: int, d: int, causal: bool, kind: str,
+                    dv: Optional[int] = None
+                    ) -> Optional[Tuple[int, int]]:
     """Measured winner from the nearest tuned length of the same
     (d, mode) class whose blocks divide this ``t``. Rationale
     (measured on the v5e, devices/kernel_tuning.json): the per-device
@@ -240,7 +250,7 @@ def _nearest_blocks(t: int, d: int, causal: bool,
     operand dtypes. A head size no sweep has covered resolves to
     DEFAULT_BLOCKS, which ``_note_default_blocks`` counts and logs."""
     pref = "flash_t"
-    suf = "_d%d_%s" % (d, "causal" if causal else "full")
+    suf = "_%s_%s" % (_widths(d, dv), "causal" if causal else "full")
     from .flash_attention import supported
     best = None
     for key, entry in _device_db(kind).items():
@@ -254,7 +264,7 @@ def _nearest_blocks(t: int, d: int, causal: bool,
             bq, bk = int(entry["block_q"]), int(entry["block_k"])
         except (KeyError, TypeError, ValueError):
             continue
-        if not supported(t, d, bq, bk):
+        if not supported(t, max(d, dv or d), bq, bk):
             continue
         dist = abs(t_entry - t)
         if best is None or dist < best[0]:
@@ -264,7 +274,8 @@ def _nearest_blocks(t: int, d: int, causal: bool,
 
 def _check_inherited(t: int, d: int, causal: bool,
                      blocks: Tuple[int, int], kind: str,
-                     dtype=None) -> Tuple[int, int]:
+                     dtype=None, dv: Optional[int] = None
+                     ) -> Tuple[int, int]:
     """First use of a length-INHERITED winner at this ``t``: confirm
     the custom-VJP pair actually LOWERS at the call's operand dtype
     (the sweep only compiled it at the swept lengths and dtype) and
@@ -279,10 +290,11 @@ def _check_inherited(t: int, d: int, causal: bool,
     import jax
     if jax.default_backend() != "tpu":
         return blocks
-    memo_key = (kind, "inherit_ok", t, d, causal, blocks, str(dtype))
+    memo_key = (kind, "inherit_ok", t, d, dv, causal, blocks, str(dtype))
     ok = _memo.get(memo_key)
     if ok is None:
-        ok = _memo[memo_key] = _bwd_compiles(t, d, causal, blocks, dtype)
+        ok = _memo[memo_key] = _bwd_compiles(t, d, causal, blocks, dtype,
+                                             dv)
     return blocks if ok else DEFAULT_BLOCKS
 
 
@@ -315,7 +327,8 @@ def _note_default_blocks(kind: str, key: str) -> None:
 
 def flash_blocks_fwd_bwd(t: int, d: int, causal: bool = True,
                          window: int = 0,
-                         device_kind: Optional[str] = None, dtype=None
+                         device_kind: Optional[str] = None, dtype=None,
+                         dv: Optional[int] = None
                          ) -> Tuple[Tuple[int, int], Tuple[int, int]]:
     """THE policy lookup ``flash_attention`` resolves its default
     blocks through, (forward's, backward pair's): the committed entry
@@ -327,24 +340,25 @@ def flash_blocks_fwd_bwd(t: int, d: int, causal: bool = True,
     ``bwd_block_q``/``bwd_block_k``. Read-only — the same commit
     resolves the same blocks on every machine. Hits are memoized;
     misses are not, so a ``record()`` later in the process (a sweep
-    command) changes the answer."""
+    command) changes the answer. A value width ``dv`` apart from the
+    key width ``d`` is a shape class of its own."""
     kind = device_kind or current_device_kind()
-    key = flash_key(t, d, causal, window)
+    key = flash_key(t, d, causal, window, dv)
     memo_key = (kind, key)
     if memo_key in _memo:
         return _memo[memo_key]
     hit = lookup(key, kind)
     if hit is None and window:
-        hit = lookup(flash_key(t, d, causal), kind)
+        hit = lookup(flash_key(t, d, causal, dv=dv), kind)
     if hit is not None:
         fwd = (int(hit["block_q"]), int(hit["block_k"]))
         bwd = (int(hit.get("bwd_block_q", fwd[0])),
                int(hit.get("bwd_block_k", fwd[1])))
         _memo[memo_key] = (fwd, bwd)
         return fwd, bwd
-    inherited = _nearest_blocks(t, d, causal, kind)
+    inherited = _nearest_blocks(t, d, causal, kind, dv)
     blocks = (DEFAULT_BLOCKS if inherited is None else
-              _check_inherited(t, d, causal, inherited, kind, dtype))
+              _check_inherited(t, d, causal, inherited, kind, dtype, dv))
     if blocks == DEFAULT_BLOCKS:
         _note_default_blocks(kind, key)
     return blocks, blocks

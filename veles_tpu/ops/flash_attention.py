@@ -19,7 +19,12 @@ as the oracle and the fallback
 
 Layout contract: (B, T, H, D) like the rest of the attention stack; heads
 are folded into the grid's leading dimension. D is zero-padded to the
-128-lane width (zero features change neither scores nor outputs).
+128-lane width (zero features change neither scores nor outputs). The
+values may be narrower than the keys (latent attention's 192-wide keys
+over 128-wide values): q and k are padded to their width's lanes, v, o,
+dO and dV to theirs, and the scale is ``1/sqrt`` of the unpadded key
+width. A call whose widths agree lowers as it did before they could
+differ.
 """
 
 from __future__ import annotations
@@ -178,12 +183,13 @@ def _kv_fold_of(h: int, kv: int):
 def _fwd_pallas(q, k, v, causal: bool, scale: float, block_q: int,
                 block_k: int, interpret: bool, window: int = 0,
                 h: int = 1, kv: int = 1):
-    """q: (B*h, T, D); k/v: (B*kv, T, D) with D == LANE (kv == h is
-    MHA) → (o (B*h, T, D), lse (B*h, 8, T) sublane-padded — callers
-    use ``lse[:, 0, :]``)."""
+    """q, k: (B*h, T, D), (B*kv, T, D); v: (B*kv, T, Dv), both widths
+    lane multiples (kv == h is MHA) → (o (B*h, T, Dv), lse (B*h, 8, T)
+    sublane-padded — callers use ``lse[:, 0, :]``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     g, t, d = q.shape
+    dv = v.shape[-1]
     kv_of = _kv_fold_of(h, kv)
     grid = (g, t // block_q, t // block_k)
     kernel = functools.partial(_kernel, scale=scale, causal=causal,
@@ -198,22 +204,22 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float, block_q: int,
             pl.BlockSpec((1, block_k, d),
                          lambda b, i, j: (kv_of(b), j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d),
+            pl.BlockSpec((1, block_k, dv),
                          lambda b, i, j: (kv_of(b), j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((g, t, d), q.dtype),
+            jax.ShapeDtypeStruct((g, t, dv), q.dtype),
             jax.ShapeDtypeStruct((g, 8, t), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, LANE), jnp.float32),
             pltpu.VMEM((block_q, LANE), jnp.float32),
         ],
@@ -224,8 +230,8 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float, block_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(
-                block_q, block_k, d, _itemsize(q, k, v), q_tiles=2,
-                k_tiles=2, f32_elems=block_q * (d + 2 * LANE),
+                block_q, block_k, max(d, dv), _itemsize(q, k, v),
+                q_tiles=2, k_tiles=2, f32_elems=block_q * (dv + 2 * LANE),
                 scores=FWD_SCORES)),
         interpret=interpret,
         # the name is the device operation's in a profiler capture
@@ -241,17 +247,18 @@ def _bwd_blockwise(causal, scale, block_k, window, res, do):
     the recompute and the dk/dv contributions summed back per group."""
     q, k, v, o, lse = res
     g, t, d = q.shape
+    d_v = v.shape[-1]
     gk = k.shape[0]
     if gk != g:
         group = g // gk
         kx = jnp.broadcast_to(k[:, None], (gk, group, t, d)
                               ).reshape(g, t, d)
-        vx = jnp.broadcast_to(v[:, None], (gk, group, t, d)
-                              ).reshape(g, t, d)
+        vx = jnp.broadcast_to(v[:, None], (gk, group, t, d_v)
+                              ).reshape(g, t, d_v)
         dq, dk, dv = _bwd_blockwise(causal, scale, block_k, window,
                                     (q, kx, vx, o, lse), do)
         dk = dk.reshape(gk, group, t, d).sum(1).astype(k.dtype)
-        dv = dv.reshape(gk, group, t, d).sum(1).astype(v.dtype)
+        dv = dv.reshape(gk, group, t, d_v).sum(1).astype(v.dtype)
         return dq, dk, dv
     nk = t // block_k
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)
@@ -284,7 +291,7 @@ def _bwd_blockwise(causal, scale, block_k, window, res, do):
     dq0 = jnp.zeros(q.shape, jnp.float32)
     dq, (dks, dvs) = jax.lax.scan(body, dq0, jnp.arange(nk))
     dk = jnp.moveaxis(dks, 0, 1).reshape(g, t, d)
-    dv = jnp.moveaxis(dvs, 0, 1).reshape(g, t, d)
+    dv = jnp.moveaxis(dvs, 0, 1).reshape(g, t, d_v)
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
 
 
@@ -422,10 +429,12 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
     (query-head-in-group, q-block) folded into its sequential
     dimension — each kv head's gradient accumulates the contributions
     of all h/kv query heads with no expanded operands and no racy
-    parallel writes."""
+    parallel writes. v, dO and dV are ``Dv`` wide, q, k, dQ and dK
+    ``D``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     g, t, d = q.shape
+    d_v = v.shape[-1]
     gk = k.shape[0]
     group = h // kv
     nq, nk = t // block_q, t // block_k
@@ -440,37 +449,36 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
     lse8 = jnp.broadcast_to(lse[:, None, :], (g, 8, t))
     common = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k, window=window)
-    qspec = pl.BlockSpec((1, block_q, d),
-                         lambda b, i, j: (q_of(b, j), j % nq, 0),
-                         memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM)
+    def qspec(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b, i, j: (q_of(b, j), j % nq, 0),
+                            memory_space=pltpu.VMEM)
+
+    def kspec(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, i, j: (b, i, 0),
+                            memory_space=pltpu.VMEM)
     row_q = pl.BlockSpec((1, 8, block_q),
                          lambda b, i, j: (q_of(b, j), 0, j % nq),
                          memory_space=pltpu.VMEM)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, n_q_blocks=nq, **common),
         grid=(gk, nk, nq * group),
-        in_specs=[qspec, qspec, kspec, kspec, row_q, row_q],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[qspec(d), qspec(d_v), kspec(d), kspec(d_v), row_q, row_q],
+        out_specs=[kspec(d), kspec(d_v)],
         out_shape=[
             jax.ShapeDtypeStruct((gk, t, d), out_dtype or k.dtype),
-            jax.ShapeDtypeStruct((gk, t, d), out_dtype or v.dtype),
+            jax.ShapeDtypeStruct((gk, t, d_v), out_dtype or v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(
-                block_q, block_k, d, itemsize, q_tiles=2, k_tiles=4,
-                f32_elems=2 * block_k * d, scores=BWD_SCORES)),
+                block_q, block_k, max(d, d_v), itemsize, q_tiles=2,
+                k_tiles=4, f32_elems=block_k * (d + d_v),
+                scores=BWD_SCORES)),
         interpret=interpret,
         name="veles_flash_bwd_dkv",
     )(q, do, k, v, lse8, pad8)
@@ -480,12 +488,12 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, d),
                          lambda b, i, j: (kv_of(b), j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d),
+            pl.BlockSpec((1, block_k, d_v),
                          lambda b, i, j: (kv_of(b), j, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i),
@@ -502,8 +510,8 @@ def _bwd_pallas_core(q, k, v, lse, delta, do, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(
-                block_q, block_k, d, itemsize, q_tiles=3, k_tiles=2,
-                f32_elems=block_q * d, scores=BWD_SCORES)),
+                block_q, block_k, max(d, d_v), itemsize, q_tiles=3,
+                k_tiles=2, f32_elems=block_q * d, scores=BWD_SCORES)),
         interpret=interpret,
         name="veles_flash_bwd_dq",
     )(q, do, k, v, lse8, pad8)
@@ -519,14 +527,14 @@ def _use_pallas_bwd() -> bool:
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, causal, scale, blocks, bwd_blocks, interpret,
-           window, h, kv, d_logical):
+           window, h, kv, widths):
     o, _ = _fwd_pallas(q, k, v, causal, scale, *blocks,
                        interpret, window, h, kv)
     return o
 
 
 def _flash_fwd(q, k, v, causal, scale, blocks, bwd_blocks, interpret,
-               window, h, kv, d_logical):
+               window, h, kv, widths):
     o, lse = _fwd_pallas(q, k, v, causal, scale, *blocks,
                          interpret, window, h, kv)
     # residuals keep the GROUPED k/v — the GQA memory saving holds
@@ -535,18 +543,18 @@ def _flash_fwd(q, k, v, causal, scale, blocks, bwd_blocks, interpret,
 
 
 def _flash_bwd(causal, scale, blocks, bwd_blocks, interpret, window,
-               h, kv, d_logical, res, do):
+               h, kv, widths, res, do):
     block_q, block_k = bwd_blocks
     q = res[0]
     # trace-time analytic note for the backward pair (standard 2.5×
     # the forward: blockwise recompute + 4 gradient matmuls), billed
-    # at the LOGICAL head dim (``d_logical`` rides the nondiff args:
-    # the folded residual is lane-padded, and model FLOPs count the
-    # useful dim, matching the forward note)
+    # at the LOGICAL key and value widths (``widths`` rides the nondiff
+    # args: the folded residuals are lane-padded, and model FLOPs count
+    # the useful widths, matching the forward note)
     from ..telemetry.cost import note_kernel_cost
     note_kernel_cost(analytic_cost(
-        q.shape[0] // h, q.shape[1], h, d_logical, causal,
-        window).scaled(2.5))
+        q.shape[0] // h, q.shape[1], h, widths[0], causal,
+        window, dv=widths[1]).scaled(2.5))
     if _use_pallas_bwd():
         q, k, v, o, lse = res
         return _bwd_pallas(q, k, v, o, lse, do, causal, scale,
@@ -602,28 +610,31 @@ def choose_flash(t: int, d: int) -> bool:
 
 def analytic_cost(b: int, t: int, h: int, d: int, causal: bool = False,
                   window: int = 0, train: bool = False,
-                  dtype_bytes: int = 2):
+                  dtype_bytes: int = 2, dv: Optional[int] = None):
     """Telemetry fallback cost of one flash-attention call
     (veles_tpu/telemetry/cost.py): the Pallas custom call is opaque to
     XLA's HLO cost model, so the kernel's owner publishes the standard
-    analytic model instead. FLOPs: 2·T·T_ctx·D per head for QK^T plus
-    the same for PV (T_ctx = T/2 causal, min(T, W) windowed); training
+    analytic model instead. FLOPs: 2·T·T_ctx·D per head for QK^T at the
+    key width ``d`` plus 2·T·T_ctx·Dv for PV at the value width ``dv``
+    (``d`` where None; T_ctx = T/2 causal, min(T, W) windowed); training
     adds the blockwise backward at the standard 2.5× forward
     (recompute + 4 gradient matmuls). Bytes: the HBM traffic floor —
-    q/k/v read + o written (+lse), ×3 round trips under training."""
+    q/k read at ``d``, v read and o written at ``dv`` (+lse), ×3 round
+    trips under training."""
     from ..telemetry.cost import Cost
+    dv = d if dv is None else dv
     t_ctx = float(t)
     if window:
         t_ctx = min(t_ctx, float(window))
     elif causal:
         t_ctx = t / 2.0
-    fwd = 4.0 * b * h * t * t_ctx * d
+    fwd = 2.0 * b * h * t * t_ctx * (d + dv)
     flops = fwd * 3.5 if train else fwd
-    io = b * h * t * d * dtype_bytes
+    io = b * h * t * (2 * d + 2 * dv) * dtype_bytes
     lse = b * h * t * 4
-    bytes_accessed = (4 * io + lse) * (3 if train else 1)
+    bytes_accessed = (io + lse) * (3 if train else 1)
     # VMEM working set: ~5 f32 (block, D_padded) tiles per grid step
-    d_pad = ((d + LANE - 1) // LANE) * LANE
+    d_pad = ((max(d, dv) + LANE - 1) // LANE) * LANE
     peak = 5.0 * 128 * d_pad * 4
     return Cost(flops, bytes_accessed, peak, source="analytic")
 
@@ -636,16 +647,17 @@ def _prepare(q, k, v, scale, block_q, block_k, interpret, caller,
     build's port of the reference's measured-per-device block sizes,
     `veles/backends.py:623-731`), and the head-fold + lane-pad of the
     operands. Returns (q3, k3, v3, scale, interpret, b, t, h, kv, d,
-    blocks, bwd_blocks): the backward pair's blocks are the forward's
-    unless the DB's row carries its own."""
+    dv, blocks, bwd_blocks): ``d`` the key width, ``dv`` the value
+    width; the backward pair's blocks are the forward's unless the DB's
+    row carries its own."""
     b, t, h, d = q.shape
-    kv = k.shape[2]
+    kv, dv = k.shape[2], v.shape[-1]
     bwd_q, bwd_k = block_q, block_k
     if block_q is None or block_k is None:
         from .autotune import flash_blocks_fwd_bwd
         fwd, bwd = flash_blocks_fwd_bwd(
             t, d, causal=causal, window=window,
-            dtype=jnp.result_type(q, k, v))
+            dtype=jnp.result_type(q, k, v), dv=dv)
         if block_q is None:
             block_q, bwd_q = fwd[0], bwd[0]
         if block_k is None:
@@ -654,12 +666,16 @@ def _prepare(q, k, v, scale, block_q, block_k, interpret, caller,
         raise ValueError(
             "k/v head counts must match and divide q heads: q has %d, "
             "k %d, v %d" % (h, kv, v.shape[2]))
+    if k.shape[-1] != d:
+        raise ValueError("%s: q is %d wide, k %d" % (caller, d,
+                                                     k.shape[-1]))
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     for bq, bk in {(block_q, block_k), (bwd_q, bwd_k)}:
-        if not supported(t, d, bq, bk):
-            raise ValueError("%s: T=%d D=%d not supported with blocks "
-                             "(%d, %d)" % (caller, t, d, bq, bk))
+        if not supported(t, max(d, dv), bq, bk):
+            raise ValueError("%s: T=%d D=%d Dv=%d not supported with "
+                             "blocks (%d, %d)"
+                             % (caller, t, d, dv, bq, bk))
     if interpret is None:
         # compiled, or an error: a kernel reached off-TPU must not run
         # interpreted without a word. The one exception is the test
@@ -668,17 +684,19 @@ def _prepare(q, k, v, scale, block_q, block_k, interpret, caller,
         from ..config import root
         interpret = (root.common.engine.flash_attention == "force"
                      and jax.default_backend() != "tpu")
+    return (fold_heads(q), fold_heads(k), fold_heads(v), float(scale),
+            interpret, b, t, h, kv, d, dv, (block_q, block_k),
+            (bwd_q, bwd_k))
+
+
+def fold_heads(x):
+    """(B, T, H, D) -> (B*H, T, D padded with noughts to whole lanes)."""
+    b, t, heads, d = x.shape
     d_pad = ((d + LANE - 1) // LANE) * LANE
-
-    def fold(x):
-        heads = x.shape[2]
-        xt = jnp.moveaxis(x, 2, 1).reshape(b * heads, t, d)
-        if d < d_pad:
-            xt = jnp.pad(xt, ((0, 0), (0, 0), (0, d_pad - d)))
-        return xt
-
-    return (fold(q), fold(k), fold(v), float(scale), interpret,
-            b, t, h, kv, d, (block_q, block_k), (bwd_q, bwd_k))
+    xt = jnp.moveaxis(x, 2, 1).reshape(b * heads, t, d)
+    if d < d_pad:
+        xt = jnp.pad(xt, ((0, 0), (0, 0), (0, d_pad - d)))
+    return xt
 
 
 def flash_attention_fwd_lse(q, k, v, causal: bool = False,
@@ -692,12 +710,12 @@ def flash_attention_fwd_lse(q, k, v, causal: bool = False,
     partials by lse and defines the blockwise ring backward itself
     (parallel/ring_attention.py). Same folding/padding/support rules
     as :func:`flash_attention`."""
-    q3, k3, v3, scale, interpret, b, t, h, kv, d, blocks, _ = \
+    q3, k3, v3, scale, interpret, b, t, h, kv, d, dv, blocks, _ = \
         _prepare(q, k, v, scale, block_q, block_k, interpret,
                  "flash_attention_fwd_lse", causal=causal)
     o, lse = _fwd_pallas(q3, k3, v3, causal, scale, *blocks,
                          interpret, 0, h, kv)
-    o = jnp.moveaxis(o[..., :d].reshape(b, h, t, d), 1, 2)
+    o = jnp.moveaxis(o[..., :dv].reshape(b, h, t, dv), 1, 2)
     lse = jnp.moveaxis(lse[:, 0, :].reshape(b, h, t), 1, 2)  # (B,T,H)
     return o, lse
 
@@ -715,29 +733,27 @@ def flash_attention_bwd_lse(q, k, v, lse, delta, do,
     attention's per-step backward engine (the global lse makes each
     block's probabilities exact regardless of which blocks this call
     sees). VMEM-resident kernels; no (T, T) materialization."""
-    q3, k3, v3, scale, interpret, b, t, h, kv, d, _, blocks = \
+    q3, k3, v3, scale, interpret, b, t, h, kv, d, dv, _, blocks = \
         _prepare(q, k, v, scale, block_q, block_k, interpret,
                  "flash_attention_bwd_lse", causal=causal)
 
     def fold_g(x):      # (B, T, H) → (B*H, T)
         return jnp.moveaxis(x, -1, 1).reshape(b * h, t)
 
-    d_pad = q3.shape[-1]
-    do3 = jnp.moveaxis(do, 2, 1).reshape(b * h, t, d)
-    if d < d_pad:
-        do3 = jnp.pad(do3, ((0, 0), (0, 0), (0, d_pad - d)))
+    do3 = fold_heads(do)
     # f32 outputs: these are PARTIAL contributions the ring sums across
     # steps — rounding each partial to bf16 before the f32 accumulation
     # would grow error O(ring size) over the einsum engine
-    dq, dk, dv = _bwd_pallas_core(
+    dq, dk, dv_ = _bwd_pallas_core(
         q3, k3, v3, fold_g(lse).astype(jnp.float32),
         fold_g(delta).astype(jnp.float32), do3, causal, scale,
         *blocks, interpret, 0, h, kv, out_dtype=jnp.float32)
 
-    def unfold(x, heads):
-        return jnp.moveaxis(x[..., :d].reshape(b, heads, t, d), 1, 2)
+    def unfold(x, heads, width):
+        return jnp.moveaxis(x[..., :width].reshape(b, heads, t, width),
+                            1, 2)
 
-    return unfold(dq, h), unfold(dk, kv), unfold(dv, kv)
+    return unfold(dq, h, d), unfold(dk, kv, d), unfold(dv_, kv, dv)
 
 
 def flash_attention(q, k, v, causal: bool = False,
@@ -746,7 +762,8 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None):
-    """(B, T, H, D) × 3 → (B, T, H, D), differentiable.
+    """q, k (B, T, H, D), v (B, T, H, Dv) → (B, T, H, Dv),
+    differentiable; the scale is ``1/sqrt(D)`` unless given.
 
     Falls back is the caller's job — check ``supported(T, D)`` first.
     ``interpret=None`` compiles the kernel (Mosaic; an error off-TPU)
@@ -764,9 +781,10 @@ def flash_attention(q, k, v, causal: bool = False,
         raise ValueError("sliding-window attention requires causal=True")
     if window >= q.shape[1]:
         window = 0          # a window covering everything is no window
-    q3, k3, v3, scale, interpret, b, t, h, kv, d, blocks, bwd_blocks = \
-        _prepare(q, k, v, scale, block_q, block_k, interpret,
-                 "flash_attention", causal=causal, window=window)
+    q3, k3, v3, scale, interpret, b, t, h, kv, d, dv, blocks, \
+        bwd_blocks = _prepare(q, k, v, scale, block_q, block_k, interpret,
+                              "flash_attention", causal=causal,
+                              window=window)
     # trace-time events (run once per trace, not per execution): the
     # counter records that a program containing this kernel was
     # (re)built — recompile churn shows up here first — and the
@@ -778,8 +796,8 @@ def flash_attention(q, k, v, causal: bool = False,
     inc("veles_flash_attention_traces_total")
     if interpret:
         inc("veles_flash_attention_interpret_traces_total")
-    note_kernel_cost(analytic_cost(b, t, h, d, causal, window))
+    note_kernel_cost(analytic_cost(b, t, h, d, causal, window, dv=dv))
     o = _flash(q3, k3, v3, causal, scale,
-               blocks, bwd_blocks, interpret, window, h, kv, d)
-    o = o[..., :d].reshape(b, h, t, d)
+               blocks, bwd_blocks, interpret, window, h, kv, (d, dv))
+    o = o[..., :dv].reshape(b, h, t, dv)
     return jnp.moveaxis(o, 1, 2)
